@@ -1,12 +1,12 @@
-# Developer entry points.  Everything runs on XLA:CPU unless a TPU is
-# attached; bench.py probes the device itself and falls back with honest
-# labels.
+# Developer entry points.  Targets that set JAX_PLATFORMS=cpu run on the
+# host on purpose; `make bench` and `make smoke` need a chip and fail
+# without one.  Every bench line carries the platform it ran on.
 
 PY ?= python
-OLD ?= BENCH_r05.json
+OLD ?= /tmp/bench_old.json
 NEW ?= /tmp/bench_new.json
 
-.PHONY: test lint bench bench-new bench-diff bench-merge bench-store bench-sort bench-exchange bench-query chaos chaos-query-storm chaos-device-ooo chaos-device chaos-merge chaos-store chaos-push chaos-exchange chaos-ha chaos-stream chaos-slo-burn soak docs doctor top metrics-smoke
+.PHONY: test lint smoke bench bench-new bench-diff bench-merge bench-store bench-sort bench-exchange bench-query chaos chaos-query-storm chaos-device-ooo chaos-device chaos-merge chaos-store chaos-push chaos-exchange chaos-ha chaos-stream chaos-slo-burn soak docs doctor top metrics-smoke
 
 test:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow'
@@ -15,6 +15,10 @@ test:
 # 1 = findings outside tez_tpu/tools/graftlint_baseline.json, 2 = error
 lint:
 	$(PY) -m tez_tpu.tools.graftlint
+
+# the quickest proof the main path still runs on the chip (chip_smoke.py)
+smoke:
+	$(PY) chip_smoke.py
 
 bench:
 	$(PY) bench.py
@@ -25,6 +29,7 @@ bench-new:
 	$(PY) -c "import json; print(json.dumps({'tail': open('/tmp/bench_stdout.txt').read()}))" > $(NEW)
 
 # gate: nonzero exit when NEW drops >20% below OLD on any shared metric
+# (OLD and NEW are two captures made with bench-new on the same platform)
 bench-diff:
 	$(PY) -m tez_tpu.tools.bench_diff $(OLD) $(NEW)
 

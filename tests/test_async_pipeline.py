@@ -177,7 +177,7 @@ def test_scheduler_matches_sync_kernel():
 
 def test_recompile_count_bounded_within_bucket():
     """Varying span sizes inside one padding bucket must reuse ONE compiled
-    program — the jit cache may grow by at most one entry."""
+    program — the kernel's compile cache may grow by at most one entry."""
     from tez_tpu.ops.device_pipeline import (DeviceSpanScheduler,
                                              _fused_pipeline)
     key_len = 8
@@ -189,10 +189,10 @@ def test_recompile_count_bounded_within_bucket():
         return sched.results()
 
     run(600, 0)                          # bucket warm (and maybe compile)
-    cache0 = _fused_pipeline._cache_size()
+    cache0 = _fused_pipeline.cache_size()
     for i, n in enumerate((520, 700, 1000, 1024)):   # same padding bucket
         run(n, i + 1)
-    assert _fused_pipeline._cache_size() - cache0 <= 1, \
+    assert _fused_pipeline.cache_size() - cache0 <= 1, \
         "same-bucket spans recompiled the fused pipeline"
 
 
@@ -570,3 +570,92 @@ def test_engine_auto_width_routing():
     assert _route_engine("device", 10, 100, key_nbytes=1 << 21,
                          min_key_bytes=1 << 20) == "host"
     assert _route_engine("host", 10_000, 0) == "host"
+
+
+# -- compile is set-up, and a compile error is not a sick chip (PR 21) -------
+
+def test_compile_is_outside_the_dispatch_watchdog_fake_clock():
+    """A kernel that compiles for 100 s against a 1 s dispatch deadline: the
+    watchdog's clock is stopped while ops.device.Kernel compiles and
+    restarts at launch, so nothing fires and nothing fails over.  (The same
+    100 s spent INSIDE the launch is the hung-dispatch test above.)"""
+    from tez_tpu.ops.device import Kernel
+    clock = SettableClock()
+
+    def slow_to_trace(x):
+        clock.advance(100.0)     # the compile, on the pipeline's clock
+        time.sleep(0.4)          # wall time for several monitor polls
+        return x + 1
+
+    kernel = Kernel(slow_to_trace, "slow_compile")
+    counters = TezCounters()
+    pipe = AsyncSpanPipeline(
+        dispatch_fn=lambda s: kernel(np.int32(s)),
+        readback_fn=lambda s, ids: int(s),
+        failover_fn=lambda ids, payloads: "host",
+        breaker=CircuitBreaker(failures=100), counters=counters,
+        clock=clock, watchdog_dispatch_ms=1000)
+    pipe.submit(0, 0)
+    pipe.submit(1, 1)
+    assert pipe.drain() == {0: 1, 1: 2}
+    assert kernel.cache_size() == 1
+    assert pipe.stats.watchdog_fires == 0 and pipe.stats.failovers == 0
+    assert not any(c.value for c in counters.group(COUNTER_GROUP))
+
+
+def test_compile_error_fails_the_attempt_and_is_never_failed_over():
+    """The rule of docs/device_pipeline.md: a kernel that cannot compile
+    poisons the pipeline with its name — no host re-sort, no breaker hit."""
+    from tez_tpu.ops.device import Kernel, KernelCompileError
+
+    def untraceable(x):
+        raise NotImplementedError("Unimplemented primitive in lowering")
+
+    kernel = Kernel(untraceable, "broken_kernel")
+    failed_over = []
+    counters = TezCounters()
+    br = CircuitBreaker(failures=1)
+    pipe = AsyncSpanPipeline(
+        dispatch_fn=lambda s: kernel(np.zeros((s + 4, 2), np.uint32)),
+        readback_fn=lambda s, ids: s,
+        failover_fn=lambda ids, payloads: failed_over.append(ids) or "host",
+        breaker=br, counters=counters)
+    pipe.submit(0, 0)
+    with pytest.raises(KernelCompileError,
+                       match=r"broken_kernel\[4x2\].*Unimplemented"):
+        pipe.drain()
+    assert failed_over == []
+    assert br.state == "closed" and br.trips == 0
+    fo = counters.group(COUNTER_GROUP)
+    assert fo.find_counter("device.compile.errors").value == 1
+    assert fo.find_counter("device.failover.spans").value == 0
+
+
+def test_sorter_compile_error_surfaces_but_injected_oom_fails_over(
+        monkeypatch):
+    """Same DeviceSorter, two failures: an injected RESOURCE_EXHAUSTED at
+    the split floor still re-sorts on the host bit-exactly; a span-sort
+    kernel that cannot compile fails flush_run() instead."""
+    from tez_tpu.ops import device
+    from tez_tpu.ops.device import Kernel, KernelCompileError
+    base, _ = _flush_merged(0, "")
+    got, counters = _flush_merged(
+        2, "device.dispatch.oom:fail:n=1,exc=runtime,match=span=0",
+        breaker=CircuitBreaker(failures=100))
+    assert got == base
+    assert counters.group(COUNTER_GROUP).find_counter(
+        "device.failover.spans").value == 1
+
+    def refused(lanes, lengths, num_partitions, skip_length_pass=False):
+        raise ValueError("Shape mismatch in input, indices and output")
+    broken = Kernel(refused, "resident_hash_sort",
+                    static_argnames=("num_partitions", "skip_length_pass"))
+    monkeypatch.setattr(device, "_resident_sort_donated", lambda: broken)
+    # the poison surfaces at whichever comes first: the next submit (which
+    # wraps it) or the drain (which re-raises it)
+    with pytest.raises(RuntimeError) as err:
+        _flush_merged(2, "", breaker=CircuitBreaker(failures=100))
+    cause = err.value if isinstance(err.value, KernelCompileError) \
+        else err.value.__cause__
+    assert isinstance(cause, KernelCompileError)
+    assert "resident_hash_sort" in str(cause)

@@ -126,11 +126,14 @@ def test_merge_resident_kernels_agree(seed):
     np.testing.assert_array_equal(np.sort(perm_mp), np.arange(len(all_keys)))
 
 
-def test_merge_rank_pallas_interpret_parity():
-    from tez_tpu.ops.pallas_kernels import MERGE_ROW_BLOCK, merge_rank_pallas
+def test_rank_search_matches_bisect_reference():
+    """_rank_search — the one rank body since the Pallas twin was removed
+    (Mosaic refuses its gather, CHANGES.md PR 21) — against Python bisect
+    over the same composite (lanes..., length) keys, both flavors."""
+    import bisect
     rng = np.random.default_rng(7)
-    n, m, w = 173, 2 * MERGE_ROW_BLOCK, 3   # m a block multiple: grid path
-    run_lanes = np.sort(rng.integers(0, 4, (n, w)).astype(np.uint32), axis=0)
+    n, m, w = 173, 512, 3
+    run_lanes = rng.integers(0, 4, (n, w)).astype(np.uint32)
     run_lens = rng.integers(1, 9, n).astype(np.uint32)
     q_lanes = rng.integers(0, 4, (m, w)).astype(np.uint32)
     q_lens = rng.integers(1, 9, m).astype(np.uint32)
@@ -140,12 +143,12 @@ def test_merge_rank_pallas_interpret_parity():
     order = np.lexsort((run_lens,) + tuple(
         run_lanes[:, i] for i in range(w - 1, -1, -1)))
     run_lanes, run_lens = run_lanes[order], run_lens[order]
-    for count_equal in (False, True):
-        golden = device._rank_search(
+    run_keys = [tuple(run_lanes[i]) + (run_lens[i],) for i in range(n)]
+    q_keys = [tuple(q_lanes[j]) + (q_lens[j],) for j in range(m)]
+    for count_equal, side in ((False, bisect.bisect_left),
+                              (True, bisect.bisect_right)):
+        got = device._rank_search(
             jnp.asarray(run_lanes), jnp.asarray(run_lens),
             jnp.asarray(q_lanes), jnp.asarray(q_lens), count_equal)
-        got = merge_rank_pallas(
-            jnp.asarray(run_lanes), jnp.asarray(run_lens),
-            jnp.asarray(q_lanes), jnp.asarray(q_lens),
-            count_equal=count_equal, interpret=True)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(golden))
+        want = np.array([side(run_keys, q) for q in q_keys], np.int32)
+        np.testing.assert_array_equal(np.asarray(got), want)

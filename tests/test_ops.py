@@ -263,18 +263,19 @@ def test_async_sortmaster_matches_sync():
         list(outs[1].batch.iter_pairs())
 
 
-def test_pallas_fnv_matches_reference_kernel():
-    """Pallas FNV hash (interpret mode on CPU) == the XLA kernel == the host
-    partitioner."""
-    from tez_tpu.ops.pallas_kernels import hash_partition_pallas
+def test_fnv_partition_kernel_matches_bytewise_reference():
+    """The XLA FNV kernel (the one hash path since the Pallas twin was
+    removed — Mosaic refuses it, CHANGES.md PR 21) == a per-key Python
+    FNV-1a, on a shape that is neither bucket- nor block-aligned."""
+    from tez_tpu.parallel.exchange import fnv_bytes_host
     pairs = random_pairs(700, seed=31, max_key=24)
     b = KVBatch.from_pairs(pairs)
     klens = b.key_offsets[1:] - b.key_offsets[:-1]
     w = 1 << max(2, (int(klens.max()) - 1).bit_length())
     mat, lengths = pad_to_matrix(b.key_bytes, b.key_offsets, w)
-    golden = device.hash_partition(mat, lengths, 5)
-    got = hash_partition_pallas(mat, lengths, 5, interpret=True)
-    np.testing.assert_array_equal(got, golden)
+    got = device.hash_partition(mat, lengths, 5)
+    want = np.array([fnv_bytes_host(k) % 5 for k, _ in pairs], np.int32)
+    np.testing.assert_array_equal(got, want)
 
 
 def test_custom_comparator_sorter_and_merge():

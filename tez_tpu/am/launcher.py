@@ -9,6 +9,7 @@ runner process on a TPU host.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import logging
 import threading
@@ -131,12 +132,57 @@ class RunnerPool:
                 t.join(timeout=max(0.1, deadline - clock.wall_s()))
 
 
+#: env var naming the chip a runner process was given; the runner claims it
+#: at start-up and exits non-zero if it cannot (runtime/remote_runner.py)
+RUNNER_CHIP_ENV = "TEZ_TPU_RUNNER_CHIP"
+#: exit status of a runner that could not claim its chip
+CHIP_CLAIM_FAILED_RC = 3
+
+
+@functools.lru_cache(maxsize=1)
+def local_tpu_chips() -> int:
+    """TPU chips attached to this host, counted on the PCI bus the way
+    libtpu's own start-up does — WITHOUT initialising a JAX backend: the AM
+    process must never claim a chip its runners need."""
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """The environment libtpu reads to give ONE process exactly one local
+    chip as its own 1x1x1 slice (a chip belongs to one process at a time;
+    without this the first runner claims every chip and the rest cannot
+    start their backend)."""
+    port = 8476 + chip
+    return {
+        RUNNER_CHIP_ENV: str(chip),
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": str(port),
+        "CLOUD_TPU_TASK_ID": "0",
+    }
+
+
+def wants_device(env: Dict[str, str]) -> bool:
+    """A runner launched with JAX_PLATFORMS=cpu (tests, host-only
+    deployments) runs the host engine by request and needs no chip."""
+    first = env.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    return first != "cpu"
+
+
 class SubprocessRunnerPool:
     """Launches runner PROCESSES (the TezChild-as-JVM analog) instead of
     threads.  Reference: ContainerLauncherManager + TezContainerLauncherImpl
     launching containers on NodeManagers; here runners are subprocesses of
     the AM host (a multi-host deployment execs the same module on each
-    worker pointed at the AM's umbilical address)."""
+    worker pointed at the AM's umbilical address).
+
+    One process for each chip: on a host with TPU chips every runner that
+    may use the device engine is handed exactly one chip through its
+    environment (:func:`chip_env`), and the pool never holds more such
+    runners than there are chips."""
 
     def __init__(self, ctx: Any, max_runners: int,
                  idle_timeout: float = 5.0):
@@ -144,6 +190,7 @@ class SubprocessRunnerPool:
         self.max_runners = max_runners
         self.idle_timeout = idle_timeout
         self._procs: Dict[int, Any] = {}
+        self._chips: Dict[int, int] = {}     # runner seq -> chip it owns
         self._seq = itertools.count()
         self._lock = threading.Lock()
         self._stopped = False
@@ -157,22 +204,30 @@ class SubprocessRunnerPool:
         # across respawns on the same machine (a multi-host deployment
         # passes each host's own stable --node-id)
         node = f"{socket.gethostname()}-{self.ctx.app_id}"
+        base_env = dict(os.environ)
+        # conf-supplied runner environment (reference: container launch
+        # context env); empty value = unset the variable
+        for k, v in (self.ctx.conf.get("tez.am.runner.env") or {}).items():
+            if v == "":
+                base_env.pop(k, None)
+            else:
+                base_env[k] = str(v)
+        chips = local_tpu_chips() if wants_device(base_env) else 0
         with self._lock:
             if self._stopped:
                 return
             self._reap()
             want = min(self.max_runners, len(self._procs) + max(0, backlog))
+            if chips:
+                want = min(want, chips)
             while len(self._procs) < want:
                 n = next(self._seq)
-                env = dict(os.environ)
-                # conf-supplied runner environment (reference: container
-                # launch context env); empty value = unset the variable
-                for k, v in (self.ctx.conf.get("tez.am.runner.env")
-                             or {}).items():
-                    if v == "":
-                        env.pop(k, None)
-                    else:
-                        env[k] = str(v)
+                env = dict(base_env)
+                if chips:
+                    chip = min(set(range(chips)) -
+                               set(self._chips.values()))
+                    env.update(chip_env(chip))
+                    self._chips[n] = chip
                 env["TEZ_TPU_JOB_TOKEN"] = self.ctx.secrets.secret.hex()
                 from tez_tpu.common.tls import export_env
                 env.update(export_env(self.ctx.conf))
@@ -186,6 +241,7 @@ class SubprocessRunnerPool:
                     faults.fire("am.container.launch", detail=cid)
                 except Exception as e:  # noqa: BLE001 — injected failure
                     log.warning("container %s launch failed: %s", cid, e)
+                    self._chips.pop(n, None)
                     break   # retried on the watchdog's next ensure_runners
                 from tez_tpu.common import config as C
                 reuse = self.ctx.conf.get(C.AM_CONTAINER_REUSE_ENABLED)
@@ -199,14 +255,25 @@ class SubprocessRunnerPool:
                     cmd += ["--max-tasks", "1"]
                 proc = subprocess.Popen(cmd, env=env)
                 self._procs[n] = (proc, cid)
+                data = {"pid": proc.pid}
+                if n in self._chips:
+                    data["tpu_chip"] = self._chips[n]
                 self.ctx.history(HistoryEvent(
                     HistoryEventType.CONTAINER_LAUNCHED,
-                    container_id=cid, data={"pid": proc.pid}))
+                    container_id=cid, data=data))
 
     def _reap(self) -> None:
         for n, (proc, cid) in list(self._procs.items()):
             if proc.poll() is not None:
                 del self._procs[n]
+                chip = self._chips.pop(n, None)
+                if chip is not None and \
+                        proc.returncode == CHIP_CLAIM_FAILED_RC:
+                    # remote_runner's claim_chip failed: say so here too —
+                    # the pool respawns while backlog remains, and a chip
+                    # held by another process fails every respawn
+                    log.error("runner %s could not claim TPU chip %d and "
+                              "exited", cid, chip)
                 self.ctx.history(HistoryEvent(
                     HistoryEventType.CONTAINER_STOPPED,
                     container_id=cid,

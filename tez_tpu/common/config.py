@@ -543,10 +543,6 @@ SORT_THREADS = _key("tez.runtime.sort.threads", 0, Scope.VERTEX,
                     "reference: PipelinedSorter sortmaster executor")
 PARTITIONER_CLASS = _key("tez.runtime.partitioner.class",
                          "tez_tpu.library.partitioners:HashPartitioner", Scope.VERTEX)
-PALLAS_HASH_ENABLED = _key("tez.runtime.tpu.pallas.hash", False, Scope.VERTEX,
-                           "Use the Pallas FNV kernel for hash partitioning "
-                           "on TPU backends (off until profiled per chip); "
-                           "non-TPU backends silently use the XLA path")
 PIPELINED_SHUFFLE_ENABLED = _key("tez.runtime.pipelined-shuffle.enabled", False, Scope.VERTEX,
                                  "Emit per-spill DMEs; disables final merge "
                                  "(reference: PipelinedSorter.java:113)")

@@ -104,8 +104,17 @@ class TaskCounter(enum.Enum):
     VIRTUAL_MEMORY_BYTES = enum.auto()
     COMMITTED_HEAP_BYTES = enum.auto()
     # TPU-specific additions (device data plane profiling)
+    # sort/merge wall whichever engine ran it (the host engines bump these
+    # too) — the *_RECORDS pairs below say which engine did the work
     DEVICE_SORT_MILLIS = enum.auto()
     DEVICE_MERGE_MILLIS = enum.auto()
+    # rows per engine, counted where the span / merge is routed
+    # (ops/sorter.py _span_engine, merge_sorted_runs): a host-routed run
+    # cannot move the DEVICE_* pair
+    DEVICE_SORT_RECORDS = enum.auto()
+    HOST_SORT_RECORDS = enum.auto()
+    DEVICE_MERGE_RECORDS = enum.auto()
+    HOST_MERGE_RECORDS = enum.auto()
     DEVICE_EXCHANGE_MILLIS = enum.auto()
     HBM_BYTES_ALLOCATED = enum.auto()
     HOST_SPILL_BYTES = enum.auto()

@@ -61,50 +61,33 @@ class VectorTokenProcessor(SimpleProcessor):
         if sorter is not None and sorter.combiner is sum_long_combiner:
             from tez_tpu.ops.native import WordCountAggregator
             agg = WordCountAggregator.create()
-            if agg is not None:
-                try:
-                    for chunk in reader.iter_chunks():
-                        agg.feed(bytes(chunk))
-                    key_bytes, key_offsets, counts = agg.emit()
-                finally:
-                    agg.close()
-                enc = (counts.view(np.uint64)
-                       ^ np.uint64(1 << 63)).astype(">u8")
-                val_bytes = np.frombuffer(enc.tobytes(),
-                                          dtype=np.uint8).copy()
-                val_offsets = np.arange(len(counts) + 1,
-                                        dtype=np.int64) * 8
-                # keys are already unique (one row per distinct word):
-                # pre_combined lets a single-span sort skip its redundant
-                # pre-sort hash combine pass
-                writer.write_batch(KVBatch(key_bytes, key_offsets,
-                                           val_bytes, val_offsets,
-                                           pre_combined=True))
-                return
+            try:
+                for chunk in reader.iter_chunks():
+                    agg.feed(bytes(chunk))
+                key_bytes, key_offsets, counts = agg.emit()
+            finally:
+                agg.close()
+            enc = (counts.view(np.uint64)
+                   ^ np.uint64(1 << 63)).astype(">u8")
+            val_bytes = np.frombuffer(enc.tobytes(),
+                                      dtype=np.uint8).copy()
+            val_offsets = np.arange(len(counts) + 1,
+                                    dtype=np.int64) * 8
+            # keys are already unique (one row per distinct word):
+            # pre_combined lets a single-span sort skip its redundant
+            # pre-sort hash combine pass
+            writer.write_batch(KVBatch(key_bytes, key_offsets,
+                                       val_bytes, val_offsets,
+                                       pre_combined=True))
+            return
 
         from tez_tpu.ops.native import split_ws_native
         for chunk in reader.iter_chunks():
-            native = split_ws_native(bytes(chunk))
-            if native is not None:
-                # one C pass (GIL released): compacted word bytes + offsets
-                key_bytes, key_offsets = native
-                n = len(key_offsets) - 1
-                if n == 0:
-                    continue
-            else:
-                data = np.frombuffer(chunk, dtype=np.uint8)
-                # full bytes.split() whitespace set: space \t \n \v \f \r
-                ws = (data == 32) | ((data >= 9) & (data <= 13))
-                sel = ~ws
-                if not sel.any():
-                    continue
-                key_bytes = data[sel].copy()
-                starts_mask = sel & np.concatenate(([True], ws[:-1]))
-                run_id = np.cumsum(starts_mask)[sel]    # 1-based word id
-                lengths = np.bincount(run_id - 1)
-                n = len(lengths)
-                key_offsets = np.zeros(n + 1, np.int64)
-                np.cumsum(lengths, out=key_offsets[1:])
+            # one C pass (GIL released): compacted word bytes + offsets
+            key_bytes, key_offsets = split_ws_native(bytes(chunk))
+            n = len(key_offsets) - 1
+            if n == 0:
+                continue
             val_bytes = np.frombuffer(one * n, dtype=np.uint8).copy()
             val_offsets = np.arange(n + 1, dtype=np.int64) * len(one)
             writer.write_batch(KVBatch(key_bytes, key_offsets,

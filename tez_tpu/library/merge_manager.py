@@ -428,11 +428,13 @@ class ShuffleMergeManager:
         items = sorted(items)
         runs = [_as_run(b) for _, _, b in items if b.num_records > 0]
         return merge_sorted_runs(runs, 1, self.key_width,
+                                 counters=self.counters,
                                  engine=self.engine if engine is None
                                  else engine,
                                  device_min_records=self.device_min_records,
                                  merge_factor=self.merge_factor,
-                                 key_normalizer=self.key_normalizer) \
+                                 key_normalizer=self.key_normalizer,
+                                 final=False) \
             if runs else _as_run(KVBatch.empty())
 
     def _pipe_dispatch(self, payload):
@@ -480,10 +482,11 @@ class ShuffleMergeManager:
         halves = [self._merge_mem_items(part, engine="device")
                   for part in (live[:mid], live[mid:])]
         merged = merge_sorted_runs(halves, 1, self.key_width,
-                                   engine="device",
+                                   counters=self.counters, engine="device",
                                    device_min_records=self.device_min_records,
                                    merge_factor=self.merge_factor,
-                                   key_normalizer=self.key_normalizer)
+                                   key_normalizer=self.key_normalizer,
+                                   final=False)
         return (kind, raw, self._write_chunked([merged]))
 
     def _pipe_complete(self, ids, result) -> None:
@@ -613,7 +616,8 @@ class ShuffleMergeManager:
             engine=self.engine if engine is None else engine,
             key_normalizer=self.key_normalizer,
             merge_factor=self.merge_factor,
-            device_min_records=self.device_min_records)
+            device_min_records=self.device_min_records,
+            counters=self.counters)
 
     def _stream_merge_to_disk(self, paths: List[str],
                               engine: Optional[str] = None) -> str:

@@ -46,12 +46,10 @@ class UnorderedPartitionedWriter:
     """Hash-partition spans on device; no key sort."""
 
     def __init__(self, num_partitions: int, span_budget_bytes: int,
-                 counters: Any, single_partition_skip_buffer: bool = True,
-                 use_pallas_hash: bool = False):
+                 counters: Any, single_partition_skip_buffer: bool = True):
         self.num_partitions = num_partitions
         self.span_budget = span_budget_bytes
         self.counters = counters
-        self.use_pallas_hash = use_pallas_hash
         self._span = SpanBuffer()
         self._runs: List[Run] = []
         self.num_spills = 0
@@ -89,8 +87,7 @@ class UnorderedPartitionedWriter:
         hash_w = 1 << max(2, (wmax - 1).bit_length())
         mat, lengths = pad_to_matrix(batch.key_bytes, batch.key_offsets,
                                      hash_w)
-        partitions = device.hash_partition(mat, lengths, self.num_partitions,
-                                           use_pallas=self.use_pallas_hash)
+        partitions = device.hash_partition(mat, lengths, self.num_partitions)
         # single stable pass groups rows by partition, preserving arrival
         # order within each partition
         sorted_parts, perm = device.sort_run(
@@ -159,9 +156,7 @@ class UnorderedPartitionedKVOutput(LogicalOutput):
         self._final_merge = bool(_conf_get(
             ctx, "tez.runtime.enable.final-merge.in.output", True))
         self.writer_impl = UnorderedPartitionedWriter(
-            self.num_physical_outputs, buffer_mb << 20, ctx.counters,
-            use_pallas_hash=bool(_conf_get(
-                ctx, "tez.runtime.tpu.pallas.hash", False)))
+            self.num_physical_outputs, buffer_mb << 20, ctx.counters)
         ctx.request_initial_memory(buffer_mb << 20, None,
                            component_type="PARTITIONED_UNSORTED_OUTPUT")
         self.service = local_shuffle_service()

@@ -41,11 +41,22 @@ Failure containment (active only when a ``failover_fn`` is wired):
   injectable clock) on every in-progress device attempt.  A hung dispatch
   or readback is *abandoned* — the group is claimed away from its worker,
   journaled as a span event, and re-sorted through ``failover_fn`` — so a
-  wedged chip can never wedge ``drain()``/``flush()``.
-* **Host-engine failover + circuit breaker.**  Any device-attempt failure
-  (watchdog fire, device exception, worker death) re-routes the group
-  through ``failover_fn`` (DeviceSorter wires the host engine, which is
-  golden-tested bit-exact against the device kernels).  Consecutive
+  wedged chip can never wedge ``drain()``/``flush()``.  The dispatch
+  deadline bounds a LAUNCH: kernels compile ahead of launch
+  (``ops.device.Kernel``), and while one compiles on the dispatching
+  thread the clock is stopped and restarts from zero at launch — a cold
+  span-sort ladder compiles for ~25 s on a v5e.
+* **Deterministic failures are not failed over.**  A kernel that cannot
+  be traced, lowered or compiled (``KernelCompileError``), and a
+  ``NotImplementedError`` / ``TypeError`` out of a stage, fail the same
+  way on a healthy chip; re-sorting on the host would report success for
+  a device path that does not exist.  They poison the pipeline — the task
+  attempt fails with the kernel's name — and never count against the
+  breaker.
+* **Host-engine failover + circuit breaker.**  Any other device-attempt
+  failure (watchdog fire, device runtime error, worker death) re-routes
+  the group through ``failover_fn`` (DeviceSorter wires the host engine,
+  which is golden-tested bit-exact against the device kernels).  Consecutive
   failures trip a sticky per-process :class:`CircuitBreaker`; while open,
   new groups short-circuit straight to host, and after ``cooldown_ms`` one
   half-open probe group is allowed back on the device — success re-arms
@@ -73,6 +84,7 @@ decisions emit ``DeviceFailover`` counters (``device.failover.spans``,
 from __future__ import annotations
 
 import collections
+import functools
 import logging
 import queue
 import threading
@@ -735,7 +747,9 @@ class AsyncSpanPipeline:
                             faults.fire("device.dispatch.hang",
                                         f"span={sid}")
                     with tracing.span(STAGE_DISPATCH, cat="device",
-                                      spans=repr(list(ids))):
+                                      spans=repr(list(ids))), \
+                            _compile_listener(functools.partial(
+                                self._watch_compile, group, ids)):
                         inflight = self._dispatch_fn(one)
                 finally:
                     self._watch_end(group)
@@ -797,11 +811,19 @@ class AsyncSpanPipeline:
     # -- failure containment -------------------------------------------------
     def _contain_failure(self, group: _Group, ids: Tuple[Any, ...],
                          exc: BaseException) -> None:
-        """The containment ladder for a device-attempt failure: OOM ->
-        split retry on device -> host failover; anything else -> host
+        """The containment ladder for a device-attempt failure:
+        deterministic (compile-class) -> poison the pipeline, loudly; OOM
+        -> split retry on device -> host failover; anything else -> host
         failover; no failover hook -> poison the pipeline (the original
         contract)."""
-        if self._failover_fn is None or \
+        deterministic = _is_deterministic(exc)
+        if deterministic and self._failover_fn is not None:
+            _count(self._counters, "device.compile.errors")
+            log.error("%s: spans %s hit a deterministic device-path "
+                      "failure; failing the attempt instead of re-sorting "
+                      "on the host: %s: %s", self._name, list(ids),
+                      type(exc).__name__, exc)
+        if self._failover_fn is None or deterministic or \
                 isinstance(exc, (KeyboardInterrupt, SystemExit)):
             self._gate_release(group)
             self._fail(exc)
@@ -877,6 +899,17 @@ class AsyncSpanPipeline:
         with self._lock:
             self._watch.pop(id(group), None)
 
+    def _watch_compile(self, group: _Group, ids: Tuple[Any, ...],
+                       compiling: bool) -> None:
+        """ops.device.compile_listener hook for the dispatching thread: the
+        clock stops while a kernel compiles and restarts with the full
+        budget when the launch begins."""
+        if compiling:
+            self._watch_end(group)
+        else:
+            self._watch_begin(group, ids, STAGE_DISPATCH,
+                              self._watchdog_dispatch_ms)
+
     def _watchdog_loop(self) -> None:
         while not self._monitor_stop.wait(self._poll_s):
             now = self._clock()
@@ -937,6 +970,19 @@ class AsyncSpanPipeline:
 def _is_oom(exc: BaseException) -> bool:
     from tez_tpu.ops.device import is_resource_exhausted
     return is_resource_exhausted(exc)
+
+
+def _is_deterministic(exc: BaseException) -> bool:
+    """Compile-class failure: the same inputs fail the same way on a
+    healthy chip (module docstring)."""
+    from tez_tpu.ops.device import KernelCompileError
+    return isinstance(exc, (KernelCompileError, NotImplementedError,
+                            TypeError))
+
+
+def _compile_listener(fn: Callable[[bool], None]):
+    from tez_tpu.ops.device import compile_listener
+    return compile_listener(fn)
 
 
 def overlap_pairs(events: Sequence[Tuple[Any, str, str, float]]

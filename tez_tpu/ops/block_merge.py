@@ -163,10 +163,11 @@ def iter_merged_blocks(
         if len(slices) == 1:
             yield slices[0].batch
         elif slices:
+            # one round of many: the caller reports the merge as a whole
             merged = merge_sorted_runs(
                 slices, 1, key_width, counters=counters, engine=engine,
                 merge_factor=merge_factor, key_normalizer=key_normalizer,
-                device_min_records=device_min_records)
+                device_min_records=device_min_records, final=False)
             yield merged.batch
         # phase 2: rows == boundary, streamed per source IN SOURCE ORDER and
         # contiguously across each source's block boundaries — exactly the

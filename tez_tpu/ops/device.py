@@ -16,59 +16,167 @@ preserves within-key arrival order like the reference's MergeQueue).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
-from typing import Tuple
+import threading
+import time
+from typing import Any, Callable, Dict, Iterator, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Persistent compilation cache: sort-kernel compiles are seconds-to-minutes
-# on TPU; cache them across processes (runner reuse only caches in-process).
-try:
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("TEZ_TPU_JAX_CACHE",
-                                     "/tmp/tez_tpu_jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:  # noqa: BLE001 — older jax without the knob
-    pass
+from tez_tpu.ops import compile_cache    # importing it places the cache
 
 FNV_OFFSET = np.uint32(2166136261)
 FNV_PRIME = np.uint32(16777619)
 
 
 @functools.lru_cache(maxsize=1)
+def backend_platform() -> str:
+    """Platform of the INITIALISED default backend — the one backend query
+    every engine decision in the process hangs off.
+
+    Nothing here is caught: a backend that cannot initialise (the chip is
+    held by another process, libtpu cannot start) raises out of the caller.
+    "cpu" is an answer only when it was asked for — ``JAX_PLATFORMS=cpu``
+    or the equivalent ``jax.config`` update, as the tests do.  With the
+    variable unset JAX drops to the CPU silently when the accelerator
+    plugin fails; that case raises too, because a process that could not
+    claim its chip must not sort on the host and report success."""
+    platform = jax.default_backend()
+    if platform == "cpu" and not compile_cache.cpu_requested():
+        raise RuntimeError(
+            "JAX initialised the CPU backend without being asked to "
+            f"(jax_platforms={jax.config.jax_platforms!r}): no accelerator "
+            "could be claimed.  Set JAX_PLATFORMS=cpu to run on the host "
+            "on purpose.")
+    return platform
+
+
 def single_pass_variadic() -> bool:
     """True when the sort body should use ONE variadic multi-key `lax.sort`
     instead of chained single-key LSD passes.
 
     XLA:CPU compiles the N-operand variadic sort instantly and runs it ~2x
     faster than the chained ladder (one comparator walk instead of L+2
-    full passes over the permutation).  On TPU the variadic sort costs
-    minutes of XLA compile time at large N, so accelerator backends keep
-    the chained passes.  Evaluated at trace time (Python-level branch in
-    the jitted bodies); cached — one backend query per process."""
+    full passes over the permutation).  Accelerator backends keep the
+    chained passes (~25 s to compile per shape on a v5e; the variadic
+    sort's compile time there is unmeasured — ROADMAP S2).  Evaluated at
+    trace time (Python-level branch in the jitted bodies)."""
     if os.environ.get("TEZ_TPU_FORCE_LSD_PASSES"):
         return False
-    try:
-        return jax.default_backend() == "cpu"
-    except Exception:  # noqa: BLE001
-        return False
+    return backend_platform() == "cpu"
 
 
-@functools.lru_cache(maxsize=1)
 def accelerator_present() -> bool:
     """True when the default JAX backend is an accelerator (TPU/GPU).
 
     The `auto` engine routes through this: device kernels are a *loss* on
     the CPU backend (XLA CPU sort + dispatch overhead vs numpy/native), so
     auto picks the host engine there and the device engine whenever a real
-    chip answers.  Cached — one backend query per process."""
+    chip answers.  A backend that fails to initialise raises
+    (:func:`backend_platform`) — it is never read as "no accelerator"."""
+    return backend_platform() != "cpu"
+
+
+# ---------------------------------------------------------------------------
+# kernels: compiled ahead of launch
+# ---------------------------------------------------------------------------
+class KernelCompileError(RuntimeError):
+    """Tracing, lowering or compiling a kernel failed.  Deterministic by
+    construction — the same shapes fail the same way on a healthy chip — so
+    the containment plane (ops/async_stage.py) never retries it on the host:
+    the attempt fails with the kernel's name in the message."""
+
+
+_compile_tls = threading.local()
+
+
+@contextlib.contextmanager
+def compile_listener(fn: Callable[[bool], None]) -> Iterator[None]:
+    """While active on this thread, ``fn(True)`` / ``fn(False)`` bracket
+    every kernel compile the thread performs.  The async pipeline uses it to
+    stop its dispatch watchdog for the duration: the deadline bounds a
+    LAUNCH, and a cold ladder compiles for ~25 s."""
+    prev = getattr(_compile_tls, "fn", None)
+    _compile_tls.fn = fn
     try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:  # noqa: BLE001 — backend init failure = no accelerator
-        return False
+        yield
+    finally:
+        _compile_tls.fn = prev
+
+
+#: (kernel name, signature, compile seconds, wall-clock time it finished)
+#: of every compile this process did — chip_smoke.py prints it as the cold
+#: set-up cost per kernel.
+COMPILE_LOG: List[Tuple[str, str, float, float]] = []
+
+
+class Kernel:
+    """A jitted entry point that is lowered and compiled ONCE per argument
+    signature, apart from its launches.
+
+    ``jax.jit`` compiles inside the first call, so a caller cannot tell a
+    25 s compile from a hung dispatch, nor a lowering error from a device
+    fault.  Here the first call with a new signature compiles under
+    :func:`compile_listener` (failures raise :class:`KernelCompileError`)
+    and every call then launches the compiled executable, which returns as
+    soon as the work is enqueued.  Static arguments are keyword-only."""
+
+    def __init__(self, fn: Callable, name: str,
+                 static_argnames: Tuple[str, ...] = (),
+                 donate_argnums: Tuple[int, ...] = ()) -> None:
+        self.name = name
+        self._jit = jax.jit(fn, static_argnames=static_argnames,
+                            donate_argnums=donate_argnums)
+        self._compiled: Dict[Any, Any] = {}
+        self._key_locks: Dict[Any, threading.Lock] = {}
+        self._lock = threading.Lock()       # guards _key_locks only
+
+    def __call__(self, *args: Any, **static: Any) -> Any:
+        leaves, tree = jax.tree.flatten(args)
+        key = (tree, tuple((np.shape(x), jnp.result_type(x)) for x in leaves),
+               tuple(sorted(static.items())))
+        exe = self._compiled.get(key)
+        if exe is None:
+            exe = self._compile(key, args, static)
+        return exe(*args)
+
+    def cache_size(self) -> int:
+        """Compiled signatures held (tests bound recompiles with it)."""
+        return len(self._compiled)
+
+    def _compile(self, key: Any, args: tuple, static: Dict[str, Any]) -> Any:
+        listener = getattr(_compile_tls, "fn", None)
+        if listener is not None:
+            listener(True)
+        try:
+            # tasks that need the SAME signature share one compile; other
+            # signatures of this kernel compile alongside it
+            with self._lock:
+                key_lock = self._key_locks.setdefault(key, threading.Lock())
+            with key_lock:
+                exe = self._compiled.get(key)
+                if exe is None:
+                    sig = ",".join("x".join(map(str, s)) or "()"
+                                   for s, _ in key[1])
+                    t0 = time.perf_counter()
+                    try:
+                        exe = self._jit.lower(*args, **static).compile()
+                    except Exception as e:
+                        raise KernelCompileError(
+                            f"kernel {self.name}[{sig}] failed to compile: "
+                            f"{type(e).__name__}: {e}") from e
+                    COMPILE_LOG.append((self.name, sig,
+                                        time.perf_counter() - t0,
+                                        time.time()))
+                    self._compiled[key] = exe
+            return exe
+        finally:
+            if listener is not None:
+                listener(False)
 
 
 #: Substrings marking a device failure as an out-of-memory class.  XLA
@@ -111,9 +219,8 @@ def _bucket(n: int, floor: int = 256) -> int:
 # ---------------------------------------------------------------------------
 # hash partition
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("num_partitions",))
-def _fnv_partition(key_mat: jnp.ndarray, lengths: jnp.ndarray,
-                   num_partitions: int) -> jnp.ndarray:
+def _fnv_partition_impl(key_mat: jnp.ndarray, lengths: jnp.ndarray,
+                        num_partitions: int) -> jnp.ndarray:
     """FNV-1a over each row's first `lengths[i]` bytes of key_mat[i, :].
 
     Byte-identical to library.partitioners.HashPartitioner._stable_hash for
@@ -123,24 +230,21 @@ def _fnv_partition(key_mat: jnp.ndarray, lengths: jnp.ndarray,
     return (h % jnp.uint32(num_partitions)).astype(jnp.int32)
 
 
-def hash_partition(key_mat: np.ndarray, lengths: np.ndarray,
-                   num_partitions: int, use_pallas: bool = False) -> np.ndarray:
-    """Host wrapper with shape bucketing.
+_fnv_partition = Kernel(_fnv_partition_impl, "fnv_partition",
+                        static_argnames=("num_partitions",))
 
-    use_pallas routes to the Pallas FNV kernel (same hash body) on TPU
-    backends; elsewhere it falls back to the XLA path so the flag is safe to
-    set fleet-wide."""
+
+def hash_partition(key_mat: np.ndarray, lengths: np.ndarray,
+                   num_partitions: int) -> np.ndarray:
+    """Host wrapper with shape bucketing."""
     n = key_mat.shape[0]
     if n == 0:
         return np.zeros(0, dtype=np.int32)
-    if use_pallas and jax.default_backend() == "tpu":
-        from tez_tpu.ops.pallas_kernels import hash_partition_pallas
-        return hash_partition_pallas(key_mat, lengths, num_partitions)
     nb = _bucket(n)
     if nb != n:
         key_mat = np.pad(key_mat, ((0, nb - n), (0, 0)))
         lengths = np.pad(lengths, (0, nb - n))
-    out = _fnv_partition(key_mat, jnp.asarray(lengths), num_partitions)
+    out = _fnv_partition(key_mat, lengths, num_partitions=num_partitions)
     return np.asarray(out)[:n]
 
 
@@ -246,23 +350,24 @@ def _fused_resident_hash_sort_impl(lanes: jnp.ndarray, lengths: jnp.ndarray,
     return sp, perm, lanes[perm], lengths[perm]
 
 
-_fused_resident_hash_sort = jax.jit(
-    _fused_resident_hash_sort_impl,
+_fused_resident_hash_sort = Kernel(
+    _fused_resident_hash_sort_impl, "resident_hash_sort",
     static_argnames=("num_partitions", "skip_length_pass"))
 
+_fused_resident_hash_sort_donated = Kernel(
+    _fused_resident_hash_sort_impl, "resident_hash_sort_donated",
+    static_argnames=("num_partitions", "skip_length_pass"),
+    donate_argnums=(0,))
 
-@functools.lru_cache(maxsize=1)
-def _resident_sort_donated():
+
+def _resident_sort_donated() -> Kernel:
     """Donating flavor for the async pipeline: the staged (bucketed) input
     lanes buffer aliases the sorted-lanes output, so the sort runs in-place
     in HBM instead of holding both copies live.  Accelerator backends only —
-    XLA:CPU ignores donation (with a warning per call), so the plain jit is
-    returned there."""
-    if not accelerator_present():
-        return _fused_resident_hash_sort
-    return jax.jit(_fused_resident_hash_sort_impl,
-                   static_argnames=("num_partitions", "skip_length_pass"),
-                   donate_argnums=(0,))
+    XLA:CPU ignores donation (with a warning per call), so the plain kernel
+    is returned there."""
+    return _fused_resident_hash_sort_donated if accelerator_present() \
+        else _fused_resident_hash_sort
 
 
 # -- decomposed resident-span stages (ops/async_stage.py pipeline) ----------
@@ -290,7 +395,8 @@ def dispatch_resident_span(staged, num_partitions: int):
     (JAX async dispatch) — block via readback_resident_span."""
     lanes_dev, lens_dev, n, uniform = staged
     sp, perm, out_lanes, out_lens = _resident_sort_donated()(
-        lanes_dev, lens_dev, num_partitions, skip_length_pass=uniform)
+        lanes_dev, lens_dev, num_partitions=num_partitions,
+        skip_length_pass=uniform)
     return sp, perm, out_lanes, out_lens, n
 
 
@@ -323,16 +429,15 @@ def hash_sort_span_resident(lanes: np.ndarray, lengths: np.ndarray,
     # with tail sentinels present: sentinel order is fully decided by the
     # final partition pass (partition MAX)
     sp, perm, out_lanes, out_lens = _fused_resident_hash_sort(
-        jnp.asarray(lanes), jnp.asarray(lengths), num_partitions,
-        skip_length_pass=uniform)
+        jnp.asarray(lanes), jnp.asarray(lengths),
+        num_partitions=num_partitions, skip_length_pass=uniform)
     sp = np.asarray(sp)[:n]
     perm = np.asarray(perm)[:n]
     return sp, perm, (out_lanes, out_lens, 0, n)
 
 
-@functools.partial(jax.jit, static_argnames=("out_rows", "out_lanes"))
-def _slice_to_bucket(lanes: jnp.ndarray, lengths: jnp.ndarray,
-                     lo, count, out_rows: int, out_lanes: int):
+def _slice_to_bucket_impl(lanes: jnp.ndarray, lengths: jnp.ndarray,
+                          lo, count, out_rows: int, out_lanes: int):
     """Dynamic [lo, lo+count) slice padded to a STATIC out_rows bucket with
     tail sentinels — dynamic offsets keep the compile count bounded by
     (input bucket, output bucket) pairs, not by data-dependent slice sizes.
@@ -350,8 +455,11 @@ def _slice_to_bucket(lanes: jnp.ndarray, lengths: jnp.ndarray,
     return sl, ln
 
 
-@jax.jit
-def _fused_resident_merge(lanes_list, lens_list):
+_slice_to_bucket = Kernel(_slice_to_bucket_impl, "slice_to_bucket",
+                          static_argnames=("out_rows", "out_lanes"))
+
+
+def _fused_resident_merge_impl(lanes_list, lens_list):
     """Single-partition k-way merge of device-resident sorted key columns:
     stable sort of the concatenation (TezMerger semantics — equal keys keep
     run order).  Sentinel rows (length < 0) sort to the tail."""
@@ -363,6 +471,10 @@ def _fused_resident_merge(lanes_list, lens_list):
                           lens.astype(jnp.uint32))
     _, perm = _lsd_passes(parts, lanes, sort_lens)
     return perm
+
+
+_fused_resident_merge = Kernel(_fused_resident_merge_impl,
+                               "resident_merge_sort")
 
 
 def _map_bucketed_perm(perm: np.ndarray, counts, common: int) -> np.ndarray:
@@ -401,7 +513,9 @@ def merge_resident_slices(slices, kernel: str = "merge_path") -> np.ndarray:
     width = max(l.shape[1] for (l, _n, _lo, _hi) in slices)
     lanes_list, lens_list = [], []
     for (lanes, lens, lo, hi) in slices:
-        sl, ln = _slice_to_bucket(lanes, lens, lo, hi - lo, common, width)
+        sl, ln = _slice_to_bucket(lanes, lens, np.int32(lo),
+                                  np.int32(hi - lo), out_rows=common,
+                                  out_lanes=width)
         lanes_list.append(sl)
         lens_list.append(ln)
     if kernel == "merge_path":
@@ -463,38 +577,15 @@ def _rank_search(run_lanes: jnp.ndarray, run_lens: jnp.ndarray,
     return lo
 
 
-@functools.lru_cache(maxsize=1)
-def _pallas_merge_ranks() -> bool:
-    """Route rank computation through the Pallas flavor on TPU backends
-    (same search body — pallas_kernels delegates to _rank_search)."""
-    if os.environ.get("TEZ_TPU_DISABLE_PALLAS_MERGE"):
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
-
-
-def _rank_rows(run_lanes: jnp.ndarray, run_lens: jnp.ndarray,
-               q_lanes: jnp.ndarray, q_lens: jnp.ndarray,
-               count_equal: bool) -> jnp.ndarray:
-    if _pallas_merge_ranks():
-        from tez_tpu.ops.pallas_kernels import merge_rank_pallas
-        return merge_rank_pallas(run_lanes, run_lens, q_lanes, q_lens,
-                                 count_equal)
-    return _rank_search(run_lanes, run_lens, q_lanes, q_lens, count_equal)
-
-
-@jax.jit
-def _merge_path_pair(a_lanes, a_lens, a_idx, b_lanes, b_lens, b_idx):
+def _merge_path_pair_impl(a_lanes, a_lens, a_idx, b_lanes, b_lens, b_idx):
     """One O(na+nb) merge level: scatter both runs straight to their output
     positions.  Sentinel rows participate too — A-sentinel i lands at
     i + realB and B-sentinel j at j + na, so the scatter is a collision-free
     permutation with every real row in the prefix and the output again a
     sorted run (ladder levels compose without re-compacting)."""
     na, nb = a_lanes.shape[0], b_lanes.shape[0]
-    ra = _rank_rows(b_lanes, b_lens, a_lanes, a_lens, count_equal=False)
-    rb = _rank_rows(a_lanes, a_lens, b_lanes, b_lens, count_equal=True)
+    ra = _rank_search(b_lanes, b_lens, a_lanes, a_lens, count_equal=False)
+    rb = _rank_search(a_lanes, a_lens, b_lanes, b_lens, count_equal=True)
     pos_a = jnp.arange(na, dtype=jnp.int32) + ra
     pos_b = jnp.arange(nb, dtype=jnp.int32) + rb
     out_lanes = jnp.empty((na + nb, a_lanes.shape[1]), a_lanes.dtype)
@@ -506,8 +597,10 @@ def _merge_path_pair(a_lanes, a_lens, a_idx, b_lanes, b_lens, b_idx):
     return out_lanes, out_lens, out_idx
 
 
-@jax.jit
-def _merge_path_prep(lanes, lens, base):
+_merge_path_pair = Kernel(_merge_path_pair_impl, "merge_path_pair")
+
+
+def _merge_path_prep_impl(lanes, lens, base):
     """Per-run ladder prep: int32 lengths (-1 pad sentinel) -> u32 sort
     lengths (0xFFFFFFFF sentinel) + global bucket indices.  `base` is a
     dynamic argument so per-run offsets don't multiply compile keys."""
@@ -515,6 +608,9 @@ def _merge_path_prep(lanes, lens, base):
                           lens.astype(jnp.uint32))
     idx = base + jnp.arange(lanes.shape[0], dtype=jnp.int32)
     return sort_lens, idx
+
+
+_merge_path_prep = Kernel(_merge_path_prep_impl, "merge_path_prep")
 
 
 def _merge_path_ladder(runs):
@@ -535,7 +631,7 @@ def _merge_path_ladder(runs):
 def _merge_path_resident(lanes_list, lens_list, common: int):
     runs = []
     for i, (sl, ln) in enumerate(zip(lanes_list, lens_list)):
-        sort_lens, idx = _merge_path_prep(sl, ln, i * common)
+        sort_lens, idx = _merge_path_prep(sl, ln, np.int32(i * common))
         runs.append((sl, sort_lens, idx))
     return _merge_path_ladder(runs)
 
@@ -568,9 +664,10 @@ def merge_path_runs(parts_list: list[np.ndarray],
         comp[n:] = np.uint32(0xFFFFFFFF)
         lens = np.full(common, -1, dtype=np.int32)
         lens[:n] = np.minimum(lengths_list[i].astype(np.int64), width_cap)
-        sort_lens, idx = _merge_path_prep(jnp.asarray(comp),
-                                          jnp.asarray(lens), j * common)
-        runs.append((jnp.asarray(comp), sort_lens, idx))
+        comp_dev = jnp.asarray(comp)
+        sort_lens, idx = _merge_path_prep(comp_dev, jnp.asarray(lens),
+                                          np.int32(j * common))
+        runs.append((comp_dev, sort_lens, idx))
     perm = np.asarray(_merge_path_ladder(runs))
     mapped = _map_bucketed_perm(perm, [counts[i] for i in live], common)
     if len(live) != len(counts):   # re-offset into the FULL concatenation
@@ -583,13 +680,11 @@ def merge_path_runs(parts_list: list[np.ndarray],
     return mapped
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_partitions", "skip_length_pass"))
-def _fused_hash_sort(key_mat: jnp.ndarray, hash_lengths: jnp.ndarray,
-                     lanes: jnp.ndarray, sort_lengths: jnp.ndarray,
-                     num_partitions: int,
-                     skip_length_pass: bool = False
-                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _fused_hash_sort_impl(key_mat: jnp.ndarray, hash_lengths: jnp.ndarray,
+                          lanes: jnp.ndarray, sort_lengths: jnp.ndarray,
+                          num_partitions: int,
+                          skip_length_pass: bool = False
+                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One dispatch: full-key FNV hash-partition + LSD sort.  Fusing all
     passes into a single XLA program matters on TPU: per-dispatch latency
     (host<->device round trips) would otherwise dominate small spans."""
@@ -597,11 +692,11 @@ def _fused_hash_sort(key_mat: jnp.ndarray, hash_lengths: jnp.ndarray,
     return _lsd_passes(partitions, lanes, sort_lengths, skip_length_pass)
 
 
-@functools.partial(jax.jit, static_argnames=("skip_length_pass",))
-def _fused_sort(partitions: jnp.ndarray, lanes: jnp.ndarray,
-                lengths: jnp.ndarray, skip_length_pass: bool = False
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    return _lsd_passes(partitions, lanes, lengths, skip_length_pass)
+_fused_hash_sort = Kernel(
+    _fused_hash_sort_impl, "hash_sort",
+    static_argnames=("num_partitions", "skip_length_pass"))
+
+_fused_sort = Kernel(_lsd_passes, "sort_run")
 
 
 def hash_sort_span(key_mat: np.ndarray, hash_lengths: np.ndarray,
@@ -632,7 +727,7 @@ def hash_sort_span(key_mat: np.ndarray, hash_lengths: np.ndarray,
                                 jnp.asarray(hash_lengths),
                                 jnp.asarray(lanes),
                                 jnp.asarray(slen.astype(np.uint32)),
-                                num_partitions,
+                                num_partitions=num_partitions,
                                 skip_length_pass=uniform)
     sp = np.asarray(sp)
     perm = np.asarray(perm)
@@ -695,11 +790,15 @@ def merge_runs(lanes_list: list[np.ndarray],
 # ---------------------------------------------------------------------------
 # segmented (per-partition) counts
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("num_partitions",))
-def _partition_histogram(partitions: jnp.ndarray,
-                         num_partitions: int) -> jnp.ndarray:
+def _partition_histogram_impl(partitions: jnp.ndarray,
+                              num_partitions: int) -> jnp.ndarray:
     one_hot = jax.nn.one_hot(partitions, num_partitions, dtype=jnp.int32)
     return one_hot.sum(axis=0)
+
+
+_partition_histogram = Kernel(_partition_histogram_impl,
+                              "partition_histogram",
+                              static_argnames=("num_partitions",))
 
 
 def partition_counts(partitions: np.ndarray, num_partitions: int) -> np.ndarray:
@@ -709,5 +808,6 @@ def partition_counts(partitions: np.ndarray, num_partitions: int) -> np.ndarray:
     nb = _bucket(n)
     if nb != n:
         partitions = np.pad(partitions, (0, nb - n), constant_values=-1)
-    out = _partition_histogram(jnp.asarray(partitions), num_partitions)
+    out = _partition_histogram(jnp.asarray(partitions),
+                               num_partitions=num_partitions)
     return np.asarray(out).astype(np.int64)

@@ -26,7 +26,6 @@ disk write, so fetch/commit, device merge, and spill IO overlap.
 """
 from __future__ import annotations
 
-import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -34,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tez_tpu.ops.device import (_bucket, _hash_to_partitions,
+from tez_tpu.ops.device import (Kernel, _bucket, _hash_to_partitions,
                                 _lsd_passes, accelerator_present,
                                 uniform_clamped_lengths)
 
@@ -63,23 +62,24 @@ def _fused_pipeline_impl(key_mat: jnp.ndarray, hash_lengths: jnp.ndarray,
     return sp32, out_lanes, out_vals, perm, counts
 
 
-_fused_pipeline = jax.jit(
-    _fused_pipeline_impl,
+_fused_pipeline = Kernel(
+    _fused_pipeline_impl, "fused_pipeline",
     static_argnames=("num_partitions", "skip_length_pass"))
 
+_fused_pipeline_donating = Kernel(
+    _fused_pipeline_impl, "fused_pipeline_donated",
+    static_argnames=("num_partitions", "skip_length_pass"),
+    donate_argnums=(2, 4))
 
-@functools.lru_cache(maxsize=1)
-def _fused_pipeline_donated():
+
+def _fused_pipeline_donated() -> Kernel:
     """Donating flavor for the async plane: the staged lane/value buffers
     alias the sorted outputs, so the sort+gather runs in-place in HBM —
     double-buffered staging slots don't triple the resident footprint.
     Accelerator backends only (XLA:CPU ignores donation, warning per call).
     """
-    if not accelerator_present():
-        return _fused_pipeline
-    return jax.jit(_fused_pipeline_impl,
-                   static_argnames=("num_partitions", "skip_length_pass"),
-                   donate_argnums=(2, 4))
+    return _fused_pipeline_donating if accelerator_present() \
+        else _fused_pipeline
 
 
 def device_shuffle_sort(lanes, lengths, vals, key_mat, hash_lengths,
@@ -109,7 +109,8 @@ def device_shuffle_sort(lanes, lengths, vals, key_mat, hash_lengths,
     return _fused_pipeline(jnp.asarray(key_mat),
                            jnp.asarray(hash_lengths, dtype=jnp.int32),
                            jnp.asarray(lanes), slen, jnp.asarray(vals),
-                           num_partitions, skip_length_pass=uniform)
+                           num_partitions=num_partitions,
+                           skip_length_pass=uniform)
 
 
 class DeviceSpanScheduler:
@@ -280,7 +281,8 @@ class DeviceSpanScheduler:
     def _dispatch(self, s: Dict):
         out = _fused_pipeline_donated()(
             s["key_mat"], s["hash_lengths"], s["lanes"], s["sort_lengths"],
-            s["vals"], self.num_partitions, skip_length_pass=s["uniform"])
+            s["vals"], num_partitions=self.num_partitions,
+            skip_length_pass=s["uniform"])
         return out + (s["n"],)
 
     def _readback(self, inflight, ids):

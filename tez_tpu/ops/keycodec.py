@@ -105,6 +105,8 @@ def _encode_keys_jit(key_bytes, offsets, width: int):
 
     import jax
 
+    from tez_tpu.ops import compile_cache  # noqa: F401 — places the cache
+
     @functools.partial(jax.jit, static_argnames=("width",))
     def go(data, offs, width: int):
         import jax.numpy as jnp

@@ -1,7 +1,10 @@
-"""ctypes bindings for the native host ops (tez_tpu/native/ragged.cpp).
+"""ctypes bindings for the native host ops (tez_tpu/native/*.cpp).
 
-Auto-builds `libtezhost.so` with g++ on first use (cached); every
-caller has a numpy fallback, so a missing toolchain degrades gracefully.
+`libtezhost.so` is built from the committed sources with `make` on first
+use, or loading raises: there is no prebuilt library to fall back on and no
+silent switch to a numpy path, because the host half of the main path
+(tokenizer, span sort, ragged gather) must not change engine unannounced.
+The `.so` is git-ignored; `make` rebuilds it whenever a source is newer.
 
 The native sources ship INSIDE the package (`tez_tpu/native/`) so pip
 installs get them; when the install dir is read-only (site-packages), the
@@ -51,151 +54,86 @@ def _build_dir() -> str:
             os.replace(tmp, dst)
     return bdir
 
-_lib: "ctypes.CDLL | None | bool" = None   # None=untried, False=unavailable
+_lib: "ctypes.CDLL | None" = None
+_lib_path = ""
 _lock = threading.Lock()
 
 #: Below this many bytes the thread spawn outweighs the copy.
 MIN_NATIVE_BYTES = 1 << 20
 
+_P, _I32, _I64 = ctypes.c_void_p, ctypes.c_int32, ctypes.c_int64
 
-def _load() -> "ctypes.CDLL | None":
-    global _lib
-    if _lib is False:
-        return None
+#: symbol -> (argtypes, restype) of everything Python calls
+_SIGNATURES = {
+    "gather_ragged_u8": ([_P, _P, _P, _I64, _P, _P, _I32], None),
+    "adjacent_equal_u8": ([_P, _P, _P, _I64, _P, _I32], None),
+    "tz_wc_create": ([], _P),
+    "tz_wc_feed": ([_P, _P, _I64], None),
+    "tz_wc_stats": ([_P, _P, _P], None),
+    "tz_wc_emit": ([_P, _P, _P, _P], None),
+    "tz_wc_destroy": ([_P], None),
+    "hash_sum_i64": ([_P, _P, _I64, _P, _P, _P], _I64),
+    "tz_split_ws": ([_P, _I64, _P, _P], _I64),
+    "tz_fnv32_partition": ([_P, _P, _I64, _I32, _P, _I32], None),
+    "tz_sort_partition_keys": ([_P, _P, _P, _I64, _P, _I32], None),
+    "tz_merge_runs": ([_P, _P, _P, _P, _I32, _P, _I32], None),
+    "gather_fixed_u8": ([_P, _I64, _P, _I64, _P, _I32], None),
+    "tz_span_sort_emit": ([_P, _P, _P, _P, _I64, _I32, _P, _I32,
+                           _P, _P, _P, _P, _P, _P, _I32], _I32),
+    "tz_merge_emit": ([_I32, _P, _P, _P, _P, _P, _P, _I32,
+                       _P, _P, _P, _P, _P, _P, _I32], _I32),
+    "pipelined_sorter_proxy": ([_P, _I64, _P, _I64, _I64, _I32, _I32,
+                                _P, _P, _P], ctypes.c_double),
+    "owc_proxy_v2": ([_P, _I64, _I32, _I32, _I32, _P, _I64, _P],
+                     ctypes.c_double),
+}
+
+
+def _load() -> "ctypes.CDLL":
+    """Build (make is a no-op when the .so is newer than every source) and
+    load the library; a failed build raises with the compiler's output."""
+    global _lib, _lib_path
     if _lib is not None:
         return _lib
     with _lock:
-        if _lib not in (None,):
-            return _lib if _lib is not False else None
+        if _lib is not None:
+            return _lib
+        bdir = _build_dir()
         try:
-            bdir = _build_dir()
-            so_path = os.path.join(bdir, "libtezhost.so")
-            # make is a no-op when current and rebuilds a stale .so after a
-            # source change (the .so is newer-than-sources checked)
-            try:
-                subprocess.run(["make", "-C", bdir, "-s"],
-                               check=True, capture_output=True, timeout=120)
-            except Exception:  # noqa: BLE001 — no toolchain: use stale .so
-                prebuilt = os.path.join(_NATIVE_DIR, "libtezhost.so")
-                if os.path.exists(so_path):
-                    pass
-                elif os.path.exists(prebuilt):
-                    so_path = prebuilt
-                else:
-                    raise
-            lib = ctypes.CDLL(so_path)
-            lib.gather_ragged_u8.argtypes = [
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.c_int32]
-            lib.gather_ragged_u8.restype = None
-            if hasattr(lib, "adjacent_equal_u8"):
-                # a stale prebuilt .so (no toolchain to rebuild) may lack
-                # the newer symbol; only that feature degrades, not the lib
-                lib.adjacent_equal_u8.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_int64, ctypes.c_void_p, ctypes.c_int32]
-                lib.adjacent_equal_u8.restype = None
-            if hasattr(lib, "tz_wc_create"):
-                lib.tz_wc_create.argtypes = []
-                lib.tz_wc_create.restype = ctypes.c_void_p
-                lib.tz_wc_feed.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
-                                           ctypes.c_int64]
-                lib.tz_wc_feed.restype = None
-                lib.tz_wc_stats.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
-                                            ctypes.c_void_p]
-                lib.tz_wc_stats.restype = None
-                lib.tz_wc_emit.argtypes = [ctypes.c_void_p, ctypes.c_void_p,
-                                           ctypes.c_void_p, ctypes.c_void_p]
-                lib.tz_wc_emit.restype = None
-                lib.tz_wc_destroy.argtypes = [ctypes.c_void_p]
-                lib.tz_wc_destroy.restype = None
-                lib.hash_sum_i64.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
-                lib.hash_sum_i64.restype = ctypes.c_int64
-            if hasattr(lib, "tz_split_ws"):
-                lib.tz_split_ws.argtypes = [
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-                    ctypes.c_void_p]
-                lib.tz_split_ws.restype = ctypes.c_int64
-            if hasattr(lib, "tz_sort_partition_keys"):
-                lib.tz_fnv32_partition.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
-                    ctypes.c_int32, ctypes.c_void_p, ctypes.c_int32]
-                lib.tz_fnv32_partition.restype = None
-                lib.tz_sort_partition_keys.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_int64, ctypes.c_void_p, ctypes.c_int32]
-                lib.tz_sort_partition_keys.restype = None
-            if hasattr(lib, "tz_merge_runs"):
-                lib.tz_merge_runs.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p,
-                    ctypes.c_int32]
-                lib.tz_merge_runs.restype = None
-            if hasattr(lib, "gather_fixed_u8"):
-                lib.gather_fixed_u8.argtypes = [
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-                    ctypes.c_int64, ctypes.c_void_p, ctypes.c_int32]
-                lib.gather_fixed_u8.restype = None
-            if hasattr(lib, "tz_span_sort_emit"):
-                lib.tz_span_sort_emit.argtypes = [
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
-                    ctypes.c_void_p, ctypes.c_int32,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_int32]
-                lib.tz_span_sort_emit.restype = ctypes.c_int32
-            if hasattr(lib, "tz_merge_emit"):
-                lib.tz_merge_emit.argtypes = [
-                    ctypes.c_int32,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_int32,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_int32]
-                lib.tz_merge_emit.restype = ctypes.c_int32
-            if hasattr(lib, "pipelined_sorter_proxy"):
-                lib.pipelined_sorter_proxy.argtypes = [
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-                    ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
-                    ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p]
-                lib.pipelined_sorter_proxy.restype = ctypes.c_double
-            if hasattr(lib, "owc_proxy_v2"):
-                # _v2: the combine arg changed the C ABI — a stale prebuilt
-                # .so (no-toolchain fallback) must fail the hasattr gate,
-                # never be called with the new signature
-                lib.owc_proxy_v2.argtypes = [
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
-                    ctypes.c_int32, ctypes.c_int32, ctypes.c_void_p,
-                    ctypes.c_int64, ctypes.c_void_p]
-                lib.owc_proxy_v2.restype = ctypes.c_double
-            _lib = lib
-            log.info("native host ops loaded from %s", so_path)
-        except Exception as e:  # noqa: BLE001 — toolchain may be absent
-            log.warning("native host ops unavailable (%s); numpy fallback",
-                        e)
-            _lib = False
-            return None
-        return _lib
+            subprocess.run(["make", "-C", bdir, "-s"], check=True,
+                           capture_output=True, timeout=300)
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"building libtezhost.so in {bdir} failed:\n"
+                f"{e.stderr.decode(errors='replace')[-2000:]}") from e
+        so_path = os.path.join(bdir, "libtezhost.so")
+        lib = ctypes.CDLL(so_path)
+        for name, (argtypes, restype) in _SIGNATURES.items():
+            fn = getattr(lib, name)
+            fn.argtypes = argtypes
+            fn.restype = restype
+        _lib, _lib_path = lib, so_path
+        log.info("native host ops loaded from %s", so_path)
+        return lib
+
+
+def loaded_path() -> str:
+    """Path of the library this process loaded (builds it if need be)."""
+    _load()
+    return _lib_path
 
 
 def native_available() -> bool:
-    return _load() is not None
+    """True once the library is built and loaded; a failed build raises."""
+    _load()
+    return True
 
 
 def gather_ragged_native(data: np.ndarray, offsets: np.ndarray,
                          perm: np.ndarray
-                         ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-    """Multithreaded ragged permute; returns None when the native lib is
-    unavailable (caller falls back to numpy)."""
+                         ) -> Tuple[np.ndarray, np.ndarray]:
+    """Multithreaded ragged permute."""
     lib = _load()
-    if lib is None:
-        return None
     n_out = len(perm)
     lengths = offsets[1:] - offsets[:-1]
     out_offsets = np.zeros(n_out + 1, dtype=np.int64)
@@ -217,13 +155,11 @@ def gather_ragged_native(data: np.ndarray, offsets: np.ndarray,
 
 
 def gather_fixed_native(data: np.ndarray, row_len: int, perm: np.ndarray
-                        ) -> Optional[np.ndarray]:
+                        ) -> np.ndarray:
     """Permute fixed-width rows: out[i] = data[perm[i]*row_len:+row_len].
     Skips the per-row offset lookups of the ragged gather (compile-time
-    copy sizes for the common serde widths).  None when unavailable."""
+    copy sizes for the common serde widths)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "gather_fixed_u8"):
-        return None
     n = len(perm)
     out = np.empty(n * row_len, dtype=np.uint8)
     data = np.ascontiguousarray(data)
@@ -244,11 +180,9 @@ def span_sort_emit_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
                           ) -> "Optional[tuple]":
     """Fused producer span sort: partition (optionally fnv32 in C) + stable
     (partition, key) sort + direct materialization of the sorted batch.
-    Returns (out_kb, out_ko, out_vb, out_vo, row_index) or None when the
-    native lib / symbol is unavailable."""
+    Returns (out_kb, out_ko, out_vb, out_vo, row_index), or None when the
+    native side rejects the input (non-zero rc)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "tz_span_sort_emit"):
-        return None
     n = len(key_offsets) - 1
     key_bytes = np.ascontiguousarray(key_bytes)
     key_offsets = np.ascontiguousarray(key_offsets, dtype=np.int64)
@@ -290,10 +224,9 @@ def merge_emit_native(runs: "list", num_partitions: int
     k-way merge group heads, emit contiguous segment copies (no concat, no
     row gather).  `runs` is a list of (key_bytes, key_offsets, val_bytes,
     val_offsets, row_index) tuples.  Returns (out_kb, out_ko, out_vb,
-    out_vo, row_index) or None when unavailable."""
+    out_vo, row_index), or None when the native side rejects the input
+    (non-zero rc)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "tz_merge_emit"):
-        return None
     k = len(runs)
     holders = []   # keep contiguous arrays alive across the call
     kb_ptrs = (ctypes.c_void_p * k)()
@@ -345,8 +278,7 @@ def merge_emit_native(runs: "list", num_partitions: int
 
 
 class WordCountAggregator:
-    """Fused tokenize + hash-count over byte chunks (native); None-pattern:
-    use `create()` and fall back to a numpy tokenizer when it returns None.
+    """Fused tokenize + hash-count over byte chunks (native).
 
     Each `feed()` must be whitespace-complete (line-aligned chunks from the
     text reader), so tokens never span feed boundaries.
@@ -357,11 +289,8 @@ class WordCountAggregator:
         self._h = lib.tz_wc_create()
 
     @staticmethod
-    def create() -> "WordCountAggregator | None":
-        lib = _load()
-        if lib is None or not hasattr(lib, "tz_wc_create"):
-            return None
-        return WordCountAggregator(lib)
+    def create() -> "WordCountAggregator":
+        return WordCountAggregator(_load())
 
     def feed(self, chunk: bytes) -> None:
         self._lib.tz_wc_feed(self._h, chunk, len(chunk))
@@ -399,12 +328,10 @@ class WordCountAggregator:
 
 def hash_sum_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
                     values: np.ndarray
-                    ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+                    ) -> Tuple[np.ndarray, np.ndarray]:
     """Sum int64 `values` of equal keys (first-occurrence order): returns
-    (first_idx, sums) or None when the native lib is unavailable."""
+    (first_idx, sums)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "hash_sum_i64"):
-        return None
     n = len(values)
     key_bytes = np.ascontiguousarray(key_bytes)
     key_offsets = np.ascontiguousarray(key_offsets, dtype=np.int64)
@@ -423,16 +350,13 @@ def hash_sum_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
 
 def pipelined_sorter_proxy(keys: np.ndarray, vals: np.ndarray,
                            num_producers: int, num_partitions: int
-                           ) -> "Optional[Tuple[float, np.ndarray, np.ndarray, np.ndarray]]":
+                           ) -> "Tuple[float, np.ndarray, np.ndarray, np.ndarray]":
     """Run the PipelinedSorter/TezMerger-semantics C++ baseline proxy
     (native/baseline_proxy.cpp; see BASELINE.md) over fixed-width records.
 
     keys: (n, key_len) u8; vals: (n, val_len) u8.  Returns (wall_seconds,
-    merged_keys, merged_vals, per_partition_counts) or None when the
-    native lib is unavailable."""
+    merged_keys, merged_vals, per_partition_counts)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "pipelined_sorter_proxy"):
-        return None
     n, key_len = keys.shape
     val_len = vals.shape[1] if vals.size else 0
     keys = np.ascontiguousarray(keys)
@@ -451,12 +375,10 @@ def pipelined_sorter_proxy(keys: np.ndarray, vals: np.ndarray,
     return float(secs), out_keys, out_vals, counts
 
 
-def split_ws_native(chunk: bytes) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+def split_ws_native(chunk: bytes) -> Tuple[np.ndarray, np.ndarray]:
     """One-pass whitespace split of a text chunk into compacted ragged
-    (word_bytes, word_offsets); None when the native lib is unavailable."""
+    (word_bytes, word_offsets)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "tz_split_ws"):
-        return None
     n = len(chunk)
     out_bytes = np.empty(n, dtype=np.uint8)
     out_offsets = np.empty((n + 1) // 2 + 2, dtype=np.int64)
@@ -468,13 +390,10 @@ def split_ws_native(chunk: bytes) -> Optional[Tuple[np.ndarray, np.ndarray]]:
 
 
 def fnv32_partition_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
-                           num_partitions: int) -> Optional[np.ndarray]:
+                           num_partitions: int) -> np.ndarray:
     """Threaded 32-bit FNV-1a hash partition over full ragged keys
-    (byte-identical to the device kernel and numpy host partitioner);
-    None when the native lib is unavailable."""
+    (byte-identical to the device kernel and numpy host partitioner)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "tz_fnv32_partition"):
-        return None
     n = len(key_offsets) - 1
     key_bytes = np.ascontiguousarray(key_bytes)
     key_offsets = np.ascontiguousarray(key_offsets, dtype=np.int64)
@@ -491,13 +410,10 @@ def fnv32_partition_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
 def sort_partition_keys_native(key_bytes: np.ndarray,
                                key_offsets: np.ndarray,
                                partitions: Optional[np.ndarray]
-                               ) -> Optional[np.ndarray]:
+                               ) -> np.ndarray:
     """Stable sort permutation by (partition, full key bytes) — parallel
-    native merge sort over row indices, GIL released for the whole call.
-    None when the native lib is unavailable."""
+    native merge sort over row indices, GIL released for the whole call."""
     lib = _load()
-    if lib is None or not hasattr(lib, "tz_sort_partition_keys"):
-        return None
     n = len(key_offsets) - 1
     key_bytes = np.ascontiguousarray(key_bytes)
     key_offsets = np.ascontiguousarray(key_offsets, dtype=np.int64)
@@ -516,17 +432,14 @@ def sort_partition_keys_native(key_bytes: np.ndarray,
 
 
 def owc_proxy(text: bytes, num_producers: int, num_partitions: int,
-              combine: bool = True) -> "Optional[Tuple[float, bytes]]":
+              combine: bool = True) -> "Tuple[float, bytes]":
     """Run the full-OrderedWordCount reference-semantics C++ proxy
     (native/baseline_proxy.cpp) over a text corpus: tokenize -> span sort
     (+ combiner when `combine`) -> per-partition heap merge + sum ->
     count-keyed second sort -> merged output lines.  combine=False ships
     every (word, 1) record raw — the spill-bench shape.  Returns
-    (wall_seconds, output_bytes) or None when the native lib is
-    unavailable."""
+    (wall_seconds, output_bytes)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "owc_proxy_v2"):
-        return None
     n = len(text)
     # output = unique words + "\t<count>\n" tails: usually far below the
     # input, but a mostly-distinct-short-word corpus can exceed it — grow
@@ -549,14 +462,11 @@ def owc_proxy(text: bytes, num_producers: int, num_partitions: int,
 
 def merge_runs_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
                       partitions: Optional[np.ndarray],
-                      run_bounds: np.ndarray) -> Optional[np.ndarray]:
+                      run_bounds: np.ndarray) -> np.ndarray:
     """Stable merge permutation over the concatenation of k
     (partition, key)-sorted runs — a ladder of in-place merges instead of
-    a full re-sort (GIL released).  None when the native lib is
-    unavailable."""
+    a full re-sort (GIL released)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "tz_merge_runs"):
-        return None
     key_bytes = np.ascontiguousarray(key_bytes)
     key_offsets = np.ascontiguousarray(key_offsets, dtype=np.int64)
     parts_ptr = None
@@ -579,21 +489,15 @@ def merge_runs_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
 
 def owc_proxy_counts(corpus_path: str, num_producers: int,
                      num_partitions: int, combine: bool = True
-                     ) -> "Optional[Tuple[float, dict]]":
+                     ) -> "Tuple[float, dict]":
     """Shared baseline harness for bench.py / spill_bench: run the
     reference-semantics proxy over a corpus FILE and parse its output
-    lines into {word(str): count}.  Returns None only when the native lib
-    is unavailable; parse errors (corrupt proxy output) RAISE — a wrong
-    baseline must never masquerade as an absent one."""
-    lib = _load()
-    if lib is None or not hasattr(lib, "owc_proxy_v2"):
-        return None
+    lines into {word(str): count}.  Parse errors (corrupt proxy output)
+    raise."""
     with open(corpus_path, "rb") as fh:
         text = fh.read()
-    res = owc_proxy(text, num_producers, num_partitions, combine=combine)
-    if res is None:
-        return None
-    secs, out_bytes = res
+    secs, out_bytes = owc_proxy(text, num_producers, num_partitions,
+                                combine=combine)
     counts: dict = {}
     for line in out_bytes.decode().splitlines():
         w, cnt = line.rsplit("\t", 1)
@@ -602,12 +506,9 @@ def owc_proxy_counts(corpus_path: str, num_producers: int,
 
 
 def adjacent_equal_native(data: np.ndarray, offsets: np.ndarray,
-                          cand: np.ndarray) -> Optional[np.ndarray]:
-    """Threaded per-pair memcmp for adjacent-row equality; None when the
-    native lib is unavailable (caller falls back to numpy)."""
+                          cand: np.ndarray) -> np.ndarray:
+    """Threaded per-pair memcmp for adjacent-row equality."""
     lib = _load()
-    if lib is None or not hasattr(lib, "adjacent_equal_u8"):
-        return None
     data = np.ascontiguousarray(data)
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
     cand64 = np.ascontiguousarray(cand, dtype=np.int64)

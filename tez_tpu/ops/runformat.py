@@ -102,14 +102,10 @@ def gather_ragged(data: np.ndarray, offsets: np.ndarray,
             if 0 < w <= 64 and int(offsets[-1]) == n_src * w and \
                     not bool((offsets[1:] != offsets[:-1] + w).any()):
                 from tez_tpu.ops.native import gather_fixed_native
-                fixed = gather_fixed_native(data, w, perm)
-                if fixed is not None:
-                    return fixed, np.arange(len(perm) + 1,
-                                            dtype=np.int64) * w
+                return (gather_fixed_native(data, w, perm),
+                        np.arange(len(perm) + 1, dtype=np.int64) * w)
         from tez_tpu.ops.native import gather_ragged_native
-        native = gather_ragged_native(data, offsets, perm)
-        if native is not None:
-            return native
+        return gather_ragged_native(data, offsets, perm)
     lengths = offsets[1:] - offsets[:-1]
     new_lengths = lengths[perm]
     new_offsets = np.zeros(len(perm) + 1, dtype=np.int64)
@@ -134,9 +130,7 @@ def adjacent_equal_rows(data: np.ndarray, offsets: np.ndarray,
         # the numpy path materializes one int64 index per BYTE (8x memory
         # expansion); the native threaded memcmp avoids it on large runs
         from tez_tpu.ops.native import adjacent_equal_native
-        native = adjacent_equal_native(data, offsets, cand)
-        if native is not None:
-            return native
+        return adjacent_equal_native(data, offsets, cand)
     out = np.ones(m, dtype=bool)          # zero-length pairs are equal
     nz = np.flatnonzero(lengths)
     if len(nz) == 0:

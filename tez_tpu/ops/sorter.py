@@ -171,10 +171,13 @@ DEVICE_SPLIT_MIN_BYTES = 1 << 20
 
 
 def resolve_engine(engine: str) -> str:
-    """Resolve the `auto` engine: device kernels when an accelerator
-    backend answers, host kernels on the CPU fallback (where an XLA:CPU
-    sort + dispatch round-trip loses to numpy/native outright).  Per-span
-    width/count routing happens later (DeviceSorter._span_engine)."""
+    """Resolve the `auto` engine: device kernels on an accelerator backend,
+    host kernels when the backend is the CPU because that was asked for
+    (an XLA:CPU sort + dispatch round-trip loses to the native engine
+    outright).  A backend that cannot initialise raises — `auto` never
+    turns a chip that could not be claimed into a host run
+    (ops.device.backend_platform).  Per-span width/count routing happens
+    later (DeviceSorter._span_engine)."""
     if engine == "auto":
         return "device" if device.accelerator_present() else "host"
     return engine
@@ -344,10 +347,8 @@ class DeviceSorter:
         from tez_tpu.ops.native import hash_sum_native
         from tez_tpu.ops.serde import decode_longs_be, encode_longs_be
         decoded = decode_longs_be(batch.val_bytes, n)
-        res = hash_sum_native(batch.key_bytes, batch.key_offsets, decoded)
-        if res is None:
-            return batch   # native lib unavailable
-        first_idx, sums = res
+        first_idx, sums = hash_sum_native(batch.key_bytes,
+                                          batch.key_offsets, decoded)
         kb2, ko2 = gather_ragged(batch.key_bytes, batch.key_offsets,
                                  first_idx)
         vb = encode_longs_be(sums)
@@ -578,7 +579,8 @@ class DeviceSorter:
                 inflight["inflight"])
             sorted_batch = inflight["batch"].take(perm)
             sorted_batch.dev_keys = dev
-            self._record_sort_ms(inflight["t0"])
+            self._record_sort(inflight["t0"], "device",
+                              sorted_batch.num_records)
             run = Run.from_sorted_batch(sorted_batch, sp,
                                         self.num_partitions)
         else:
@@ -661,10 +663,15 @@ class DeviceSorter:
                              key_nbytes=key_nbytes,
                              min_key_bytes=self.engine_min_bytes)
 
-    def _record_sort_ms(self, t0: float) -> None:
+    def _record_sort(self, t0: float, engine: str, rows: int) -> None:
+        """One span sorted by `engine` ('device' | 'host')."""
         ms = (time.time() - t0) * 1000.0
-        self.counters.find_counter(TaskCounter.DEVICE_SORT_MILLIS)\
-            .increment(int(ms))
+        with self._store_lock:   # two readback workers land here at once
+            self.counters.find_counter(TaskCounter.DEVICE_SORT_MILLIS)\
+                .increment(int(ms))
+            self.counters.increment(
+                TaskCounter.DEVICE_SORT_RECORDS if engine == "device"
+                else TaskCounter.HOST_SORT_RECORDS, rows)
         from tez_tpu.common import metrics
         metrics.observe("device.sort", ms, counters=self.counters)
 
@@ -714,7 +721,7 @@ class DeviceSorter:
                                                    self.num_partitions)
                 sorted_batch = batch.take(perm)
                 sorted_batch.dev_keys = dev
-                self._record_sort_ms(t0)
+                self._record_sort(t0, "device", batch.num_records)
                 return Run.from_sorted_batch(sorted_batch, sorted_partitions,
                                              self.num_partitions)
         if self.key_normalizer is not None:
@@ -723,21 +730,13 @@ class DeviceSorter:
         else:
             sort_bytes, sort_offsets = batch.key_bytes, batch.key_offsets
         if engine == "host":
-            run = self._native_host_sort(batch, sort_bytes, sort_offsets,
-                                         custom_partitions, t0)
-            if run is not None:
-                return run
+            return self._native_host_sort(batch, sort_bytes, sort_offsets,
+                                          custom_partitions, t0)
         mat, lengths = pad_to_matrix(sort_bytes, sort_offsets, self.key_width)
         lanes = matrix_to_lanes(mat)
         if custom_partitions is not None:
-            partitions = custom_partitions
-            if engine == "host":
-                from tez_tpu.ops.host_sort import host_sort_run
-                sorted_partitions, perm = host_sort_run(partitions, lanes,
-                                                        lengths)
-            else:
-                sorted_partitions, perm = device.sort_run(partitions, lanes,
-                                                          lengths)
+            sorted_partitions, perm = device.sort_run(custom_partitions,
+                                                      lanes, lengths)
         elif self.partitioner == "hash":
             # fused single-dispatch kernel: full-key FNV hash (matrix padded
             # to the longest key so every byte is hashed — host-partitioner
@@ -747,25 +746,11 @@ class DeviceSorter:
             hash_w = 1 << max(2, (wmax - 1).bit_length())
             hmat, hlens = pad_to_matrix(batch.key_bytes, batch.key_offsets,
                                         hash_w)
-            if engine == "host":
-                from tez_tpu.ops.host_sort import (host_hash_partition,
-                                                   host_sort_run)
-                partitions = host_hash_partition(hmat, hlens,
-                                                 self.num_partitions)
-                sorted_partitions, perm = host_sort_run(partitions, lanes,
-                                                        lengths)
-            else:
-                sorted_partitions, perm = device.hash_sort_span(
-                    hmat, hlens, lanes, lengths, self.num_partitions)
+            sorted_partitions, perm = device.hash_sort_span(
+                hmat, hlens, lanes, lengths, self.num_partitions)
         else:
-            partitions = np.zeros(batch.num_records, dtype=np.int32)
-            if engine == "host":
-                from tez_tpu.ops.host_sort import host_sort_run
-                sorted_partitions, perm = host_sort_run(partitions, lanes,
-                                                        lengths)
-            else:
-                sorted_partitions, perm = device.sort_run(partitions, lanes,
-                                                          lengths)
+            sorted_partitions, perm = device.sort_run(
+                np.zeros(batch.num_records, dtype=np.int32), lanes, lengths)
         sorted_batch = batch.take(perm)
         sort_lengths, keyfn = _sorted_key_view(sort_bytes, sort_offsets, perm)
         refinement = _exact_tiebreak(
@@ -773,19 +758,18 @@ class DeviceSorter:
             keyfn)
         if refinement is not None:
             sorted_batch = sorted_batch.take(refinement)
-        self._record_sort_ms(t0)
+        self._record_sort(t0, "device", batch.num_records)
         return Run.from_sorted_batch(sorted_batch, sorted_partitions,
                                      self.num_partitions)
 
     def _native_host_sort(self, batch: KVBatch, sort_bytes: np.ndarray,
                           sort_offsets: np.ndarray,
                           custom_parts: Optional[np.ndarray],
-                          t0: float) -> Optional[Run]:
+                          t0: float) -> Run:
         """C-speed host span sort: threaded FNV partition + stable parallel
         index sort over the ragged sort keys (full-key compares — no padded
         matrix, no tie-break pass), GIL released so concurrent tasks
-        overlap.  None when the native lib is unavailable (numpy lexsort
-        path takes over)."""
+        overlap."""
         from tez_tpu.ops.native import (fnv32_partition_native,
                                         sort_partition_keys_native,
                                         span_sort_emit_native)
@@ -803,7 +787,7 @@ class DeviceSorter:
                               self.partitioner == "hash"))
             if fused is not None:
                 out_kb, out_ko, out_vb, out_vo, row_index = fused
-                self._record_sort_ms(t0)
+                self._record_sort(t0, "host", batch.num_records)
                 return Run(KVBatch(out_kb, out_ko, out_vb, out_vo),
                            row_index)
         parts: Optional[np.ndarray]
@@ -813,19 +797,15 @@ class DeviceSorter:
             parts = fnv32_partition_native(batch.key_bytes,
                                            batch.key_offsets,
                                            self.num_partitions)
-            if parts is None:
-                return None
         else:
             parts = None    # everything lands in partition 0
         perm = sort_partition_keys_native(sort_bytes, sort_offsets, parts)
-        if perm is None:
-            return None
         sorted_batch = batch.take(perm)
         if parts is None:
             sorted_partitions = np.zeros(batch.num_records, dtype=np.int32)
         else:
             sorted_partitions = parts[perm]
-        self._record_sort_ms(t0)
+        self._record_sort(t0, "host", batch.num_records)
         return Run.from_sorted_batch(sorted_batch, sorted_partitions,
                                      self.num_partitions)
 
@@ -968,7 +948,8 @@ class DeviceSorter:
                         srcs, self.key_width, engine=self.engine,
                         key_normalizer=self.key_normalizer,
                         merge_factor=self.merge_factor,
-                        device_min_records=self.device_min_records):
+                        device_min_records=self.device_min_records,
+                        counters=self.counters):
                     if self.combiner is not None:
                         # block-local combine: legal for the (associative)
                         # combiner contract; a key split across block edges
@@ -1002,6 +983,22 @@ def _record_merge_ms(counters: Optional[TezCounters], t0: float) -> None:
     from tez_tpu.common import metrics
     metrics.observe("device.merge", (time.time() - t0) * 1000.0,
                     counters=counters)
+
+
+def _record_merge(counters: Optional[TezCounters], t0: float, engine: str,
+                  rows: int, num_runs: int, final: bool) -> None:
+    """One merge pass done by `engine` ('device' | 'host'): rows always
+    count toward that engine; wall and MERGED_MAP_OUTPUTS only when this
+    pass is the one the task reports (`final`)."""
+    if counters is None:
+        return
+    counters.increment(
+        TaskCounter.DEVICE_MERGE_RECORDS if engine == "device"
+        else TaskCounter.HOST_MERGE_RECORDS, rows)
+    if final:
+        counters.find_counter(TaskCounter.DEVICE_MERGE_MILLIS)\
+            .increment(int((time.time() - t0) * 1000))
+        counters.increment(TaskCounter.MERGED_MAP_OUTPUTS, num_runs)
 
 
 def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int
@@ -1050,10 +1047,15 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
                       merge_factor: int = 0,
                       key_normalizer: Optional[Callable[[bytes], bytes]]
                       = None,
-                      device_min_records: int = DEVICE_SORT_MIN_RECORDS
-                      ) -> Run:
+                      device_min_records: int = DEVICE_SORT_MIN_RECORDS,
+                      final: bool = True) -> Run:
     """k-way merge of partition-sorted runs (TezMerger analog): concatenate,
     stable device sort by (partition, key prefix), host tie-break.
+
+    `counters` always receives the rows under the engine that merged them;
+    final=False marks a pass the task does not report as its merge (inner
+    cascade levels, block-merge rounds, background mem->disk merges), which
+    skips MERGED_MAP_OUTPUTS and the merge wall.
 
     merge_factor > 0 bounds how many runs merge per pass (io.sort.factor):
     each device sort then works on at most factor runs' worth of rows, which
@@ -1066,13 +1068,13 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
             nxt = []
             for i in range(0, len(level), merge_factor):
                 chunk = level[i:i + merge_factor]
-                # inner passes skip counters: only the final pass reports
-                # (avoids double-counting MERGED_MAP_OUTPUTS / merge millis)
+                # only the final pass reports MERGED_MAP_OUTPUTS / millis
                 nxt.append(chunk[0] if len(chunk) == 1 else
                            merge_sorted_runs(
-                               chunk, num_partitions, key_width, None,
+                               chunk, num_partitions, key_width, counters,
                                engine, key_normalizer=key_normalizer,
-                               device_min_records=device_min_records))
+                               device_min_records=device_min_records,
+                               final=False))
             level = nxt
         runs = level
     t0 = time.time()
@@ -1094,10 +1096,8 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
             _record_merge_ms(counters, t0)
             batch = KVBatch.concat([r.batch for r in live])
             sorted_batch = batch.take(perm)
-            if counters is not None:
-                counters.find_counter(TaskCounter.DEVICE_MERGE_MILLIS)\
-                    .increment(int((time.time() - t0) * 1000))
-                counters.increment(TaskCounter.MERGED_MAP_OUTPUTS, len(runs))
+            _record_merge(counters, t0, "device", batch.num_records,
+                          len(runs), final)
             if row_index is None:
                 row_index = np.array([0, sorted_batch.num_records], np.int64)
             return Run(sorted_batch, row_index)
@@ -1120,11 +1120,8 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
                  for r in live], num_partitions)
             if fused is not None:
                 out_kb, out_ko, out_vb, out_vo, row_index = fused
-                if counters is not None:
-                    counters.find_counter(TaskCounter.DEVICE_MERGE_MILLIS)\
-                        .increment(int((time.time() - t0) * 1000))
-                    counters.increment(TaskCounter.MERGED_MAP_OUTPUTS,
-                                       len(runs))
+                _record_merge(counters, t0, "host", len(out_ko) - 1,
+                              len(runs), final)
                 return Run(KVBatch(out_kb, out_ko, out_vb, out_vo),
                            row_index)
     batch = KVBatch.concat([r.batch for r in runs])
@@ -1147,48 +1144,39 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
         perm_n = merge_runs_native(
             sort_bytes, sort_offsets,
             partitions if num_partitions > 1 else None, run_bounds)
-        if perm_n is not None:
-            sorted_batch = batch.take(perm_n)
-            sorted_partitions = partitions[perm_n]
-            if counters is not None:
-                counters.find_counter(TaskCounter.DEVICE_MERGE_MILLIS)\
-                    .increment(int((time.time() - t0) * 1000))
-                counters.increment(TaskCounter.MERGED_MAP_OUTPUTS, len(runs))
-            return Run.from_sorted_batch(sorted_batch, sorted_partitions,
-                                         num_partitions)
+        sorted_batch = batch.take(perm_n)
+        sorted_partitions = partitions[perm_n]
+        _record_merge(counters, t0, "host", batch.num_records,
+                      len(runs), final)
+        return Run.from_sorted_batch(sorted_batch, sorted_partitions,
+                                     num_partitions)
     mat, lengths = pad_to_matrix(sort_bytes, sort_offsets, key_width)
     lanes = matrix_to_lanes(mat)
-    if engine == "host":
-        from tez_tpu.ops.host_sort import host_sort_run
-        sorted_partitions, perm = host_sort_run(partitions, lanes, lengths)
-    else:
-        # the inputs are PRE-SORTED runs: the O(N) merge-path ladder
-        # (cross-rank scatter per level) replaces the O(N log N)
-        # concatenate+re-sort dispatch.  Same composite comparator as
-        # sort_run, equal keys keep run-arrival order, and prefix-equal
-        # beyond-cap keys still fall to the host tie-break below.
-        run_bounds = np.zeros(len(runs) + 1, dtype=np.int64)
-        np.cumsum([r.batch.num_records for r in runs], out=run_bounds[1:])
-        t_dev = time.time()
-        perm = device.merge_path_runs(
-            [partitions[run_bounds[i]:run_bounds[i + 1]]
-             for i in range(len(runs))],
-            [lanes[run_bounds[i]:run_bounds[i + 1]]
-             for i in range(len(runs))],
-            [lengths[run_bounds[i]:run_bounds[i + 1]]
-             for i in range(len(runs))])
-        _record_merge_ms(counters, t_dev)
-        sorted_partitions = partitions[perm]
+    # the inputs are PRE-SORTED runs: the merge-path ladder (cross-rank
+    # scatter per level) replaces the concatenate+re-sort dispatch.  Same
+    # composite comparator as sort_run, equal keys keep run-arrival order,
+    # and prefix-equal beyond-cap keys still fall to the host tie-break
+    # below.
+    run_bounds = np.zeros(len(runs) + 1, dtype=np.int64)
+    np.cumsum([r.batch.num_records for r in runs], out=run_bounds[1:])
+    t_dev = time.time()
+    perm = device.merge_path_runs(
+        [partitions[run_bounds[i]:run_bounds[i + 1]]
+         for i in range(len(runs))],
+        [lanes[run_bounds[i]:run_bounds[i + 1]]
+         for i in range(len(runs))],
+        [lengths[run_bounds[i]:run_bounds[i + 1]]
+         for i in range(len(runs))])
+    _record_merge_ms(counters, t_dev)
+    sorted_partitions = partitions[perm]
     sorted_batch = batch.take(perm)
     sort_lengths, keyfn = _sorted_key_view(sort_bytes, sort_offsets, perm)
     refinement = _exact_tiebreak(sort_lengths, sorted_partitions,
                                  lanes[perm], key_width, keyfn)
     if refinement is not None:
         sorted_batch = sorted_batch.take(refinement)
-    if counters is not None:
-        counters.find_counter(TaskCounter.DEVICE_MERGE_MILLIS)\
-            .increment(int((time.time() - t0) * 1000))
-        counters.increment(TaskCounter.MERGED_MAP_OUTPUTS, len(runs))
+    _record_merge(counters, t0, "device", batch.num_records, len(runs),
+                  final)
     return Run.from_sorted_batch(sorted_batch, sorted_partitions,
                                  num_partitions)
 

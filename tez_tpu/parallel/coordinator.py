@@ -167,6 +167,9 @@ class MeshExchangeCoordinator:
         self.partition_splits = 0
         self.coded_buddy_wins = 0
         self.last_engine: Optional[str] = None
+        #: lane -> jax device.id that held the newest exchange's output
+        #: shard (chip_smoke.py prints it: proof of which chips took part)
+        self.last_shard_devices: Dict[int, int] = {}
         #: cumulative rows landed per device lane (coded duplicates
         #: included — they occupy the lane), feeding the
         #: ``mesh.lane.<i>.*`` occupancy gauges via telemetry_collector
@@ -410,6 +413,8 @@ class MeshExchangeCoordinator:
         for a in arrs:
             shard_maps.append(
                 {pos[s.device]: s.data for s in a.addressable_shards})
+        self.last_shard_devices = {
+            pos[s.device]: s.device.id for s in arrs[0].addressable_shards}
         events = [threading.Event() for _ in range(D)]
         results: List[object] = [None] * D
         any_done = threading.Event()

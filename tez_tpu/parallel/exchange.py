@@ -52,13 +52,16 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from tez_tpu.ops import compile_cache  # noqa: F401 — places the cache
 from tez_tpu.parallel.mesh import WORKER_AXIS
 
 log = logging.getLogger(__name__)
 
-INVALID = jnp.uint32(0xFFFFFFFF)
+#: a numpy scalar: a jnp one would initialise the backend at import
+INVALID = np.uint32(0xFFFFFFFF)
 
 EXCHANGE_ENGINES = ("auto", "padded", "ragged")
 
@@ -278,10 +281,6 @@ def build_distributed_shuffle(mesh, num_lanes: int, rows_per_worker: int,
     sharded over the mesh.  ``explicit_dests`` adds the dests input and
     routes by it instead of the on-device key hash (coordinator splitter /
     coded-buddy seam)."""
-    try:
-        from jax import shard_map          # jax >= 0.8
-    except ImportError:                    # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
     num_workers = mesh.devices.size
 
     if ragged:
@@ -291,17 +290,13 @@ def build_distributed_shuffle(mesh, num_lanes: int, rows_per_worker: int,
     else:
         body = functools.partial(_shuffle_step_local,
                                  num_workers=num_workers, cap=cap_per_pair)
-    import inspect
-    # replication-check kwarg was renamed check_rep -> check_vma in jax 0.8
-    check_kw = "check_vma" if "check_vma" in \
-        inspect.signature(shard_map).parameters else "check_rep"
     n_in = 5 if explicit_dests else 4
     smapped = shard_map(
         body, mesh=mesh,
         in_specs=tuple(P(WORKER_AXIS) for _ in range(n_in)),
         out_specs=(P(WORKER_AXIS), P(WORKER_AXIS), P(WORKER_AXIS),
                    P(WORKER_AXIS), P(WORKER_AXIS)),
-        **{check_kw: False})
+        check_vma=False)
     return jax.jit(smapped)
 
 
@@ -316,8 +311,7 @@ _RAGGED_PROBE: Dict[Tuple[int, str], Tuple[bool, str]] = {}
 def _ragged_unsupported_reason(e: BaseException, platform: str) -> str:
     """Classify a probe failure as 'backend lacks it' vs a real bug; the
     same triage the guarded parity test used before the probe existed."""
-    if "UNIMPLEMENTED" in str(e) or isinstance(e, NotImplementedError) or \
-            (isinstance(e, AttributeError) and "ragged_all_to_all" in str(e)):
+    if "UNIMPLEMENTED" in str(e) or isinstance(e, NotImplementedError):
         return (f"{platform} backend lacks the ragged-all-to-all thunk "
                 f"({type(e).__name__})")
     raise e
@@ -335,20 +329,17 @@ def probe_ragged_support(mesh) -> Tuple[bool, str]:
         cached = _RAGGED_PROBE.get(key)
     if cached is not None:
         return cached
-    if not hasattr(jax.lax, "ragged_all_to_all"):
-        result = (False, "this jax has no jax.lax.ragged_all_to_all")
-    else:
-        W = mesh.devices.size
-        try:
-            fn = build_distributed_shuffle(mesh, 1, 1, 1, value_words=1,
-                                           ragged=True)
-            jax.device_get(fn(np.zeros((W, 1), np.uint32),
-                              np.ones(W, np.uint32),
-                              np.zeros((W, 1), np.uint32),
-                              np.ones(W, bool)))
-            result = (True, f"ragged_all_to_all available on {platform}")
-        except Exception as e:  # noqa: BLE001 — classified, re-raised if real
-            result = (False, _ragged_unsupported_reason(e, platform))
+    W = mesh.devices.size
+    try:
+        fn = build_distributed_shuffle(mesh, 1, 1, 1, value_words=1,
+                                       ragged=True)
+        jax.device_get(fn(np.zeros((W, 1), np.uint32),
+                          np.ones(W, np.uint32),
+                          np.zeros((W, 1), np.uint32),
+                          np.ones(W, bool)))
+        result = (True, f"ragged_all_to_all available on {platform}")
+    except Exception as e:  # noqa: BLE001 — classified, re-raised if real
+        result = (False, _ragged_unsupported_reason(e, platform))
     with _probe_lock:
         _RAGGED_PROBE[key] = result
     return result

@@ -114,23 +114,21 @@ def run_loop(am_host: str, am_port: int, node_id: str, token_hex: str,
     return 0
 
 
-def _repin_jax_platform() -> None:
-    """Honor the caller's JAX_PLATFORMS request verbatim: an ambient
-    sitecustomize may have pinned a different platform in jax.config, which
-    outranks the env var — a runner handed JAX_PLATFORMS=cpu (e.g. the test
-    mesh, or a host-only deployment) must not initialize the TPU backend."""
-    env_platforms = os.environ.get("JAX_PLATFORMS", "")
-    if not env_platforms:
-        return
-    try:
-        import jax
-        jax.config.update("jax_platforms", env_platforms)
-    except Exception:  # noqa: BLE001 — backend already initialized / no jax
-        pass
+def claim_chip(chip: str) -> None:
+    """The launcher gave this process one TPU chip (am/launcher.py
+    chip_env): initialise the backend NOW and check it is that one chip.
+    Raises when the chip cannot be claimed — a runner without its chip must
+    die, not sort on the host."""
+    import jax
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) != 1:
+        raise RuntimeError(
+            f"runner was assigned TPU chip {chip} but JAX reports "
+            f"{len(devices)} {devices[0].platform} device(s)")
+    log.info("runner owns TPU chip %s: %s", chip, devices[0])
 
 
 def main() -> int:
-    _repin_jax_platform()
     parser = argparse.ArgumentParser()
     parser.add_argument("--am-host", default="127.0.0.1")
     parser.add_argument("--am-port", type=int, required=True)
@@ -149,6 +147,15 @@ def main() -> int:
     logging.basicConfig(level=os.environ.get("TEZ_TPU_LOG", "INFO"))
     from tez_tpu.common import ndc
     ndc.install()   # every task log line carries its attempt id (%(ndc)s)
+    from tez_tpu.am.launcher import CHIP_CLAIM_FAILED_RC, RUNNER_CHIP_ENV
+    chip = os.environ.get(RUNNER_CHIP_ENV, "")
+    if chip:
+        try:
+            claim_chip(chip)
+        except Exception as e:  # noqa: BLE001 — any failure is fatal here
+            print(f"runner cannot claim TPU chip {chip}: "
+                  f"{type(e).__name__}: {e}", file=sys.stderr)
+            return CHIP_CLAIM_FAILED_RC
     return run_loop(args.am_host, args.am_port, args.node_id, token,
                     idle_timeout=args.idle_timeout,
                     container_id=args.container_id,

@@ -7,7 +7,7 @@ OLD/NEW are either the driver's ``BENCH_*.json`` wrappers
 (``{"tail": ..., "parsed": ...}``: every JSON metric line is recovered
 from the captured stdout tail) or raw ``bench.py`` stdout saved to a file.
 Metrics are matched across runs by the text up to the first ``(`` —
-parenthesised qualifiers (record counts, fallback labels) change between
+parenthesised qualifiers (record counts, corpus sizes) change between
 revisions, the headline name does not.
 
 All bench metrics are throughputs (higher is better): a metric REGRESSES

@@ -105,7 +105,7 @@ def _mbs(wall: float) -> float:
     return ROWS * (KEY_BYTES + VAL_BYTES) / wall / 1e6
 
 
-def bench_exchange(cpu_fallback: bool) -> List[Dict]:
+def bench_exchange() -> List[Dict]:
     """Metric records for the four exchange legs (bench_diff schema)."""
     import jax
     from tez_tpu.parallel.coordinator import MeshExchangeCoordinator

@@ -1,14 +1,12 @@
-"""On-chip kernel profiling harness: settles the XLA-vs-Pallas-vs-host
-questions with measured numbers instead of defaults.
+"""On-chip kernel timing harness for two host-vs-device questions.
 
 Reference role: the reference tunes its hot loops by JMH-style
-micro-measurement; here the decisions are (a) whether the Pallas FNV hash
-beats the XLA fori_loop version (tez.runtime.tpu.pallas.hash), (b) whether
-device-side ragged->lanes encode beats the host encode + padded upload
-(tez.runtime.tpu.device.encode).
+micro-measurement; here the measurements are (a) the XLA fori_loop FNV hash
+partition and (b) whether device-side ragged->lanes encode beats the host
+encode + padded upload (tez.runtime.tpu.device.encode).
 
 Run on the target chip:  python -m tez_tpu.tools.profile_kernels [n_rows]
-Prints one JSON line per measurement; exit code 0 always (advisory tool).
+Prints one JSON line carrying the backend it ran on.
 """
 from __future__ import annotations
 
@@ -49,23 +47,9 @@ def main() -> int:
 
     results = {}
 
-    # -- hash: XLA fori_loop vs Pallas ------------------------------------
-    def xla_hash():
-        out = device.hash_partition(mat, lengths, 8, use_pallas=False)
-        return out
-
-    results["hash_xla_s"] = _time(xla_hash)
-    if backend == "tpu":
-        def pallas_hash():
-            return device.hash_partition(mat, lengths, 8, use_pallas=True)
-        try:
-            a, b = xla_hash(), pallas_hash()
-            assert np.array_equal(a, b), "pallas hash diverges from XLA"
-            results["hash_pallas_s"] = _time(pallas_hash)
-            results["pallas_speedup"] = round(
-                results["hash_xla_s"] / results["hash_pallas_s"], 3)
-        except Exception as e:  # noqa: BLE001 — advisory
-            results["hash_pallas_error"] = f"{e!r:.200}"
+    # -- hash: XLA fori_loop ----------------------------------------------
+    results["hash_xla_s"] = _time(
+        lambda: device.hash_partition(mat, lengths, 8))
 
     # -- encode: host pad+pack+upload vs device gather --------------------
     def host_encode():

@@ -15,7 +15,10 @@ DISABLED here anyway — the point is the raw spill path).
 
 Usage:
     python -m tez_tpu.tools.spill_bench --mb 1024 --sort-mb 64 \
-        --out SPILL_r03.json
+        --out chiprun_out/spill.json
+
+chip_smoke.py drives the same DAG at the same size as its proof that the
+main path runs on the chip; this tool adds the C++ proxy comparison.
 """
 from __future__ import annotations
 
@@ -30,26 +33,48 @@ import time
 import numpy as np
 
 
-def make_corpus(path: str, target_mb: int, vocab: int, seed: int = 0
-                ) -> "tuple[int, np.ndarray]":
-    """Zipfian corpus over w<id> words; returns (bytes, counts[vocab])."""
+def make_corpus(path: str, target_mb: int, vocab: int, seed: int = 0,
+                part_bytes: int = 0) -> "tuple[int, np.ndarray]":
+    """Zipfian corpus over w<id> words; returns (bytes, counts[vocab]).
+
+    One file at `path`, or — with `part_bytes` — a directory `path` of
+    part-NNNNN.txt files cut at line ends once they reach that size (the
+    same bytes either way; a machine with a file-size limit refuses a single
+    1 GB file)."""
     rng = np.random.default_rng(seed)
     width = len(str(vocab - 1))
     counts = np.zeros(vocab, dtype=np.int64)
     total = 0
     chunk_words = 1 << 20
     words_per_line = 8192
-    with open(path, "w") as fh:
+    if part_bytes:
+        os.makedirs(path)
+    parts = 0
+    in_part = 0
+    fh = None
+    try:
         while total < target_mb << 20:
             ids = rng.zipf(1.2, chunk_words).astype(np.int64) % vocab
             counts += np.bincount(ids, minlength=vocab)
             chunk = np.char.add("w", np.char.zfill(
                 ids.astype(f"U{width}"), width))
             for s in range(0, len(chunk), words_per_line):
+                if fh is not None and part_bytes and in_part >= part_bytes:
+                    fh.close()
+                    fh = None
+                if fh is None:
+                    fh = open(os.path.join(path, f"part-{parts:05d}.txt")
+                              if part_bytes else path, "w")
+                    parts += 1
+                    in_part = 0
                 text = " ".join(chunk[s:s + words_per_line])
                 fh.write(text)
                 fh.write("\n")
                 total += len(text) + 1
+                in_part += len(text) + 1
+    finally:
+        if fh is not None:
+            fh.close()
     return total, counts
 
 
@@ -68,8 +93,9 @@ def verify_output(out_dir: str, golden_counts: np.ndarray) -> int:
                 w, c = line.rsplit(None, 1)
                 got[int(w[1:])] += int(c)
                 n_lines += 1
-    assert np.array_equal(got, golden_counts), (
-        f"output mismatch: {int((got != golden_counts).sum())} words differ")
+    if not np.array_equal(got, golden_counts):
+        raise ValueError(f"output mismatch: "
+                         f"{int((got != golden_counts).sum())} words differ")
     return n_lines
 
 
@@ -123,32 +149,20 @@ def run(target_mb: int, vocab: int, sort_mb: int, engine: str,
         # the exact machinery this bench stresses.  All-RAM and
         # single-pass (no spill I/O), which makes it a CONSERVATIVE
         # baseline: the reference would also pay disk at this scale.
-        proxy_s = None
-        try:
-            from tez_tpu.ops.native import owc_proxy_counts
-            res = owc_proxy_counts(corpus, parallelism, parallelism,
-                                   combine=False)
-        except (ImportError, OSError) as e:   # availability, never parse
-            print(f"# owc_proxy baseline unavailable: {e}",
-                  file=sys.stderr)
-            res = None
-        if res is not None:
-            proxy_s, counts_by_word = res
-            got = np.zeros_like(golden)
-            for w, cnt in counts_by_word.items():
-                got[int(w[1:])] += cnt
-            if not np.array_equal(got, golden):
-                raise RuntimeError(
-                    "owc_proxy(no-combine) output mismatch vs golden")
+        from tez_tpu.ops.native import owc_proxy_counts
+        proxy_s, counts_by_word = owc_proxy_counts(
+            corpus, parallelism, parallelism, combine=False)
+        got = np.zeros_like(golden)
+        for w, cnt in counts_by_word.items():
+            got[int(w[1:])] += cnt
+        if not np.array_equal(got, golden):
+            raise RuntimeError(
+                "owc_proxy(no-combine) output mismatch vs golden")
+        import jax
+        from tez_tpu.ops.device import backend_platform
         from tez_tpu.ops.sorter import resolve_engine
         resolved = resolve_engine(engine)
-        if engine == "host":
-            # --engine host exists to BYPASS the device stack; querying the
-            # backend just for metadata would block on a stalled PJRT init
-            backend = "(not queried)"
-        else:
-            import jax
-            backend = jax.default_backend()
+        backend = backend_platform()
         return {
             "metric": (f"OrderedWordCount spill-scale E2E ({target_mb} MB "
                        f"input, vocab {vocab}, io.sort.mb={sort_mb}, "
@@ -159,13 +173,13 @@ def run(target_mb: int, vocab: int, sort_mb: int, engine: str,
             "engine_requested": engine,
             "engine_resolved": resolved,
             "jax_backend": backend,
+            "device_kind": jax.devices()[0].device_kind,
             "value": round(nbytes / 1e6 / wall, 2),
             "unit": "MB/s",
-            "vs_baseline": round(proxy_s / wall, 3) if proxy_s else 0.0,
+            "vs_baseline": round(proxy_s / wall, 3),
             "baseline": (f"C++ reference-semantics OrderedWordCount proxy, "
                          f"combine off, all-RAM single-pass (conservative): "
-                         f"{proxy_s:.1f}s on the same corpus"
-                         if proxy_s else "proxy unavailable"),
+                         f"{proxy_s:.1f}s on the same corpus"),
             "wall_seconds": round(wall, 1),
             "corpus_gen_seconds": round(gen_s, 1),
             "verify_seconds": round(verify_s, 1),
@@ -183,8 +197,8 @@ def main() -> int:
     ap.add_argument("--sort-mb", type=int, default=64)
     ap.add_argument("--engine", default="auto",
                     help="auto|device|host sorter engine (auto = device "
-                         "kernels when an accelerator backend answers, "
-                         "host kernels on the CPU fallback)")
+                         "kernels on an accelerator backend, host kernels "
+                         "when JAX_PLATFORMS=cpu was asked for)")
     ap.add_argument("--parallelism", type=int, default=4)
     ap.add_argument("--pipelined", action="store_true",
                     help="one event per spilled span; no producer final "

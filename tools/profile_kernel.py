@@ -1,9 +1,9 @@
-"""Stage-by-stage wall profile of the CPU-fallback kernel headline path.
+"""Stage-by-stage wall profile of the NATIVE HOST engine (no device in it).
 
-Round-5 target: kernel vs_baseline >= 3.0 against the PipelinedSorter-
-semantics C++ proxy (BASELINE.json).  This breaks the native host engine's
-2M-record run into its stages so optimization goes where the time is.
-Run alone on the single bench core (memory: never two benches at once).
+Breaks the host engine's 2M-record span sort + merge into its stages and
+sets them beside the PipelinedSorter-semantics C++ proxy (BASELINE.json).
+Host code only: it pins JAX_PLATFORMS=cpu for itself, spawns nothing, and
+none of its numbers is a device number.
 """
 from __future__ import annotations
 

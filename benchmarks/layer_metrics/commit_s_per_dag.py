@@ -1,0 +1,6 @@
+"""commit_s_per_dag: see commit_s_per_dag.json."""
+import span_metrics
+
+
+def read(obs):
+    return span_metrics.self_s_per_dag(obs, ("output.commit", "am.dag.commit"))

@@ -1,0 +1,6 @@
+"""tokenize_s_per_dag: see tokenize_s_per_dag.json."""
+import span_metrics
+
+
+def read(obs):
+    return span_metrics.self_s_per_dag(obs, ("input.read", "processor.tokenize", "output.write"))

@@ -362,40 +362,3 @@ def test_client_session_expiry(tmp_path):
         if proc.poll() is None:
             proc.terminate()
         proc.wait(timeout=10)
-
-
-def test_task_jax_profile_trace(tmp_path):
-    """tez.task.jax-profile.dir writes a per-attempt XLA profiler trace —
-    the TPU-native per-kernel tracing story (SURVEY.md §5.1)."""
-    from tez_tpu.client.tez_client import TezClient
-    from tez_tpu.common.payload import ProcessorDescriptor
-    from tez_tpu.dag.dag import DAG, Vertex
-    prof = str(tmp_path / "prof")
-    c = TezClient.create("prof", {"tez.staging-dir": str(tmp_path / "s"),
-                                  "tez.task.jax-profile.dir": prof}).start()
-    try:
-
-        dag = DAG.create("profdag").add_vertex(Vertex.create(
-            "v", ProcessorDescriptor.create(ComputeProcessor), 1))
-        # generous: the XLA profiler's first start in a loaded process
-        # can pay tens of seconds of one-time setup (observed flaking
-        # at 60s under full-suite load)
-        st = c.submit_dag(dag).wait_for_completion(timeout=300)
-        assert st.state.name == "SUCCEEDED"
-    finally:
-        c.stop()
-    # one trace dir per attempt, containing xplane protobufs
-    entries = os.listdir(prof)
-    assert any(e.startswith("attempt_") for e in entries), entries
-    found = []
-    for root, _dirs, files in os.walk(prof):
-        found.extend(f for f in files if f.endswith(".xplane.pb"))
-    assert found, "no xplane trace written"
-
-
-class ComputeProcessor(SimpleProcessor):
-    def run(self, inputs, outputs):
-        import jax.numpy as jnp
-        import jax
-        x = jnp.arange(1024, dtype=jnp.float32)
-        jax.block_until_ready(jnp.dot(x, x))

@@ -1,5 +1,6 @@
 """Tracing-plane tests: span runtime, carrier propagation, Perfetto export,
 latency histograms, /metrics surface, and the span critical-path analyzer."""
+import collections
 import json
 import threading
 import typing
@@ -337,3 +338,457 @@ def test_e2e_trace_and_metrics(tmp_path):
     assert "dominant vertex:" in res.headline, res.headline
     assert ("producer" in res.headline) or ("consumer" in res.headline), \
         res.headline
+
+
+# ------------------------------------------- the plane's repairs (ISSUE 26)
+
+def test_same_named_threads_get_distinct_keys_and_self_times():
+    """Thread NAMES repeat (every sorter has a sortmaster_0); Span.thread
+    does not, so a reader that nests spans by thread keeps two threads'
+    self times apart."""
+    import os
+    import sys
+    import time
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        import trace_reduce
+    finally:
+        sys.path.remove(bench)
+    tracing.arm(scope="t")
+    gate = threading.Barrier(2)
+
+    def worker():
+        gate.wait()                       # both alive at once: two idents
+        with tracing.span("outer", cat="x"):
+            with tracing.span("inner", cat="x"):
+                time.sleep(0.02)
+        gate.wait()
+
+    threads = [threading.Thread(target=worker, name="sortmaster_0")
+               for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    spans = tracing.snapshot()
+    keys = {s.thread for s in spans}
+    assert len(keys) == 2
+    assert all(k.startswith("sortmaster_0#") for k in keys)
+    selfs = trace_reduce.self_intervals(
+        [(s.name, s.start, s.end, s.thread) for s in spans])
+    inner = sum(b - a for n, a, b in selfs if n == "inner")
+    outer = sum(b - a for n, a, b in selfs if n == "outer")
+    assert inner >= 0.04 - 1e-3           # two threads' worth, not one
+    # under ONE key the second thread's spans would nest in the first's and
+    # 'outer' would lose or gain a whole 'inner'; apart, outer's self time
+    # is only the few microseconds around each inner
+    assert outer < 0.01
+    # the export shows the readable part
+    from tez_tpu.tools import trace_export
+    names = {e["args"]["name"]
+             for e in trace_export.spans_to_events(spans)
+             if e["name"] == "thread_name"}
+    assert names == {"sortmaster_0"}
+
+
+def test_ring_counts_what_it_evicts():
+    tracing.arm(scope="t", capacity=8)
+    assert tracing.dropped() == 0
+    for i in range(20):
+        with tracing.span(f"s{i}"):
+            pass
+    assert len(tracing.snapshot()) == 8
+    assert tracing.dropped() == 12
+    tracing.clear_all()
+    assert tracing.dropped() == 0
+
+
+NEW_SPAN_SITES = [
+    ("am.task.queue", "am"), ("am.task.done", "am"),
+    ("am.dag.commit", "am"),
+    ("input.wait_splits", "task"), ("input.open", "task"),
+    ("input.read", "task"), ("input.group", "task"),
+    ("processor.tokenize", "task"), ("processor.sum", "task"),
+    ("processor.format", "task"),
+    ("output.write", "task"), ("output.close", "task"),
+    ("output.commit", "task"),
+    ("sort.collect", "sort"), ("sort.flush", "sort"),
+    ("sort.final_merge", "sort"),
+    ("spill.write", "spill"), ("spill.read", "spill"),
+    ("merge.stage", "merge"), ("merge.launch", "merge"),
+    ("merge.readback", "merge"), ("merge.gather", "merge"),
+    ("kernel.merge_path_pair", "kernel"), ("kernel.compile", "kernel"),
+    ("exchange.wait_peers", "exchange"), ("exchange.plan", "exchange"),
+    ("exchange.pack", "exchange"), ("exchange.launch", "exchange"),
+    ("exchange.readback", "exchange"), ("exchange.decode", "exchange"),
+]
+
+
+@pytest.mark.parametrize("name,cat", NEW_SPAN_SITES)
+def test_disarmed_new_sites_are_noop(name, cat):
+    """Every new site goes through span()/start_span()/metrics.timer():
+    disarmed, each is the shared NOOP singleton and records nothing."""
+    assert not tracing.armed()
+    assert tracing.span(name, cat=cat, rows=1) is tracing.NOOP_SPAN
+    assert tracing.start_span(name, cat=cat, lane="am#dag_1") \
+        is tracing.NOOP_SPAN
+    with metrics.timer(name):
+        pass
+    tracing.event(name, rows=1)
+    assert tracing.snapshot() == []
+
+
+def test_disarmed_sorter_and_merge_record_nothing():
+    """The sites themselves, disarmed: a device sort, a flush and a device
+    merge leave the buffer empty (and the always-on counters count)."""
+    from tez_tpu.common.counters import TaskCounter
+    counters, merged = _four_run_merge()
+    assert not tracing.armed()
+    assert tracing.snapshot() == []
+    assert merged.batch.num_records == 1200
+    assert counters.find_counter(TaskCounter.DEVICE_MERGE_LAUNCHES).value > 0
+
+
+def test_timer_opens_a_span_when_armed():
+    tracing.arm(scope="t")
+    with metrics.timer("spill.write"):
+        pass
+    (sp,) = tracing.snapshot()
+    assert (sp.name, sp.cat) == ("spill.write", "spill")
+
+
+def test_span_enters_a_profiler_annotation_once_jax_is_loaded(monkeypatch):
+    """Armed and jax imported: a with-span enters TraceAnnotation("tez." +
+    name); a start_span (no thread, no annotation) does not."""
+    import jax
+    entered = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    tracing.arm(scope="t")
+    with tracing.span("merge.readback", cat="merge"):
+        pass
+    tracing.start_span("am.task.queue", cat="am", lane="am#d").finish()
+    assert entered == [("enter", "tez.merge.readback"),
+                       ("exit", "tez.merge.readback")]
+
+
+def test_start_span_lane_overrides_the_thread_key():
+    tracing.arm(scope="t")
+    sp = tracing.start_span("am.task.queue", cat="am", lane="am#dag_1")
+    sp.finish()
+    assert sp.thread == "am#dag_1"
+    assert tracing.start_span("x").thread == tracing.thread_key()
+
+
+def test_bound_carries_the_callers_context_to_another_thread():
+    """The hand-off rule in one call: a thread started on bound(fn) opens
+    its spans under the caller's; with nothing to carry it is fn itself."""
+    import threading
+
+    def work():
+        with tracing.span("merge.readback", cat="merge"):
+            pass
+
+    assert tracing.bound(work) is work
+    tracing.arm(scope="t")
+    with tracing.span("run", cat="task") as parent:
+        t = threading.Thread(target=tracing.bound(work))
+        t.start()
+        t.join()
+    child = next(s for s in tracing.snapshot() if s.name == "merge.readback")
+    assert (child.trace_id, child.parent_id) == (parent.trace_id,
+                                                 parent.span_id)
+    assert child.thread != parent.thread
+
+
+def test_start_span_can_open_in_the_past():
+    """A wait known only once it is over (exchange.wait_peers): ``start``
+    goes in at the call, armed or not, and never onto NOOP_SPAN."""
+    assert tracing.start_span("exchange.wait_peers", start=1.0) \
+        is tracing.NOOP_SPAN
+    tracing.arm(scope="t")
+    sp = tracing.start_span("exchange.wait_peers", cat="exchange", start=1.0)
+    sp.finish()
+    assert sp.start == 1.0 and sp.end > 1.0
+    assert tracing.snapshot() == [sp]
+
+
+# ------------------------------------------------ launch counters (ISSUE 26)
+
+def _four_run_merge():
+    """Four sorted runs of 300 rows through the device merge-path ladder."""
+    import numpy as np
+    from tez_tpu.ops.runformat import KVBatch, Run
+    from tez_tpu.ops.sorter import merge_sorted_runs
+    runs = []
+    for r in range(4):
+        keys = sorted(f"k{r}{i:05d}".encode() for i in range(300))
+        batch = KVBatch.from_pairs([(k, b"v") for k in keys])
+        runs.append(Run(batch, np.array([0, 300], dtype=np.int64)))
+    counters = TezCounters()
+    merged = merge_sorted_runs(runs, 1, 16, counters=counters,
+                               engine="device", device_min_records=0)
+    return counters, merged
+
+
+def test_merge_launch_rows_are_levels_times_padding():
+    """Worked by hand: 4 runs x 300 rows pad to the common bucket 512.
+    Level 1: two pair merges of 512+512; level 2: one of 1024+1024 — three
+    comparing launches on 4096 rows for 1200 records: 2 levels x (2048 /
+    1200) padding.  With the four prep programs: 7 launches."""
+    from tez_tpu.common.counters import TaskCounter
+    counters, merged = _four_run_merge()
+    c = {t: counters.find_counter(t).value for t in (
+        TaskCounter.DEVICE_MERGE_RECORDS, TaskCounter.DEVICE_MERGE_LAUNCHES,
+        TaskCounter.DEVICE_MERGE_LAUNCH_ROWS)}
+    assert c[TaskCounter.DEVICE_MERGE_RECORDS] == 1200
+    assert c[TaskCounter.DEVICE_MERGE_LAUNCH_ROWS] == 2 * 4 * 512
+    assert c[TaskCounter.DEVICE_MERGE_LAUNCHES] == 4 + 3
+    assert c[TaskCounter.DEVICE_MERGE_LAUNCH_ROWS] / \
+        c[TaskCounter.DEVICE_MERGE_RECORDS] == 2 * (4 * 512 / 1200)
+    keys = [merged.batch.key(i) for i in range(merged.batch.num_records)]
+    assert keys == sorted(keys)
+
+
+# ------------------------------------------- cause across threads (ISSUE 26)
+
+def _chains_end_in(spans, root):
+    by_id = {s.span_id: s for s in spans}
+    bad = []
+    for s in spans:
+        cur, hops = s, 0
+        while cur.parent_id is not None and hops < 64:
+            if cur.parent_id not in by_id:
+                bad.append((s.name, "unresolved parent"))
+                break
+            cur, hops = by_id[cur.parent_id], hops + 1
+        else:
+            if cur is not root:
+                bad.append((s.name, f"chain ends in {cur.name}"))
+    return bad
+
+
+@pytest.mark.parametrize("pipeline_depth,sort_threads", [(2, 0), (0, 1)])
+def test_sorter_worker_threads_inherit_the_submitters_context(
+        pipeline_depth, sort_threads):
+    """The async pipeline's staging/readback threads and the sortmaster
+    executor: spans they record hang under the span that was current where
+    the work was handed over."""
+    from tez_tpu.ops.sorter import DeviceSorter
+    tracing.arm(scope="t")
+    with tracing.span("attempt:x", cat="task") as root:
+        sorter = DeviceSorter(num_partitions=2, engine="device",
+                              device_min_records=0,
+                              span_budget_bytes=4096,
+                              pipeline_depth=pipeline_depth,
+                              sort_threads=sort_threads)
+        for i in range(600):
+            sorter.write(f"k{i % 97:05d}".encode(), b"v")
+        sorter.flush()
+    spans = tracing.snapshot()
+    assert {s.trace_id for s in spans} == {root.trace_id}
+    assert _chains_end_in(spans, root) == []
+    off_thread = {s.name for s in spans if s.thread != root.thread}
+    if pipeline_depth:
+        assert {"device.encode", "device.h2d", "device.dispatch",
+                "device.d2h"} <= off_thread
+    else:
+        assert any(n.startswith("kernel.") for n in off_thread)
+    assert any(s.thread.startswith(("sorter-pipeline", "sortmaster"))
+               for s in spans)
+
+
+def _owc_corpus(tmp_path, words_per_file=100_000, files=4):
+    import random
+    rng = random.Random(26)
+    paths = []
+    for i in range(files):
+        p = tmp_path / f"in{i}.txt"
+        with open(p, "w") as fh:
+            for _ in range(words_per_file // 100):
+                fh.write(" ".join(f"w{rng.randrange(50_000):07d}"
+                                  for _ in range(100)) + "\n")
+        paths.append(str(p))
+    return paths
+
+
+@pytest.fixture(scope="module")
+def traced_owc(tmp_path_factory):
+    """One OrderedWordCount DAG, device engine forced, spans small enough
+    that tokenizers sort several, spill and final-merge; traced."""
+    from tez_tpu.client.tez_client import TezClient
+    from tez_tpu.examples.ordered_wordcount import build_dag
+    from tez_tpu.am.history import HistoryEventType
+    tmp_path = tmp_path_factory.mktemp("owc")
+    tracing.clear_all()
+    conf = {"tez.staging-dir": str(tmp_path / "s"),
+            "tez.runner.mode": "threads",
+            "tez.runtime.sorter.class": "device",
+            "tez.runtime.tpu.device.sort.min.records": 0,
+            "tez.runtime.io.sort.mb": 1,
+            "tez.runtime.tpu.host.spill.dir": str(tmp_path / "spill"),
+            "tez.trace.enabled": True, "tez.trace.buffer.spans": 262144}
+    client = TezClient.create("traced-owc", conf, session=True).start()
+    try:
+        dag = build_dag(_owc_corpus(tmp_path), str(tmp_path / "out"),
+                        tokenizer_parallelism=4, summation_parallelism=4,
+                        sorter_parallelism=1, combine=False,
+                        tokenizer_mode="vector", exchange="host")
+        status = client.submit_dag(dag).wait_for_completion(timeout=300)
+        finished = client.framework_client.am.logging_service.of_type(
+            HistoryEventType.DAG_FINISHED)
+    finally:
+        client.stop()
+    spans = tracing.snapshot()
+    dropped = tracing.dropped()
+    tracing.clear_all()
+    return status, finished, spans, dropped
+
+
+def test_owc_every_span_hangs_under_the_dag_root(traced_owc):
+    status, _finished, spans, dropped = traced_owc
+    assert status.state.name == "SUCCEEDED" and dropped == 0
+    (root,) = [s for s in spans if s.cat == "dag"]
+    assert root.name == "dag:OrderedWordCount"
+    assert {s.trace_id for s in spans} == {root.trace_id}
+    assert _chains_end_in(spans, root) == []
+    names = {s.name.split(":")[0] for s in spans}
+    # the worker-thread spans are the point: staging/readback threads,
+    # merges on reduce-side threads, the AM's lane
+    assert {"device.encode", "device.h2d", "device.dispatch", "device.d2h",
+            "sort.collect", "sort.flush", "sort.final_merge",
+            "merge.stage", "merge.launch", "merge.readback", "merge.gather",
+            "spill.write", "spill.read", "kernel.merge_path_pair",
+            "input.open", "input.read", "input.group",
+            "processor.tokenize", "processor.sum", "processor.format",
+            "output.write", "output.close", "output.commit",
+            "am.task.queue", "am.task.done", "am.dag.commit",
+            "am.vertex", "am.dag.finish"} <= names
+    worker = {s.name for s in spans
+              if s.thread.startswith("sorter-pipeline")}
+    assert {"device.encode", "device.d2h"} <= worker
+    # the AM's open spans stand on the DAG's lane, the commit on its thread
+    lanes = {s.name.split(":")[0]: s.thread for s in spans if s.cat in
+             ("am", "dag")}
+    assert lanes["dag"] == lanes["am.task.queue"] \
+        == f"am#{root.args['dag_id']}"
+    # what brackets work without doing any is a point, not a span: a
+    # reader that asks what the threads did must not answer with it
+    points = [s for s in spans if s.name in ("am.vertex", "am.dag.finish")]
+    assert {s.cat for s in points} == {"instant"}
+    assert [s.args["state"] for s in points
+            if s.args.get("vertex") == "tokenizer"] == ["STARTED",
+                                                        "SUCCEEDED"]
+    assert sum(s.name == "am.dag.finish" for s in points) == 1
+    (commit,) = [s for s in spans if s.name == "am.dag.commit"]
+    assert all(s.thread == commit.thread and s.parent_id == commit.span_id
+               for s in spans if s.name == "output.commit")
+    # the budget: nowhere near one span a record
+    assert len(spans) < 4000
+
+
+def test_owc_span_names_are_the_documented_vocabulary(traced_owc):
+    import os
+    from tests.trace_schema import undocumented_spans
+    _status, _finished, spans, _dropped = traced_owc
+    doc = open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "observability.md")).read()
+    assert undocumented_spans({s.name for s in spans}, doc) == set()
+    assert undocumented_spans({"made.up"}, doc) == {"made.up"}
+
+
+def test_dag_status_time_taken_is_the_events_local(traced_owc):
+    status, finished, _spans, _dropped = traced_owc
+    (event,) = finished
+    assert status.time_taken == event.data["time_taken"]
+    assert status.time_taken > 0
+
+
+def test_mesh_exchange_spans_hang_under_the_dag_root(tmp_path):
+    from tez_tpu.client.tez_client import TezClient
+    from tez_tpu.examples.ordered_wordcount import build_dag
+    conf = {"tez.staging-dir": str(tmp_path / "s"),
+            "tez.runner.mode": "threads",
+            "tez.runtime.sorter.class": "device",
+            "tez.runtime.tpu.device.sort.min.records": 0,
+            "tez.trace.enabled": True}
+    client = TezClient.create("traced-mesh", conf, session=True).start()
+    try:
+        dag = build_dag(_owc_corpus(tmp_path, words_per_file=5000),
+                        str(tmp_path / "out"), tokenizer_parallelism=4,
+                        summation_parallelism=4, sorter_parallelism=1,
+                        combine=False, tokenizer_mode="vector",
+                        exchange="mesh")
+        status = client.submit_dag(dag).wait_for_completion(timeout=300)
+    finally:
+        client.stop()
+    assert status.state.name == "SUCCEEDED"
+    spans = tracing.snapshot()
+    (root,) = [s for s in spans if s.cat == "dag"]
+    assert _chains_end_in(spans, root) == []
+    names = collections.Counter(s.name for s in spans)
+    assert {"exchange.wait_peers", "exchange.plan", "exchange.pack",
+            "exchange.launch", "exchange.readback", "exchange.decode",
+            "shuffle.wait"} <= set(names)
+    assert names["exchange.wait_peers"] == 4       # one a producer
+    readers = [s for s in spans if s.name == "exchange.readback"
+               and s.thread.startswith("mesh-exchange-read-")]
+    assert len(readers) >= 4 and \
+        all(s.trace_id == root.trace_id for s in readers)
+    waits = [s for s in spans if s.name == "exchange.wait_peers"]
+    assert len({s.thread for s in waits}) == 4     # a lane each
+    assert all(s.end >= s.start for s in waits)
+
+
+def test_dag_status_time_taken_over_the_wire(tmp_path):
+    """The remote client reads the number the AM's DAG_FINISHED event holds,
+    through the DAGClientServer socket protocol."""
+    from tez_tpu.am.app_master import DAGAppMaster
+    from tez_tpu.am.client_server import DAGClientServer
+    from tez_tpu.am.history import HistoryEventType
+    from tez_tpu.client.tez_client import TezClient
+    from tez_tpu.common import config as C
+    from tez_tpu.common.ids import new_app_id
+    from tez_tpu.common.payload import ProcessorDescriptor
+    from tez_tpu.common.security import JobTokenSecretManager
+    from tez_tpu.dag.dag import DAG, Vertex
+    token = JobTokenSecretManager().secret.hex()
+    am = DAGAppMaster(new_app_id(), C.TezConfiguration({
+        "tez.staging-dir": str(tmp_path / "stg"),
+        "tez.runner.mode": "threads", "tez.am.local.num-containers": 2,
+        "tez.job.token": token}))
+    am.start()
+    server = DAGClientServer(am, am.secrets, host="127.0.0.1",
+                             port=0).start()
+    try:
+        client = TezClient.create("remote", {
+            "tez.framework.mode": "remote",
+            "tez.am.address": f"127.0.0.1:{server.port}",
+            "tez.job.token": token}).start()
+        try:
+            dag = DAG.create("wire").add_vertex(Vertex.create(
+                "v", ProcessorDescriptor.create(
+                    "tez_tpu.library.processors:SleepProcessor",
+                    payload={"sleep_ms": 20}), 2))
+            status = client.submit_dag(dag).wait_for_completion(timeout=60)
+        finally:
+            client.stop()
+        (event,) = am.logging_service.of_type(HistoryEventType.DAG_FINISHED)
+    finally:
+        server.stop()
+        am.stop()
+    assert status.state.name == "SUCCEEDED"
+    assert status.time_taken == event.data["time_taken"]
+    assert status.time_taken >= 0.02

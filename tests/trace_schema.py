@@ -48,3 +48,39 @@ def check_trace(trace: Dict[str, Any]) -> int:
     for i, ev in enumerate(events):
         check_event(ev, i)
     return len(events)
+
+
+# ---------------------------------------------------------------------------
+# the closed span vocabulary (docs/observability.md "Span vocabulary")
+# ---------------------------------------------------------------------------
+
+def documented_span_names(doc_text: str) -> set:
+    """Names in the first column of the vocabulary table: every back-quoted
+    name of a row's first cell, with ``:<...>`` / ``<...>`` parts cut
+    (``dag:<name>`` -> ``dag``, ``kernel.<Kernel.name>`` -> ``kernel.``)."""
+    import re
+    section = doc_text.split("### Span vocabulary", 1)[1]
+    section = section.split("\n### ", 1)[0]
+    names = set()
+    for line in section.splitlines():
+        if not line.startswith("| ") or line.startswith("| span |") or \
+                line.startswith("|---"):
+            continue
+        first = line.split("|")[1]
+        for name in re.findall(r"`([^`]+)`", first):
+            names.add(name.split(":", 1)[0].split("<", 1)[0])
+    return names
+
+
+def undocumented_spans(span_names, doc_text: str) -> set:
+    """Span names (as recorded) that the vocabulary table does not hold.
+    A documented name that ends in ``.`` is a prefix (``kernel.``)."""
+    documented = documented_span_names(doc_text)
+    prefixes = tuple(n for n in documented if n.endswith("."))
+    missing = set()
+    for raw in span_names:
+        name = raw.split(":", 1)[0]
+        if name in documented or (prefixes and name.startswith(prefixes)):
+            continue
+        missing.add(name)
+    return missing

@@ -454,6 +454,8 @@ class DAGAppMaster:
         from tez_tpu.common import tracing
         sp = getattr(dag, "trace_span", None)
         if sp is not None:
+            # the root ends where the client's wait can return: all that
+            # is left below is the notify
             sp.annotate(final_state=final.name)
             sp.finish()
         tracing.clear(str(dag.dag_id))
@@ -559,7 +561,7 @@ class DAGAppMaster:
         from tez_tpu.common import tracing
         if tracing.install_from_conf(dag.conf, scope=str(dag_id)):
             sp = tracing.start_span(
-                f"dag:{plan.name}", cat="dag",
+                f"dag:{plan.name}", cat="dag", lane=dag.trace_lane,
                 dag_id=str(dag_id), am_epoch=self.attempt)
             dag.trace_span = sp
             dag.trace_carrier = sp.context.carrier()

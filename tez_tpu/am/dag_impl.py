@@ -11,7 +11,7 @@ import enum
 import logging
 from typing import Any, Dict, List, Optional, Sequence, TYPE_CHECKING
 
-from tez_tpu.common import clock
+from tez_tpu.common import clock, tracing
 from tez_tpu.am.edge import EdgeImpl
 from tez_tpu.am.events import (DAGEvent, DAGEventType, VertexEvent,
                                VertexEventType)
@@ -42,8 +42,29 @@ TERMINAL_DAG_STATES = frozenset(
     {DAGState.SUCCEEDED, DAGState.FAILED, DAGState.KILLED, DAGState.ERROR})
 
 
+def am_span(ctx: Any, dag_id: DAGId, name: str, **args: Any) -> Any:
+    """``start_am_span`` of DAG `dag_id` under the AM `ctx`, for AM parts
+    that hold an id and not the DAG (scheduler, task communicator); NOOP
+    when the plane is disarmed or the DAG unknown."""
+    find = getattr(ctx, "find_dag", None)
+    if find is None or not tracing.armed():
+        return tracing.NOOP_SPAN
+    dag = find(dag_id, include_retired=True)
+    if dag is None:
+        return tracing.NOOP_SPAN
+    return dag.start_am_span(name, **args)
+
+
 class DAGImpl:
     _factory: StateMachineFactory = None
+    #: span plane (set by app_master.submit_dag when tez.trace.enabled):
+    #: the open root span and its carrier for TaskSpecs
+    trace_span: Any = None
+    trace_carrier = ""
+    _commit_span: Any = tracing.NOOP_SPAN
+    #: seconds, DAG_STARTED -> DAG_FINISHED on the AM's clock: the value the
+    #: DAG_FINISHED event holds; None until the DAG has finished
+    time_taken: Optional[float] = None
 
     def __init__(self, dag_id: DAGId, plan: DAGPlan, ctx: Any,
                  recovery_data: Any = None):
@@ -70,6 +91,31 @@ class DAGImpl:
         self._committed = False
         self._state_update_registry: Dict[str, List[Any]] = {}
         self.sm = self._factory.make(self)
+
+    @property
+    def trace_lane(self) -> str:
+        """The row the AM's open spans of this DAG stand on (root,
+        am.task.queue, am.task.done): they belong to no thread, and nest
+        among themselves there."""
+        return f"am#{self.dag_id}"
+
+    def start_am_span(self, name: str, **args: Any) -> Any:
+        """An AM span of this DAG: child of the root span, on the DAG's
+        lane.  NOOP where this DAG is not traced (another DAG may have
+        armed the plane: a span here would be a root of its own)."""
+        if self.trace_span is None:
+            return tracing.NOOP_SPAN
+        return tracing.start_span(name, cat="am", parent=self.trace_span,
+                                  lane=self.trace_lane, **args)
+
+    def am_instant(self, name: str, **args: Any) -> None:
+        """A point on this DAG's trace (a vertex started or finished, the
+        last vertex done).  A bracket from one such point to the next
+        would be a span that waits and does no work, and a reader that
+        asks what the threads were doing (the benchmark's idle-gap labels)
+        would answer with it: so these are points, not spans."""
+        if self.trace_span is not None:
+            tracing.event(name, parent=self.trace_span, **args)
 
     @property
     def state(self) -> DAGState:
@@ -179,6 +225,9 @@ class DAGImpl:
                 f"vertex {event.vertex_name} failed")
             self._terminate_vertices("DAG failing: vertex failed")
         if self.completed_vertices == len(self.vertices):
+            # last vertex finished; the root span ends where the client's
+            # wait can return (app_master.on_dag_finished)
+            self.am_instant("am.dag.finish")
             return self._finish()
         return DAGState.RUNNING
 
@@ -203,11 +252,19 @@ class DAGImpl:
         # ledger record 1/2: COMMIT_STARTED is fsync'd (summary event,
         # synchronous ctx.history) BEFORE any committer mutates the
         # filesystem — the write-ahead half of the two-phase commit
-        self.ctx.history(HistoryEvent(
-            HistoryEventType.DAG_COMMIT_STARTED, dag_id=str(self.dag_id),
-            data={"dag_name": self.name}))
+        commit_span = self._commit_span = self.start_am_span(
+            "am.dag.commit", committers=len(committers))
+        # attached: the ledger's fsync event hangs under the commit
+        with tracing.attached(commit_span.context):
+            self.ctx.history(HistoryEvent(
+                HistoryEventType.DAG_COMMIT_STARTED, dag_id=str(self.dag_id),
+                data={"dag_name": self.name}))
 
         def _commit() -> None:
+            # the commit stands on the thread that runs the committers, so
+            # that their output.commit spans come off its self time
+            if commit_span is not tracing.NOOP_SPAN:
+                commit_span.thread = tracing.thread_key()
             from tez_tpu.common import epoch as epoch_registry
             from tez_tpu.common import faults
             from tez_tpu.common.epoch import EpochFencedError
@@ -239,7 +296,8 @@ class DAGImpl:
                                            self.dag_id, succeeded=False,
                                            diagnostics=repr(e)))
 
-        self.ctx.submit_to_executor(_commit)
+        self.ctx.submit_to_executor(
+            tracing.bound(_commit, commit_span.context))
         return DAGState.COMMITTING
 
     def _collect_committers(self) -> List[Any]:
@@ -252,6 +310,13 @@ class DAGImpl:
         return out
 
     def _on_commit_completed(self, event: DAGEvent) -> DAGState:
+        try:
+            with tracing.attached(self._commit_span.context):
+                return self._end_commit(event)
+        finally:
+            self._commit_span.finish()
+
+    def _end_commit(self, event: DAGEvent) -> DAGState:
         self.finish_time = clock.wall_s()
         if getattr(event, "fenced", False):
             # A superseded incarnation owns nothing anymore: it must not
@@ -341,11 +406,12 @@ class DAGImpl:
         self.counters = TezCounters()
         for v in self.vertices.values():
             self.counters.aggregate(v.counters)
+        self.time_taken = self.finish_time - (self.start_time or
+                                              self.finish_time)
         self.ctx.history(HistoryEvent(
             HistoryEventType.DAG_FINISHED, dag_id=str(self.dag_id),
             data={"dag_name": self.name, "state": final.name,
-                  "time_taken": self.finish_time - (self.start_time or
-                                                    self.finish_time),
+                  "time_taken": self.time_taken,
                   "diagnostics": "; ".join(self.diagnostics),
                   "counters": self.counters.to_dict()}))
         self.ctx.on_dag_finished(self, final)
@@ -397,6 +463,7 @@ class DAGImpl:
             "name": self.name, "state": self.state.name,
             "progress": (succeeded / total) if total else 0.0,
             "diagnostics": list(self.diagnostics),
+            "time_taken": self.time_taken,
             "vertices": {v.name: v.status_dict()
                          for v in self.vertices.values()},
         }

@@ -19,6 +19,7 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 from tez_tpu.api.events import TezAPIEvent, TezEvent
 from tez_tpu.am.events import (TaskAttemptEvent, TaskAttemptEventType,
                                VertexEvent, VertexEventType)
+from tez_tpu.am.dag_impl import am_span
 from tez_tpu.common import clock, epoch as epoch_registry
 from tez_tpu.common import faults, tracing
 from tez_tpu.common.counters import TezCounters
@@ -223,10 +224,16 @@ class TaskCommunicatorManager:
         if self._fenced(epoch, f"task_done {attempt_id}",
                         window_id=window_id, stream=stream):
             return
+        # task_done received -> the attempt's transition made and the
+        # task's queued (TaskAttemptImpl._on_done ends it, on the
+        # dispatcher): what the AM's event loop adds to a task's end
+        span = am_span(self.ctx, attempt_id.dag_id, "am.task.done",
+                       attempt=str(attempt_id))
         if events:
             self._route_events(attempt_id, events)
         self.ctx.dispatch(TaskAttemptEvent(
-            TaskAttemptEventType.TA_DONE, attempt_id, counters=counters))
+            TaskAttemptEventType.TA_DONE, attempt_id, counters=counters,
+            trace_span=span))
         self._drop_session(attempt_id)
 
     def task_failed(self, attempt_id: TaskAttemptId, diagnostics: str,

@@ -145,6 +145,9 @@ class TaskAttemptImpl:
                                     self.attempt_id.task_id,
                                     attempt_id=self.attempt_id))
         self._notify_scheduler_ended()
+        span = getattr(event, "trace_span", None)   # am.task.done
+        if span is not None:
+            span.finish()
 
     def _on_failed(self, event: TaskAttemptEvent) -> None:
         self.finish_time = clock.wall_s()

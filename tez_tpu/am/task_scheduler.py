@@ -19,7 +19,8 @@ import logging
 import threading
 from typing import Any, Dict, List, Optional, Set, Tuple, TYPE_CHECKING
 
-from tez_tpu.common import clock
+from tez_tpu.am.dag_impl import am_span
+from tez_tpu.common import clock, metrics, tracing
 from tez_tpu.am.events import (SchedulerEvent, SchedulerEventType,
                                TaskAttemptEvent, TaskAttemptEventType)
 from tez_tpu.common.ids import ContainerId, TaskAttemptId
@@ -64,6 +65,7 @@ class LocalTaskSchedulerService(TaskSchedulerService):
         self._heap: List[Any] = []
         self._seq = itertools.count()
         self._queued: Dict[TaskAttemptId, float] = {}   # -> enqueue time
+        self._queue_spans: Dict[TaskAttemptId, Any] = {}  # am.task.queue
         self._priorities: Dict[TaskAttemptId, int] = {}
         self._running: Dict[TaskAttemptId, ContainerId] = {}
         self._preempting: Set[TaskAttemptId] = set()
@@ -113,6 +115,12 @@ class LocalTaskSchedulerService(TaskSchedulerService):
             heapq.heappush(self._heap,
                            (priority, next(self._seq), attempt_id, task_spec))
             self._queued[attempt_id] = clock.wall_s()
+            if tracing.armed():
+                # scheduled -> a runner picks it up (get_task ends it, on
+                # the runner's thread); on the DAG's lane, under the root
+                self._queue_spans[attempt_id] = am_span(
+                    self.ctx, attempt_id.dag_id, "am.task.queue",
+                    attempt=str(attempt_id))
             self._priorities[attempt_id] = priority
             self._queued_tenant[attempt_id] = tenant
             self._tenant_queued[tenant] = \
@@ -236,6 +244,7 @@ class LocalTaskSchedulerService(TaskSchedulerService):
         with self._lock:
             if self._queued.pop(attempt_id, None) is not None:
                 self._drop_queued_tenant_locked(attempt_id)
+                self._queue_spans.pop(attempt_id, None)  # never picked up
             self._preempting.discard(attempt_id)
             self._priorities.pop(attempt_id, None)
             container = self._running.pop(attempt_id, None)
@@ -317,7 +326,9 @@ class LocalTaskSchedulerService(TaskSchedulerService):
                 if handout is not None:
                     prio, seq, attempt_id, spec = handout
                     tenant = self._queued_tenant.get(attempt_id, "")
-                    self._queued.pop(attempt_id, None)
+                    queued_at = self._queued.pop(attempt_id, None)
+                    queue_span = self._queue_spans.pop(attempt_id,
+                                                       tracing.NOOP_SPAN)
                     self._drop_queued_tenant_locked(attempt_id)
                     self._running[attempt_id] = container_id
                     self._vertex_running[attempt_id.vertex_id] = \
@@ -326,11 +337,18 @@ class LocalTaskSchedulerService(TaskSchedulerService):
                         # charge whichever tenant actually got the slot
                         d = self._tenant_deficit.get(tenant, 0.0)
                         self._tenant_deficit[tenant] = max(0.0, d - 1.0)
-                    return spec
+                    break
                 if self._shutdown:
                     return None
                 if not self._available.wait(timeout):
                     return None
+        # picked up, on the runner's thread and outside the lock: the wait
+        # from schedule() is over
+        if queued_at is not None:
+            metrics.observe("am.task.queue_wait",
+                            (clock.wall_s() - queued_at) * 1000.0)
+        queue_span.finish()
+        return spec
 
     def _drr_pick_locked(self) -> Optional[str]:
         """Next tenant owed a slot (deficit round-robin): visiting a tenant

@@ -387,6 +387,7 @@ class VertexImpl:
 
     def _do_start(self) -> VertexState:
         self.start_time = clock.wall_s()
+        self.dag.am_instant("am.vertex", vertex=self.name, state="STARTED")
         self.ctx.history(HistoryEvent(
             HistoryEventType.VERTEX_STARTED,
             dag_id=str(self.vertex_id.dag_id), vertex_id=str(self.vertex_id),
@@ -608,6 +609,8 @@ class VertexImpl:
                   "time_taken": self.finish_time - (self.start_time or
                                                     self.finish_time),
                   "counters": self.counters.to_dict()}))
+        self.dag.am_instant("am.vertex", vertex=self.name,
+                            state="SUCCEEDED")
         # a finished source is definitionally fully scheduled: release any
         # controlled downstream holdback (covers 0-task sources, which never
         # emit the schedule-time signal)
@@ -667,6 +670,7 @@ class VertexImpl:
             dag_id=str(self.vertex_id.dag_id), vertex_id=str(self.vertex_id),
             data={"vertex_name": self.name, "state": final,
                   "diagnostics": "; ".join(self.diagnostics)}))
+        self.dag.am_instant("am.vertex", vertex=self.name, state=final)
         self.dag.on_vertex_completed(
             self, VertexState[final] if final in VertexState.__members__
             else VertexState.FAILED)

@@ -65,6 +65,9 @@ class DAGStatus:
     vertex_status: Dict[str, VertexStatus]
     diagnostics: List[str]
     counters: Optional[TezCounters] = None
+    #: seconds the AM took, DAG_STARTED -> DAG_FINISHED on its own clock
+    #: (the DAG_FINISHED event's ``time_taken``); None until it finished
+    time_taken: Optional[float] = None
 
     @property
     def is_completed(self) -> bool:
@@ -102,7 +105,7 @@ class DAGClient:
                                                    DAGStatusState.SUBMITTED),
             progress=raw.get("progress", 0.0),
             vertex_status=vs, diagnostics=raw.get("diagnostics", []),
-            counters=counters)
+            counters=counters, time_taken=raw.get("time_taken"))
 
     def wait_for_completion(self, timeout: Optional[float] = None,
                             poll: float = 0.05) -> DAGStatus:

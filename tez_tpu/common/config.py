@@ -486,10 +486,6 @@ GROUPING_MIN_SIZE = _key(
 GROUPING_MAX_SIZE = _key(
     "tez.grouping.max-size", 1024 * 1024 * 1024, Scope.VERTEX,
     "Upper bound on average grouped-split size")
-TASK_JAX_PROFILE_DIR = _key(
-    "tez.task.jax-profile.dir", "", Scope.VERTEX,
-    "Write a per-task-attempt XLA profiler trace (TensorBoard/Perfetto) "
-    "under this dir; '' disables (the TPU-native per-kernel tracing story)")
 AM_WEB_ENABLED = _key("tez.am.web.enabled", False, Scope.AM,
                       "Serve the live status endpoint (AMWebController analog)")
 AM_WEB_PORT = _key("tez.am.web.port", 0, Scope.AM, "0 = ephemeral")

@@ -115,11 +115,13 @@ class TaskCounter(enum.Enum):
     HOST_SORT_RECORDS = enum.auto()
     DEVICE_MERGE_RECORDS = enum.auto()
     HOST_MERGE_RECORDS = enum.auto()
-    DEVICE_EXCHANGE_MILLIS = enum.auto()
-    HBM_BYTES_ALLOCATED = enum.auto()
     HOST_SPILL_BYTES = enum.auto()
-    H2D_TRANSFER_BYTES = enum.auto()
-    D2H_TRANSFER_BYTES = enum.auto()
+    # launches and padded rows of the device programs behind a merge
+    # (ops/sorter.py _record_launches): every program it launched; the
+    # rows, sentinels included, its comparing programs ran on — over
+    # DEVICE_MERGE_RECORDS that is ladder levels x padding
+    DEVICE_MERGE_LAUNCHES = enum.auto()
+    DEVICE_MERGE_LAUNCH_ROWS = enum.auto()
 
 
 # Mesh ICI exchange plane (parallel/coordinator.py): string-named counters

@@ -24,6 +24,7 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Mapping, Optional, Tuple
 
+from tez_tpu.common import tracing as _tracing
 from tez_tpu.obs import flight as _flight
 
 # Upper bounds of the finite buckets, in milliseconds: 1, 2, 4 ... 65536.
@@ -151,6 +152,10 @@ WELL_KNOWN_HISTOGRAMS = ("shuffle.fetch.rtt", "spill.write", "shuffle.merge",
                          # QUEUE-verdict submission parks before the consumer
                          # promotes it to a running DAG
                          "am.admit.queue_wait",
+                         # task scheduling (am/task_scheduler.py): a task
+                         # attempt scheduled -> a runner thread picks it up
+                         # (the am.task.queue span's duration)
+                         "am.task.queue_wait",
                          # flight recorder (obs/flight.py): one snapshot
                          # serialize + atomic write when a dump trigger
                          # (DAG failure, breaker-open, watchdog, shed) fires
@@ -228,10 +233,13 @@ def observe(name: str, ms: float, counters: Any = None) -> None:
 
 @contextmanager
 def timer(name: str, counters: Any = None) -> Iterator[None]:
-    """Time a block and observe() its duration in milliseconds."""
+    """Time a block and observe() its duration in milliseconds.  With the
+    span plane armed the block is also a span of the same name (cat = the
+    name's first component), so every timed site is a span site."""
     t0 = time.perf_counter()
     try:
-        yield
+        with _tracing.span(name, cat=name.split(".", 1)[0]):
+            yield
     finally:
         observe(name, (time.perf_counter() - t0) * 1000.0, counters)
 

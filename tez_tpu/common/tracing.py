@@ -18,7 +18,19 @@ Design rules (mirroring common/faults.py):
   production run that never arms tracing pays one attribute load per call
   and allocates nothing.
 - Bounded in-memory ring buffer (``collections.deque(maxlen=...)``) —
-  a runaway DAG evicts its oldest spans instead of eating the heap.
+  a runaway DAG evicts its oldest spans instead of eating the heap, and
+  ``dropped()`` says how many it evicted: a reader that needs every span
+  of a window (benchmarks/span_metrics.py) refuses a buffer that lost any.
+- Cause crosses threads by hand: whoever gives work to another thread
+  captures ``current_context()`` at the hand-off and the worker runs under
+  ``attached(ctx)`` (or opens its spans with ``parent=ctx``).  A span opened
+  on a thread with no context is a root with a fresh trace id, tied to no
+  DAG — docs/observability.md "Starting a thread".
+- One clock: spans stamp ``time.time()`` (epoch seconds, the realtime clock
+  the XLA profiler stamps with).  A span used as a context manager also
+  enters a ``jax.profiler.TraceAnnotation("tez." + name)`` when jax is
+  already imported, so the program's spans stand in the profiler's own
+  file, on the profiler's clock, beside ``XLA Modules``.
 
 Carrier format is W3C trace-context shaped (``00-<trace_id>-<span_id>-01``)
 so the strings stamped into TaskSpec / heartbeats stay greppable and could
@@ -26,7 +38,9 @@ interop with a real OTLP exporter later.
 """
 from __future__ import annotations
 
+import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -74,16 +88,25 @@ def _gen_span_id() -> str:
     return os.urandom(8).hex()
 
 
+def thread_key() -> str:
+    """``Span.thread`` of a span opened on this thread now."""
+    return f"{threading.current_thread().name}#{threading.get_ident()}"
+
+
 # --------------------------------------------------------------------------
 # Spans
 # --------------------------------------------------------------------------
 
 class Span:
     """One timed unit of work.  start/end are epoch seconds (time.time) so
-    spans recorded on different threads/processes align on one axis."""
+    spans recorded on different threads/processes align on one axis.
+    ``thread`` is ``<thread name>#<ident>``: names repeat (every sorter has
+    a ``sortmaster_0``), and a reader that nests spans by thread needs a
+    key that does not."""
 
     __slots__ = ("name", "cat", "trace_id", "span_id", "parent_id",
-                 "start", "end", "args", "events", "thread", "_recorded")
+                 "start", "end", "args", "events", "thread", "_recorded",
+                 "_annotation", "_seq")
 
     def __init__(self, name: str, cat: str, trace_id: str,
                  parent_id: Optional[str], args: Dict[str, Any]) -> None:
@@ -96,8 +119,10 @@ class Span:
         self.end: Optional[float] = None
         self.args = args
         self.events: List[Tuple[float, str, Dict[str, Any]]] = []
-        self.thread = threading.current_thread().name
+        self.thread = thread_key()
         self._recorded = False
+        self._annotation: Any = None
+        self._seq = -1            # order of recording (TracePlane.record)
 
     # -- annotation -------------------------------------------------------
     def annotate(self, **kv: Any) -> "Span":
@@ -133,9 +158,20 @@ class Span:
     # -- context-manager protocol (pushes onto the thread-local stack) ----
     def __enter__(self) -> "Span":
         _stack().append(self)
+        # the twin in the profiler's own trace: only once jax is loaded
+        # (the AM process must not import it), a no-op TraceMe while no
+        # profiler session is active
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(
+                "tez." + self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -229,14 +265,28 @@ def span(name: str, cat: str = "", parent: Any = None, **args: Any):
     return Span(name, cat, trace_id, parent_id, args)
 
 
-def start_span(name: str, cat: str = "", parent: Any = None, **args: Any):
+def start_span(name: str, cat: str = "", parent: Any = None,
+               lane: Optional[str] = None, start: Optional[float] = None,
+               **args: Any):
     """Start a span WITHOUT touching the thread-local stack — for
     long-lived / cross-thread spans (e.g. the DAG root span the AM holds
-    open until on_dag_finished).  Caller must invoke .finish()."""
+    open until on_dag_finished).  Caller must invoke .finish().
+
+    Such a span belongs to no thread, and a reader that nests spans by
+    ``Span.thread`` would bill it against whatever its starting thread did
+    meanwhile: ``lane`` names the row it stands on instead (the AM keeps the
+    root, queue and task.done spans of one DAG on ``am#<dag_id>``, so they
+    nest among themselves).  ``start`` (epoch seconds) opens it in the
+    past: a wait that is known only once it is over."""
     if not _armed:
         return NOOP_SPAN
     trace_id, parent_id = _resolve_parent(parent)
-    return Span(name, cat, trace_id, parent_id, args)
+    sp = Span(name, cat, trace_id, parent_id, args)
+    if lane is not None:
+        sp.thread = lane
+    if start is not None:
+        sp.start = start
+    return sp
 
 
 def event(name: str, parent: Any = None, **attrs: Any) -> None:
@@ -297,6 +347,23 @@ def attached(parent: Any) -> Iterator[Optional[TraceContext]]:
         _TLS.ambient = prev
 
 
+def bound(fn: Any, ctx: Optional[TraceContext] = None) -> Any:
+    """`fn`, to be run on another thread under `ctx` (this thread's context
+    of now, unless one captured earlier is given): what a hand-off to an
+    executor or a new thread passes in place of `fn`
+    (docs/observability.md "Starting a thread").  `fn` itself where there
+    is no context to carry — always, while nothing is traced."""
+    if ctx is None:
+        ctx = current_context()
+    if ctx is None:
+        return fn
+
+    def run(*args: Any, **kwargs: Any) -> Any:
+        with attached(ctx):
+            return fn(*args, **kwargs)
+    return run
+
+
 # --------------------------------------------------------------------------
 # The plane (arming + ring buffer)
 # --------------------------------------------------------------------------
@@ -308,6 +375,7 @@ class TracePlane:
         self._lock = threading.Lock()
         self._scopes: set = set()
         self._buf: Optional[deque] = None
+        self._seq = itertools.count()    # next() is atomic: no lock to record
 
     def install(self, scope: str,
                 capacity: int = DEFAULT_BUFFER_SPANS) -> None:
@@ -333,12 +401,24 @@ class TracePlane:
         with self._lock:
             self._scopes.clear()
             self._buf = None
+            self._seq = itertools.count()
             _armed = False
 
     def record(self, sp: Span) -> None:
+        # lock-free: spans finish under other modules' locks, and deque
+        # appends and count steps are atomic
         buf = self._buf
         if buf is not None:
-            buf.append(sp)       # deque.append with maxlen is atomic
+            sp._seq = next(self._seq)
+            buf.append(sp)
+
+    def dropped(self) -> int:
+        """Spans evicted from the ring since clear_all: those recorded
+        (the highest sequence number held, plus one) less those held."""
+        held = self.snapshot()
+        if not held:
+            return 0
+        return max(sp._seq for sp in held) + 1 - len(held)
 
     def snapshot(self) -> List[Span]:
         buf = self._buf
@@ -376,6 +456,11 @@ def clear_all() -> None:
 
 def snapshot() -> List[Span]:
     return _PLANE.snapshot()
+
+
+def dropped() -> int:
+    """How many spans the ring evicted: 0 means snapshot() is complete."""
+    return _PLANE.dropped()
 
 
 def install_from_conf(conf: Any, scope: str) -> bool:

@@ -14,6 +14,7 @@ from typing import Dict
 
 from tez_tpu.api.runtime import LogicalInput, LogicalOutput
 from tez_tpu.client.tez_client import TezClient
+from tez_tpu.common import tracing
 from tez_tpu.common.payload import (InputDescriptor,
                                     InputInitializerDescriptor,
                                     OutputCommitterDescriptor,
@@ -63,7 +64,9 @@ class VectorTokenProcessor(SimpleProcessor):
             agg = WordCountAggregator.create()
             try:
                 for chunk in reader.iter_chunks():
-                    agg.feed(bytes(chunk))
+                    with tracing.span("processor.tokenize", cat="task",
+                                      bytes=len(chunk)):
+                        agg.feed(bytes(chunk))
                 key_bytes, key_offsets, counts = agg.emit()
             finally:
                 agg.close()
@@ -83,15 +86,18 @@ class VectorTokenProcessor(SimpleProcessor):
 
         from tez_tpu.ops.native import split_ws_native
         for chunk in reader.iter_chunks():
-            # one C pass (GIL released): compacted word bytes + offsets
-            key_bytes, key_offsets = split_ws_native(bytes(chunk))
-            n = len(key_offsets) - 1
-            if n == 0:
-                continue
-            val_bytes = np.frombuffer(one * n, dtype=np.uint8).copy()
-            val_offsets = np.arange(n + 1, dtype=np.int64) * len(one)
-            writer.write_batch(KVBatch(key_bytes, key_offsets,
-                                       val_bytes, val_offsets))
+            with tracing.span("processor.tokenize", cat="task",
+                              bytes=len(chunk)):
+                # one C pass (GIL released): compacted word bytes + offsets
+                key_bytes, key_offsets = split_ws_native(bytes(chunk))
+                n = len(key_offsets) - 1
+                if n == 0:
+                    continue
+                val_bytes = np.frombuffer(one * n, dtype=np.uint8).copy()
+                val_offsets = np.arange(n + 1, dtype=np.int64) * len(one)
+                batch = KVBatch(key_bytes, key_offsets, val_bytes,
+                                val_offsets)
+            writer.write_batch(batch)
 
 
 class SumProcessor(SimpleProcessor):
@@ -117,15 +123,17 @@ class SumProcessor(SimpleProcessor):
                 if n == 0:
                     continue
                 if bool(np.all(np.diff(batch.val_offsets) == 8)):
-                    decoded = decode_longs_be(batch.val_bytes, n)
-                    sums = np.add.reduceat(decoded, starts)
-                    words_b, words_o = gather_ragged(
-                        batch.key_bytes, batch.key_offsets, starts)
-                    key_bytes = encode_longs_be(sums)
-                    key_offsets = np.arange(len(sums) + 1,
-                                            dtype=np.int64) * 8
-                    writer.write_batch(KVBatch(key_bytes, key_offsets,
-                                               words_b, words_o))
+                    with tracing.span("processor.sum", cat="task", rows=n):
+                        decoded = decode_longs_be(batch.val_bytes, n)
+                        sums = np.add.reduceat(decoded, starts)
+                        words_b, words_o = gather_ragged(
+                            batch.key_bytes, batch.key_offsets, starts)
+                        key_bytes = encode_longs_be(sums)
+                        key_offsets = np.arange(len(sums) + 1,
+                                                dtype=np.int64) * 8
+                        out = KVBatch(key_bytes, key_offsets, words_b,
+                                      words_o)
+                    writer.write_batch(out)
                 else:
                     # mixed-width values (non-long serde): per-record via
                     # the reader's OWN serdes for this block only — groups
@@ -174,24 +182,28 @@ class NoOpSorterProcessor(SimpleProcessor):
                             word = reader.val_serde.from_bytes(batch.value(i))
                             writer.write(word, str(count))
                     continue
-                counts = decode_longs_be(batch.key_bytes, n)
-                tails = [sep + b"%d\n" % int(counts[s]) for s in starts]
-                tail_bytes = np.frombuffer(b"".join(tails), dtype=np.uint8)
-                tail_lens = np.array([len(t) for t in tails],
-                                     dtype=np.int64)
-                pool_bytes = np.concatenate([batch.val_bytes, tail_bytes])
-                pool_offsets = np.concatenate([
-                    batch.val_offsets,
-                    batch.val_offsets[-1] + np.cumsum(tail_lens)])
-                # record i -> rows (word_i, tail_of_group(i))
-                group_of = np.zeros(n, dtype=np.int64)
-                group_of[starts[1:]] = 1
-                group_of = np.cumsum(group_of)
-                perm = np.empty(2 * n, dtype=np.int64)
-                perm[0::2] = np.arange(n)
-                perm[1::2] = n + group_of
-                lines, _ = gather_ragged(pool_bytes, pool_offsets, perm)
-                writer.write_raw(lines.tobytes(), n)
+                with tracing.span("processor.format", cat="task", rows=n):
+                    counts = decode_longs_be(batch.key_bytes, n)
+                    tails = [sep + b"%d\n" % int(counts[s]) for s in starts]
+                    tail_bytes = np.frombuffer(b"".join(tails),
+                                               dtype=np.uint8)
+                    tail_lens = np.array([len(t) for t in tails],
+                                         dtype=np.int64)
+                    pool_bytes = np.concatenate([batch.val_bytes,
+                                                 tail_bytes])
+                    pool_offsets = np.concatenate([
+                        batch.val_offsets,
+                        batch.val_offsets[-1] + np.cumsum(tail_lens)])
+                    # record i -> rows (word_i, tail_of_group(i))
+                    group_of = np.zeros(n, dtype=np.int64)
+                    group_of[starts[1:]] = 1
+                    group_of = np.cumsum(group_of)
+                    perm = np.empty(2 * n, dtype=np.int64)
+                    perm[0::2] = np.arange(n)
+                    perm[1::2] = n + group_of
+                    lines, _ = gather_ragged(pool_bytes, pool_offsets, perm)
+                    data = lines.tobytes()
+                writer.write_raw(data, n)
             return
         for count, words in reader:
             for word in words:

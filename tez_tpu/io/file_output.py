@@ -14,7 +14,7 @@ from tez_tpu.api.events import TezAPIEvent
 from tez_tpu.api.initializer import OutputCommitter
 from tez_tpu.api.runtime import KeyValueWriter, LogicalOutput, Writer
 from tez_tpu.common import epoch as epoch_registry
-from tez_tpu.common import faults
+from tez_tpu.common import faults, tracing
 from tez_tpu.common.counters import FileSystemCounter, TaskCounter
 from tez_tpu.common.epoch import EpochFencedError
 from tez_tpu.ops.serde import get_serde
@@ -52,9 +52,10 @@ class _PartWriter(KeyValueWriter):
     def write_raw(self, data: bytes, n_records: int) -> None:
         """Pre-formatted record bytes (separators/newlines included) from a
         vectorized consumer — one write call for the whole block."""
-        self._fh.write(data)
-        self._records_ctr.increment(n_records)
-        self._bytes_ctr.increment(len(data))
+        with tracing.span("output.write", cat="task", rows=n_records):
+            self._fh.write(data)
+            self._records_ctr.increment(n_records)
+            self._bytes_ctr.increment(len(data))
 
     def close(self) -> None:
         self._fh.close()
@@ -132,34 +133,36 @@ class FileOutputCommitter(OutputCommitter):
                 f"{detail}")
 
     def commit_output(self) -> None:
-        tmp = os.path.join(self.out_dir, TMP_SUBDIR)
-        success = os.path.join(self.out_dir, "_SUCCESS")
-        if not os.path.isdir(tmp):
-            # tmp tree already gone: a prior incarnation finished publishing
-            # and was interrupted at (or after) the _SUCCESS marker — roll
-            # forward by (re)writing the marker, nothing else to do
+        with tracing.span("output.commit", cat="task", path=self.out_dir):
+            tmp = os.path.join(self.out_dir, TMP_SUBDIR)
+            success = os.path.join(self.out_dir, "_SUCCESS")
+            if not os.path.isdir(tmp):
+                # tmp tree already gone: a prior incarnation finished
+                # publishing and was interrupted at (or after) the _SUCCESS
+                # marker — roll forward by (re)writing the marker, nothing
+                # else to do
+                self._fence("_SUCCESS")
+                with open(success, "w"):
+                    pass
+                return
+            committed = os.path.join(tmp, "committed")
+            if os.path.isdir(committed):
+                with open(os.path.join(tmp, PUBLISH_MANIFEST), "a") as mf:
+                    for f in sorted(os.listdir(committed)):
+                        # fault point FIRST (delay mode parks the commit
+                        # right here), so a zombie held mid-commit re-checks
+                        # the fence when it wakes
+                        faults.fire("commit.publish", detail=f)
+                        self._fence(f)
+                        mf.write(f + "\n")
+                        mf.flush()
+                        os.fsync(mf.fileno())
+                        os.replace(os.path.join(committed, f),
+                                   os.path.join(self.out_dir, f))
             self._fence("_SUCCESS")
+            shutil.rmtree(tmp, ignore_errors=True)
             with open(success, "w"):
                 pass
-            return
-        committed = os.path.join(tmp, "committed")
-        if os.path.isdir(committed):
-            with open(os.path.join(tmp, PUBLISH_MANIFEST), "a") as mf:
-                for f in sorted(os.listdir(committed)):
-                    # fault point FIRST (delay mode parks the commit right
-                    # here), so a zombie held mid-commit re-checks the fence
-                    # when it wakes
-                    faults.fire("commit.publish", detail=f)
-                    self._fence(f)
-                    mf.write(f + "\n")
-                    mf.flush()
-                    os.fsync(mf.fileno())
-                    os.replace(os.path.join(committed, f),
-                               os.path.join(self.out_dir, f))
-        self._fence("_SUCCESS")
-        shutil.rmtree(tmp, ignore_errors=True)
-        with open(success, "w"):
-            pass
 
     def abort_output(self, final_state: str) -> None:
         """Roll back a (possibly partial) commit: un-publish every file the

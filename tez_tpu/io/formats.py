@@ -21,6 +21,7 @@ from tez_tpu.api.events import InputDataInformationEvent, TezAPIEvent
 from tez_tpu.api.initializer import (InputConfigureVertexTasksEvent,
                                      InputInitializer)
 from tez_tpu.api.runtime import KeyValueReader, LogicalInput, Reader
+from tez_tpu.common import tracing
 from tez_tpu.common.counters import FileSystemCounter, TaskCounter
 
 
@@ -100,7 +101,9 @@ class _LineReader(KeyValueReader):
         read_ops = self.context.counters.find_counter(
             FileSystemCounter.FILE_READ_OPS)
         for split in self.splits:
-            with open(split.path, "rb") as fh:
+            with tracing.span("input.open", cat="task", path=split.path):
+                fh = open(split.path, "rb")
+            with fh:
                 read_ops.increment()
                 fh.seek(split.start)
                 pos = split.start
@@ -111,14 +114,16 @@ class _LineReader(KeyValueReader):
                 end = split.start + split.length
                 while pos <= end:
                     want = min(chunk_bytes, end - pos + 1)
-                    chunk = fh.read(want)
+                    with tracing.span("input.read", cat="task", bytes=want):
+                        chunk = fh.read(want)
+                        if chunk and not chunk.endswith(b"\n"):
+                            # extend to the line boundary (the line STARTING
+                            # at or before `end` belongs to this split in
+                            # full)
+                            tail = fh.readline()
+                            chunk += tail
                     if not chunk:
                         break
-                    if not chunk.endswith(b"\n"):
-                        # extend to the line boundary (the line STARTING at
-                        # or before `end` belongs to this split in full)
-                        tail = fh.readline()
-                        chunk += tail
                     pos += len(chunk)
                     bytes_read.increment(len(chunk))
                     self.context.notify_progress()
@@ -378,12 +383,17 @@ class MRInput(LogicalInput):
 
     def _wait_splits(self) -> None:
         import time
+        if self._has_split_event:
+            return
+        # the task is up before its root-input event: it polls until a
+        # heartbeat brings the splits (device idle meanwhile: a named wait)
         deadline = time.time() + 60
-        while not self._has_split_event:
-            if time.time() > deadline:
-                raise TimeoutError("no split event received")
-            time.sleep(0.01)
-            self.context.notify_progress()
+        with tracing.span("input.wait_splits", cat="task"):
+            while not self._has_split_event:
+                if time.time() > deadline:
+                    raise TimeoutError("no split event received")
+                time.sleep(0.01)
+                self.context.notify_progress()
 
     def get_reader(self) -> Reader:
         self._wait_splits()

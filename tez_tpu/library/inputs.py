@@ -136,7 +136,7 @@ class ShuffleFetchTable:
                     epoch=getattr(ctx, "am_epoch", 0),
                     app_id=getattr(ctx, "app_id", ""))
             self._scheduler = FetchScheduler(
-                deliver=self._remote_done,
+                deliver=tracing.bound(self._remote_done, self._trace),
                 session_factory=factory,
                 num_fetchers=int(_k(C.SHUFFLE_PARALLEL_COPIES)),
                 max_per_fetch=int(_k(C.SHUFFLE_FETCH_MAX_TASK_OUTPUT_AT_ONCE)),
@@ -244,6 +244,13 @@ class ShuffleFetchTable:
 
     def on_payload(self, slot: int, partition: int, payload: ShufflePayload,
                    version: int = 0) -> None:
+        # event delivery runs on the heartbeat thread: whatever it opens
+        # (fetch, an oversized batch's spill write) is this task's
+        with tracing.attached(self._trace):
+            self._on_payload(slot, partition, payload, version)
+
+    def _on_payload(self, slot: int, partition: int,
+                    payload: ShufflePayload, version: int = 0) -> None:
         mm = self.merge_manager
         with self.lock:
             s = self.slots[slot]
@@ -600,19 +607,21 @@ class GroupedKVReader(KeyValuesReader):
         n = batch.num_records
         if n == 0:
             return np.zeros(0, dtype=np.int64)
-        if key_normalizer is not None:
-            # comparator-equality grouping (e.g. case-insensitive): adjacent
-            # keys with equal NORMALIZED forms form one group — materialize
-            # the normalized keys once, then the same vectorized path
-            from tez_tpu.ops.sorter import normalize_batch_keys
-            kb, ko = normalize_batch_keys(batch, key_normalizer)
-        else:
-            kb, ko = batch.key_bytes, batch.key_offsets
-        lengths = ko[1:] - ko[:-1]
-        same = np.zeros(n, dtype=bool)
-        cand = np.flatnonzero(lengths[1:] == lengths[:-1])
-        same[cand + 1] = adjacent_equal_rows(kb, ko, cand)
-        return np.flatnonzero(~same).astype(np.int64)
+        with tracing.span("input.group", cat="task", rows=n):
+            if key_normalizer is not None:
+                # comparator-equality grouping (e.g. case-insensitive):
+                # adjacent keys with equal NORMALIZED forms form one group —
+                # materialize the normalized keys once, then the same
+                # vectorized path
+                from tez_tpu.ops.sorter import normalize_batch_keys
+                kb, ko = normalize_batch_keys(batch, key_normalizer)
+            else:
+                kb, ko = batch.key_bytes, batch.key_offsets
+            lengths = ko[1:] - ko[:-1]
+            same = np.zeros(n, dtype=bool)
+            cand = np.flatnonzero(lengths[1:] == lengths[:-1])
+            same[cand + 1] = adjacent_equal_rows(kb, ko, cand)
+            return np.flatnonzero(~same).astype(np.int64)
 
     def __iter__(self) -> Iterator[Tuple[Any, Iterator[Any]]]:
         n = self.batch.num_records

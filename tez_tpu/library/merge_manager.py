@@ -32,6 +32,7 @@ from typing import Any, Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from tez_tpu.common import tracing
 from tez_tpu.common.counters import TaskCounter, TezCounters
 from tez_tpu.ops.block_merge import iter_merged_blocks
 from tez_tpu.ops.runformat import (ChunkedRunWriter, KVBatch, Run,
@@ -148,7 +149,10 @@ class ShuffleMergeManager:
                 breaker, watchdog_dispatch_ms, watchdog_readback_ms)
         self._merger: Optional[threading.Thread] = None
         if self.budget > 0:
-            self._merger = threading.Thread(target=self._merge_loop,
+            # the merger thread (and, through it, the async merge lane)
+            # works for the task that built this manager
+            self._merger = threading.Thread(target=tracing.bound(
+                                                self._merge_loop),
                                             daemon=True,
                                             name="shuffle-merger")
             self._merger.start()
@@ -585,11 +589,13 @@ class ShuffleMergeManager:
     def _write_chunked(self, runs: Sequence[Run]) -> str:
         path = os.path.join(self.spill_dir,
                             f"mmerge_{uuid.uuid4().hex}.crun")
-        w = ChunkedRunWriter(path, codec=self.codec,
-                             block_records=self.block_records)
-        for r in runs:
-            w.append(r.batch)
-        w.close()
+        from tez_tpu.common import metrics
+        with metrics.timer("spill.write"):
+            w = ChunkedRunWriter(path, codec=self.codec,
+                                 block_records=self.block_records)
+            for r in runs:
+                w.append(r.batch)
+            w.close()
         self.counters.increment(TaskCounter.ADDITIONAL_SPILLS_BYTES_WRITTEN,
                                 w.bytes_written)
         return path

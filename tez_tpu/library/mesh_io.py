@@ -33,6 +33,7 @@ from tez_tpu.api.events import (CompositeDataMovementEvent,
                                 VertexManagerEvent)
 from tez_tpu.api.runtime import (KeyValuesWriter, LogicalInput, LogicalOutput,
                                  Writer)
+from tez_tpu.common import tracing
 from tez_tpu.common.counters import TaskCounter
 from tez_tpu.library.inputs import GroupedKVReader
 from tez_tpu.library.util import conf_get as _conf_get
@@ -104,12 +105,14 @@ class MeshOrderedPartitionedKVOutput(LogicalOutput):
 
             def write_batch(self, batch: KVBatch) -> None:
                 """Batch-first path: pre-serialized records."""
-                output._batches.append(batch)
-                output.context.counters.increment(
-                    TaskCounter.OUTPUT_RECORDS, batch.num_records)
-                output.context.counters.increment(
-                    TaskCounter.OUTPUT_BYTES, batch.nbytes)
-                output.context.notify_progress()
+                with tracing.span("output.write", cat="task",
+                                  rows=batch.num_records):
+                    output._batches.append(batch)
+                    output.context.counters.increment(
+                        TaskCounter.OUTPUT_RECORDS, batch.num_records)
+                    output.context.counters.increment(
+                        TaskCounter.OUTPUT_BYTES, batch.nbytes)
+                    output.context.notify_progress()
 
         return _W()
 
@@ -120,9 +123,11 @@ class MeshOrderedPartitionedKVOutput(LogicalOutput):
         from tez_tpu.parallel.coordinator import mesh_coordinator
         ctx = self.context
         parts = list(self._batches)
-        if self._pairs:
-            parts.append(KVBatch.from_pairs(self._pairs))
-        batch = KVBatch.concat(parts) if parts else KVBatch.empty()
+        with tracing.span("exchange.pack", cat="exchange", stage="concat",
+                          batches=len(parts)):
+            if self._pairs:
+                parts.append(KVBatch.from_pairs(self._pairs))
+            batch = KVBatch.concat(parts) if parts else KVBatch.empty()
         self._pairs = []
         self._batches = []
         edge = _edge_id(ctx.task_attempt_id.dag_id, ctx.vertex_name,
@@ -254,16 +259,19 @@ class MeshOrderedGroupedKVInput(LogicalInput):
             import time
             ctx = self.context
             t0 = time.time()
-            remaining = self._wait_complete()
             from tez_tpu.parallel.coordinator import mesh_coordinator
             edge = _edge_id(ctx.task_attempt_id.dag_id,
                             ctx.source_vertex_name, ctx.vertex_name)
-            batch = mesh_coordinator().wait_consumer(
-                edge, ctx.task_index,
-                num_producers=self.num_physical_inputs,
-                num_consumers=ctx.vertex_parallelism,
-                timeout=remaining,
-                progress=ctx.notify_progress)
+            # the consumer's wait for its input, as on the host shuffle:
+            # every producer's event, then the exchange itself
+            with tracing.span("shuffle.wait", cat="shuffle", edge="mesh"):
+                remaining = self._wait_complete()
+                batch = mesh_coordinator().wait_consumer(
+                    edge, ctx.task_index,
+                    num_producers=self.num_physical_inputs,
+                    num_consumers=ctx.vertex_parallelism,
+                    timeout=remaining,
+                    progress=ctx.notify_progress)
             with self._lock:
                 if self._failed:
                     raise RuntimeError(self._failed)

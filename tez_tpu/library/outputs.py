@@ -15,6 +15,7 @@ from tez_tpu.api.events import (CompositeDataMovementEvent, ShufflePayload,
                                 TezAPIEvent, VertexManagerEvent,
                                 pack_empty_partitions)
 from tez_tpu.api.runtime import KeyValuesWriter, LogicalOutput, Writer
+from tez_tpu.common import tracing
 from tez_tpu.common.counters import TaskCounter
 from tez_tpu.ops.runformat import Run
 from tez_tpu.ops.serde import get_serde
@@ -84,10 +85,12 @@ class _SorterWriter(KeyValuesWriter):
             raise ValueError("write_batch requires the stock hash "
                              "partitioner (custom Partitioner sees logical "
                              "records)")
-        self.sorter.write_batch(batch)
-        self.context.counters.increment(TaskCounter.OUTPUT_BYTES,
-                                        batch.nbytes)
-        self.context.notify_progress()
+        with tracing.span("output.write", cat="task",
+                          rows=batch.num_records):
+            self.sorter.write_batch(batch)
+            self.context.counters.increment(TaskCounter.OUTPUT_BYTES,
+                                            batch.nbytes)
+            self.context.notify_progress()
 
 
 class OrderedPartitionedKVOutput(LogicalOutput):

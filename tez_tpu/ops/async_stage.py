@@ -371,11 +371,16 @@ class _Group:
     """One dispatch unit: one span, or several coalesced small spans."""
 
     __slots__ = ("ids", "payloads", "staged", "inflight", "t_dispatch",
-                 "claimed", "gate_held")
+                 "claimed", "gate_held", "ctx")
 
-    def __init__(self, ids: List[Any], payloads: List[Any]) -> None:
+    def __init__(self, ids: List[Any], payloads: List[Any],
+                 ctx: Any = None) -> None:
         self.ids = ids
         self.payloads = payloads
+        #: trace context of the thread that submitted the (first) span:
+        #: the staging, readback and monitor threads attach it, so their
+        #: spans hang under the submitter's, not under fresh roots
+        self.ctx = ctx
         self.staged: Any = None
         self.inflight: Any = None
         self.t_dispatch = 0.0
@@ -496,7 +501,7 @@ class AsyncSpanPipeline:
 
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
-        self._pending: "collections.deque[Tuple[Any, Any, bool]]" = \
+        self._pending: "collections.deque[Tuple[Any, Any, bool, Any]]" = \
             collections.deque()
         self._in_flight = 0          # groups past the staging gate
         self._open_spans = 0         # submitted, not yet completed
@@ -565,7 +570,8 @@ class AsyncSpanPipeline:
                     f"{self._name}: pipeline failed") from self._error
             if self._closed:
                 raise RuntimeError(f"{self._name}: submit after drain")
-            self._pending.append((span_id, payload, coalesce))
+            self._pending.append((span_id, payload, coalesce,
+                                  tracing.current_context()))
             self._open_spans += 1
             self.stats.submitted += 1
             self._cv.notify_all()
@@ -625,13 +631,13 @@ class AsyncSpanPipeline:
                 if self._closed:
                     return None
                 self._cv.wait(timeout=0.5)
-            span_id, payload, coalesce = self._pending.popleft()
+            span_id, payload, coalesce, ctx = self._pending.popleft()
             ids, payloads = [span_id], [payload]
             if coalesce and self._coalesce_fn is not None and \
                     self.coalesce_records > 0:
                 total = self._records_fn(payload)
                 while self._pending:
-                    nid, npay, nco = self._pending[0]
+                    nid, npay, nco, _ctx = self._pending[0]
                     if not nco:
                         break
                     nrec = self._records_fn(npay)
@@ -643,7 +649,7 @@ class AsyncSpanPipeline:
                     total += nrec
                 if len(ids) > 1:
                     self.stats.coalesced_groups += 1
-            return _Group(ids, payloads)
+            return _Group(ids, payloads, ctx)
 
     def _gate_acquire(self, group: _Group) -> None:
         """The dispatch-ahead bound: wait until fewer than ``depth`` groups
@@ -700,76 +706,86 @@ class AsyncSpanPipeline:
             if group is None:
                 return
             ids = tuple(group.ids)
+            with tracing.attached(group.ctx):
+                if not self._stage_group(group, ids):
+                    return
+
+    def _stage_group(self, group: _Group, ids: Tuple[Any, ...]) -> bool:
+        """Encode, stage and dispatch one group; False when the staging
+        thread has to stop (pipeline failed, or the watchdog took the
+        queue)."""
+        try:
+            # The gate is taken BEFORE encode: depth bounds everything
+            # past raw payloads, so host staging memory (padded
+            # matrices + lane arrays) is bounded by depth spans too.
+            self._gate_acquire(group)
+            if self._error is not None:
+                self._gate_release(group)
+                return False
+            if self._breaker is not None and \
+                    not self._breaker.allow_device():
+                # breaker open: the device engine is sick — route the
+                # group straight to the host engine, never touch the
+                # chip
+                _count(self._counters, "device.breaker.short_circuits",
+                       len(ids))
+                self._claim(group)
+                self._failover_group(group, ids, reason="breaker-open")
+                return True
+            t0 = self._mark(ids, STAGE_ENCODE, "start")
+            with tracing.span(STAGE_ENCODE, cat="device",
+                              spans=repr(list(ids))):
+                staged = [self._encode_fn(p) for p in group.payloads]
+            t1 = self._mark(ids, STAGE_ENCODE, "end")
+            self._observe(STAGE_ENCODE, t0, t1)
+            one = staged[0] if len(staged) == 1 else \
+                self._coalesce_fn(staged)
+            t0 = self._mark(ids, STAGE_H2D, "start")
+            with tracing.span(STAGE_H2D, cat="device",
+                              spans=repr(list(ids))):
+                if self._stage_fn is not None:
+                    one = self._stage_fn(one)
+            t1 = self._mark(ids, STAGE_H2D, "end")
+            self._observe(STAGE_H2D, t0, t1)
+            t_d = self._mark(ids, STAGE_DISPATCH, "start")
+            self._watch_begin(group, ids, STAGE_DISPATCH,
+                              self._watchdog_dispatch_ms)
             try:
-                # The gate is taken BEFORE encode: depth bounds everything
-                # past raw payloads, so host staging memory (padded
-                # matrices + lane arrays) is bounded by depth spans too.
-                self._gate_acquire(group)
-                if self._error is not None:
-                    self._gate_release(group)
-                    return
-                if self._breaker is not None and \
-                        not self._breaker.allow_device():
-                    # breaker open: the device engine is sick — route the
-                    # group straight to the host engine, never touch the
-                    # chip
-                    _count(self._counters, "device.breaker.short_circuits",
-                           len(ids))
-                    self._claim(group)
-                    self._failover_group(group, ids, reason="breaker-open")
-                    continue
-                t0 = self._mark(ids, STAGE_ENCODE, "start")
-                with tracing.span(STAGE_ENCODE, cat="device",
-                                  spans=repr(list(ids))):
-                    staged = [self._encode_fn(p) for p in group.payloads]
-                t1 = self._mark(ids, STAGE_ENCODE, "end")
-                self._observe(STAGE_ENCODE, t0, t1)
-                one = staged[0] if len(staged) == 1 else \
-                    self._coalesce_fn(staged)
-                t0 = self._mark(ids, STAGE_H2D, "start")
-                with tracing.span(STAGE_H2D, cat="device",
-                                  spans=repr(list(ids))):
-                    if self._stage_fn is not None:
-                        one = self._stage_fn(one)
-                t1 = self._mark(ids, STAGE_H2D, "end")
-                self._observe(STAGE_H2D, t0, t1)
-                t_d = self._mark(ids, STAGE_DISPATCH, "start")
-                self._watch_begin(group, ids, STAGE_DISPATCH,
-                                  self._watchdog_dispatch_ms)
-                try:
-                    # chaos seams: an injected hang (delay mode) sits
-                    # inside the watch window like a stuck XLA dispatch;
-                    # an injected OOM drives the split/fallback ladder
-                    if faults.armed():
-                        for sid in ids:
-                            faults.fire("device.dispatch.oom",
-                                        f"span={sid}")
-                            faults.fire("device.dispatch.hang",
-                                        f"span={sid}")
-                    with tracing.span(STAGE_DISPATCH, cat="device",
-                                      spans=repr(list(ids))), \
-                            _compile_listener(functools.partial(
-                                self._watch_compile, group, ids)):
-                        inflight = self._dispatch_fn(one)
-                finally:
-                    self._watch_end(group)
-                self._mark(ids, STAGE_DISPATCH, "end")
-                if group.claimed:
-                    # the watchdog abandoned this dispatch while we were
-                    # stuck in it and already failed the group over; our
-                    # late result is dead and so is this thread's queue
-                    # (the monitor owns _pending once _wedged is set)
-                    return
-                group.staged = None
-                group.inflight = inflight
-                group.t_dispatch = t_d
-                with self._lock:
-                    self.stats.dispatched += 1
-                self._readback.submit(self._readback_one, group, ids)
-            except BaseException as e:  # noqa: BLE001 — surfaces via drain
-                self._contain_failure(group, ids, e)
-                if self._error is not None:
-                    return
+                # chaos seams: an injected hang (delay mode) sits
+                # inside the watch window like a stuck XLA dispatch;
+                # an injected OOM drives the split/fallback ladder
+                if faults.armed():
+                    for sid in ids:
+                        faults.fire("device.dispatch.oom",
+                                    f"span={sid}")
+                        faults.fire("device.dispatch.hang",
+                                    f"span={sid}")
+                with tracing.span(STAGE_DISPATCH, cat="device",
+                                  spans=repr(list(ids))), \
+                        _compile_listener(functools.partial(
+                            self._watch_compile, group, ids)):
+                    inflight = self._dispatch_fn(one)
+            finally:
+                self._watch_end(group)
+            self._mark(ids, STAGE_DISPATCH, "end")
+            if group.claimed:
+                # the watchdog abandoned this dispatch while we were
+                # stuck in it and already failed the group over; our
+                # late result is dead and so is this thread's queue
+                # (the monitor owns _pending once _wedged is set)
+                return False
+            group.staged = None
+            group.inflight = inflight
+            group.t_dispatch = t_d
+            with self._lock:
+                self.stats.dispatched += 1
+            self._readback.submit(tracing.bound(self._readback_one),
+                                  group, ids)
+        except BaseException as e:  # noqa: BLE001 — surfaces via drain
+            self._contain_failure(group, ids, e)
+            if self._error is not None:
+                return False
+        return True
 
     # -- readback workers ----------------------------------------------------
     def _readback_one(self, group: _Group, ids: Tuple[Any, ...]) -> None:
@@ -865,9 +881,11 @@ class AsyncSpanPipeline:
         a failover failure is final (poisons the pipeline)."""
         try:
             t0 = self._mark(ids, STAGE_FAILOVER, "start")
-            tracing.event("device.failover", spans=repr(list(ids)),
-                          reason=reason)
-            with tracing.span(STAGE_FAILOVER, cat="device",
+            # parent given by hand: the watchdog's monitor thread lands here
+            # with no context of its own
+            tracing.event("device.failover", parent=group.ctx,
+                          spans=repr(list(ids)), reason=reason)
+            with tracing.span(STAGE_FAILOVER, cat="device", parent=group.ctx,
                               spans=repr(list(ids)), reason=reason):
                 result = self._failover_fn(ids, group.payloads)
             t1 = self._mark(ids, STAGE_FAILOVER, "end")
@@ -938,7 +956,7 @@ class AsyncSpanPipeline:
                "device.watchdog.dispatch_fires"
                if stage == STAGE_DISPATCH else
                "device.watchdog.readback_fires")
-        tracing.event("device.watchdog.fired", stage=stage,
+        tracing.event("device.watchdog.fired", parent=group.ctx, stage=stage,
                       spans=repr(list(ids)))
         _flight.record(_flight.WATCHDOG, stage, a=len(ids))
         _flight.auto_dump(f"device.watchdog.{stage}")
@@ -960,8 +978,8 @@ class AsyncSpanPipeline:
             with self._cv:
                 if not self._pending:
                     return
-                span_id, payload, _co = self._pending.popleft()
-            group = _Group([span_id], [payload])
+                span_id, payload, _co, ctx = self._pending.popleft()
+            group = _Group([span_id], [payload], ctx)
             group.claimed = True
             _count(self._counters, "device.failover.drained")
             self._failover_group(group, (span_id,), reason="staging-wedged")

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tez_tpu.common import tracing
 from tez_tpu.ops import compile_cache    # importing it places the cache
 
 FNV_OFFSET = np.uint32(2166136261)
@@ -108,6 +109,31 @@ def compile_listener(fn: Callable[[bool], None]) -> Iterator[None]:
         _compile_tls.fn = prev
 
 
+_launch_tls = threading.local()
+
+
+@contextlib.contextmanager
+def launch_tally() -> Iterator[Dict[str, List[int]]]:
+    """While active on this thread, every kernel launch the thread makes
+    lands in the yielded dict as ``{Kernel.name: [launches, rows]}``, rows
+    being what the program was launched on, sentinels included.  The caller
+    that owns the task's counters turns it into DEVICE_MERGE_LAUNCHES and
+    DEVICE_MERGE_LAUNCH_ROWS (ops/sorter.py): launches are enqueued on the
+    calling thread, so the thread is the task."""
+    prev = getattr(_launch_tls, "tally", None)
+    tally: Dict[str, List[int]] = {}
+    _launch_tls.tally = tally
+    try:
+        yield tally
+    finally:
+        _launch_tls.tally = prev
+
+
+def _first_operand_rows(*args: Any) -> int:
+    shape = np.shape(jax.tree.leaves(args)[0])
+    return int(shape[0]) if shape else 1
+
+
 #: (kernel name, signature, compile seconds, wall-clock time it finished)
 #: of every compile this process did — chip_smoke.py prints it as the cold
 #: set-up cost per kernel.
@@ -127,8 +153,14 @@ class Kernel:
 
     def __init__(self, fn: Callable, name: str,
                  static_argnames: Tuple[str, ...] = (),
-                 donate_argnums: Tuple[int, ...] = ()) -> None:
+                 donate_argnums: Tuple[int, ...] = (),
+                 launch_rows: Callable[..., int] = _first_operand_rows
+                 ) -> None:
         self.name = name
+        #: rows one launch works on (padded: sentinels included), from its
+        #: operands — the first operand's unless the kernel takes two runs
+        self._launch_rows = launch_rows
+        self._span_name = "kernel." + name
         self._jit = jax.jit(fn, static_argnames=static_argnames,
                             donate_argnums=donate_argnums)
         self._compiled: Dict[Any, Any] = {}
@@ -142,7 +174,18 @@ class Kernel:
         exe = self._compiled.get(key)
         if exe is None:
             exe = self._compile(key, args, static)
-        return exe(*args)
+        tally = getattr(_launch_tls, "tally", None)
+        if tally is None and not tracing.armed():
+            return exe(*args)
+        rows = self._launch_rows(*args)
+        if tally is not None:
+            entry = tally.setdefault(self.name, [0, 0])
+            entry[0] += 1
+            entry[1] += rows
+        # the launch returns once the work is enqueued: this span is the
+        # enqueue, the wait shows where the host reads the result back
+        with tracing.span(self._span_name, cat="kernel", rows=rows):
+            return exe(*args)
 
     def cache_size(self) -> int:
         """Compiled signatures held (tests bound recompiles with it)."""
@@ -164,7 +207,9 @@ class Kernel:
                                    for s, _ in key[1])
                     t0 = time.perf_counter()
                     try:
-                        exe = self._jit.lower(*args, **static).compile()
+                        with tracing.span("kernel.compile", cat="kernel",
+                                          kernel=self.name, signature=sig):
+                            exe = self._jit.lower(*args, **static).compile()
                     except Exception as e:
                         raise KernelCompileError(
                             f"kernel {self.name}[{sig}] failed to compile: "
@@ -473,8 +518,10 @@ def _fused_resident_merge_impl(lanes_list, lens_list):
     return perm
 
 
-_fused_resident_merge = Kernel(_fused_resident_merge_impl,
-                               "resident_merge_sort")
+_fused_resident_merge = Kernel(
+    _fused_resident_merge_impl, "resident_merge_sort",
+    launch_rows=lambda lanes_list, lens_list: sum(
+        int(l.shape[0]) for l in lanes_list))
 
 
 def _map_bucketed_perm(perm: np.ndarray, counts, common: int) -> np.ndarray:
@@ -512,17 +559,26 @@ def merge_resident_slices(slices, kernel: str = "merge_path") -> np.ndarray:
     common = _bucket(max(counts))
     width = max(l.shape[1] for (l, _n, _lo, _hi) in slices)
     lanes_list, lens_list = [], []
-    for (lanes, lens, lo, hi) in slices:
-        sl, ln = _slice_to_bucket(lanes, lens, np.int32(lo),
-                                  np.int32(hi - lo), out_rows=common,
-                                  out_lanes=width)
-        lanes_list.append(sl)
-        lens_list.append(ln)
-    if kernel == "merge_path":
-        perm = np.asarray(_merge_path_resident(lanes_list, lens_list, common))
-    else:
-        perm = np.asarray(_fused_resident_merge(lanes_list, lens_list))
-    return _map_bucketed_perm(perm, counts, common)
+    with tracing.span("merge.stage", cat="merge", runs=len(slices),
+                      rows=sum(counts), bucket=common):
+        for (lanes, lens, lo, hi) in slices:
+            sl, ln = _slice_to_bucket(lanes, lens, np.int32(lo),
+                                      np.int32(hi - lo), out_rows=common,
+                                      out_lanes=width)
+            lanes_list.append(sl)
+            lens_list.append(ln)
+    with tracing.span("merge.launch", cat="merge", runs=len(slices)):
+        if kernel == "merge_path":
+            perm_dev = _merge_path_resident(lanes_list, lens_list, common)
+        else:
+            perm_dev = _fused_resident_merge(lanes_list, lens_list)
+    # the host blocks here for the device: this merge's own work and every
+    # launch other threads queued ahead of it on the chip
+    with tracing.span("merge.readback", cat="merge",
+                      rows=common * len(counts)):
+        perm = np.asarray(perm_dev)
+    with tracing.span("merge.gather", cat="merge", rows=sum(counts)):
+        return _map_bucketed_perm(perm, counts, common)
 
 
 # ---------------------------------------------------------------------------
@@ -597,7 +653,16 @@ def _merge_path_pair_impl(a_lanes, a_lens, a_idx, b_lanes, b_lens, b_idx):
     return out_lanes, out_lens, out_idx
 
 
-_merge_path_pair = Kernel(_merge_path_pair_impl, "merge_path_pair")
+_merge_path_pair = Kernel(
+    _merge_path_pair_impl, "merge_path_pair",
+    launch_rows=lambda a_lanes, a_lens, a_idx, b_lanes, b_lens, b_idx:
+    int(a_lanes.shape[0]) + int(b_lanes.shape[0]))
+
+#: the programs that compare rows in a merge; ``slice_to_bucket`` and
+#: ``merge_path_prep`` stage their operands.  DEVICE_MERGE_LAUNCH_ROWS sums
+#: the rows of these, so that over DEVICE_MERGE_RECORDS it reads levels x
+#: padding (ops/sorter.py _record_launches).
+MERGE_LEVEL_KERNELS = ("merge_path_pair", "resident_merge_sort")
 
 
 def _merge_path_prep_impl(lanes, lens, base):
@@ -655,29 +720,40 @@ def merge_path_runs(parts_list: list[np.ndarray],
     width_cap = width * 4 + 1
     common = _bucket(max(counts[i] for i in live))
     runs = []
-    for j, i in enumerate(live):
-        n = counts[i]
-        comp = np.empty((common, width + 1), dtype=np.uint32)
-        comp[:n, 0] = parts_list[i].astype(np.uint32)
-        comp[:n, 1:1 + lanes_list[i].shape[1]] = lanes_list[i]
-        comp[:n, 1 + lanes_list[i].shape[1]:] = 0
-        comp[n:] = np.uint32(0xFFFFFFFF)
-        lens = np.full(common, -1, dtype=np.int32)
-        lens[:n] = np.minimum(lengths_list[i].astype(np.int64), width_cap)
-        comp_dev = jnp.asarray(comp)
-        sort_lens, idx = _merge_path_prep(comp_dev, jnp.asarray(lens),
-                                          np.int32(j * common))
-        runs.append((comp_dev, sort_lens, idx))
-    perm = np.asarray(_merge_path_ladder(runs))
-    mapped = _map_bucketed_perm(perm, [counts[i] for i in live], common)
-    if len(live) != len(counts):   # re-offset into the FULL concatenation
-        all_offsets = np.zeros(len(counts), dtype=np.int64)
-        np.cumsum(counts[:-1], out=all_offsets[1:])
-        live_offsets = np.zeros(len(live), dtype=np.int64)
-        np.cumsum([counts[i] for i in live[:-1]], out=live_offsets[1:])
-        run_id = np.searchsorted(live_offsets[1:], mapped, side="right")
-        mapped = mapped - live_offsets[run_id] + all_offsets[np.asarray(live)[run_id]]
-    return mapped
+    # each run's prep is enqueued as the run is uploaded: its launch stands
+    # inside merge.stage as a kernel.merge_path_prep span of its own
+    with tracing.span("merge.stage", cat="merge", runs=len(live),
+                      rows=sum(counts), bucket=common):
+        for j, i in enumerate(live):
+            n = counts[i]
+            comp = np.empty((common, width + 1), dtype=np.uint32)
+            comp[:n, 0] = parts_list[i].astype(np.uint32)
+            comp[:n, 1:1 + lanes_list[i].shape[1]] = lanes_list[i]
+            comp[:n, 1 + lanes_list[i].shape[1]:] = 0
+            comp[n:] = np.uint32(0xFFFFFFFF)
+            lens = np.full(common, -1, dtype=np.int32)
+            lens[:n] = np.minimum(lengths_list[i].astype(np.int64),
+                                  width_cap)
+            comp_dev = jnp.asarray(comp)
+            sort_lens, idx = _merge_path_prep(comp_dev, jnp.asarray(lens),
+                                              np.int32(j * common))
+            runs.append((comp_dev, sort_lens, idx))
+    with tracing.span("merge.launch", cat="merge", runs=len(live)):
+        perm_dev = _merge_path_ladder(runs)
+    with tracing.span("merge.readback", cat="merge",
+                      rows=common * len(live)):
+        perm = np.asarray(perm_dev)
+    with tracing.span("merge.gather", cat="merge", rows=sum(counts)):
+        mapped = _map_bucketed_perm(perm, [counts[i] for i in live], common)
+        if len(live) != len(counts):   # re-offset into the FULL concatenation
+            all_offsets = np.zeros(len(counts), dtype=np.int64)
+            np.cumsum(counts[:-1], out=all_offsets[1:])
+            live_offsets = np.zeros(len(live), dtype=np.int64)
+            np.cumsum([counts[i] for i in live[:-1]], out=live_offsets[1:])
+            run_id = np.searchsorted(live_offsets[1:], mapped, side="right")
+            mapped = mapped - live_offsets[run_id] + \
+                all_offsets[np.asarray(live)[run_id]]
+        return mapped
 
 
 def _fused_hash_sort_impl(key_mat: jnp.ndarray, hash_lengths: jnp.ndarray,

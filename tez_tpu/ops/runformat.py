@@ -20,7 +20,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from tez_tpu.common import faults
+from tez_tpu.common import faults, tracing
 
 MAGIC = b"TPRUN1"
 #: MAGIC + pack("<BIQ", flag, crc32(payload), len(payload)).  The CRC covers
@@ -502,7 +502,9 @@ def iter_chunked_run(path: str):
             if len(raw) < 8:
                 return
             (n,) = struct.unpack("<Q", raw)
-            yield Run.from_bytes(fh.read(n), where=path).batch
+            with tracing.span("spill.read", cat="spill", bytes=n):
+                batch = Run.from_bytes(fh.read(n), where=path).batch
+            yield batch
 
 
 PR_MAGIC = b"TZPRUN1\n"
@@ -653,9 +655,14 @@ class FileRun:
             pos = lo
             while pos < hi:
                 (n,) = struct.unpack("<Q", fh.read(8))
-                blob = faults.corrupt_bytes("spill.read", self.path,
-                                            fh.read(n), lo=RUN_HEADER_NBYTES)
-                yield Run.from_bytes(blob, where=self.path).batch
+                # one span a block read back for a merge; never across the
+                # yield, where the consumer's time would be billed to it
+                with tracing.span("spill.read", cat="spill", bytes=n):
+                    blob = faults.corrupt_bytes(
+                        "spill.read", self.path, fh.read(n),
+                        lo=RUN_HEADER_NBYTES)
+                    batch = Run.from_bytes(blob, where=self.path).batch
+                yield batch
                 pos += 8 + n
 
     def partition(self, p: int) -> KVBatch:

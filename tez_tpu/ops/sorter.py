@@ -21,6 +21,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from tez_tpu.common import tracing
 from tez_tpu.common.counters import TaskCounter, TezCounters
 from tez_tpu.ops import device
 from tez_tpu.ops.keycodec import encode_keys, pad_to_matrix, matrix_to_lanes
@@ -319,10 +320,12 @@ class DeviceSorter:
             self._sort_span()
 
     def write_batch(self, batch: KVBatch) -> None:
-        self._span.add_batch(batch)
-        self._out_records_ctr.increment(batch.num_records)
-        if self._span.nbytes >= self.span_budget:
-            self._sort_span()
+        with tracing.span("sort.collect", cat="sort",
+                          rows=batch.num_records):
+            self._span.add_batch(batch)
+            self._out_records_ctr.increment(batch.num_records)
+            if self._span.nbytes >= self.span_budget:
+                self._sort_span()
 
     # -- span sort (device) --------------------------------------------------
     def _precombine(self, batch: KVBatch,
@@ -644,7 +647,7 @@ class DeviceSorter:
                     with self._store_lock:
                         self._store_run(run)
 
-            self._pending.append(self._executor.submit(_bg))
+            self._pending.append(self._executor.submit(tracing.bound(_bg)))
             return
         run = self._finalize_span()
         if self.on_spill is not None:
@@ -882,14 +885,16 @@ class DeviceSorter:
             # the drain barrier collects out-of-order completions and
             # restores spill-id order
             self._sort_span()
-            self._drain_async()
-            self._drain_pending(store=True)   # no-op unless sortmaster ran
+            with tracing.span("sort.flush", cat="sort"):
+                self._drain_async()
+                self._drain_pending(store=True)  # no-op unless sortmaster ran
             if self.on_spill is not None:
                 return None
         elif self.on_spill is not None:
             if self._span.num_records > 0:
                 self._sort_span()
-            self._drain_pending(store=False)
+            with tracing.span("sort.flush", cat="sort"):
+                self._drain_pending(store=False)
             return None
         else:
             if self._span.num_records > 0 and not self._runs and \
@@ -897,29 +902,32 @@ class DeviceSorter:
                 # common fast path: everything fit one span
                 return self._finalize_span()
             self._sort_span()
-            self._drain_pending(store=True)
+            with tracing.span("sort.flush", cat="sort"):
+                self._drain_pending(store=True)
         runs = list(self._runs)
         self._runs = []
         if not runs:
             return Run(KVBatch.empty(),
                        np.zeros(self.num_partitions + 1, dtype=np.int64))
-        if not any(isinstance(r, str) for r in runs):
-            if len(runs) == 1:
-                return runs[0]
-            merged = merge_sorted_runs(
-                runs, self.num_partitions, self.key_width,
-                counters=self.counters, engine=self.engine,
-                merge_factor=self.merge_factor,
-                key_normalizer=self.key_normalizer,
-                device_min_records=self.device_min_records)
-            if self.combiner is not None:
-                merged = self.combiner(merged)
-            return merged
-        return self._stream_final_merge(runs)
+        if len(runs) == 1 and not isinstance(runs[0], str):
+            return runs[0]
+        with tracing.span("sort.final_merge", cat="sort", runs=len(runs)):
+            if not any(isinstance(r, str) for r in runs):
+                merged = merge_sorted_runs(
+                    runs, self.num_partitions, self.key_width,
+                    counters=self.counters, engine=self.engine,
+                    merge_factor=self.merge_factor,
+                    key_normalizer=self.key_normalizer,
+                    device_min_records=self.device_min_records)
+                if self.combiner is not None:
+                    merged = self.combiner(merged)
+                return merged
+            return self._stream_final_merge(runs)
 
     def _stream_final_merge(self, runs: List["Run | str"]) -> "FileRun":
         """Blockwise partition-major merge of spilled + resident spans into
         one partition-indexed file."""
+        from tez_tpu.common import metrics
         from tez_tpu.ops.block_merge import iter_merged_blocks
         sources: List["Run | FileRun"] = []
         for r in runs:
@@ -959,7 +967,8 @@ class DeviceSorter:
                             block, np.array([0, block.num_records],
                                             dtype=np.int64)))
                         block = combined.batch
-                    writer.append(block, p)
+                    with metrics.timer("spill.write"):
+                        writer.append(block, p)
             writer.close()
         except BaseException:
             writer.abort()
@@ -1001,6 +1010,21 @@ def _record_merge(counters: Optional[TezCounters], t0: float, engine: str,
         counters.increment(TaskCounter.MERGED_MAP_OUTPUTS, num_runs)
 
 
+def _record_launches(counters: Optional[TezCounters], tally: dict) -> None:
+    """Kernel launches of one merge (device.launch_tally) into the task's
+    counters.  DEVICE_MERGE_LAUNCHES counts every program the merge
+    launched, staging ones too; DEVICE_MERGE_LAUNCH_ROWS the rows of the
+    programs that compare (device.MERGE_LEVEL_KERNELS), so that over
+    DEVICE_MERGE_RECORDS it is ladder levels x padding."""
+    if counters is None:
+        return
+    counters.increment(TaskCounter.DEVICE_MERGE_LAUNCHES,
+                       sum(n for n, _r in tally.values()))
+    counters.increment(TaskCounter.DEVICE_MERGE_LAUNCH_ROWS,
+                       sum(tally[k][1] for k in device.MERGE_LEVEL_KERNELS
+                           if k in tally))
+
+
 def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int
                                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Multi-partition device-resident merge: each run's HBM key columns are
@@ -1026,13 +1050,14 @@ def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int
         if not slices:
             continue
         perm = device.merge_resident_slices(slices)
-        cnts = np.asarray([hi - lo for (_l, _n, lo, hi) in slices],
-                          dtype=np.int64)
-        bounds = np.zeros(len(cnts) + 1, dtype=np.int64)
-        np.cumsum(cnts, out=bounds[1:])
-        sl = np.searchsorted(bounds[1:], perm, side="right")
-        pieces.append(np.asarray(bases, dtype=np.int64)[sl] +
-                      (perm - bounds[sl]))
+        with tracing.span("merge.gather", cat="merge", rows=len(perm)):
+            cnts = np.asarray([hi - lo for (_l, _n, lo, hi) in slices],
+                              dtype=np.int64)
+            bounds = np.zeros(len(cnts) + 1, dtype=np.int64)
+            np.cumsum(cnts, out=bounds[1:])
+            sl = np.searchsorted(bounds[1:], perm, side="right")
+            pieces.append(np.asarray(bases, dtype=np.int64)[sl] +
+                          (perm - bounds[sl]))
         counts[p] = len(perm)
     row_index = np.zeros(num_partitions + 1, dtype=np.int64)
     np.cumsum(counts, out=row_index[1:])
@@ -1087,15 +1112,18 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
             # device-resident merge: key columns are already in HBM from
             # the producers' span sorts — only the permutation comes back
             # (VERDICT r1 item 4; TezMerger semantics preserved)
-            if num_partitions == 1:
-                perm = device.merge_resident_slices(views)
-                row_index = None
-            else:
-                perm, row_index = _merge_resident_partitioned(
-                    live, num_partitions)
+            with device.launch_tally() as tally:
+                if num_partitions == 1:
+                    perm = device.merge_resident_slices(views)
+                    row_index = None
+                else:
+                    perm, row_index = _merge_resident_partitioned(
+                        live, num_partitions)
             _record_merge_ms(counters, t0)
-            batch = KVBatch.concat([r.batch for r in live])
-            sorted_batch = batch.take(perm)
+            _record_launches(counters, tally)
+            with tracing.span("merge.gather", cat="merge", rows=len(perm)):
+                batch = KVBatch.concat([r.batch for r in live])
+                sorted_batch = batch.take(perm)
             _record_merge(counters, t0, "device", batch.num_records,
                           len(runs), final)
             if row_index is None:
@@ -1160,21 +1188,25 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
     run_bounds = np.zeros(len(runs) + 1, dtype=np.int64)
     np.cumsum([r.batch.num_records for r in runs], out=run_bounds[1:])
     t_dev = time.time()
-    perm = device.merge_path_runs(
-        [partitions[run_bounds[i]:run_bounds[i + 1]]
-         for i in range(len(runs))],
-        [lanes[run_bounds[i]:run_bounds[i + 1]]
-         for i in range(len(runs))],
-        [lengths[run_bounds[i]:run_bounds[i + 1]]
-         for i in range(len(runs))])
+    with device.launch_tally() as tally:
+        perm = device.merge_path_runs(
+            [partitions[run_bounds[i]:run_bounds[i + 1]]
+             for i in range(len(runs))],
+            [lanes[run_bounds[i]:run_bounds[i + 1]]
+             for i in range(len(runs))],
+            [lengths[run_bounds[i]:run_bounds[i + 1]]
+             for i in range(len(runs))])
     _record_merge_ms(counters, t_dev)
-    sorted_partitions = partitions[perm]
-    sorted_batch = batch.take(perm)
-    sort_lengths, keyfn = _sorted_key_view(sort_bytes, sort_offsets, perm)
-    refinement = _exact_tiebreak(sort_lengths, sorted_partitions,
-                                 lanes[perm], key_width, keyfn)
-    if refinement is not None:
-        sorted_batch = sorted_batch.take(refinement)
+    _record_launches(counters, tally)
+    with tracing.span("merge.gather", cat="merge", rows=len(perm)):
+        sorted_partitions = partitions[perm]
+        sorted_batch = batch.take(perm)
+        sort_lengths, keyfn = _sorted_key_view(sort_bytes, sort_offsets,
+                                               perm)
+        refinement = _exact_tiebreak(sort_lengths, sorted_partitions,
+                                     lanes[perm], key_width, keyfn)
+        if refinement is not None:
+            sorted_batch = sorted_batch.take(refinement)
     _record_merge(counters, t0, "device", batch.num_records, len(runs),
                   final)
     return Run.from_sorted_batch(sorted_batch, sorted_partitions,

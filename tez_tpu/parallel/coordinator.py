@@ -37,11 +37,12 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from tez_tpu.common import faults
+from tez_tpu.common import faults, tracing
 from tez_tpu.common.counters import MESH_EXCHANGE_GROUP
 from tez_tpu.obs import flight as _flight
 from tez_tpu.ops.keycodec import matrix_to_lanes, pad_to_matrix
@@ -103,6 +104,10 @@ class _EdgeState:
         self.split_after: Optional[int] = None
         self.counters = None                  # triggering producer's sink
         self.spans: Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
+        #: producer -> (time.time() its rows were in, its trace context),
+        #: kept while the span plane is armed: what the
+        #: exchange.wait_peers spans are made from
+        self.arrived: Dict[int, Tuple[float, object]] = {}
         self.results: Optional[List[KVBatch]] = None
         self.error: Optional[BaseException] = None
         self.executing = False     # an _execute is in flight on some thread
@@ -256,10 +261,12 @@ class MeshExchangeCoordinator:
                     f"{max_value_bytes}B, found {max_val}B; use the host "
                     f"shuffle edge for records this large")
             value_width = max(value_width, ((max_val + 3) // 4) * 4)
-        kmat, klens = pad_to_matrix(batch.key_bytes, batch.key_offsets,
-                                    key_width)
-        lanes = matrix_to_lanes(kmat)
-        vwords = _encode_values(batch, value_width)
+        with tracing.span("exchange.pack", cat="exchange", stage="producer",
+                          rows=batch.num_records):
+            kmat, klens = pad_to_matrix(batch.key_bytes, batch.key_offsets,
+                                        key_width)
+            lanes = matrix_to_lanes(kmat)
+            vwords = _encode_values(batch, value_width)
         with self.lock:
             st = self.edges.setdefault(
                 edge_id, _EdgeState(num_producers, num_consumers, edge_id))
@@ -276,6 +283,9 @@ class MeshExchangeCoordinator:
             st.spans[task_index] = (lanes,
                                     klens.astype(np.uint32),
                                     vwords)
+            if tracing.armed():
+                st.arrived[task_index] = (time.time(),
+                                          tracing.current_context())
             if isinstance(st.error, TimeoutError):
                 # a straggler poisoned the edge, and here it is: the edge
                 # is viable again — consumer RETRIES must see a fresh
@@ -418,12 +428,16 @@ class MeshExchangeCoordinator:
         events = [threading.Event() for _ in range(D)]
         results: List[object] = [None] * D
         any_done = threading.Event()
+        ctx = tracing.current_context()    # the executing producer's
 
         def _read(d: int) -> None:
             try:
-                faults.fire("mesh.exchange.delay",
-                            detail=f"{edge_id}:round={round_idx}:device={d}")
-                results[d] = tuple(np.asarray(m[d]) for m in shard_maps)
+                with tracing.span("exchange.readback", cat="exchange",
+                                  parent=ctx, device=d, round=round_idx):
+                    faults.fire(
+                        "mesh.exchange.delay",
+                        detail=f"{edge_id}:round={round_idx}:device={d}")
+                    results[d] = tuple(np.asarray(m[d]) for m in shard_maps)
             except BaseException as e:  # noqa: BLE001 — surfaced by reader
                 results[d] = e
             finally:
@@ -479,8 +493,6 @@ class MeshExchangeCoordinator:
         rank-sliced rounds and each consumer's rounds merge at the end.
         See the module docstring for the skew levers layered on top
         (histogram round sizing, the splitter, coded r2)."""
-        import time
-
         from tez_tpu.common import metrics
         from tez_tpu.ops.host_sort import fnv_rows_host
         from tez_tpu.ops.sorter import merge_sorted_runs
@@ -491,131 +503,143 @@ class MeshExchangeCoordinator:
         # chaos hits the exchange at entry (the caller's error path turns
         # this into the edge-wide failure consumers see)
         faults.fire("mesh.exchange", detail=st.edge_id)
-        W = st.num_consumers
-        D = self.devices_for(W)     # devices carrying the exchange; each
-        mesh = self.mesh_for(D)     # holds W/D consumer partitions
-        with self.lock:
-            spans = [st.spans[i] for i in sorted(st.spans)]
-        # harmonize widths: spans auto-widened independently — zero-pad
-        # narrow ones (zero lanes/words == absent bytes; order unaffected)
-        max_lanes = max((s[0].shape[1] for s in spans), default=1)
-        max_vw = max((s[2].shape[1] for s in spans), default=1)
-
-        def _widen(a: np.ndarray, width: int) -> np.ndarray:
-            if a.shape[1] == width:
-                return a
-            return np.pad(a, ((0, 0), (0, width - a.shape[1])))
-
-        lanes = np.concatenate([_widen(s[0], max_lanes) for s in spans]) \
-            if spans else np.zeros((0, 1), np.uint32)
-        klens = np.concatenate([s[1] for s in spans]) \
-            if spans else np.zeros((0,), np.uint32)
-        vwords = np.concatenate([_widen(s[2], max_vw) for s in spans]) \
-            if spans else np.zeros((0, 1), np.uint32)
-        total = lanes.shape[0]
-        num_lanes = lanes.shape[1]
-        value_words = vwords.shape[1]
-        if total == 0:
-            return [KVBatch.empty() for _ in range(W)]
-
-        # exact routing on host: byte-masked FNV over the padded key matrix
-        # (reconstruct the byte matrix from lanes — cheap, vectorized).
-        # Routing is hash % D; with D | W that equals (hash % W) % D, so
-        # device d receives exactly the rows of consumer partitions
-        # {c : c % D == d} (split apart after the exchange).
-        from tez_tpu.ops.device import _bucket
-        from tez_tpu.ops.keycodec import lanes_to_matrix
-        kmat = lanes_to_matrix(lanes)
-        hashes = fnv_rows_host(kmat, klens.astype(np.int64))
-        rdest = (hashes % np.uint32(D)).astype(np.int64)
-        counts = np.bincount(rdest, minlength=D)
-        per_round = st.max_rows_per_round or self.max_rows_per_round
-
-        # ---- fair-shuffle splitter: an edge whose largest partition has
-        # exceeded the round budget split_after times IN A ROW (recurring
-        # runs share the id suffix; the dag prefix changes per run) gets
-        # each hot destination re-partitioned across d_sub sub-destinations
-        # in contiguous arrival blocks.  Routing stops being key-derivable
-        # for those rows, but the CONSUMER identity (hash % W) still is —
-        # the merge-side recombine below reassembles split partitions.
-        skew_key = st.edge_id.split("/", 1)[-1] or st.edge_id
-        over_budget = int(counts.max()) > per_round
-        with self.lock:
-            if over_budget:
-                streak = self._skew_history.get(skew_key, 0) + 1
-                self._skew_history[skew_key] = streak
-            else:
-                self._skew_history.pop(skew_key, None)
-                streak = 0
-        split_after = st.split_after if st.split_after is not None \
-            else self.split_after
-        splits = 0
-        if over_budget and D > 1 and split_after > 0 and \
-                streak >= split_after:
-            hot = np.flatnonzero(counts > per_round)
-            load = counts.astype(np.int64).copy()
-            load[hot] = per_round      # each hot dest keeps a full round
-            # split every hot dest against the ORIGINAL routing snapshot:
-            # rows an earlier split re-homed INTO d are not d's to re-split
-            orig_rdest = rdest.copy()
-            # biggest partition gets first pick of the headroom
-            for d in hot[np.argsort(-counts[hot], kind="stable")]:
-                n_d = int(counts[d])
-                amounts = np.zeros(D, dtype=np.int64)
-                amounts[d] = per_round
-                remaining = n_d - per_round
-                # fill other destinations' headroom, least-loaded first:
-                # whenever the total fits in D*per_round at all, the
-                # exchange comes out single-round
-                for t in np.argsort(load, kind="stable"):
-                    if remaining == 0:
-                        break
-                    if t == d or load[t] >= per_round:
-                        continue
-                    take = min(int(per_round - load[t]), remaining)
-                    amounts[t] += take
-                    load[t] += take
-                    remaining -= take
-                if remaining:
-                    # no headroom left: multi-round is inevitable; spread
-                    # the rest evenly so no destination re-rounds alone
-                    base, extra = divmod(remaining, D)
-                    add = np.full(D, base, dtype=np.int64)
-                    add[:extra] += 1
-                    amounts += add
-                    load += add
-                # carve d's arrival-ordered rows into contiguous blocks
-                # handed to destinations in ASCENDING device index: the
-                # consumer-side recombine merges runs in device order, so
-                # ascending blocks reconstruct arrival order exactly
-                # (deterministic equal-key ties, same as the unsplit path)
-                rows = np.flatnonzero(orig_rdest == d)  # ascending==arrival
-                rdest[rows] = np.repeat(np.arange(D), amounts)
-                splits += 1
-            counts = np.bincount(rdest, minlength=D)
+        if tracing.armed():
+            # how long each producer's rows lay waiting for the slowest
+            # peer: one span a producer, made now that the wait is over, on
+            # a lane of its own (no thread waited: the rows did)
             with self.lock:
-                self.partition_splits += splits
-            log.info("mesh exchange %s: splitter engaged after %d "
-                     "over-budget exchange(s); %d hot partition(s) "
-                     "re-partitioned", st.edge_id, streak, splits)
+                arrived = dict(st.arrived)
+            for producer, (t_in, ctx) in arrived.items():
+                tracing.start_span(
+                    "exchange.wait_peers", cat="exchange", parent=ctx,
+                    lane=f"exchange.wait_peers#{st.edge_id}/{producer}",
+                    start=t_in, producer=producer).finish()
+        with tracing.span("exchange.plan", cat="exchange"):
+            W = st.num_consumers
+            D = self.devices_for(W)     # devices carrying the exchange; each
+            mesh = self.mesh_for(D)     # holds W/D consumer partitions
+            with self.lock:
+                spans = [st.spans[i] for i in sorted(st.spans)]
+            # harmonize widths: spans auto-widened independently — zero-pad
+            # narrow ones (zero lanes/words == absent bytes; order unaffected)
+            max_lanes = max((s[0].shape[1] for s in spans), default=1)
+            max_vw = max((s[2].shape[1] for s in spans), default=1)
 
-        engine, engine_reason = resolve_engine(st.engine or self.engine,
-                                               mesh)
-        self.last_engine = engine
-        log.debug("mesh exchange %s: engine=%s (%s)", st.edge_id, engine,
-                  engine_reason)
-        coded = (st.coded or "off") == "r2" and D > 1
-        plan = plan_rounds(counts, per_round, D, legacy=self.legacy_sizing)
-        _flight.record(_flight.EXCHANGE, "plan", st.edge_id,
-                       a=len(plan), b=total)
+            def _widen(a: np.ndarray, width: int) -> np.ndarray:
+                if a.shape[1] == width:
+                    return a
+                return np.pad(a, ((0, 0), (0, width - a.shape[1])))
 
-        # rank of each row within its routing partition (arrival order)
-        order = np.argsort(rdest, kind="stable")
-        ranks = np.empty(total, dtype=np.int64)
-        starts = np.zeros(D + 1, dtype=np.int64)
-        np.cumsum(counts, out=starts[1:])
-        ranks[order] = np.arange(total, dtype=np.int64) - \
-            np.repeat(starts[:-1], counts)
+            lanes = np.concatenate([_widen(s[0], max_lanes) for s in spans]) \
+                if spans else np.zeros((0, 1), np.uint32)
+            klens = np.concatenate([s[1] for s in spans]) \
+                if spans else np.zeros((0,), np.uint32)
+            vwords = np.concatenate([_widen(s[2], max_vw) for s in spans]) \
+                if spans else np.zeros((0, 1), np.uint32)
+            total = lanes.shape[0]
+            num_lanes = lanes.shape[1]
+            value_words = vwords.shape[1]
+            if total == 0:
+                return [KVBatch.empty() for _ in range(W)]
+
+            # exact routing on host: byte-masked FNV over the padded key matrix
+            # (reconstruct the byte matrix from lanes — cheap, vectorized).
+            # Routing is hash % D; with D | W that equals (hash % W) % D, so
+            # device d receives exactly the rows of consumer partitions
+            # {c : c % D == d} (split apart after the exchange).
+            from tez_tpu.ops.device import _bucket
+            from tez_tpu.ops.keycodec import lanes_to_matrix
+            kmat = lanes_to_matrix(lanes)
+            hashes = fnv_rows_host(kmat, klens.astype(np.int64))
+            rdest = (hashes % np.uint32(D)).astype(np.int64)
+            counts = np.bincount(rdest, minlength=D)
+            per_round = st.max_rows_per_round or self.max_rows_per_round
+
+            # ---- fair-shuffle splitter: an edge whose largest partition has
+            # exceeded the round budget split_after times IN A ROW (recurring
+            # runs share the id suffix; the dag prefix changes per run) gets
+            # each hot destination re-partitioned across d_sub sub-destinations
+            # in contiguous arrival blocks.  Routing stops being key-derivable
+            # for those rows, but the CONSUMER identity (hash % W) still is —
+            # the merge-side recombine below reassembles split partitions.
+            skew_key = st.edge_id.split("/", 1)[-1] or st.edge_id
+            over_budget = int(counts.max()) > per_round
+            with self.lock:
+                if over_budget:
+                    streak = self._skew_history.get(skew_key, 0) + 1
+                    self._skew_history[skew_key] = streak
+                else:
+                    self._skew_history.pop(skew_key, None)
+                    streak = 0
+            split_after = st.split_after if st.split_after is not None \
+                else self.split_after
+            splits = 0
+            if over_budget and D > 1 and split_after > 0 and \
+                    streak >= split_after:
+                hot = np.flatnonzero(counts > per_round)
+                load = counts.astype(np.int64).copy()
+                load[hot] = per_round      # each hot dest keeps a full round
+                # split every hot dest against the ORIGINAL routing snapshot:
+                # rows an earlier split re-homed INTO d are not d's to re-split
+                orig_rdest = rdest.copy()
+                # biggest partition gets first pick of the headroom
+                for d in hot[np.argsort(-counts[hot], kind="stable")]:
+                    n_d = int(counts[d])
+                    amounts = np.zeros(D, dtype=np.int64)
+                    amounts[d] = per_round
+                    remaining = n_d - per_round
+                    # fill other destinations' headroom, least-loaded first:
+                    # whenever the total fits in D*per_round at all, the
+                    # exchange comes out single-round
+                    for t in np.argsort(load, kind="stable"):
+                        if remaining == 0:
+                            break
+                        if t == d or load[t] >= per_round:
+                            continue
+                        take = min(int(per_round - load[t]), remaining)
+                        amounts[t] += take
+                        load[t] += take
+                        remaining -= take
+                    if remaining:
+                        # no headroom left: multi-round is inevitable; spread
+                        # the rest evenly so no destination re-rounds alone
+                        base, extra = divmod(remaining, D)
+                        add = np.full(D, base, dtype=np.int64)
+                        add[:extra] += 1
+                        amounts += add
+                        load += add
+                    # carve d's arrival-ordered rows into contiguous blocks
+                    # handed to destinations in ASCENDING device index: the
+                    # consumer-side recombine merges runs in device order, so
+                    # ascending blocks reconstruct arrival order exactly
+                    # (deterministic equal-key ties, same as the unsplit path)
+                    rows = np.flatnonzero(orig_rdest == d)  # ascending==arrival
+                    rdest[rows] = np.repeat(np.arange(D), amounts)
+                    splits += 1
+                counts = np.bincount(rdest, minlength=D)
+                with self.lock:
+                    self.partition_splits += splits
+                log.info("mesh exchange %s: splitter engaged after %d "
+                         "over-budget exchange(s); %d hot partition(s) "
+                         "re-partitioned", st.edge_id, streak, splits)
+
+            engine, engine_reason = resolve_engine(st.engine or self.engine,
+                                                   mesh)
+            self.last_engine = engine
+            log.debug("mesh exchange %s: engine=%s (%s)", st.edge_id, engine,
+                      engine_reason)
+            coded = (st.coded or "off") == "r2" and D > 1
+            plan = plan_rounds(counts, per_round, D, legacy=self.legacy_sizing)
+            _flight.record(_flight.EXCHANGE, "plan", st.edge_id,
+                           a=len(plan), b=total)
+
+            # rank of each row within its routing partition (arrival order)
+            order = np.argsort(rdest, kind="stable")
+            ranks = np.empty(total, dtype=np.int64)
+            starts = np.zeros(D + 1, dtype=np.int64)
+            np.cumsum(counts, out=starts[1:])
+            ranks[order] = np.arange(total, dtype=np.int64) - \
+                np.repeat(starts[:-1], counts)
 
         row_words = num_lanes + 1 + value_words   # lanes + klen + vwords
         sent_rows = dup_rows = buddy_wins = rounds_run = 0
@@ -628,81 +652,88 @@ class MeshExchangeCoordinator:
             if n_round == 0:
                 continue
             t_round = time.perf_counter()
-            rows_idx = sel
-            dests_all = rdest[sel]
-            rtag = None
-            if coded:
-                # r2: every row ALSO goes to its destination's rotation
-                # buddy.  An extra value word carries the routing partition
-                # (same on both copies) so each shard can tell its primary
-                # rows from buddy copies — not derivable from the key once
-                # the splitter has re-routed rows.
-                rows_idx = np.concatenate([sel, sel])
-                rtag = np.concatenate([dests_all, dests_all]) \
-                    .astype(np.uint32)
-                dests_all = np.concatenate(
-                    [dests_all, (dests_all + 1) % D])
-                dup_rows += n_round
-            qc = np.bincount(dests_all, minlength=D)
-            lane_counts += qc
-            if coded:
-                # duplication doubled the quotas; re-derive the balanced
-                # cap from the combined histogram (coded always uses
-                # balanced placement — legacy tail-packing could put a
-                # whole destination's copies on one sender)
-                cap = min(_bucket(max(1, -(-int(qc.max()) // D))),
-                          per_round)
-            balanced = coded or not self.legacy_sizing
-            if balanced:
-                # balanced blocked placement: destination d's rows split
-                # into <= D contiguous arrival-order chunks, chunk j ->
-                # sender j, so no (sender, dest) pair exceeds
-                # ceil(quota_d / D) <= cap.  Contiguous chunks + the
-                # receiver's stable sender-major merge preserve global
-                # arrival order for equal keys.
-                qorder = np.argsort(dests_all, kind="stable")
-                lrank = np.empty(dests_all.size, dtype=np.int64)
-                qstarts = np.zeros(D + 1, dtype=np.int64)
-                np.cumsum(qc, out=qstarts[1:])
-                lrank[qorder] = np.arange(dests_all.size, dtype=np.int64) \
-                    - np.repeat(qstarts[:-1], qc)
-                chunk_d = np.maximum(1, -(-qc // D))
-                senders = lrank // chunk_d[dests_all]
-                loads = np.bincount(senders, minlength=D)
-                N = _bucket(int(loads.max()))
-                place = np.argsort(senders, kind="stable")
-                within = np.empty(senders.size, dtype=np.int64)
-                lstarts = np.zeros(D + 1, dtype=np.int64)
-                np.cumsum(loads, out=lstarts[1:])
-                within[place] = \
-                    np.arange(senders.size, dtype=np.int64) - \
-                    np.repeat(lstarts[:-1], loads)
-                pos = senders * N + within
-            else:
-                # legacy layout: rows in arrival order, zero tail pad
-                N = _bucket(-(-dests_all.size // D))
-                pos = np.arange(dests_all.size, dtype=np.int64)
-            vw = value_words + (1 if coded else 0)
-            r_lanes = np.zeros((D * N, num_lanes), np.uint32)
-            r_klens = np.zeros(D * N, np.uint32)
-            r_vwords = np.zeros((D * N, vw), np.uint32)
-            r_valid = np.zeros(D * N, bool)
-            r_dests = np.zeros(D * N, np.uint32)
-            r_lanes[pos] = lanes[rows_idx]
-            r_klens[pos] = klens[rows_idx]
-            r_vwords[pos, :value_words] = vwords[rows_idx]
-            if coded:
-                r_vwords[pos, value_words] = rtag
-            r_valid[pos] = True
-            r_dests[pos] = dests_all.astype(np.uint32)
-            fn = self._compiled_fn(mesh, num_lanes, N, cap, vw,
-                                   ragged=(engine == "ragged"))
-            out_lanes, out_klens, out_vwords, out_valid, dropped = \
-                fn(r_lanes, r_klens, r_vwords, r_valid, r_dests)
+            with tracing.span("exchange.pack", cat="exchange", round=r,
+                              rows=n_round):
+                rows_idx = sel
+                dests_all = rdest[sel]
+                rtag = None
+                if coded:
+                    # r2: every row ALSO goes to its destination's rotation
+                    # buddy.  An extra value word carries the routing partition
+                    # (same on both copies) so each shard can tell its primary
+                    # rows from buddy copies — not derivable from the key once
+                    # the splitter has re-routed rows.
+                    rows_idx = np.concatenate([sel, sel])
+                    rtag = np.concatenate([dests_all, dests_all]) \
+                        .astype(np.uint32)
+                    dests_all = np.concatenate(
+                        [dests_all, (dests_all + 1) % D])
+                    dup_rows += n_round
+                qc = np.bincount(dests_all, minlength=D)
+                lane_counts += qc
+                if coded:
+                    # duplication doubled the quotas; re-derive the balanced
+                    # cap from the combined histogram (coded always uses
+                    # balanced placement — legacy tail-packing could put a
+                    # whole destination's copies on one sender)
+                    cap = min(_bucket(max(1, -(-int(qc.max()) // D))),
+                              per_round)
+                balanced = coded or not self.legacy_sizing
+                if balanced:
+                    # balanced blocked placement: destination d's rows split
+                    # into <= D contiguous arrival-order chunks, chunk j ->
+                    # sender j, so no (sender, dest) pair exceeds
+                    # ceil(quota_d / D) <= cap.  Contiguous chunks + the
+                    # receiver's stable sender-major merge preserve global
+                    # arrival order for equal keys.
+                    qorder = np.argsort(dests_all, kind="stable")
+                    lrank = np.empty(dests_all.size, dtype=np.int64)
+                    qstarts = np.zeros(D + 1, dtype=np.int64)
+                    np.cumsum(qc, out=qstarts[1:])
+                    lrank[qorder] = np.arange(dests_all.size, dtype=np.int64) \
+                        - np.repeat(qstarts[:-1], qc)
+                    chunk_d = np.maximum(1, -(-qc // D))
+                    senders = lrank // chunk_d[dests_all]
+                    loads = np.bincount(senders, minlength=D)
+                    N = _bucket(int(loads.max()))
+                    place = np.argsort(senders, kind="stable")
+                    within = np.empty(senders.size, dtype=np.int64)
+                    lstarts = np.zeros(D + 1, dtype=np.int64)
+                    np.cumsum(loads, out=lstarts[1:])
+                    within[place] = \
+                        np.arange(senders.size, dtype=np.int64) - \
+                        np.repeat(lstarts[:-1], loads)
+                    pos = senders * N + within
+                else:
+                    # legacy layout: rows in arrival order, zero tail pad
+                    N = _bucket(-(-dests_all.size // D))
+                    pos = np.arange(dests_all.size, dtype=np.int64)
+                vw = value_words + (1 if coded else 0)
+                r_lanes = np.zeros((D * N, num_lanes), np.uint32)
+                r_klens = np.zeros(D * N, np.uint32)
+                r_vwords = np.zeros((D * N, vw), np.uint32)
+                r_valid = np.zeros(D * N, bool)
+                r_dests = np.zeros(D * N, np.uint32)
+                r_lanes[pos] = lanes[rows_idx]
+                r_klens[pos] = klens[rows_idx]
+                r_vwords[pos, :value_words] = vwords[rows_idx]
+                if coded:
+                    r_vwords[pos, value_words] = rtag
+                r_valid[pos] = True
+                r_dests[pos] = dests_all.astype(np.uint32)
+            with tracing.span("exchange.launch", cat="exchange", round=r,
+                              rows=D * N):
+                fn = self._compiled_fn(mesh, num_lanes, N, cap, vw,
+                                       ragged=(engine == "ragged"))
+                out_lanes, out_klens, out_vwords, out_valid, dropped = \
+                    fn(r_lanes, r_klens, r_vwords, r_valid, r_dests)
             # the dropped flag is a tiny replicated array: reading it does
             # not serialize the per-device readback below (the delay fault
-            # stalls our reader threads, not device compute)
-            dropped_total = int(np.asarray(dropped).sum())
+            # stalls our reader threads, not device compute) — but it does
+            # wait for the program, so it is the first of the readback
+            with tracing.span("exchange.readback", cat="exchange", round=r,
+                              what="dropped"):
+                dropped_total = int(np.asarray(dropped).sum())
             if dropped_total:
                 raise MeshCapacityError(
                     f"mesh exchange overflow: {dropped_total} rows dropped "
@@ -712,23 +743,33 @@ class MeshExchangeCoordinator:
                 st.edge_id, r)
             round_parts: List[KVBatch] = []
             if coded:
-                chosen, wins = self._select_coded(events, results,
-                                                  any_done, D)
+                with tracing.span("exchange.readback", cat="exchange",
+                                  round=r, what="first_copies"):
+                    chosen, wins = self._select_coded(events, results,
+                                                      any_done, D)
                 buddy_wins += wins
-                for p in range(D):
-                    dl, dk, dv, dval = results[chosen[p]]
-                    keep = dval.astype(bool) & (dv[:, value_words] == p)
-                    round_parts.append(_decode_rows(
-                        dl, dk,
-                        np.ascontiguousarray(dv[:, :value_words]), keep))
+                with tracing.span("exchange.decode", cat="exchange",
+                                  round=r):
+                    for p in range(D):
+                        dl, dk, dv, dval = results[chosen[p]]
+                        keep = dval.astype(bool) & \
+                            (dv[:, value_words] == p)
+                        round_parts.append(_decode_rows(
+                            dl, dk,
+                            np.ascontiguousarray(dv[:, :value_words]),
+                            keep))
             else:
                 for d in range(D):
-                    events[d].wait()
+                    with tracing.span("exchange.readback", cat="exchange",
+                                      round=r, what="shard", device=d):
+                        events[d].wait()
                     if isinstance(results[d], BaseException):
                         raise results[d]
                     dl, dk, dv, dval = results[d]
-                    round_parts.append(
-                        _decode_rows(dl, dk, dv, dval.astype(bool)))
+                    with tracing.span("exchange.decode", cat="exchange",
+                                      round=r, device=d):
+                        round_parts.append(
+                            _decode_rows(dl, dk, dv, dval.astype(bool)))
             per_round_results.append(round_parts)
             metrics.observe("mesh.exchange.round",
                             (time.perf_counter() - t_round) * 1000.0,
@@ -759,57 +800,58 @@ class MeshExchangeCoordinator:
             g.find_counter("exchange.coded.buddy.wins").increment(
                 buddy_wins)
 
-        if len(per_round_results) == 1:
-            per_device = per_round_results[0]
-        else:
-            per_device = []
-            for w in range(D):
-                runs = [Run(res[w],
-                            np.array([0, res[w].num_records],
-                                     dtype=np.int64))
-                        for res in per_round_results
-                        if res[w].num_records > 0]
-                if not runs:
-                    per_device.append(KVBatch.empty())
-                elif len(runs) == 1:
-                    per_device.append(runs[0].batch)
-                else:
-                    per_device.append(merge_sorted_runs(
-                        runs, 1, num_lanes * 4, engine="host").batch)
-        if W == D and splits == 0:
-            return per_device
-        # general consumer assembly, covering both W > D (device d holds
-        # consumer partitions {c : c % D == d} key-sorted) and the
-        # splitter's merge-side recombine (a split consumer's rows landed
-        # on several devices; each device's slice is key-sorted, so a
-        # stable host merge in device order reassembles the partition with
-        # arrival-order ties).  The TRUE consumer hash (fnv % W) is always
-        # key-derivable, even for re-routed rows.
-        runs_per_consumer: List[List[KVBatch]] = [[] for _ in range(W)]
-        for d in range(D):
-            batch = per_device[d]
-            if batch.num_records == 0:
-                continue
-            bmat, blens = pad_to_matrix(batch.key_bytes, batch.key_offsets,
-                                        num_lanes * 4)
-            c_part = (fnv_rows_host(bmat, blens.astype(np.int64)) %
-                      np.uint32(W)).astype(np.int64)
-            for c in np.unique(c_part):
-                csel = np.flatnonzero(c_part == c)
-                runs_per_consumer[int(c)].append(batch.take(csel))
-        results_out: List[KVBatch] = []
-        for c in range(W):
-            runs = runs_per_consumer[c]
-            if not runs:
-                results_out.append(KVBatch.empty())
-            elif len(runs) == 1:
-                results_out.append(runs[0])
+        with tracing.span("exchange.decode", cat="exchange", stage="assemble"):
+            if len(per_round_results) == 1:
+                per_device = per_round_results[0]
             else:
-                results_out.append(merge_sorted_runs(
-                    [Run(b, np.array([0, b.num_records], dtype=np.int64))
-                     for b in runs], 1, num_lanes * 4,
-                    engine="host").batch)
-        return results_out
+                per_device = []
+                for w in range(D):
+                    runs = [Run(res[w],
+                                np.array([0, res[w].num_records],
+                                         dtype=np.int64))
+                            for res in per_round_results
+                            if res[w].num_records > 0]
+                    if not runs:
+                        per_device.append(KVBatch.empty())
+                    elif len(runs) == 1:
+                        per_device.append(runs[0].batch)
+                    else:
+                        per_device.append(merge_sorted_runs(
+                            runs, 1, num_lanes * 4, engine="host").batch)
+            if W == D and splits == 0:
+                return per_device
+            # general consumer assembly, covering both W > D (device d holds
+            # consumer partitions {c : c % D == d} key-sorted) and the
+            # splitter's merge-side recombine (a split consumer's rows landed
+            # on several devices; each device's slice is key-sorted, so a
+            # stable host merge in device order reassembles the partition with
+            # arrival-order ties).  The TRUE consumer hash (fnv % W) is always
+            # key-derivable, even for re-routed rows.
+            runs_per_consumer: List[List[KVBatch]] = [[] for _ in range(W)]
+            for d in range(D):
+                batch = per_device[d]
+                if batch.num_records == 0:
+                    continue
+                bmat, blens = pad_to_matrix(batch.key_bytes, batch.key_offsets,
+                                            num_lanes * 4)
+                c_part = (fnv_rows_host(bmat, blens.astype(np.int64)) %
+                          np.uint32(W)).astype(np.int64)
+                for c in np.unique(c_part):
+                    csel = np.flatnonzero(c_part == c)
+                    runs_per_consumer[int(c)].append(batch.take(csel))
+            results_out: List[KVBatch] = []
+            for c in range(W):
+                runs = runs_per_consumer[c]
+                if not runs:
+                    results_out.append(KVBatch.empty())
+                elif len(runs) == 1:
+                    results_out.append(runs[0])
+                else:
+                    results_out.append(merge_sorted_runs(
+                        [Run(b, np.array([0, b.num_records], dtype=np.int64))
+                         for b in runs], 1, num_lanes * 4,
+                        engine="host").batch)
+            return results_out
 
 
 _coordinator: Optional[MeshExchangeCoordinator] = None

@@ -8,7 +8,6 @@ batching events/counters, receiving routed events back).
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 import traceback
@@ -28,10 +27,6 @@ from tez_tpu.runtime.task_spec import TaskSpec
 log = logging.getLogger(__name__)
 
 HEARTBEAT_INTERVAL = 0.05
-
-#: serializes per-task XLA profiler traces (the profiler is process-global)
-_PROFILE_LOCK = threading.Lock()
-
 
 class TaskRunner:
     """Runs one task attempt to completion and reports to the umbilical."""
@@ -256,21 +251,7 @@ class TaskRunner:
         grouped = {v for g in self.spec.group_inputs for v in g.group_vertices}
         run_inputs = {name: inp for name, inp in self.inputs.items()
                       if name not in grouped}
-        # The TPU-native tracing story (SURVEY.md §5.1): a per-task XLA
-        # profiler trace (kernel timings, HBM traffic) viewable in
-        # TensorBoard/Perfetto, gated off by default
-        profile_dir = self.spec.conf.get("tez.task.jax-profile.dir", "")
-        if profile_dir:
-            import jax
-            trace_dir = os.path.join(str(profile_dir),
-                                     str(self.spec.attempt_id))
-            # the XLA profiler is process-global (one profile at a time);
-            # in-process runners share a process, so profiled tasks
-            # serialize — a debugging mode, not the hot path
-            with _PROFILE_LOCK, jax.profiler.trace(trace_dir):
-                self.processor.run(run_inputs, self.outputs)
-        else:
-            self.processor.run(run_inputs, self.outputs)
+        self.processor.run(run_inputs, self.outputs)
 
     def _close(self) -> None:
         self.check_killed()
@@ -278,8 +259,9 @@ class TaskRunner:
             evs = inp.close() or []
             if evs and not isinstance(inp, MergedLogicalInput):
                 inp.context.send_events(evs)
-        for out in self.outputs.values():
-            evs = out.close() or []
+        for name, out in self.outputs.items():
+            with tracing.span("output.close", cat="task", output=name):
+                evs = out.close() or []
             if evs:
                 out.context.send_events(evs)
         self.processor.close()

@@ -115,12 +115,15 @@ RECOVERY_REPLICA_COUNTERS = ("store.replica.bytes", "store.replica.failover")
 STREAM_HISTS = ("stream.window.latency", "stream.window.lag")
 
 
-#: Observability plane (obs/flight.py, am/admission.py).  Queue wait is
-#: admission pressure — growth means submissions parked longer before
-#: promotion; flight-dump wall is the recorder's own cost, which must
+#: Observability plane (obs/flight.py, am/admission.py,
+#: am/task_scheduler.py).  Queue wait is admission pressure — growth means
+#: submissions parked longer before promotion; task queue wait is a task
+#: attempt's scheduled -> picked-up-by-a-runner time; flight-dump wall is
+#: the recorder's own cost, which must
 #: stay negligible (a dump storm in B that A never paid shows up here
 #: before it shows up anywhere else).
-OBS_HISTS = ("am.admit.queue_wait", "obs.flight.dump")
+OBS_HISTS = ("am.admit.queue_wait", "am.task.queue_wait",
+             "obs.flight.dump")
 
 
 def tenant_summary(dags: Dict) -> Dict[str, Dict]:

@@ -49,8 +49,10 @@ def spans_to_events(spans: Iterable[Span]) -> List[Dict[str, Any]]:
     tid_names: Dict[int, str] = {}
     for sp in spans:
         end = sp.end if sp.end is not None else sp.start
+        # Span.thread is "<name>#<ident>": the lane is the thread (two
+        # threads of one name get two lanes), its label the readable part
         tid = _tid(sp.thread)
-        tid_names.setdefault(tid, sp.thread)
+        tid_names.setdefault(tid, sp.thread.split("#", 1)[0])
         args = dict(sp.args)
         args["trace_id"] = sp.trace_id
         args["span_id"] = sp.span_id
@@ -217,7 +219,11 @@ def critical_path(spans: List[Span]) -> List[Span]:
     """The longest causal chain: starting from each root span, follow the
     child whose end time is latest (what actually gated the parent's end),
     and return the root->leaf path of the trace that finished last.  Spans
-    still open (end is None) participate with their start as end."""
+    still open (end is None) participate with their start as end.  The AM's
+    own spans (cat ``am``: queue, task.done, commit) and its instants
+    (vertex boundaries, the last vertex's end) stand around the tasks' work
+    — they end after the last attempt by construction — so the chain
+    follows them only where a span has no other child."""
     by_parent: Dict[Optional[str], List[Span]] = {}
     roots: List[Span] = []
     ids = {sp.span_id for sp in spans}
@@ -239,7 +245,8 @@ def critical_path(spans: List[Span]) -> List[Span]:
         kids = by_parent.get(cur.span_id)
         if not kids:
             return path
-        cur = max(kids, key=end_of)
+        cur = max([k for k in kids if k.cat not in ("am", "instant")]
+                  or kids, key=end_of)
         path.append(cur)
 
 

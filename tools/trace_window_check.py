@@ -1,0 +1,174 @@
+"""One traced benchmark window, with the checks a result line does not carry.
+
+    python3 tools/trace_window_check.py --workload <cell> --seed <n> \
+        --seconds 45 --trace 1
+
+Runs ``benchmarks/run.py`` in this process with the same arguments (its
+result line goes to standard output as always).  The reduction stays the
+benchmark's: this tool listens at two of ``trace_reduce``'s own calls and
+adds nothing of its own to what they compute.
+
+at ``reduce_trace``  the profiler's file is still there (``run.py`` removes
+    its working directory afterwards): its size, the clock check, and the
+    ``XLA Modules`` events of the merge programs (what
+    ``merge_launches_per_dag`` has to agree with) from the planes
+    ``load_xplane`` returns
+at ``label_gap``     for each idle gap the reducer labels, every span name's
+    cover of it and not only the largest (``breakdown.idle_gaps`` keeps
+    that one)
+
+and, after the run, reads the span buffer for
+
+orphans      spans whose ``trace_id`` is no DAG's root span's, and spans
+             whose ``parent_id`` resolves to nothing recorded
+dropped      ``tracing.dropped()``
+spans_a_dag  spans of the window over the DAGs that started in it, by name
+self_s_a_dag ``trace_reduce.self_intervals`` over the window's
+             ``program_spans()``, by name, over those DAGs: what a task's
+             wall is made of
+
+The clock check: for every span with a ``tez.<name>`` twin in the profiler's
+file, |(annotation start - marker offset) - span start| — the reducer's
+marker arithmetic, checked at every span instead of at the marker alone.
+
+The summary is one JSON object on standard error (prefix ``trace_check:``)
+and in ``chiprun_out/trace_check_<workload>_<seed>.json``.
+"""
+from __future__ import annotations
+
+import bisect
+import collections
+import json
+import os
+import statistics
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks")]
+
+MERGE_PROGRAMS = ("_merge_path_pair_impl", "_merge_path_prep_impl",
+                  "_slice_to_bucket_impl", "_fused_resident_merge_impl")
+MATCH_WINDOW_S = 0.005
+
+
+def clock_check(path, spans, marks, mark_name):
+    """Spans against their annotation twins on the host planes."""
+    from jax.profiler import ProfileData
+    twins = collections.defaultdict(list)
+    mark_ns = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name == mark_name:
+                    mark_ns.append(e.start_ns)
+                elif e.name.startswith("tez."):
+                    twins[e.name[4:]].append(e.start_ns / 1e9)
+    offset = min(mark_ns) / 1e9 - marks["mark"]
+    for starts in twins.values():
+        starts.sort()
+    errors = []
+    for sp in spans:
+        starts = twins.get(sp.name)
+        if not starts or not marks["start"] <= sp.start <= marks["stop"]:
+            continue
+        want = sp.start + offset
+        i = bisect.bisect_left(starts, want)
+        near = min(abs(starts[j] - want) for j in (i - 1, i)
+                   if 0 <= j < len(starts))
+        if near <= MATCH_WINDOW_S:
+            errors.append(near)
+    out = {"twins": len(errors),
+           "annotations": sum(map(len, twins.values()))}
+    if errors:
+        errors.sort()
+        out.update(median_us=statistics.median(errors) * 1e6,
+                   p99_us=errors[int(0.99 * (len(errors) - 1))] * 1e6,
+                   max_us=errors[-1] * 1e6)
+    return out
+
+
+def main() -> int:
+    import run as bench_run
+    import trace_reduce
+    from tez_tpu.common import tracing
+
+    found = {"gaps": []}
+    label_gap = trace_reduce.label_gap
+
+    def reduce_and_keep(path, n_devices, spans, marks):
+        found["marks"] = dict(marks)
+        found["trace_bytes"] = os.path.getsize(path)
+        found["clock"] = clock_check(path, tracing.snapshot(), marks,
+                                     trace_reduce.MARK)
+        planes = trace_reduce.load_xplane(path)
+        found["merge_program_events"] = collections.Counter(
+            trace_reduce.program_name(e[0])
+            for p in planes["planes"] if trace_reduce.DEVICE_PLANE.match(
+                p["name"])
+            for line in p["lines"] if line["name"] == trace_reduce.MODULES_LINE
+            for e in line["events"]
+            if trace_reduce.program_name(e[0]) in MERGE_PROGRAMS)
+        return trace_reduce.reduce_planes(planes, n_devices, spans, marks)
+
+    def label_and_keep(lo, hi, selfs):
+        cover = collections.Counter()
+        for name, a, b in selfs:
+            if min(b, hi) > max(a, lo):
+                cover[name] += (min(b, hi) - max(a, lo)) / (hi - lo)
+        found["gaps"].append({
+            "seconds": hi - lo,
+            "threads_in": {k: round(v, 2) for k, v in cover.most_common()
+                           if v >= 0.05}})
+        return label_gap(lo, hi, selfs)
+
+    trace_reduce.reduce_trace = reduce_and_keep
+    trace_reduce.label_gap = label_and_keep
+    rc = bench_run.main(sys.argv[1:])
+    if "marks" not in found:
+        return rc
+
+    marks = found.pop("marks")
+    spans = [s for s in tracing.snapshot() if s.end is not None]
+    roots = {s.trace_id: s for s in spans if s.cat == "dag"}
+    ids = {s.span_id for s in spans}
+    orphans = collections.Counter(
+        s.name.split(":")[0] for s in spans if s.trace_id not in roots)
+    unresolved = collections.Counter(
+        s.name.split(":")[0] for s in spans
+        if s.parent_id and s.parent_id not in ids)
+    dags = sum(marks["start"] <= r.start <= marks["stop"]
+               for r in roots.values()) or 1
+    window = [row for row in trace_reduce.program_spans()
+              if marks["start"] <= row[1] <= marks["stop"]]
+    self_s = collections.Counter()
+    for name, a, b in trace_reduce.self_intervals(window):
+        self_s[name] += b - a
+    events = found.pop("merge_program_events")
+    args = dict(zip(sys.argv[1::2], sys.argv[2::2]))
+    summary = {
+        "workload": args.get("--workload"), "seed": args.get("--seed"),
+        "spans": len(spans), "dropped": tracing.dropped(),
+        "dag_roots": len(roots), "window_dags": dags,
+        "orphan_spans": dict(orphans), "unresolved_parents": dict(unresolved),
+        "spans_a_dag": len(window) / dags,
+        "spans_a_dag_by_name": {k: round(v / dags, 1) for k, v in
+                                collections.Counter(
+                                    row[0] for row in window).most_common()},
+        "self_s_a_dag": {k: round(v / dags, 4)
+                         for k, v in self_s.most_common()},
+        "merge_program_events": dict(events),
+        "merge_program_events_a_dag": sum(events.values()) / dags,
+        **found}
+    print("trace_check: " + json.dumps(summary), file=sys.stderr, flush=True)
+    out = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, f"trace_check_{summary['workload']}_"
+                                f"{summary['seed']}.json"), "w") as fh:
+        json.dump(summary, fh, indent=1)
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())

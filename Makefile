@@ -6,7 +6,7 @@ PY ?= python
 OLD ?= /tmp/bench_old.json
 NEW ?= /tmp/bench_new.json
 
-.PHONY: test lint smoke bench bench-new bench-diff bench-merge bench-store bench-sort bench-exchange bench-query chaos chaos-query-storm chaos-device-ooo chaos-device chaos-merge chaos-store chaos-push chaos-exchange chaos-ha chaos-stream chaos-slo-burn soak docs doctor top metrics-smoke
+.PHONY: test lint smoke bench bench-new bench-diff bench-store bench-sort bench-exchange bench-query chaos chaos-query-storm chaos-device-ooo chaos-device chaos-merge chaos-store chaos-push chaos-exchange chaos-ha chaos-stream chaos-slo-burn soak docs doctor top metrics-smoke
 
 test:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow'
@@ -32,11 +32,6 @@ bench-new:
 # (OLD and NEW are two captures made with bench-new on the same platform)
 bench-diff:
 	$(PY) -m tez_tpu.tools.bench_diff $(OLD) $(NEW)
-
-# reduce-side merge-path micro-bench only: prints the info-line JSON with
-# the min_vs_baseline ratio floor bench-diff enforces
-bench-merge:
-	JAX_PLATFORMS=cpu TEZ_BENCH_MERGE_ONLY=1 $(PY) bench.py
 
 # buffer-store short-circuit micro-bench only: store leased zero-copy fetch
 # vs loopback TCP, plus the lineage seal/republish session leg

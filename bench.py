@@ -11,8 +11,7 @@ Default run, one JSON line per leg (the LAST line is the headline):
 
 1. info: async device pipeline vs the numpy-lexsort host engine, with the
    per-stage breakdown.
-2. info: reduce-side merge-path ladder vs concatenate+re-sort.
-3. FRAMEWORK: OrderedWordCount end-to-end through the full stack — DAG
+2. FRAMEWORK: OrderedWordCount end-to-end through the full stack — DAG
    submission, vectorized tokenizer, device sorter, shuffle service,
    consumer merge, committed file output — following BASELINE.md's protocol
    (input MB/s, SHUFFLE_BYTES / SPILLED_RECORDS counters, output verified
@@ -20,12 +19,12 @@ Default run, one JSON line per leg (the LAST line is the headline):
    against the C++ reference-semantics OrderedWordCount proxy
    (native/baseline_proxy.cpp owc_proxy) on the identical corpus.  Runs in
    this same process: the parent holds the chip, so a child could not.
-4. KERNEL (headline): the partitioned sort + k-way merge core
+3. KERNEL (headline): the partitioned sort + k-way merge core
    (PipelinedSorter/TezMerger semantics, SURVEY.md §2.5) on synthetic
    records, device-resident, keys+values byte-verified; vs_baseline is the
    C++ PipelinedSorter/TezMerger proxy (no JVM in this image; BASELINE.md).
 
-``TEZ_BENCH_{MERGE,STORE,SORT,EXCHANGE,QUERY}_ONLY=1`` run one leg each
+``TEZ_BENCH_{STORE,SORT,EXCHANGE,QUERY}_ONLY=1`` run one leg each
 (the Makefile's bench-* targets).  These legs are what earlier rounds left;
 ROADMAP S1 replaces them with the cell table.
 """
@@ -161,59 +160,6 @@ def pipeline_path(spans, num_partitions: int, key_len: int):
         sched.submit_ragged(sid, kb, ko, vb, 8)
     sched.resume()
     return sched.results()
-
-
-def bench_merge(num_records: int, key_len: int) -> dict:
-    """Reduce-side merge micro-bench (info line): two pre-sorted
-    HBM-resident runs — the merge ladder's pairwise rung — merged by the
-    O(N) merge-path rank kernel vs concatenating and re-sorting the same
-    views.  The perm is bit-verified across kernels; vs_baseline =
-    re-sort wall / merge-path wall, and min_vs_baseline is the ratio
-    floor bench_diff enforces (the merge-path kernel must keep beating
-    concatenate+re-sort)."""
-    import jax.numpy as jnp
-    from tez_tpu.ops import device
-    from tez_tpu.ops.keycodec import matrix_to_lanes, pad_to_matrix
-    n = min(num_records, 1_000_000)
-    num_runs = 2
-    kb, _, _, _ = make_records(n, key_len, seed=3)
-    keys = kb.reshape(n, key_len)
-    per = n // num_runs
-    views, total_bytes = [], 0
-    for r in range(num_runs):
-        lo, hi = r * per, ((r + 1) * per if r < num_runs - 1 else n)
-        sub = keys[lo:hi]
-        order = np.lexsort([sub[:, j] for j in range(key_len - 1, -1, -1)])
-        flat = np.ascontiguousarray(sub[order]).reshape(-1)
-        offs = np.arange(hi - lo + 1, dtype=np.int64) * key_len
-        mat, lengths = pad_to_matrix(flat, offs, key_len)
-        views.append((jnp.asarray(matrix_to_lanes(mat)),
-                      jnp.asarray(lengths.astype(np.int32)), 0, hi - lo))
-        total_bytes += flat.nbytes
-
-    def once(kernel):
-        return np.asarray(device.merge_resident_slices(views, kernel=kernel))
-
-    p_mp, p_sort = once("merge_path"), once("sort")   # warm both programs
-    assert np.array_equal(p_mp, p_sort), \
-        "merge-path perm diverges from concat+re-sort"
-    reps = 3
-    t0 = time.time()
-    for _ in range(reps):
-        once("merge_path")
-    mp_s = (time.time() - t0) / reps
-    t0 = time.time()
-    for _ in range(reps):
-        once("sort")
-    sort_s = (time.time() - t0) / reps
-    return {
-        "metric": (f"reduce-side merge-path vs concat+re-sort (info line; "
-                   f"{num_runs} pre-sorted runs x {per} recs, HBM-resident, "
-                   f"perm bit-verified across kernels)"),
-        "value": round(total_bytes / 1e6 / mp_s, 2), "unit": "MB/s",
-        "vs_baseline": round(sort_s / mp_s, 3),
-        "min_vs_baseline": 1.3,
-    }
 
 
 def bench_store(num_records: int, key_len: int) -> dict:
@@ -502,11 +448,6 @@ def main() -> int:
         for rec in bench_query():
             emit(rec)
         return 0
-    if os.environ.get("TEZ_BENCH_MERGE_ONLY") == "1":
-        # make bench-merge: just the reduce-side merge-path info line
-        num_records = int(sys.argv[1]) if len(sys.argv) > 1 else 2_000_000
-        emit(bench_merge(num_records, 12))
-        return 0
     num_records = int(sys.argv[1]) if len(sys.argv) > 1 else 2_000_000
     key_len = 12
     num_producers, num_partitions = 4, 4
@@ -586,11 +527,7 @@ def main() -> int:
         "vs_baseline": round(host_s / tpu_s, 3),
         "stage_ms": stage_ms})
 
-    # -- stage 2: reduce-side merge-path micro-bench (info line; the
-    # bench_diff gate enforces its min_vs_baseline ratio floor)
-    emit(bench_merge(num_records, key_len))
-
-    # -- stage 3: framework E2E, in THIS process — it holds the chip
+    # -- stage 2: framework E2E, in THIS process — it holds the chip
     if os.environ.get("TEZ_BENCH_SKIP_E2E") != "1":
         emit(bench_framework(platform))
 

@@ -1,0 +1,269 @@
+"""Byte-exactness and launch shape of the device merge (ops/device.py
+merge_runs / merge_resident_slices): ONE stable sort of ONE padded
+concatenation of the runs, against the host merge engine and Python's
+stable ``sorted``.
+
+The contract under test is the TezMerger MergeQueue one: merged output is
+(partition, key)-sorted with equal (partition, key) groups emitting in run
+arrival order — keys AND values byte-identical across engines, across the
+property matrix (random widths past the lane cap, duplicate-heavy keys,
+empty runs, single runs, > merge_factor cascades).
+"""
+import random
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tez_tpu.common.counters import TaskCounter, TezCounters
+from tez_tpu.ops import device
+from tez_tpu.ops.keycodec import matrix_to_lanes, pad_to_matrix
+from tez_tpu.ops.runformat import KVBatch, Run
+from tez_tpu.ops.sorter import merge_sorted_runs
+
+from test_ops import golden_sorted, random_pairs
+
+
+def _partition_sorted_run(pairs, num_partitions):
+    golden = golden_sorted(pairs, num_partitions)
+    batch = KVBatch.from_pairs([(k, v) for _, k, _, v in golden])
+    counts = np.bincount([p for p, *_ in golden], minlength=num_partitions)
+    row_index = np.zeros(num_partitions + 1, dtype=np.int64)
+    np.cumsum(counts, out=row_index[1:])
+    return Run(batch, row_index)
+
+
+def _merge_both_engines(chunks, num_partitions, key_width, merge_factor=0):
+    """Merge the same pre-sorted runs through the device merge and the host
+    engine; return both pair lists."""
+    runs_d = [_partition_sorted_run(c, num_partitions) for c in chunks]
+    runs_h = [_partition_sorted_run(c, num_partitions) for c in chunks]
+    dev = merge_sorted_runs(runs_d, num_partitions, key_width,
+                            engine="device", merge_factor=merge_factor,
+                            device_min_records=0)
+    host = merge_sorted_runs(runs_h, num_partitions, key_width,
+                             engine="host", merge_factor=merge_factor)
+    return dev, host
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_device_merge_matches_host_engine_property_matrix(seed):
+    rng = random.Random(seed)
+    num_partitions = rng.choice([1, 4, 7])
+    key_width = rng.choice([4, 12, 16])
+    # max_key beyond key_width exercises the beyond-cap host tie-break;
+    # small alphabets force duplicate keys across and within runs
+    max_key = rng.choice([3, key_width, key_width + 9])
+    k = rng.randrange(2, 7)
+    chunks = []
+    for i in range(k):
+        n = rng.choice([0, 1, rng.randrange(2, 400)])
+        chunks.append([(bytes(rng.randrange(4) for _ in
+                        range(rng.randrange(1, max_key + 1))),
+                        bytes([i, j % 256])) for j in range(n)])
+    dev, host = _merge_both_engines(chunks, num_partitions, key_width)
+    assert list(dev.batch.iter_pairs()) == list(host.batch.iter_pairs())
+    np.testing.assert_array_equal(dev.row_index, host.row_index)
+
+
+def test_device_merge_equal_keys_keep_run_arrival_order():
+    # every run holds the SAME keys; values carry (run, row) so any tie
+    # mis-order is visible in the value column
+    keys = [b"a", b"a", b"b", b"zz"]
+    chunks = [[(k, bytes([r, j])) for j, k in enumerate(keys)]
+              for r in range(5)]
+    dev, host = _merge_both_engines(chunks, 2, 8)
+    got = list(dev.batch.iter_pairs())
+    assert got == list(host.batch.iter_pairs())
+    for key in set(keys):
+        runs_seen = [v[0] for kk, v in got if kk == key]
+        assert runs_seen == sorted(runs_seen)
+
+
+def test_device_merge_single_run_and_all_empty():
+    pairs = random_pairs(200, seed=9)
+    dev, host = _merge_both_engines([pairs], 3, 16)
+    assert list(dev.batch.iter_pairs()) == list(host.batch.iter_pairs())
+    dev, host = _merge_both_engines([[], [], []], 3, 16)
+    assert dev.batch.num_records == 0
+    assert list(dev.batch.iter_pairs()) == list(host.batch.iter_pairs())
+
+
+def test_device_merge_cascade_beyond_merge_factor():
+    pairs = random_pairs(700, seed=10, max_key=6)   # duplicate-heavy
+    chunks = [pairs[i::7] for i in range(7)]
+    dev, host = _merge_both_engines(chunks, 4, 16, merge_factor=3)
+    one_pass, _ = _merge_both_engines(chunks, 4, 16)
+    assert list(dev.batch.iter_pairs()) == list(host.batch.iter_pairs())
+    assert list(dev.batch.iter_pairs()) == list(one_pass.batch.iter_pairs())
+
+
+def _key_columns(keys, key_width):
+    b = KVBatch.from_pairs([(k, b"") for k in keys])
+    mat, lengths = pad_to_matrix(b.key_bytes, b.key_offsets, key_width)
+    return matrix_to_lanes(mat), lengths
+
+
+def _resident_view(keys, key_width):
+    """Device-resident (lanes, lengths, lo, hi) view of an already-sorted
+    key list — the dev_keys shape producers hand to the resident merge."""
+    lanes, lengths = _key_columns(keys, key_width)
+    return (jnp.asarray(lanes), jnp.asarray(lengths.astype(np.int32)),
+            0, len(keys))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_resident_merge_is_the_stable_sort_of_the_concatenation(seed):
+    rng = random.Random(100 + seed)
+    key_width = rng.choice([4, 8])
+    views, all_keys = [], []
+    for _ in range(rng.randrange(2, 6)):
+        n = rng.choice([1, rng.randrange(1, 300)])
+        keys = sorted(bytes(rng.randrange(5) for _ in
+                            range(rng.randrange(1, key_width + 1)))
+                      for _ in range(n))
+        views.append(_resident_view(keys, key_width))
+        all_keys.extend(keys)
+    perm = device.merge_resident_slices(views)
+    # Python's sorted is stable: ties resolve by position in the concat
+    want = sorted(range(len(all_keys)), key=all_keys.__getitem__)
+    np.testing.assert_array_equal(perm, want)
+
+
+# ---------------------------------------------------- the mechanism itself
+
+def _host_fed_runs(sizes, seed=0):
+    rng = random.Random(seed)
+    runs = []
+    for r, n in enumerate(sizes):
+        keys = sorted(f"w{rng.randrange(10 ** 7):07d}".encode()
+                      for _ in range(n))
+        batch = KVBatch.from_pairs([(k, bytes([r])) for k in keys])
+        runs.append(Run(batch, np.array([0, n], dtype=np.int64)))
+    return runs
+
+
+def _merge_counted(runs):
+    counters = TezCounters()
+    merged = merge_sorted_runs(runs, 1, 8, counters=counters,
+                               engine="device", device_min_records=0)
+    return merged, {t: counters.find_counter(t).value for t in (
+        TaskCounter.DEVICE_MERGE_RECORDS, TaskCounter.DEVICE_MERGE_LAUNCHES,
+        TaskCounter.DEVICE_MERGE_LAUNCH_ROWS)}
+
+
+@pytest.mark.parametrize("k", range(2, 6))
+def test_host_fed_merge_is_one_launch_on_one_bucket(k):
+    """Whatever k: ONE program, and it is a comparing one (only
+    MERGE_LEVEL_KERNELS count rows), launched on the bucket of the sum —
+    not k runs each padded to the largest run's bucket."""
+    sizes = [6000 + 700 * i for i in range(k)]
+    merged, c = _merge_counted(_host_fed_runs(sizes, seed=k))
+    assert c[TaskCounter.DEVICE_MERGE_RECORDS] == sum(sizes)
+    assert c[TaskCounter.DEVICE_MERGE_LAUNCHES] == 1
+    assert c[TaskCounter.DEVICE_MERGE_LAUNCH_ROWS] == device._bucket(
+        sum(sizes))
+    keys = [merged.batch.key(i) for i in range(merged.batch.num_records)]
+    assert keys == sorted(keys)
+
+
+def test_run_sizes_with_one_sum_bucket_share_one_compiled_program():
+    """The compile key is (bucket of the sum, lanes, the length-pass flag):
+    how the rows are split into runs never reaches it."""
+    device._merge_sort._compiled.clear()
+    for sizes in ([9000, 9000, 9000], [20000, 300, 40], [5000] * 5,
+                  [17000, 1]):
+        assert device._bucket(sum(sizes)) == 1 << 15
+        merge_sorted_runs(_host_fed_runs(sizes), 1, 8, engine="device",
+                          device_min_records=0)
+    assert device._merge_sort.cache_size() == 1
+
+
+ALL_FF = b"\xff" * 8
+
+
+def test_host_fed_all_ff_keys_at_the_lane_cap_sort_before_the_pads():
+    """Real rows of 0xFF bytes filling every lane look like nothing else a
+    pad could be told from by its lanes: the partition pass alone places
+    the pads, so no pad index reaches the permutation."""
+    runs = [[b"a", ALL_FF, ALL_FF], [ALL_FF], [b"b", b"c", ALL_FF]]
+    keys = [k for run in runs for k in run]
+    lanes, lengths = _key_columns(keys, 8)
+    partitions = np.zeros(len(keys), dtype=np.int32)
+    perm = device.merge_runs(partitions, lanes, lengths)
+    np.testing.assert_array_equal(
+        perm, sorted(range(len(keys)), key=keys.__getitem__))
+    # a partition id as large as they come is still under the pads'
+    perm = device.merge_runs(partitions + (np.iinfo(np.int32).max - 1),
+                             lanes, lengths)
+    np.testing.assert_array_equal(np.sort(perm), np.arange(len(keys)))
+
+
+def test_resident_all_ff_keys_at_the_lane_cap_sort_before_the_pads():
+    runs = [[b"a", ALL_FF, ALL_FF], [ALL_FF], [b"b", b"c", ALL_FF]]
+    keys = [k for run in runs for k in run]
+    perm = device.merge_resident_slices([_resident_view(r, 8) for r in runs])
+    np.testing.assert_array_equal(
+        perm, sorted(range(len(keys)), key=keys.__getitem__))
+    # one length everywhere, the length pass left out: pads still last
+    same_length = [[ALL_FF, ALL_FF], [b"aaaaaaaa", ALL_FF]]
+    perm = device.merge_resident_slices(
+        [_resident_view(r, 8) for r in same_length], uniform_lengths=True)
+    np.testing.assert_array_equal(perm, [2, 0, 1, 3])
+
+
+def _static_flags(kernel):
+    return {dict(key[2])["skip_length_pass"] for key in kernel._compiled}
+
+
+def test_uniform_lengths_skip_the_length_pass_and_mixed_do_not():
+    """What decides is the input: one clamped length on every real row
+    compiles (and runs) the program without the length pass; one odd row
+    brings the pass back.  Same order from either program."""
+    rng = random.Random(5)
+    keys = sorted(f"w{rng.randrange(10 ** 4):07d}".encode()
+                  for _ in range(500)) * 2          # two equal runs
+    lanes, lengths = _key_columns(keys, 8)
+    partitions = np.zeros(len(keys), dtype=np.int32)
+    want = sorted(range(len(keys)), key=keys.__getitem__)
+
+    device._merge_sort._compiled.clear()
+    np.testing.assert_array_equal(
+        device.merge_runs(partitions, lanes, lengths), want)
+    assert _static_flags(device._merge_sort) == {True}
+    # the full program on the same rows: identical permutation
+    nb = device._bucket(len(keys))
+    full = device._merge_sort(
+        jnp.asarray(np.pad(partitions, (0, nb - len(keys)),
+                           constant_values=np.iinfo(np.int32).max)),
+        jnp.asarray(np.pad(lanes, ((0, nb - len(keys)), (0, 0)))),
+        jnp.asarray(np.pad(lengths.astype(np.uint32), (0, nb - len(keys)),
+                           constant_values=9)),
+        skip_length_pass=False)
+    np.testing.assert_array_equal(np.asarray(full)[:len(keys)], want)
+
+    device._merge_sort._compiled.clear()
+    mixed = [b"w"] + keys[:499] + keys[500:]        # still two sorted runs
+    lanes, lengths = _key_columns(mixed, 8)
+    np.testing.assert_array_equal(
+        device.merge_runs(partitions, lanes, lengths),
+        sorted(range(len(mixed)), key=mixed.__getitem__))
+    assert _static_flags(device._merge_sort) == {False}
+
+
+def test_resident_merge_reads_uniformity_from_the_runs():
+    """merge_sorted_runs makes the span sort's own test on the runs it is
+    handed and the resident program is compiled to match."""
+    def resident_run(keys):
+        batch = KVBatch.from_pairs([(k, b"v") for k in keys])
+        batch.dev_keys = _resident_view(keys, 8)
+        return Run(batch, np.array([0, len(keys)], dtype=np.int64))
+
+    for keys_a, flag in (([b"aaaa", b"cccc"], True), ([b"a", b"cccc"], False)):
+        device._fused_resident_merge._compiled.clear()
+        merged = merge_sorted_runs(
+            [resident_run(keys_a), resident_run([b"bbbb", b"cccc"])], 1, 8,
+            engine="device", device_min_records=0)
+        assert [merged.batch.key(i) for i in range(4)] == \
+            sorted(keys_a + [b"bbbb", b"cccc"])
+        assert _static_flags(device._fused_resident_merge) == {flag}

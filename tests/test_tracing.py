@@ -419,7 +419,7 @@ NEW_SPAN_SITES = [
     ("spill.write", "spill"), ("spill.read", "spill"),
     ("merge.stage", "merge"), ("merge.launch", "merge"),
     ("merge.readback", "merge"), ("merge.gather", "merge"),
-    ("kernel.merge_path_pair", "kernel"), ("kernel.compile", "kernel"),
+    ("kernel.merge_sort", "kernel"), ("kernel.compile", "kernel"),
     ("exchange.wait_peers", "exchange"), ("exchange.plan", "exchange"),
     ("exchange.pack", "exchange"), ("exchange.launch", "exchange"),
     ("exchange.readback", "exchange"), ("exchange.decode", "exchange"),
@@ -528,7 +528,7 @@ def test_start_span_can_open_in_the_past():
 # ------------------------------------------------ launch counters (ISSUE 26)
 
 def _four_run_merge():
-    """Four sorted runs of 300 rows through the device merge-path ladder."""
+    """Four sorted runs of 300 rows through the host-fed device merge."""
     import numpy as np
     from tez_tpu.ops.runformat import KVBatch, Run
     from tez_tpu.ops.sorter import merge_sorted_runs
@@ -543,21 +543,18 @@ def _four_run_merge():
     return counters, merged
 
 
-def test_merge_launch_rows_are_levels_times_padding():
-    """Worked by hand: 4 runs x 300 rows pad to the common bucket 512.
-    Level 1: two pair merges of 512+512; level 2: one of 1024+1024 — three
-    comparing launches on 4096 rows for 1200 records: 2 levels x (2048 /
-    1200) padding.  With the four prep programs: 7 launches."""
+def test_merge_launch_rows_are_the_padded_concatenation():
+    """Worked by hand: 4 runs x 300 rows are 1200 records, sorted in ONE
+    launch on the bucket of their sum, 2048 rows: padding 2048 / 1200, no
+    levels (the ladder this replaced launched 7 programs on 4096 rows)."""
     from tez_tpu.common.counters import TaskCounter
     counters, merged = _four_run_merge()
     c = {t: counters.find_counter(t).value for t in (
         TaskCounter.DEVICE_MERGE_RECORDS, TaskCounter.DEVICE_MERGE_LAUNCHES,
         TaskCounter.DEVICE_MERGE_LAUNCH_ROWS)}
     assert c[TaskCounter.DEVICE_MERGE_RECORDS] == 1200
-    assert c[TaskCounter.DEVICE_MERGE_LAUNCH_ROWS] == 2 * 4 * 512
-    assert c[TaskCounter.DEVICE_MERGE_LAUNCHES] == 4 + 3
-    assert c[TaskCounter.DEVICE_MERGE_LAUNCH_ROWS] / \
-        c[TaskCounter.DEVICE_MERGE_RECORDS] == 2 * (4 * 512 / 1200)
+    assert c[TaskCounter.DEVICE_MERGE_LAUNCH_ROWS] == 2048
+    assert c[TaskCounter.DEVICE_MERGE_LAUNCHES] == 1
     keys = [merged.batch.key(i) for i in range(merged.batch.num_records)]
     assert keys == sorted(keys)
 
@@ -670,7 +667,7 @@ def test_owc_every_span_hangs_under_the_dag_root(traced_owc):
     assert {"device.encode", "device.h2d", "device.dispatch", "device.d2h",
             "sort.collect", "sort.flush", "sort.final_merge",
             "merge.stage", "merge.launch", "merge.readback", "merge.gather",
-            "spill.write", "spill.read", "kernel.merge_path_pair",
+            "spill.write", "spill.read", "kernel.merge_sort",
             "input.open", "input.read", "input.group",
             "processor.tokenize", "processor.sum", "processor.format",
             "output.write", "output.close", "output.commit",

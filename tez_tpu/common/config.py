@@ -715,7 +715,7 @@ MERGE_ENGINE = _key(
     "engine for the reduce-side merge plane (ShuffleMergeManager / "
     "merge_sorted_runs on the consumer): device|host|auto; '' = follow "
     "tez.runtime.sorter.class.  The device engine merges pre-sorted runs "
-    "with the O(N) merge-path ladder over HBM-resident key lanes")
+    "by one stable sort of their padded concatenation")
 MERGE_ENGINE_MIN_RECORDS = _key(
     "tez.runtime.merge.engine.min-records", 0, Scope.VERTEX,
     "merges smaller than this many records run on host even under the "

@@ -123,8 +123,8 @@ WELL_KNOWN_HISTOGRAMS = ("shuffle.fetch.rtt", "spill.write", "shuffle.merge",
                          # latency, D2H readback
                          "device.encode", "device.h2d",
                          "device.dispatch_wait", "device.d2h",
-                         # reduce-side device merge latency: merge-path
-                         # ladder dispatches (ops/sorter.py merge_sorted_runs)
+                         # reduce-side device merge latency: merge
+                         # dispatches (ops/sorter.py merge_sorted_runs)
                          # and the async merge lane's dispatch->host-visible
                          # wait (library/merge_manager.py)
                          "device.merge",

@@ -5,10 +5,10 @@ The spill-scale analog of TezMerger's record-streaming MergeQueue
 this framework's batch-first data plane: instead of a per-record Python heap
 (one compare + one yield per record — the round-3 45x spill cliff), sources
 advance one *block prefix* at a time and every prefix set merges with the
-vectorized run merge (`ops.sorter.merge_sorted_runs` — numpy lexsort on the
-host, or the device merge-path kernel: the slices handed over are already
-sorted, so the device ranks rows by partitioned binary search instead of
-re-sorting them), so Python cost is O(blocks), not O(records).
+vectorized run merge (`ops.sorter.merge_sorted_runs` — the native merge on
+the host, or on the device one stable sort of the slices' concatenation,
+padded once to the bucket of its rows), so Python cost is O(blocks), not
+O(records).
 
 Algorithm (classic tournament over block boundaries):
   each source = iterator of KVBatch blocks, each internally sorted and

@@ -504,7 +504,8 @@ _slice_to_bucket = Kernel(_slice_to_bucket_impl, "slice_to_bucket",
                           static_argnames=("out_rows", "out_lanes"))
 
 
-def _fused_resident_merge_impl(lanes_list, lens_list):
+def _fused_resident_merge_impl(lanes_list, lens_list,
+                               skip_length_pass: bool = False):
     """Single-partition k-way merge of device-resident sorted key columns:
     stable sort of the concatenation (TezMerger semantics — equal keys keep
     run order).  Sentinel rows (length < 0) sort to the tail."""
@@ -514,12 +515,13 @@ def _fused_resident_merge_impl(lanes_list, lens_list):
                       jnp.int32(0))
     sort_lens = jnp.where(lens < 0, jnp.uint32(0xFFFFFFFF),
                           lens.astype(jnp.uint32))
-    _, perm = _lsd_passes(parts, lanes, sort_lens)
+    _, perm = _lsd_passes(parts, lanes, sort_lens, skip_length_pass)
     return perm
 
 
 _fused_resident_merge = Kernel(
     _fused_resident_merge_impl, "resident_merge_sort",
+    static_argnames=("skip_length_pass",),
     launch_rows=lambda lanes_list, lens_list: sum(
         int(l.shape[0]) for l in lanes_list))
 
@@ -538,19 +540,17 @@ def _map_bucketed_perm(perm: np.ndarray, counts, common: int) -> np.ndarray:
     return (host_offsets[run_id] + within)[real].astype(np.int64)
 
 
-def merge_resident_slices(slices, kernel: str = "merge_path") -> np.ndarray:
-    """k-way merge over device-resident key views.
+def merge_resident_slices(slices, uniform_lengths: bool = False
+                          ) -> np.ndarray:
+    """k-way merge over device-resident key views: one stable sort of the
+    slices' concatenation, each slice cut to a common bucket on the device.
 
-    slices: list of (lanes_dev, lens_dev, lo, hi) with identical lane
-    counts.  Returns the merge permutation into the HOST concatenation of
-    the real rows (run order preserved for equal keys).  No key bytes move
-    host->device; only the permutation comes back.
-
-    kernel="merge_path" (default) runs the O(N) partitioned binary-merge
-    ladder — each level ranks every row of one run in its sibling, so a
-    k-way merge is log2(k) linear passes instead of one O(N log N) re-sort
-    of the concatenation.  kernel="sort" keeps the concatenate+re-sort
-    program callable (bench comparison, escape hatch)."""
+    slices: list of (lanes_dev, lens_dev, lo, hi).  Returns the merge
+    permutation into the HOST concatenation of the real rows (run order
+    preserved for equal keys).  No key bytes move host->device; only the
+    permutation comes back.  uniform_lengths: the caller saw one key length
+    on every real row (uniform_clamped_lengths), so the length pass is an
+    identity reorder and is left out of the program."""
     counts = [hi - lo for (_l, _n, lo, hi) in slices]
     # ONE common bucket for every slice: the merge program's compile key is
     # then (k, B, L) instead of the full ordered tuple of per-run sizes —
@@ -568,10 +568,8 @@ def merge_resident_slices(slices, kernel: str = "merge_path") -> np.ndarray:
             lanes_list.append(sl)
             lens_list.append(ln)
     with tracing.span("merge.launch", cat="merge", runs=len(slices)):
-        if kernel == "merge_path":
-            perm_dev = _merge_path_resident(lanes_list, lens_list, common)
-        else:
-            perm_dev = _fused_resident_merge(lanes_list, lens_list)
+        perm_dev = _fused_resident_merge(lanes_list, lens_list,
+                                         skip_length_pass=uniform_lengths)
     # the host blocks here for the device: this merge's own work and every
     # launch other threads queued ahead of it on the chip
     with tracing.span("merge.readback", cat="merge",
@@ -579,181 +577,6 @@ def merge_resident_slices(slices, kernel: str = "merge_path") -> np.ndarray:
         perm = np.asarray(perm_dev)
     with tracing.span("merge.gather", cat="merge", rows=sum(counts)):
         return _map_bucketed_perm(perm, counts, common)
-
-
-# ---------------------------------------------------------------------------
-# merge-path kernel: O(N) two-way merge of pre-sorted runs via cross-ranks.
-# out_pos(a_i) = i + |{b : b < a_i}| and out_pos(b_j) = j + |{a : a <= b_j}|
-# tile [0, na+nb) exactly (the asymmetric <=/< pair is what makes equal keys
-# emit in run-arrival order — the earlier run wins, matching the stable
-# concatenate+sort kernel and TezMerger's MergeQueue).  A k-way merge is a
-# log2(k) ladder of pair merges; runs stay HBM-resident between levels, so
-# encode/H2D is paid once per cascade instead of once per level.
-# ---------------------------------------------------------------------------
-def _lex_lt(al: jnp.ndarray, alen: jnp.ndarray,
-            bl: jnp.ndarray, blen: jnp.ndarray) -> jnp.ndarray:
-    """Row-wise (lanes..., length) lexicographic less-than over equal-shape
-    batches — the SAME composite comparator the LSD sort kernels order by
-    (lane 0 most significant, clamped length last).  Sentinel rows carry
-    length 0xFFFFFFFF, above any real clamped length, so an all-FF real key
-    still sorts before the pad tail."""
-    res = alen < blen
-    for i in range(al.shape[1] - 1, -1, -1):
-        res = jnp.where(al[:, i] == bl[:, i], res, al[:, i] < bl[:, i])
-    return res
-
-
-def _rank_search(run_lanes: jnp.ndarray, run_lens: jnp.ndarray,
-                 q_lanes: jnp.ndarray, q_lens: jnp.ndarray,
-                 count_equal: bool) -> jnp.ndarray:
-    """Vectorized binary search: rank of every query row in the sorted run.
-    count_equal=False counts strictly-less rows, True counts less-or-equal
-    (resolved at trace time — two compiled flavors).  O(m log n) total work
-    versus the O((m+n) log(m+n)) comparator sort it replaces."""
-    n = run_lanes.shape[0]
-    m = q_lanes.shape[0]
-    lo = jnp.zeros((m,), jnp.int32)
-    hi = jnp.full((m,), n, jnp.int32)
-
-    def body(_, carry):
-        lo, hi = carry
-        mid = (lo + hi) >> 1
-        mid_l = jnp.take(run_lanes, mid, axis=0)
-        mid_n = jnp.take(run_lens, mid, axis=0)
-        if count_equal:   # run[mid] <= q  <=>  not (q < run[mid])
-            before = ~_lex_lt(q_lanes, q_lens, mid_l, mid_n)
-        else:             # run[mid] < q
-            before = _lex_lt(mid_l, mid_n, q_lanes, q_lens)
-        active = lo < hi
-        lo = jnp.where(active & before, mid + 1, lo)
-        hi = jnp.where(active & ~before, mid, hi)
-        return lo, hi
-
-    lo, _ = jax.lax.fori_loop(0, n.bit_length() + 1, body, (lo, hi))
-    return lo
-
-
-def _merge_path_pair_impl(a_lanes, a_lens, a_idx, b_lanes, b_lens, b_idx):
-    """One O(na+nb) merge level: scatter both runs straight to their output
-    positions.  Sentinel rows participate too — A-sentinel i lands at
-    i + realB and B-sentinel j at j + na, so the scatter is a collision-free
-    permutation with every real row in the prefix and the output again a
-    sorted run (ladder levels compose without re-compacting)."""
-    na, nb = a_lanes.shape[0], b_lanes.shape[0]
-    ra = _rank_search(b_lanes, b_lens, a_lanes, a_lens, count_equal=False)
-    rb = _rank_search(a_lanes, a_lens, b_lanes, b_lens, count_equal=True)
-    pos_a = jnp.arange(na, dtype=jnp.int32) + ra
-    pos_b = jnp.arange(nb, dtype=jnp.int32) + rb
-    out_lanes = jnp.empty((na + nb, a_lanes.shape[1]), a_lanes.dtype)
-    out_lanes = out_lanes.at[pos_a].set(a_lanes).at[pos_b].set(b_lanes)
-    out_lens = jnp.empty((na + nb,), a_lens.dtype)
-    out_lens = out_lens.at[pos_a].set(a_lens).at[pos_b].set(b_lens)
-    out_idx = jnp.empty((na + nb,), a_idx.dtype)
-    out_idx = out_idx.at[pos_a].set(a_idx).at[pos_b].set(b_idx)
-    return out_lanes, out_lens, out_idx
-
-
-_merge_path_pair = Kernel(
-    _merge_path_pair_impl, "merge_path_pair",
-    launch_rows=lambda a_lanes, a_lens, a_idx, b_lanes, b_lens, b_idx:
-    int(a_lanes.shape[0]) + int(b_lanes.shape[0]))
-
-#: the programs that compare rows in a merge; ``slice_to_bucket`` and
-#: ``merge_path_prep`` stage their operands.  DEVICE_MERGE_LAUNCH_ROWS sums
-#: the rows of these, so that over DEVICE_MERGE_RECORDS it reads levels x
-#: padding (ops/sorter.py _record_launches).
-MERGE_LEVEL_KERNELS = ("merge_path_pair", "resident_merge_sort")
-
-
-def _merge_path_prep_impl(lanes, lens, base):
-    """Per-run ladder prep: int32 lengths (-1 pad sentinel) -> u32 sort
-    lengths (0xFFFFFFFF sentinel) + global bucket indices.  `base` is a
-    dynamic argument so per-run offsets don't multiply compile keys."""
-    sort_lens = jnp.where(lens < 0, jnp.uint32(0xFFFFFFFF),
-                          lens.astype(jnp.uint32))
-    idx = base + jnp.arange(lanes.shape[0], dtype=jnp.int32)
-    return sort_lens, idx
-
-
-_merge_path_prep = Kernel(_merge_path_prep_impl, "merge_path_prep")
-
-
-def _merge_path_ladder(runs):
-    """log2(k) ladder over (lanes, sort_lens, idx) triples: pair adjacent
-    runs left-to-right (odd last carries up) so equal keys meet in run
-    order at every level.  Returns the final idx column (the merge
-    permutation over the bucketed concatenation); everything stays on
-    device until the caller reads it back."""
-    while len(runs) > 1:
-        nxt = [_merge_path_pair(*runs[i], *runs[i + 1])
-               for i in range(0, len(runs) - 1, 2)]
-        if len(runs) % 2:
-            nxt.append(runs[-1])
-        runs = nxt
-    return runs[0][2]
-
-
-def _merge_path_resident(lanes_list, lens_list, common: int):
-    runs = []
-    for i, (sl, ln) in enumerate(zip(lanes_list, lens_list)):
-        sort_lens, idx = _merge_path_prep(sl, ln, np.int32(i * common))
-        runs.append((sl, sort_lens, idx))
-    return _merge_path_ladder(runs)
-
-
-def merge_path_runs(parts_list: list[np.ndarray],
-                    lanes_list: list[np.ndarray],
-                    lengths_list: list[np.ndarray]) -> np.ndarray:
-    """Generic (non-resident) k-way merge-path merge of pre-sorted runs.
-
-    Each run is sorted by (partition, key lanes, clamped length); the
-    partition id is prepended as the most-significant u32 lane so the
-    composite comparator reproduces partition-major order.  Returns the
-    merge permutation into the host concatenation of the runs (equal keys
-    in run-arrival order).  Like sort_run, prefix-equal beyond-cap keys
-    compare equal here and are resolved by the host tie-break pass."""
-    counts = [l.shape[0] for l in lanes_list]
-    live = [i for i, c in enumerate(counts) if c > 0]
-    if not live:
-        return np.zeros(0, dtype=np.int64)
-    width = max(lanes_list[i].shape[1] for i in live)
-    width_cap = width * 4 + 1
-    common = _bucket(max(counts[i] for i in live))
-    runs = []
-    # each run's prep is enqueued as the run is uploaded: its launch stands
-    # inside merge.stage as a kernel.merge_path_prep span of its own
-    with tracing.span("merge.stage", cat="merge", runs=len(live),
-                      rows=sum(counts), bucket=common):
-        for j, i in enumerate(live):
-            n = counts[i]
-            comp = np.empty((common, width + 1), dtype=np.uint32)
-            comp[:n, 0] = parts_list[i].astype(np.uint32)
-            comp[:n, 1:1 + lanes_list[i].shape[1]] = lanes_list[i]
-            comp[:n, 1 + lanes_list[i].shape[1]:] = 0
-            comp[n:] = np.uint32(0xFFFFFFFF)
-            lens = np.full(common, -1, dtype=np.int32)
-            lens[:n] = np.minimum(lengths_list[i].astype(np.int64),
-                                  width_cap)
-            comp_dev = jnp.asarray(comp)
-            sort_lens, idx = _merge_path_prep(comp_dev, jnp.asarray(lens),
-                                              np.int32(j * common))
-            runs.append((comp_dev, sort_lens, idx))
-    with tracing.span("merge.launch", cat="merge", runs=len(live)):
-        perm_dev = _merge_path_ladder(runs)
-    with tracing.span("merge.readback", cat="merge",
-                      rows=common * len(live)):
-        perm = np.asarray(perm_dev)
-    with tracing.span("merge.gather", cat="merge", rows=sum(counts)):
-        mapped = _map_bucketed_perm(perm, [counts[i] for i in live], common)
-        if len(live) != len(counts):   # re-offset into the FULL concatenation
-            all_offsets = np.zeros(len(counts), dtype=np.int64)
-            np.cumsum(counts[:-1], out=all_offsets[1:])
-            live_offsets = np.zeros(len(live), dtype=np.int64)
-            np.cumsum([counts[i] for i in live[:-1]], out=live_offsets[1:])
-            run_id = np.searchsorted(live_offsets[1:], mapped, side="right")
-            mapped = mapped - live_offsets[run_id] + \
-                all_offsets[np.asarray(live)[run_id]]
-        return mapped
 
 
 def _fused_hash_sort_impl(key_mat: jnp.ndarray, hash_lengths: jnp.ndarray,
@@ -813,6 +636,28 @@ def hash_sort_span(key_mat: np.ndarray, hash_lengths: np.ndarray,
     return sp, perm
 
 
+def _stage_sort_columns(partitions: np.ndarray, lanes: np.ndarray,
+                        lengths: np.ndarray):
+    """Clamp lengths at the lane cap, pad the three sort columns to the
+    bucket of their rows (pads carry partition MAX: the partition pass
+    alone sweeps them to the tail) and upload.  Returns (device operands,
+    uniform) — uniform: one clamped length on every real row, so a length
+    pass would be an identity reorder."""
+    n = partitions.shape[0]
+    width_cap = lanes.shape[1] * 4 + 1
+    slen = np.minimum(lengths.astype(np.int64), width_cap)
+    uniform, pad_len = uniform_clamped_lengths(slen, width_cap)
+    slen = slen.astype(np.uint32)
+    nb = _bucket(n)
+    if nb != n:
+        partitions = np.pad(partitions, (0, nb - n),
+                            constant_values=np.iinfo(np.int32).max)
+        lanes = np.pad(lanes, ((0, nb - n), (0, 0)))
+        slen = np.pad(slen, (0, nb - n), constant_values=pad_len)
+    return (jnp.asarray(partitions), jnp.asarray(lanes),
+            jnp.asarray(slen)), uniform
+
+
 def sort_run(partitions: np.ndarray, lanes: np.ndarray,
              lengths: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """LSD radix sort by (partition, key lanes, clamped length): stable
@@ -832,35 +677,54 @@ def sort_run(partitions: np.ndarray, lanes: np.ndarray,
     n = partitions.shape[0]
     if n == 0:
         return partitions, np.zeros(0, dtype=np.int32)
-    width_cap = lanes.shape[1] * 4 + 1
-    lengths = np.minimum(lengths.astype(np.int64), width_cap)
-    nb = _bucket(n)
-    if nb != n:
-        partitions = np.pad(partitions, (0, nb - n),
-                            constant_values=np.iinfo(np.int32).max)
-        lanes = np.pad(lanes, ((0, nb - n), (0, 0)))
-        lengths = np.pad(lengths, (0, nb - n))
-    sorted_parts, perm = _fused_sort(jnp.asarray(partitions),
-                                     jnp.asarray(lanes),
-                                     jnp.asarray(lengths.astype(np.uint32)))
+    operands, _uniform = _stage_sort_columns(partitions, lanes, lengths)
+    sorted_parts, perm = _fused_sort(*operands)
     return (np.asarray(sorted_parts)[:n], np.asarray(perm)[:n])
 
 
 # ---------------------------------------------------------------------------
 # merge of sorted runs = sort of concatenation (stable; run order preserved)
 # ---------------------------------------------------------------------------
-def merge_runs(lanes_list: list[np.ndarray],
-               lengths_list: list[np.ndarray]) -> np.ndarray:
-    """k-way merge of sorted key-lane arrays -> global permutation into the
-    concatenation.  Stability keeps equal keys in run order (TezMerger
-    segment-queue semantics)."""
-    if not lanes_list:
-        return np.zeros(0, dtype=np.int32)
-    lanes = np.concatenate(lanes_list, axis=0)
-    lengths = np.concatenate(lengths_list, axis=0)
-    zeros = np.zeros(lanes.shape[0], dtype=np.int32)
-    _, perm = sort_run(zeros, lanes, lengths)
-    return perm
+def _merge_sort_impl(partitions, lanes, lengths,
+                     skip_length_pass: bool = False):
+    """The LSD sort under a program name of its own: the permutation."""
+    return _lsd_passes(partitions, lanes, lengths, skip_length_pass)[1]
+
+
+_merge_sort = Kernel(_merge_sort_impl, "merge_sort",
+                     static_argnames=("skip_length_pass",))
+
+#: the programs that compare rows in a merge; ``slice_to_bucket`` stages
+#: their operands.  DEVICE_MERGE_LAUNCH_ROWS sums the rows of these, so
+#: that over DEVICE_MERGE_RECORDS it reads the padding (ops/sorter.py
+#: _record_launches).
+MERGE_LEVEL_KERNELS = ("merge_sort", "resident_merge_sort")
+
+
+def merge_runs(partitions: np.ndarray, lanes: np.ndarray,
+               lengths: np.ndarray) -> np.ndarray:
+    """k-way merge of host-fed sorted runs, handed over as their
+    concatenation in run-arrival order: ONE launch of the stable LSD sort
+    by (partition, key lanes, clamped length) over the concatenation padded
+    once to its bucket.  Stability keeps equal keys in run order (TezMerger
+    segment-queue semantics).  Returns the permutation into the
+    concatenation; pads (partition MAX) sort to the tail and are cut.  Like
+    sort_run, prefix-equal beyond-cap keys compare equal here and are
+    resolved by the caller's host tie-break pass."""
+    n = partitions.shape[0]
+    if n == 0:
+        return np.zeros(0, dtype=np.int64)
+    nb = _bucket(n)
+    with tracing.span("merge.stage", cat="merge", rows=n, bucket=nb):
+        operands, uniform = _stage_sort_columns(partitions, lanes, lengths)
+    with tracing.span("merge.launch", cat="merge"):
+        perm_dev = _merge_sort(*operands, skip_length_pass=uniform)
+    # the host blocks here for the device: this merge's own work and every
+    # launch other threads queued ahead of it on the chip
+    with tracing.span("merge.readback", cat="merge", rows=nb):
+        perm = np.asarray(perm_dev)
+    with tracing.span("merge.gather", cat="merge", rows=n):
+        return perm[:n].astype(np.int64)
 
 
 # ---------------------------------------------------------------------------

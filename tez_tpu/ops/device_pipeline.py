@@ -19,10 +19,10 @@ Two entry points:
   device-to-device edges).
 
 The reduce side runs a third AsyncSpanPipeline instance: the merge lane in
-library/merge_manager.py, whose dispatch stage is the merge-path kernel
-(ops/device.py merge_path_runs — O(N) partitioned binary-merge of
-pre-sorted runs, no re-sort) and whose readback stage is the chunked-run
-disk write, so fetch/commit, device merge, and spill IO overlap.
+library/merge_manager.py, whose dispatch stage is the device merge
+(ops/device.py merge_runs — one stable sort of the runs' padded
+concatenation) and whose readback stage is the chunked-run disk write, so
+fetch/commit, device merge, and spill IO overlap.
 """
 from __future__ import annotations
 

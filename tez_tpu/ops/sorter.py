@@ -987,8 +987,7 @@ class DeviceSorter:
 
 def _record_merge_ms(counters: Optional[TezCounters], t0: float) -> None:
     """device.merge latency histogram: wall of one device merge dispatch
-    (merge-path ladder or resident merge), the reduce-side twin of
-    device.sort."""
+    (host-fed or resident), the reduce-side twin of device.sort."""
     from tez_tpu.common import metrics
     metrics.observe("device.merge", (time.time() - t0) * 1000.0,
                     counters=counters)
@@ -1015,7 +1014,7 @@ def _record_launches(counters: Optional[TezCounters], tally: dict) -> None:
     counters.  DEVICE_MERGE_LAUNCHES counts every program the merge
     launched, staging ones too; DEVICE_MERGE_LAUNCH_ROWS the rows of the
     programs that compare (device.MERGE_LEVEL_KERNELS), so that over
-    DEVICE_MERGE_RECORDS it is ladder levels x padding."""
+    DEVICE_MERGE_RECORDS it is the padding."""
     if counters is None:
         return
     counters.increment(TaskCounter.DEVICE_MERGE_LAUNCHES,
@@ -1025,7 +1024,8 @@ def _record_launches(counters: Optional[TezCounters], tally: dict) -> None:
                            if k in tally))
 
 
-def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int
+def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int,
+                                uniform_lengths: bool
                                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Multi-partition device-resident merge: each run's HBM key columns are
     (partition, key)-sorted, so partition p occupies the contiguous rows
@@ -1049,7 +1049,7 @@ def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int
                 bases.append(off + lo)
         if not slices:
             continue
-        perm = device.merge_resident_slices(slices)
+        perm = device.merge_resident_slices(slices, uniform_lengths)
         with tracing.span("merge.gather", cat="merge", rows=len(perm)):
             cnts = np.asarray([hi - lo for (_l, _n, lo, hi) in slices],
                               dtype=np.int64)
@@ -1112,13 +1112,19 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
             # device-resident merge: key columns are already in HBM from
             # the producers' span sorts — only the permutation comes back
             # (VERDICT r1 item 4; TezMerger semantics preserved)
+            # the same test the span sort makes: one key length on every
+            # row leaves the length pass out (resident views hold whole
+            # keys, so the clamp at the lane cap never bites)
+            uniform, _pad = device.uniform_clamped_lengths(
+                np.concatenate([np.diff(r.batch.key_offsets) for r in live]),
+                max(v[0].shape[1] for v in views) * 4 + 1)
             with device.launch_tally() as tally:
                 if num_partitions == 1:
-                    perm = device.merge_resident_slices(views)
+                    perm = device.merge_resident_slices(views, uniform)
                     row_index = None
                 else:
                     perm, row_index = _merge_resident_partitioned(
-                        live, num_partitions)
+                        live, num_partitions, uniform)
             _record_merge_ms(counters, t0)
             _record_launches(counters, tally)
             with tracing.span("merge.gather", cat="merge", rows=len(perm)):
@@ -1180,22 +1186,12 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
                                      num_partitions)
     mat, lengths = pad_to_matrix(sort_bytes, sort_offsets, key_width)
     lanes = matrix_to_lanes(mat)
-    # the inputs are PRE-SORTED runs: the merge-path ladder (cross-rank
-    # scatter per level) replaces the concatenate+re-sort dispatch.  Same
-    # composite comparator as sort_run, equal keys keep run-arrival order,
-    # and prefix-equal beyond-cap keys still fall to the host tie-break
-    # below.
-    run_bounds = np.zeros(len(runs) + 1, dtype=np.int64)
-    np.cumsum([r.batch.num_records for r in runs], out=run_bounds[1:])
+    # the concatenation is in run-arrival order: one stable sort of it IS
+    # the merge (equal keys keep run order); prefix-equal beyond-cap keys
+    # still fall to the host tie-break below
     t_dev = time.time()
     with device.launch_tally() as tally:
-        perm = device.merge_path_runs(
-            [partitions[run_bounds[i]:run_bounds[i + 1]]
-             for i in range(len(runs))],
-            [lanes[run_bounds[i]:run_bounds[i + 1]]
-             for i in range(len(runs))],
-            [lengths[run_bounds[i]:run_bounds[i + 1]]
-             for i in range(len(runs))])
+        perm = device.merge_runs(partitions, lanes, lengths)
     _record_merge_ms(counters, t_dev)
     _record_launches(counters, tally)
     with tracing.span("merge.gather", cat="merge", rows=len(perm)):

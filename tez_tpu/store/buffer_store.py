@@ -6,7 +6,7 @@ instead of bespoke per-operator files; this module is that store for the
 tez_tpu data plane.  Three capacity-accounted tiers:
 
 DEVICE  sorted key lanes pinned in HBM (``KVBatch.dev_keys``) so a
-        same-process consumer's merge-path kernel reads them without
+        same-process consumer's resident merge reads them without
         re-upload.  An entry here also holds its host arrays — the device
         pool accounts only the HBM lane bytes.
 HOST    the run's columnar numpy arrays, served as zero-copy views.

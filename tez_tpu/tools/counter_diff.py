@@ -37,11 +37,10 @@ DEVICE_STAGE_HISTS = ("device.encode", "device.h2d", "device.dispatch_wait",
                       "device.d2h")
 
 #: The reduce-side merge plane's histograms: ``device.merge`` is device
-#: merge-kernel wall (merge-path dispatches plus the async merge lane's
+#: merge-kernel wall (merge dispatches plus the async merge lane's
 #: dispatch-wait), ``shuffle.merge`` the consumer-side merge/commit wall.
 #: Diffed like the device stages — cumulative wall ms — so a reduce side
-#: that quietly fell off the merge-path kernel onto concatenate+re-sort
-#: (or host failover) shows up as a sum shift even when p95 stays inside
+#: that quietly fell off the device merge (host failover) shows up as a sum shift even when p95 stays inside
 #: one power-of-2 bucket.
 MERGE_STAGE_HISTS = ("device.merge", "shuffle.merge")
 

@@ -46,8 +46,8 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "benchmarks")]
 
-MERGE_PROGRAMS = ("_merge_path_pair_impl", "_merge_path_prep_impl",
-                  "_slice_to_bucket_impl", "_fused_resident_merge_impl")
+MERGE_PROGRAMS = ("_merge_sort_impl", "_slice_to_bucket_impl",
+                  "_fused_resident_merge_impl")
 MATCH_WINDOW_S = 0.005
 
 

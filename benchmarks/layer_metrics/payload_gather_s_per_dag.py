@@ -1,0 +1,6 @@
+"""payload_gather_s_per_dag: see payload_gather_s_per_dag.json."""
+import span_metrics
+
+
+def read(obs):
+    return span_metrics.self_s_per_dag(obs, ("payload.gather",))

@@ -650,7 +650,7 @@ def test_sorter_compile_error_surfaces_but_injected_oom_fails_over(
         raise ValueError("Shape mismatch in input, indices and output")
     broken = Kernel(refused, "resident_hash_sort",
                     static_argnames=("num_partitions", "skip_length_pass"))
-    monkeypatch.setattr(device, "_resident_sort_donated", lambda: broken)
+    monkeypatch.setattr(device, "_HASH_SORTS", (broken, broken))
     # the poison surfaces at whichever comes first: the next submit (which
     # wraps it) or the drain (which re-raises it)
     with pytest.raises(RuntimeError) as err:

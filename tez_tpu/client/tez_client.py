@@ -118,6 +118,10 @@ class TezClient:
         else:
             self.framework_client = LocalFrameworkClient(self.conf)
         self.framework_client.start()
+        # a traced session's client phases (building a DAG: TeraSort samples
+        # its split points there) are spans too; the AM arms per DAG
+        from tez_tpu.common import tracing
+        tracing.install_from_conf(self.conf, scope=f"client:{self.name}")
         self._started = True
         return self
 
@@ -250,6 +254,8 @@ class TezClient:
     def stop(self) -> None:
         if self._started:
             self.framework_client.stop()
+            from tez_tpu.common import tracing
+            tracing.clear(f"client:{self.name}")
             self._started = False
 
     def __enter__(self) -> "TezClient":

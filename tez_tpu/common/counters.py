@@ -122,6 +122,10 @@ class TaskCounter(enum.Enum):
     # DEVICE_MERGE_RECORDS that is ladder levels x padding
     DEVICE_MERGE_LAUNCHES = enum.auto()
     DEVICE_MERGE_LAUNCH_ROWS = enum.auto()
+    # key and value bytes moved by a permutation gather of records
+    # (ops/sorter.py _take: after a span sort, in a merge): over the input
+    # bytes, how many times a record is moved that way
+    PAYLOAD_GATHER_BYTES = enum.auto()
 
 
 # Mesh ICI exchange plane (parallel/coordinator.py): string-named counters

@@ -12,7 +12,7 @@ import sys
 
 from tez_tpu.examples import (cartesian_product, hash_join, mrr,
                               ordered_wordcount, simple_session,
-                              sort_merge_join, wordcount)
+                              sort_merge_join, terasort, wordcount)
 
 
 def _two_arg(run):
@@ -41,6 +41,10 @@ _PROGRAMS = {
     "mrr": (
         _two_arg(mrr.run), "<input...> <output_dir>",
         "map -> reduce -> reduce chained-shuffle DAG"),
+    "terasort": (
+        _two_arg(terasort.run), "<input...> <output_dir>",
+        "100-byte gensort records into one global order (total-order "
+        "partitioner over sampled split points)"),
     "sortmergejoin": (
         _three_arg(sort_merge_join.run), "<left> <right> <output_dir>",
         "two ordered edges merged in one joiner vertex"),

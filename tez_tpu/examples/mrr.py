@@ -1,9 +1,13 @@
-"""MRR: 3-stage map -> reduce -> reduce chain over TeraSort-style records.
+"""MRR: 3-stage map -> reduce -> reduce chain, a chained-shuffle example
+over text.
 
 Reference parity: tez-tests mapreduce examples (TestOrderedWordCount /
-MRRSleepJob — benchmark workload 4, BASELINE.md): two chained sorted
-shuffles.  Stage 1 tokenizes key:value lines, stage 2 aggregates per key,
-stage 3 re-keys by aggregate and writes globally ordered output.
+MRRSleepJob): two chained sorted shuffles.  Its input is text lines of
+``key<TAB>value`` through the hash partitioner, a record at a time: stage 1
+splits the lines, stage 2 aggregates per key, stage 3 re-keys by aggregate
+and writes globally ordered output.  It is not TeraSort: 100-byte binary
+records under a sampled total-order partitioner are
+``tez_tpu/examples/terasort.py``.
 """
 from __future__ import annotations
 

@@ -200,36 +200,57 @@ class _FixedWidthReader(KeyValueReader):
         self.key_bytes = key_bytes
         self.value_bytes = value_bytes
 
-    def __iter__(self) -> Iterator[Tuple[bytes, bytes]]:
-        rec = self.key_bytes + self.value_bytes
+    def iter_chunks(self, chunk_bytes: int = 8 << 20) -> Iterator[Any]:
+        """Batch-first reader: whole records read a granule at a time and
+        handed over as KVBatches, keys and values cut out by reshape -- no
+        per-record Python.  A granule is whole records, at least one even
+        when a single record exceeds `chunk_bytes`; a short read's partial
+        record is dropped, as the split's trailing one is."""
+        import numpy as np
+        from tez_tpu.ops import hostpool
+        from tez_tpu.ops.runformat import KVBatch
+        kb, vb = self.key_bytes, self.value_bytes
+        rec = kb + vb
         records = self.context.counters.find_counter(
             TaskCounter.INPUT_RECORDS_PROCESSED)
         bytes_read = self.context.counters.find_counter(
             FileSystemCounter.FILE_BYTES_READ)
         read_ops = self.context.counters.find_counter(
             FileSystemCounter.FILE_READ_OPS)
-        n = 0
+        granule = max(rec, chunk_bytes // rec * rec)
         for split in self.splits:
-            with open(split.path, "rb") as fh:
+            with tracing.span("input.open", cat="task", path=split.path):
+                fh = open(split.path, "rb")
+            with fh:
                 read_ops.increment()
                 fh.seek(split.start)
                 remaining = split.length
-                # whole records per read; at least one even when a single
-                # record exceeds the 8 MiB read granule
-                granule = max(rec, (8 << 20) // rec * rec)
                 while remaining >= rec:
-                    chunk = fh.read(min(remaining, granule))
-                    if not chunk:
+                    want = min(remaining // rec * rec, granule)
+                    with tracing.span("input.read", cat="task", bytes=want):
+                        raw = hostpool.empty(want)
+                        raw = raw[:fh.readinto(memoryview(raw))]
+                        n = len(raw) // rec
+                        rows = raw[:n * rec].reshape(n, rec)
+                        keys = hostpool.empty(n * kb)
+                        keys.reshape(n, kb)[...] = rows[:, :kb]
+                        values = hostpool.empty(n * vb)
+                        values.reshape(n, vb)[...] = rows[:, kb:]
+                        batch = KVBatch(
+                            keys, np.arange(n + 1, dtype=np.int64) * kb,
+                            values, np.arange(n + 1, dtype=np.int64) * vb)
+                    if not len(raw):
                         break
-                    bytes_read.increment(len(chunk))
-                    remaining -= len(chunk)
-                    for off in range(0, len(chunk) - rec + 1, rec):
-                        yield (chunk[off:off + self.key_bytes],
-                               chunk[off + self.key_bytes:off + rec])
-                        records.increment()
-                        n += 1
-                        if (n & 0x3FFF) == 0:
-                            self.context.notify_progress()
+                    bytes_read.increment(len(raw))
+                    remaining -= len(raw)
+                    records.increment(n)
+                    self.context.notify_progress()
+                    if n:
+                        yield batch
+
+    def __iter__(self) -> Iterator[Tuple[bytes, bytes]]:
+        for batch in self.iter_chunks():
+            yield from batch.iter_pairs()
 
 
 class FixedWidthKVFormat(InputFormat):

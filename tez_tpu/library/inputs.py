@@ -417,7 +417,7 @@ class OrderedGroupedKVInput(LogicalInput):
         self._stream_plan = None
         from tez_tpu.library.comparators import load_comparator
         self._key_normalizer = load_comparator(ctx)   # resolved ONCE
-        self._group_starts = None                     # cached across readers
+        self._reader = None                           # cached across calls
 
         # Bounded-memory merge (MergeManager.java:83 analog).  The budget
         # comes from an explicit key, or else from the MemoryDistributor
@@ -562,20 +562,17 @@ class OrderedGroupedKVInput(LogicalInput):
             return StreamingGroupedKVReader(self._stream_plan, self.key_serde,
                                             self.val_serde, self.context,
                                             key_normalizer=self._key_normalizer)
-        batch = self._merged
-        if self._group_starts is None:
-            # one normalization pass for group detection, cached so repeat
-            # readers are free (the merge normalized pre-sort; deriving its
-            # arrays post-refinement isn't worth the plumbing)
-            self._group_starts = GroupedKVReader._compute_groups(
-                batch, self._key_normalizer)
-        return GroupedKVReader(batch, self.key_serde,
-                               self.val_serde, self.context,
-                               group_starts=self._group_starts)
+        if self._reader is None:
+            # cached so repeat readers share the one group-detection pass,
+            # which the reader makes when a consumer first asks for groups
+            self._reader = GroupedKVReader(
+                self._merged, self.key_serde, self.val_serde, self.context,
+                key_normalizer=self._key_normalizer)
+        return self._reader
 
     def close(self) -> List[TezAPIEvent]:
         self._merged = None
-        self._group_starts = None
+        self._reader = None
         self._stream_plan = None
         if self._push_listener is not None:
             from tez_tpu.shuffle.service import local_shuffle_service
@@ -593,13 +590,22 @@ class GroupedKVReader(KeyValuesReader):
 
     def __init__(self, batch: KVBatch, key_serde: Serde, val_serde: Serde,
                  context: Any, key_normalizer: Any = None,
-                 group_starts: Any = None):
+                 group_starts: Optional[np.ndarray] = None):
         self.batch = batch
         self.key_serde = key_serde
         self.val_serde = val_serde
         self.context = context
-        self._group_starts = group_starts if group_starts is not None \
-            else self._compute_groups(batch, key_normalizer)
+        self._key_normalizer = key_normalizer
+        self._starts = group_starts
+
+    @property
+    def _group_starts(self) -> np.ndarray:
+        """Group boundaries, found when a consumer first asks for groups:
+        sorted_blocks() never does."""
+        if self._starts is None:
+            self._starts = self._compute_groups(self.batch,
+                                                self._key_normalizer)
+        return self._starts
 
     @staticmethod
     def _compute_groups(batch: KVBatch, key_normalizer: Any = None
@@ -656,6 +662,12 @@ class GroupedKVReader(KeyValuesReader):
         is a single block; the streaming reader yields many — consumers
         written against this API handle both without branching."""
         yield self.grouped_batch()
+
+    def sorted_blocks(self) -> Iterator[KVBatch]:
+        """The sorted records as blocks with no group boundaries computed:
+        for consumers that take records as they come (an identity reduce).
+        The in-RAM reader has one block."""
+        yield self.batch
 
     def peek_batch(self) -> KVBatch:
         """The merged batch WITHOUT counter effects — for consumers probing
@@ -744,6 +756,18 @@ class StreamingGroupedKVReader(KeyValuesReader):
             yield close_carry()
         counters.increment(TaskCounter.REDUCE_INPUT_GROUPS, groups)
         counters.increment(TaskCounter.REDUCE_INPUT_RECORDS, records)
+
+    def sorted_blocks(self) -> Iterator[KVBatch]:
+        """The merged blocks as they come, no group scan and no carry
+        across blocks.  Counts REDUCE_INPUT_RECORDS as grouped_blocks()
+        does."""
+        records = 0
+        for block in self.plan.iter_batches():
+            records += block.num_records
+            self.context.notify_progress()
+            yield block
+        self.context.counters.increment(TaskCounter.REDUCE_INPUT_RECORDS,
+                                        records)
 
     def __iter__(self) -> Iterator[Tuple[Any, Iterator[Any]]]:
         for batch, starts in self.grouped_blocks():

@@ -17,6 +17,7 @@ from tez_tpu.api.events import (CompositeDataMovementEvent, ShufflePayload,
 from tez_tpu.api.runtime import KeyValuesWriter, LogicalOutput, Writer
 from tez_tpu.common import tracing
 from tez_tpu.common.counters import TaskCounter
+from tez_tpu.library.partitioners import batch_form
 from tez_tpu.ops.runformat import Run
 from tez_tpu.ops.serde import get_serde
 from tez_tpu.ops.sorter import DeviceSorter, sum_long_combiner
@@ -40,13 +41,16 @@ def output_path_component(context: Any) -> str:
 
 class _SorterWriter(KeyValuesWriter):
     def __init__(self, sorter: DeviceSorter, key_serde: Any, val_serde: Any,
-                 context: Any, partition_fn: Any = None,
-                 num_partitions: int = 1):
+                 context: Any, partitioner: Any, num_partitions: int = 1):
         self.sorter = sorter
         self.key_serde = key_serde
         self.val_serde = val_serde
         self.context = context
-        self.partition_fn = partition_fn
+        self.partitioner = partitioner
+        # a partitioner with a batch form is the sorter's own step (fused
+        # into the span sort); one without is called here, a record
+        self.partition_fn = None if batch_form(partitioner) is not None \
+            else partitioner.get_partition
         self.num_partitions = num_partitions
         self._n = 0
         # resolved once: find_counter locks the registry per call
@@ -70,21 +74,22 @@ class _SorterWriter(KeyValuesWriter):
 
     @property
     def supports_batch(self) -> bool:
-        """True when write_batch() will be accepted — batch-first consumers
-        probe this BEFORE consuming their reader, so an unsupported config
-        (custom Partitioner) falls back to write() instead of failing the
-        task mid-stream."""
+        """True when write_batch() will be accepted: the partitioner has a
+        batch form (hash, total order).  Batch-first consumers probe this
+        BEFORE consuming their reader, so a Partitioner that has none falls
+        back to write() instead of failing the task mid-stream."""
         return self.partition_fn is None
 
     def write_batch(self, batch: Any) -> None:
         """Batch-first write path: a KVBatch of PRE-SERIALIZED records goes
-        straight to the sorter (no per-record Python).  Only valid with the
-        stock hash partitioner — a custom Partitioner sees logical records
-        and must use write()."""
-        if self.partition_fn is not None:
-            raise ValueError("write_batch requires the stock hash "
-                             "partitioner (custom Partitioner sees logical "
-                             "records)")
+        straight to the sorter (no per-record Python), which partitions the
+        span itself.  A Partitioner without a batch form sees logical
+        records and must use write()."""
+        if not self.supports_batch:
+            raise ValueError(
+                f"write_batch requires a partitioner with a batch form "
+                f"(hash, total order); {type(self.partitioner).__name__} "
+                f"sees logical records")
         with tracing.span("output.write", cat="task",
                           rows=batch.num_records):
             self.sorter.write_batch(batch)
@@ -120,11 +125,16 @@ class OrderedPartitionedKVOutput(LogicalOutput):
         partitioner_cls = _conf_get(ctx, "tez.runtime.partitioner.class",
                                     "tez_tpu.library.partitioners:"
                                     "HashPartitioner")
-        self.partition_fn = None
-        if partitioner_cls != ("tez_tpu.library.partitioners:"
-                               "HashPartitioner"):
-            from tez_tpu.common.payload import resolve_class
-            self.partition_fn = resolve_class(partitioner_cls)().get_partition
+        from tez_tpu.common.payload import resolve_class
+        merged: Dict[str, Any] = dict(ctx.conf)
+        payload = ctx.user_payload.load()
+        if isinstance(payload, dict):
+            merged.update(payload)
+        cls = resolve_class(partitioner_cls)
+        # a class that only looks like a Partitioner has no from_conf
+        self.partitioner = cls.from_conf(merged) \
+            if hasattr(cls, "from_conf") else cls()
+        form = batch_form(self.partitioner)
         from tez_tpu.library.comparators import load_comparator
         spill_codec = None
         if _conf_get(ctx, "tez.runtime.compress", False):
@@ -139,6 +149,9 @@ class OrderedPartitionedKVOutput(LogicalOutput):
             spill_dir=spill_dir,
             counters=ctx.counters,
             combiner=_COMBINERS.get(combiner_name),
+            partitioner=form or "custom",
+            split_points=self.partitioner.split_points
+            if form == "range" else (),
             engine=engine,
             sort_threads=sort_threads,
             merge_factor=merge_factor,
@@ -184,10 +197,6 @@ class OrderedPartitionedKVOutput(LogicalOutput):
         # the task conf (in-process mode finds the AM's); outputs publish
         # with a lineage tag so a later identical DAG can reuse them
         from tez_tpu.store import ensure_store
-        merged: Dict[str, Any] = dict(ctx.conf)
-        payload = ctx.user_payload.load()
-        if isinstance(payload, dict):
-            merged.update(payload)
         ensure_store(merged)
         self._lineage = ""
         self._reused = False
@@ -254,7 +263,7 @@ class OrderedPartitionedKVOutput(LogicalOutput):
 
     def get_writer(self) -> Writer:
         return _SorterWriter(self.sorter, self.key_serde, self.val_serde,
-                             self.context, partition_fn=self.partition_fn,
+                             self.context, self.partitioner,
                              num_partitions=self.num_physical_outputs)
 
     def handle_events(self, events: Sequence[TezAPIEvent]) -> None:

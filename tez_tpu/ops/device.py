@@ -378,45 +378,107 @@ def _fnv_rows_from_lanes(lanes: jnp.ndarray,
     return h
 
 
-def _fused_resident_hash_sort_impl(lanes: jnp.ndarray, lengths: jnp.ndarray,
-                                   num_partitions: int,
-                                   skip_length_pass: bool = False
-                                   ) -> Tuple[jnp.ndarray, ...]:
-    """hash-from-lanes + LSD sort; ALSO returns the sorted key columns as
-    device arrays so downstream merges never re-upload them.  Sentinel rows
-    (length < 0) take partition MAX and sort to the tail."""
-    h = _fnv_rows_from_lanes(lanes, lengths)
+def _resident_span_sort(sentinel: jnp.ndarray, partitions: jnp.ndarray,
+                        lanes: jnp.ndarray, lengths: jnp.ndarray,
+                        skip_length_pass: bool) -> Tuple[jnp.ndarray, ...]:
+    """What the fused resident span sorts share, everything but the
+    partition step: sentinel rows (length < 0) take partition MAX and sort
+    to the tail; the LSD passes; the sorted key columns returned as device
+    arrays so downstream merges never re-upload them.  `sentinel` is traced
+    by the caller BEFORE its partition step: the hash kernel's operations
+    then stand in the order they always had, and its compiled programs
+    (and their compile-cache keys) are the ones from before the range
+    kernel existed."""
     partitions = jnp.where(
-        lengths < 0, jnp.int32(np.iinfo(np.int32).max),
-        (h % jnp.uint32(num_partitions)).astype(jnp.int32))
+        sentinel, jnp.int32(np.iinfo(np.int32).max), partitions)
     sort_lens = jnp.where(lengths < 0, jnp.uint32(0xFFFFFFFF),
                           lengths.astype(jnp.uint32))
     sp, perm = _lsd_passes(partitions, lanes, sort_lens, skip_length_pass)
     return sp, perm, lanes[perm], lengths[perm]
 
 
-_fused_resident_hash_sort = Kernel(
+def _fused_resident_hash_sort_impl(lanes: jnp.ndarray, lengths: jnp.ndarray,
+                                   num_partitions: int,
+                                   skip_length_pass: bool = False
+                                   ) -> Tuple[jnp.ndarray, ...]:
+    """hash-from-lanes + the resident span sort."""
+    h = _fnv_rows_from_lanes(lanes, lengths)
+    return _resident_span_sort(
+        lengths < 0, (h % jnp.uint32(num_partitions)).astype(jnp.int32),
+        lanes, lengths, skip_length_pass)
+
+
+def _range_partitions(lanes: jnp.ndarray, lengths: jnp.ndarray,
+                      split_lanes: jnp.ndarray, split_lengths: jnp.ndarray
+                      ) -> jnp.ndarray:
+    """Partition id = number of split rows <= the row's key, compared
+    lexicographically by (lanes..., length) as the sort orders them: one
+    broadcast compare of N rows against S = P-1 split rows, no gather.
+    A key equal to split i goes to partition i + 1
+    (library.partitioners.TotalOrderPartitioner, keycodec.range_partitions
+    on the host)."""
+    if split_lanes.shape[0] == 0:
+        return jnp.zeros(lanes.shape[0], dtype=jnp.int32)
+    ge = lengths[:, None] >= split_lengths[None, :]
+    for i in range(lanes.shape[1] - 1, -1, -1):
+        col, split = lanes[:, i][:, None], split_lanes[:, i][None, :]
+        ge = (col > split) | ((col == split) & ge)
+    return ge.sum(axis=1, dtype=jnp.int32)
+
+
+def _fused_resident_range_sort_impl(lanes: jnp.ndarray, lengths: jnp.ndarray,
+                                    split_lanes: jnp.ndarray,
+                                    split_lengths: jnp.ndarray,
+                                    skip_length_pass: bool = False
+                                    ) -> Tuple[jnp.ndarray, ...]:
+    """range-partition-from-lanes + the resident span sort."""
+    return _resident_span_sort(
+        lengths < 0,
+        _range_partitions(lanes, lengths, split_lanes, split_lengths),
+        lanes, lengths, skip_length_pass)
+
+
+def _resident_sort_kernels(fn: Callable, name: str,
+                           static_argnames: Tuple[str, ...]
+                           ) -> Tuple[Kernel, Kernel]:
+    """(plain, donating) flavors of one fused resident span sort.  The
+    donating one is the async pipeline's: the staged (bucketed) input lanes
+    buffer aliases the sorted-lanes output, so the sort runs in place in
+    HBM instead of holding both copies live."""
+    return (Kernel(fn, name, static_argnames=static_argnames),
+            Kernel(fn, name + "_donated", static_argnames=static_argnames,
+                   donate_argnums=(0,)))
+
+
+#: (plain, donating) by the partitioner's batch form
+_HASH_SORTS = _resident_sort_kernels(
     _fused_resident_hash_sort_impl, "resident_hash_sort",
-    static_argnames=("num_partitions", "skip_length_pass"))
-
-_fused_resident_hash_sort_donated = Kernel(
-    _fused_resident_hash_sort_impl, "resident_hash_sort_donated",
-    static_argnames=("num_partitions", "skip_length_pass"),
-    donate_argnums=(0,))
+    ("num_partitions", "skip_length_pass"))
+_RANGE_SORTS = _resident_sort_kernels(
+    _fused_resident_range_sort_impl, "fused_resident_range_sort",
+    ("skip_length_pass",))
 
 
-def _resident_sort_donated() -> Kernel:
-    """Donating flavor for the async pipeline: the staged (bucketed) input
-    lanes buffer aliases the sorted-lanes output, so the sort runs in-place
-    in HBM instead of holding both copies live.  Accelerator backends only —
-    XLA:CPU ignores donation (with a warning per call), so the plain kernel
-    is returned there."""
-    return _fused_resident_hash_sort_donated if accelerator_present() \
-        else _fused_resident_hash_sort
+def _resident_sort(num_partitions: int, splits, donate: bool) -> Callable:
+    """The fused span sort of one partitioner, picked here and nowhere
+    else: the hash kernel with its partition count, or, where `splits`
+    (split_lanes u32[P-1, L], split_lengths i32[P-1], encoded at the span's
+    lane width) are given, the range kernel with them.  Returns
+    launch(lanes, lengths, skip_length_pass).  Donation on accelerator
+    backends only -- XLA:CPU ignores it (with a warning per call)."""
+    if splits is None:
+        pair, rows, static = _HASH_SORTS, (), {"num_partitions":
+                                               num_partitions}
+    else:
+        pair, rows, static = _RANGE_SORTS, tuple(
+            jnp.asarray(s) for s in splits), {}
+    kernel = pair[bool(donate and accelerator_present())]
+    return lambda lanes, lengths, skip: kernel(
+        lanes, lengths, *rows, skip_length_pass=skip, **static)
 
 
 # -- decomposed resident-span stages (ops/async_stage.py pipeline) ----------
-# hash_sort_span_resident = stage + dispatch + readback run back-to-back;
+# sort_span_resident = stage + dispatch + readback run back-to-back;
 # the async pipeline runs them on different threads so span k+1's staging
 # overlaps span k's in-flight sort.
 
@@ -435,31 +497,31 @@ def stage_resident_span(lanes: np.ndarray, lengths: np.ndarray):
             jax.device_put(jnp.asarray(lengths)), n, uniform)
 
 
-def dispatch_resident_span(staged, num_partitions: int):
+def dispatch_resident_span(staged, num_partitions: int, splits=None):
     """Launch the fused kernel; returns in-flight device arrays immediately
-    (JAX async dispatch) — block via readback_resident_span."""
+    (JAX async dispatch) — block via readback_resident_span.  splits as
+    _resident_sort takes them: by range instead of by hash."""
     lanes_dev, lens_dev, n, uniform = staged
-    sp, perm, out_lanes, out_lens = _resident_sort_donated()(
-        lanes_dev, lens_dev, num_partitions=num_partitions,
-        skip_length_pass=uniform)
+    sp, perm, out_lanes, out_lens = _resident_sort(
+        num_partitions, splits, donate=True)(lanes_dev, lens_dev, uniform)
     return sp, perm, out_lanes, out_lens, n
 
 
 def readback_resident_span(inflight):
     """Block until host-visible; same return shape as
-    hash_sort_span_resident."""
+    sort_span_resident."""
     sp, perm, out_lanes, out_lens, n = inflight
     return (np.asarray(sp)[:n], np.asarray(perm)[:n],
             (out_lanes, out_lens, 0, n))
 
 
-def hash_sort_span_resident(lanes: np.ndarray, lengths: np.ndarray,
-                            num_partitions: int):
+def sort_span_resident(lanes: np.ndarray, lengths: np.ndarray,
+                       num_partitions: int, splits=None):
     """Fused span kernel, resident flavor: upload = lanes + lengths ONLY
     (~20B/row vs ~36B for the matrix path); returns host (sorted partitions,
     permutation) plus device (sorted lanes, sorted lengths, bucketed) whose
     rows >= n are tail sentinels.  Caller guarantees max true length <=
-    lane bytes."""
+    lane bytes.  splits as dispatch_resident_span takes them."""
     n = lanes.shape[0]
     if n == 0:
         return (np.zeros(0, np.int32), np.zeros(0, np.int32), None)
@@ -473,9 +535,9 @@ def hash_sort_span_resident(lanes: np.ndarray, lengths: np.ndarray,
     # uniform real lengths make the length pass an identity reorder even
     # with tail sentinels present: sentinel order is fully decided by the
     # final partition pass (partition MAX)
-    sp, perm, out_lanes, out_lens = _fused_resident_hash_sort(
-        jnp.asarray(lanes), jnp.asarray(lengths),
-        num_partitions=num_partitions, skip_length_pass=uniform)
+    sp, perm, out_lanes, out_lens = _resident_sort(
+        num_partitions, splits, donate=False)(
+            jnp.asarray(lanes), jnp.asarray(lengths), uniform)
     sp = np.asarray(sp)[:n]
     perm = np.asarray(perm)[:n]
     return sp, perm, (out_lanes, out_lens, 0, n)

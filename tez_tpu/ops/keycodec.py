@@ -78,6 +78,41 @@ def encode_keys(key_bytes: np.ndarray, offsets: np.ndarray,
     return matrix_to_lanes(mat), lengths
 
 
+def range_partitions(key_bytes: np.ndarray, offsets: np.ndarray,
+                     splits: "list[bytes]") -> np.ndarray:
+    """Partition id of every ragged key against sorted split keys: the
+    number of splits <= the key in raw-byte order (a key equal to split i
+    goes to partition i + 1).  The host twin of the device range kernel
+    (ops/device.py _range_partitions): the same (lanes..., length) compare,
+    one vectorized pass a split, so O(rows x splits) -- the host engine's
+    and the failover's path, not the span sort's."""
+    n = len(offsets) - 1
+    parts = np.zeros(n, dtype=np.int32)
+    if n == 0 or not splits:
+        return parts
+    klens = offsets[1:] - offsets[:-1]
+    width = max(int(klens.max(initial=1)), max(len(s) for s in splits), 1)
+    lanes, lengths = encode_keys(key_bytes, offsets, width)
+    split_lanes, split_lengths = encode_split_keys(splits, lanes.shape[1] * 4)
+    for srow, slen in zip(split_lanes, split_lengths):
+        ge = lengths >= slen
+        for i in range(lanes.shape[1] - 1, -1, -1):
+            col = lanes[:, i]
+            ge = (col > srow[i]) | ((col == srow[i]) & ge)
+        parts += ge
+    return parts
+
+
+def encode_split_keys(splits: "list[bytes]", width: int
+                      ) -> tuple[np.ndarray, np.ndarray]:
+    """Split keys -> (uint32 lanes [S, width/4], lengths int32[S]); every
+    split has to fit `width` bytes (the caller sizes it)."""
+    offsets = np.zeros(len(splits) + 1, dtype=np.int64)
+    np.cumsum([len(s) for s in splits], out=offsets[1:])
+    data = np.frombuffer(b"".join(splits), dtype=np.uint8)
+    return encode_keys(data, offsets, width)
+
+
 def encode_keys_device(key_bytes: np.ndarray, offsets: np.ndarray,
                        width: int):
     """Device-resident ragged->lanes encode: upload the RAW ragged bytes +

@@ -23,6 +23,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from tez_tpu.ops import hostpool
+
 log = logging.getLogger(__name__)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(
@@ -136,9 +138,10 @@ def gather_ragged_native(data: np.ndarray, offsets: np.ndarray,
     lib = _load()
     n_out = len(perm)
     lengths = offsets[1:] - offsets[:-1]
-    out_offsets = np.zeros(n_out + 1, dtype=np.int64)
+    out_offsets = hostpool.empty(n_out + 1, np.int64)
+    out_offsets[0] = 0
     np.cumsum(lengths[perm], out=out_offsets[1:])
-    out = np.empty(int(out_offsets[-1]), dtype=np.uint8)
+    out = hostpool.empty(int(out_offsets[-1]))
     data = np.ascontiguousarray(data)
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
     perm64 = np.ascontiguousarray(perm, dtype=np.int64)
@@ -161,7 +164,7 @@ def gather_fixed_native(data: np.ndarray, row_len: int, perm: np.ndarray
     copy sizes for the common serde widths)."""
     lib = _load()
     n = len(perm)
-    out = np.empty(n * row_len, dtype=np.uint8)
+    out = hostpool.empty(n * row_len)
     data = np.ascontiguousarray(data)
     perm64 = np.ascontiguousarray(perm, dtype=np.int64)
     lib.gather_fixed_u8(

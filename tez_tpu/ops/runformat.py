@@ -21,6 +21,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from tez_tpu.common import faults, tracing
+from tez_tpu.ops import hostpool
 
 MAGIC = b"TPRUN1"
 #: MAGIC + pack("<BIQ", flag, crc32(payload), len(payload)).  The CRC covers
@@ -93,17 +94,22 @@ def gather_ragged(data: np.ndarray, offsets: np.ndarray,
     """Permute a ragged array: returns (new_data, new_offsets).
 
     Large batches go through the native multithreaded per-row memcpy
-    (native/ragged.cpp); numpy fancy indexing otherwise."""
+    (native/ragged.cpp) -- rows of one width, whatever the width, as one
+    strided gather with no offset lookups; numpy fancy indexing
+    otherwise."""
     from tez_tpu.ops.native import MIN_NATIVE_BYTES
     if data.nbytes >= MIN_NATIVE_BYTES:
         n_src = len(offsets) - 1
         if n_src > 0:
             w = int(offsets[1]) - int(offsets[0])
-            if 0 < w <= 64 and int(offsets[-1]) == n_src * w and \
+            if w > 0 and int(offsets[-1]) == n_src * w and \
                     not bool((offsets[1:] != offsets[:-1] + w).any()):
                 from tez_tpu.ops.native import gather_fixed_native
-                return (gather_fixed_native(data, w, perm),
-                        np.arange(len(perm) + 1, dtype=np.int64) * w)
+                new_offsets = hostpool.empty(len(perm) + 1, np.int64)
+                new_offsets[0] = 0
+                new_offsets[1:] = w
+                np.cumsum(new_offsets, out=new_offsets)   # [0, w, 2w, ...]
+                return gather_fixed_native(data, w, perm), new_offsets
         from tez_tpu.ops.native import gather_ragged_native
         return gather_ragged_native(data, offsets, perm)
     lengths = offsets[1:] - offsets[:-1]
@@ -154,9 +160,10 @@ def concat_ragged(parts: Sequence[Tuple[np.ndarray, np.ndarray]]
     if not parts:
         return np.zeros(0, np.uint8), np.zeros(1, np.int64)
     datas = [p[0] for p in parts]
-    data = np.concatenate(datas) if datas else np.zeros(0, np.uint8)
+    data = hostpool.concatenate(datas)
     sizes = [len(p[1]) - 1 for p in parts]
-    offsets = np.zeros(sum(sizes) + 1, dtype=np.int64)
+    offsets = hostpool.empty(sum(sizes) + 1, np.int64)
+    offsets[0] = 0
     pos, base = 1, 0
     for (d, o), sz in zip(parts, sizes):
         offsets[pos:pos + sz] = o[1:] + base

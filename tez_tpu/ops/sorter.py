@@ -13,6 +13,7 @@ order equals full raw-byte order for ANY key length (SURVEY.md §7
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import time
@@ -24,7 +25,9 @@ import numpy as np
 from tez_tpu.common import tracing
 from tez_tpu.common.counters import TaskCounter, TezCounters
 from tez_tpu.ops import device
-from tez_tpu.ops.keycodec import encode_keys, pad_to_matrix, matrix_to_lanes
+from tez_tpu.ops.keycodec import (encode_keys, encode_split_keys,
+                                  matrix_to_lanes, pad_to_matrix,
+                                  range_partitions)
 from tez_tpu.ops.runformat import (FileRun, KVBatch, PartitionedRunWriter,
                                    Run, adjacent_equal_rows, gather_ragged,
                                    save_run_partitioned)
@@ -93,6 +96,21 @@ def normalize_batch_keys(batch: KVBatch,
     np.cumsum([len(k) for k in keys], out=offsets[1:])
     data = np.frombuffer(b"".join(keys), dtype=np.uint8)
     return data, offsets
+
+
+def _take(batch: KVBatch, perm: np.ndarray,
+          counters: Optional[TezCounters], lock=None) -> KVBatch:
+    """batch.take(perm) where a sort's or a merge's permutation moves the
+    records themselves: the one named site of the payload's gather.  `lock`
+    where other threads write the same counters (a sorter's two readback
+    workers)."""
+    with tracing.span("payload.gather", cat="sort", rows=len(perm)):
+        out = batch.take(perm)
+    if counters is not None:
+        with lock if lock is not None else contextlib.nullcontext():
+            counters.increment(TaskCounter.PAYLOAD_GATHER_BYTES,
+                               out.key_bytes.nbytes + out.val_bytes.nbytes)
+    return out
 
 
 class SpanBuffer:
@@ -207,6 +225,7 @@ class DeviceSorter:
                  counters: Optional[TezCounters] = None,
                  combiner: Optional[Combiner] = None,
                  partitioner: str = "hash",
+                 split_points: Sequence[bytes] = (),
                  mem_budget_bytes: Optional[int] = None,
                  engine: str = "device",
                  sort_threads: int = 0,
@@ -280,7 +299,17 @@ class DeviceSorter:
         self._out_records_ctr = self.counters.find_counter(
             TaskCounter.OUTPUT_RECORDS)
         self.combiner = combiner
+        #: 'hash' (FNV of the key, fused into the span sort), 'range'
+        #: (total order over `split_points`, fused likewise), anything else:
+        #: one partition unless write() is given one a record
         self.partitioner = partitioner
+        self.split_points: List[bytes] = list(split_points)
+        if partitioner == "range" and \
+                len(self.split_points) != num_partitions - 1:
+            raise ValueError(
+                f"{len(self.split_points)} split points for "
+                f"{num_partitions} partitions")
+        self._split_lanes: dict = {}     # lane count -> encoded split rows
         self.mem_budget = mem_budget_bytes or (span_budget_bytes * 2)
         #: bounded k-way merge width (reference: io.sort.factor)
         self.merge_factor = merge_factor
@@ -526,20 +555,46 @@ class DeviceSorter:
         batch = self._precombine(payload["batch"], payload["custom_parts"],
                                  skip=payload["skip_pre"])
         custom_parts = payload["custom_parts"]
-        engine = self._span_engine(batch)
-        if custom_parts is None and self.partitioner == "hash" and \
-                engine != "host" and self.key_normalizer is None and \
-                self.resident_keys and batch.num_records > 0:
-            klens = batch.key_offsets[1:] - batch.key_offsets[:-1]
-            wmax = int(klens.max(initial=1))
-            if wmax <= self.key_width:
-                eff = ((max(wmax, 1) + 3) // 4) * 4
-                mat, lengths = pad_to_matrix(batch.key_bytes,
-                                             batch.key_offsets, eff)
-                return {"kind": "resident", "batch": batch,
-                        "lanes": matrix_to_lanes(mat), "lengths": lengths}
+        resident = None
+        if custom_parts is None and batch.num_records > 0:
+            resident = self._resident_encode(batch, self._span_engine(batch))
+        if resident is not None:
+            return {"kind": "resident", "batch": batch,
+                    "lanes": resident[0], "lengths": resident[1]}
         return {"kind": "generic", "batch": batch,
                 "custom_parts": custom_parts}
+
+    def _resident_encode(self, batch: KVBatch, engine: str
+                         ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """(lanes, lengths) where the span takes the device-resident fast
+        path: a partitioner the span sort fuses (hash, range), the device
+        engine, raw-byte order, and every key (and split point) inside the
+        lane width.  Lanes are sized to the ACTUAL longest key (fewer upload
+        bytes); whole keys fit them, so the partition derives from lanes ON
+        DEVICE, prefix order IS exact byte order (no tie-break), and the
+        sorted key columns stay in HBM for the consumer merge."""
+        if self.partitioner not in ("hash", "range") or engine == "host" or \
+                self.key_normalizer is not None or not self.resident_keys:
+            return None
+        klens = batch.key_offsets[1:] - batch.key_offsets[:-1]
+        wmax = max([int(klens.max(initial=1))] +
+                   [len(s) for s in self.split_points])
+        if wmax > self.key_width:
+            return None
+        eff = ((max(wmax, 1) + 3) // 4) * 4
+        mat, lengths = pad_to_matrix(batch.key_bytes, batch.key_offsets, eff)
+        return matrix_to_lanes(mat), lengths
+
+    def _splits_for(self, lanes: np.ndarray):
+        """The range partitioner's split rows at these lanes' width (None:
+        the hash kernel)."""
+        if self.partitioner != "range":
+            return None
+        width = lanes.shape[1]
+        if width not in self._split_lanes:
+            self._split_lanes[width] = encode_split_keys(self.split_points,
+                                                         width * 4)
+        return self._split_lanes[width]
 
     def _async_coalesce(self, staged_list: List[dict]) -> dict:
         batch = KVBatch.concat([s["batch"] for s in staged_list])
@@ -565,8 +620,9 @@ class DeviceSorter:
     def _async_dispatch(self, staged: dict) -> dict:
         t0 = time.time()
         if staged["kind"] == "resident":
-            inflight = device.dispatch_resident_span(staged["staged_dev"],
-                                                     self.num_partitions)
+            inflight = device.dispatch_resident_span(
+                staged["staged_dev"], self.num_partitions,
+                self._splits_for(staged["lanes"]))
             return {"kind": "resident", "batch": staged["batch"],
                     "inflight": inflight, "t0": t0}
         # generic spans (normalizer / custom partitioner / host-routed /
@@ -580,7 +636,8 @@ class DeviceSorter:
         if inflight["kind"] == "resident":
             sp, perm, dev = device.readback_resident_span(
                 inflight["inflight"])
-            sorted_batch = inflight["batch"].take(perm)
+            sorted_batch = _take(inflight["batch"], perm, self.counters,
+                                 self._store_lock)
             sorted_batch.dev_keys = dev
             self._record_sort(inflight["t0"], "device",
                               sorted_batch.num_records)
@@ -703,30 +760,22 @@ class DeviceSorter:
         # round-trip, even under the device engine
         if engine is None:
             engine = self._span_engine(batch)
-        if custom_partitions is None and self.partitioner == "hash" and \
-                engine != "host" and self.key_normalizer is None and \
-                self.resident_keys:
-            klens = batch.key_offsets[1:] - batch.key_offsets[:-1]
-            wmax = int(klens.max(initial=1))
-            if wmax <= self.key_width:
-                # device-resident fast path: lanes sized to the ACTUAL max
-                # key length (fewer upload bytes), full keys fit them, so
-                # the FNV hash derives from lanes ON DEVICE (no hash-matrix
-                # upload), prefix order IS exact byte order (no tie-break),
-                # and the sorted key columns stay in HBM for the consumer
-                # merge (VERDICT r1 item 4)
-                eff = ((max(wmax, 1) + 3) // 4) * 4
-                mat, lengths = pad_to_matrix(batch.key_bytes,
-                                             batch.key_offsets, eff)
-                lanes = matrix_to_lanes(mat)
-                sorted_partitions, perm, dev = \
-                    device.hash_sort_span_resident(lanes, lengths,
-                                                   self.num_partitions)
-                sorted_batch = batch.take(perm)
-                sorted_batch.dev_keys = dev
-                self._record_sort(t0, "device", batch.num_records)
-                return Run.from_sorted_batch(sorted_batch, sorted_partitions,
-                                             self.num_partitions)
+        resident = self._resident_encode(batch, engine) \
+            if custom_partitions is None else None
+        if resident is not None:
+            lanes, lengths = resident
+            sorted_partitions, perm, dev = device.sort_span_resident(
+                lanes, lengths, self.num_partitions, self._splits_for(lanes))
+            sorted_batch = _take(batch, perm, self.counters, self._store_lock)
+            sorted_batch.dev_keys = dev
+            self._record_sort(t0, "device", batch.num_records)
+            return Run.from_sorted_batch(sorted_batch, sorted_partitions,
+                                         self.num_partitions)
+        if custom_partitions is None and self.partitioner == "range":
+            # off the fused path (host engine, failover, a comparator, keys
+            # over the lane width): the same partition ids from the host
+            custom_partitions = range_partitions(
+                batch.key_bytes, batch.key_offsets, self.split_points)
         if self.key_normalizer is not None:
             sort_bytes, sort_offsets = normalize_batch_keys(
                 batch, self.key_normalizer)
@@ -754,13 +803,14 @@ class DeviceSorter:
         else:
             sorted_partitions, perm = device.sort_run(
                 np.zeros(batch.num_records, dtype=np.int32), lanes, lengths)
-        sorted_batch = batch.take(perm)
+        sorted_batch = _take(batch, perm, self.counters, self._store_lock)
         sort_lengths, keyfn = _sorted_key_view(sort_bytes, sort_offsets, perm)
         refinement = _exact_tiebreak(
             sort_lengths, sorted_partitions, lanes[perm], self.key_width,
             keyfn)
         if refinement is not None:
-            sorted_batch = sorted_batch.take(refinement)
+            sorted_batch = _take(sorted_batch, refinement, self.counters,
+                                 self._store_lock)
         self._record_sort(t0, "device", batch.num_records)
         return Run.from_sorted_batch(sorted_batch, sorted_partitions,
                                      self.num_partitions)
@@ -803,7 +853,7 @@ class DeviceSorter:
         else:
             parts = None    # everything lands in partition 0
         perm = sort_partition_keys_native(sort_bytes, sort_offsets, parts)
-        sorted_batch = batch.take(perm)
+        sorted_batch = _take(batch, perm, self.counters, self._store_lock)
         if parts is None:
             sorted_partitions = np.zeros(batch.num_records, dtype=np.int32)
         else:
@@ -1129,7 +1179,7 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
             _record_launches(counters, tally)
             with tracing.span("merge.gather", cat="merge", rows=len(perm)):
                 batch = KVBatch.concat([r.batch for r in live])
-                sorted_batch = batch.take(perm)
+            sorted_batch = _take(batch, perm, counters)
             _record_merge(counters, t0, "device", batch.num_records,
                           len(runs), final)
             if row_index is None:
@@ -1178,7 +1228,7 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
         perm_n = merge_runs_native(
             sort_bytes, sort_offsets,
             partitions if num_partitions > 1 else None, run_bounds)
-        sorted_batch = batch.take(perm_n)
+        sorted_batch = _take(batch, perm_n, counters)
         sorted_partitions = partitions[perm_n]
         _record_merge(counters, t0, "host", batch.num_records,
                       len(runs), final)
@@ -1194,15 +1244,15 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
         perm = device.merge_runs(partitions, lanes, lengths)
     _record_merge_ms(counters, t_dev)
     _record_launches(counters, tally)
+    sorted_batch = _take(batch, perm, counters)
     with tracing.span("merge.gather", cat="merge", rows=len(perm)):
         sorted_partitions = partitions[perm]
-        sorted_batch = batch.take(perm)
         sort_lengths, keyfn = _sorted_key_view(sort_bytes, sort_offsets,
                                                perm)
         refinement = _exact_tiebreak(sort_lengths, sorted_partitions,
                                      lanes[perm], key_width, keyfn)
-        if refinement is not None:
-            sorted_batch = sorted_batch.take(refinement)
+    if refinement is not None:
+        sorted_batch = _take(sorted_batch, refinement, counters)
     _record_merge(counters, t0, "device", batch.num_records, len(runs),
                   final)
     return Run.from_sorted_batch(sorted_batch, sorted_partitions,

@@ -193,3 +193,175 @@ def test_coded_r2_both_copies_failed_raises():
         "mesh.exchange.delay:fail:n=1,match=device=3"))
     with pytest.raises(Exception, match="copies"):
         _run(coord, spans, "bothfail/a->b", consumers, coded="r2")
+
+
+# --------------------------------------------------------- routing parity
+
+MAX_KEY = 256     # tez.runtime.tpu.mesh.max.key.bytes: the edge's maximum
+
+
+def _ragged_keys(seed):
+    """Keys of every length the lane layout cares about — empty, under a
+    lane, one lane, a lane and a byte, two lanes, the edge's maximum — of
+    random bytes (half of them >= 0x80), in random order."""
+    rng = np.random.default_rng(seed)
+    keys = [rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+            for n in (0, 1, 3, 4, 5, 8, MAX_KEY) for _ in range(24)]
+    keys += [b"\x80", b"\xff" * 8, b"\x00" * 5, b"\xfe" * MAX_KEY]
+    return [keys[i] for i in rng.permutation(len(keys))]
+
+
+@pytest.mark.parametrize("consumers", [1, 4, 7, 8, 16])
+def test_producer_routing_equals_every_partitioner(consumers):
+    """The routing a producer stores (native FNV over its raw ragged key
+    bytes, % W) is the numpy host partitioner's over the padded matrix,
+    the device kernel's over the lanes and the scalar reference's; % D it
+    is the destination the parent's plan computed from the lanes."""
+    import jax.numpy as jnp
+    from tez_tpu.ops.host_sort import fnv_rows_host
+    from tez_tpu.ops.keycodec import lanes_to_matrix
+    from tez_tpu.parallel.exchange import _fnv_lanes, fnv_bytes_host
+    keys = _ragged_keys(consumers)
+    batch = KVBatch.from_pairs([(k, b"v") for k in keys])
+    coord = MeshExchangeCoordinator()
+    # one of two producers: the exchange does not run, the span is kept
+    coord.register_producer("parity/a->b", 0, 2, consumers, batch, 16, 4)
+    lanes, klens, _, part = coord.edges["parity/a->b"].spans[0]
+    W, D = consumers, coord.devices_for(consumers)
+    assert part.dtype == np.uint8 and part.shape == (len(keys),)
+    assert lanes.shape[1] * 4 == MAX_KEY
+    host = fnv_rows_host(lanes_to_matrix(lanes), klens.astype(np.int64))
+    np.testing.assert_array_equal(part, host % np.uint32(W))
+    device = np.asarray(_fnv_lanes(jnp.asarray(lanes), jnp.asarray(klens)))
+    np.testing.assert_array_equal(part, device % np.uint32(W))
+    np.testing.assert_array_equal(
+        part, [fnv_bytes_host(k) % W for k in keys])
+    # the parent's plan: hashes % D as int64, from the rebuilt byte matrix
+    parent_rdest = (host % np.uint32(D)).astype(np.int64)
+    np.testing.assert_array_equal(part % D, parent_rdest)
+
+
+def test_routing_dtype_follows_the_consumer_count():
+    """The stored routing is the narrowest unsigned dtype holding W."""
+    from tez_tpu.parallel.exchange import fnv_bytes_host
+    batch = KVBatch.from_pairs([(b"k%d" % i, b"v") for i in range(50)])
+    coord = MeshExchangeCoordinator()
+    for W, dtype in ((255, np.uint8), (256, np.uint16), (70_000, np.uint32)):
+        coord.register_producer(f"w{W}/a->b", 0, 2, W, batch, 8, 4)
+        part = coord.edges[f"w{W}/a->b"].spans[0][3]
+        assert part.dtype == dtype
+        np.testing.assert_array_equal(
+            part, [fnv_bytes_host(b"k%d" % i) % W for i in range(50)])
+    with pytest.raises(ValueError, match="consumers"):
+        coord.register_producer("w255/a->b", 1, 2, 4, batch, 8, 4)
+
+
+def _wide_ranks(group, groups):
+    """The parent's rank within a group: a stable argsort of int64 keys
+    (a merge sort of whole keys) and a scatter."""
+    group = group.astype(np.int64)
+    counts = np.bincount(group, minlength=groups)
+    order = np.argsort(group, kind="stable")
+    ranks = np.empty(group.size, dtype=np.int64)
+    starts = np.zeros(groups + 1, dtype=np.int64)
+    np.cumsum(counts, out=starts[1:])
+    ranks[order] = np.arange(group.size, dtype=np.int64) - \
+        np.repeat(starts[:-1], counts)
+    return ranks
+
+
+def _parent_device_inputs(spans, D, per_round, coded):
+    """The parent's plan and pack, kept as the plain reference: routing
+    from the byte matrix rebuilt out of the lanes, int64 destinations, a
+    stable argsort for the ranks and two more a round (``qorder``,
+    ``place``).  Returns what each round hands the device program."""
+    from tez_tpu.ops.device import _bucket
+    from tez_tpu.ops.host_sort import fnv_rows_host
+    from tez_tpu.ops.keycodec import lanes_to_matrix
+    lanes = np.concatenate([s[0] for s in spans])
+    klens = np.concatenate([s[1] for s in spans])
+    vwords = np.concatenate([s[2] for s in spans])
+    value_words = vwords.shape[1]
+    hashes = fnv_rows_host(lanes_to_matrix(lanes), klens.astype(np.int64))
+    rdest = (hashes % np.uint32(D)).astype(np.int64)
+    counts = np.bincount(rdest, minlength=D)
+    ranks = _wide_ranks(rdest, D)
+    rounds = []
+    for r, _ in enumerate(plan_rounds(counts, per_round, D)):
+        lo = r * per_round
+        sel = np.flatnonzero((ranks >= lo) & (ranks < lo + per_round))
+        rows_idx, dests_all, rtag = sel, rdest[sel], None
+        if coded:
+            rows_idx = np.concatenate([sel, sel])
+            rtag = np.concatenate([dests_all, dests_all]).astype(np.uint32)
+            dests_all = np.concatenate([dests_all, (dests_all + 1) % D])
+        qc = np.bincount(dests_all, minlength=D)
+        lrank = _wide_ranks(dests_all, D)                  # ``qorder``
+        senders = lrank // np.maximum(1, -(-qc // D))[dests_all]
+        N = _bucket(int(np.bincount(senders, minlength=D).max()))
+        pos = senders * N + _wide_ranks(senders, D)        # ``place``
+        vw = value_words + (1 if coded else 0)
+        r_lanes = np.zeros((D * N, lanes.shape[1]), np.uint32)
+        r_klens = np.zeros(D * N, np.uint32)
+        r_vwords = np.zeros((D * N, vw), np.uint32)
+        r_valid = np.zeros(D * N, bool)
+        r_dests = np.zeros(D * N, np.uint32)
+        r_lanes[pos] = lanes[rows_idx]
+        r_klens[pos] = klens[rows_idx]
+        r_vwords[pos, :value_words] = vwords[rows_idx]
+        if coded:
+            r_vwords[pos, value_words] = rtag
+        r_valid[pos] = True
+        r_dests[pos] = dests_all.astype(np.uint32)
+        rounds.append((r_lanes, r_klens, r_vwords, r_valid, r_dests))
+    return rounds
+
+
+@pytest.mark.parametrize("coded", ["off", "r2"])
+def test_device_inputs_equal_the_parents_plan(coded, monkeypatch):
+    """A skewed exchange of several rounds: every array handed to the
+    device program — so every rank, round rank and position behind it —
+    is what the parent's formulas give for the same spans."""
+    consumers, per_round = 8, 500
+    spans = _corpus(5_000, 4, consumers, hot_frac=0.45, hot_part=5, seed=9)
+    coord = MeshExchangeCoordinator(max_rows_per_round=per_round,
+                                    split_after=0)
+    handed = []
+    compiled_fn = coord._compiled_fn
+
+    def _spy(*args, **kw):
+        fn = compiled_fn(*args, **kw)
+
+        def _call(*arrays):
+            handed.append(tuple(np.array(a) for a in arrays))
+            return fn(*arrays)
+        return _call
+
+    monkeypatch.setattr(coord, "_compiled_fn", _spy)
+    edge = f"inputs-{coded}/a->b"
+    out = _run(coord, spans, edge, consumers, engine="auto", coded=coded)
+    assert _sig(out) == _golden(spans, consumers)
+    stored = [coord.edges[edge].spans[i] for i in range(len(spans))]
+    expected = _parent_device_inputs(stored, coord.devices_for(consumers),
+                                     per_round, coded == "r2")
+    assert len(handed) == len(expected) > 2       # multi-round
+    for got, want in zip(handed, expected):
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(g, w)
+
+
+def test_arrival_ranks_equal_the_wide_sort():
+    """``arrival_ranks`` on a narrow key is the parent's int64 stable
+    argsort and scatter, for one group, many groups and empty groups."""
+    from tez_tpu.parallel.coordinator import arrival_ranks
+    rng = np.random.default_rng(3)
+    for groups, dtype in ((1, np.uint8), (8, np.uint8), (255, np.uint8),
+                          (300, np.uint16)):
+        group = rng.zipf(1.3, 20_000) % groups
+        group[group == groups // 2] = 0            # an empty group
+        got = arrival_ranks(group.astype(dtype),
+                            np.bincount(group, minlength=groups))
+        assert got.dtype == np.int64
+        np.testing.assert_array_equal(got, _wide_ranks(group, groups))
+    assert arrival_ranks(np.zeros(0, np.uint8), np.zeros(4, np.int64)).size == 0

@@ -31,15 +31,19 @@ def make_batch(pairs):
     return KVBatch.from_pairs([(k.encode(), v.encode()) for k, v in pairs])
 
 
-def reference_route(pairs, num_workers):
+def _plain_route(pairs, num_workers):
+    """Plain reference over raw bytes: consumer = scalar FNV % W, rows
+    stably sorted by key (equal keys stay in arrival order)."""
     from tez_tpu.parallel.exchange import fnv_bytes_host
     out = [[] for _ in range(num_workers)]
     for k, v in pairs:
-        out[fnv_bytes_host(k.encode()) % num_workers].append(
-            (k.encode(), v.encode()))
-    for part in out:
-        part.sort(key=lambda kv: kv[0])
-    return out
+        out[fnv_bytes_host(k) % num_workers].append((k, v))
+    return [sorted(part, key=lambda kv: kv[0]) for part in out]
+
+
+def reference_route(pairs, num_workers):
+    return _plain_route([(k.encode(), v.encode()) for k, v in pairs],
+                        num_workers)
 
 
 def test_coordinator_exchange_matches_host_routing():
@@ -155,6 +159,71 @@ def test_producer_reregistration_reruns_exchange():
     assert sorted(sum(second.values(), [])) == \
         sorted([(b"k1", b"new"), (b"k2", b"vb")])
     assert coord.exchanges_run == 2
+
+
+def _ragged_pairs(rng, n, tag):
+    """Binary keys of 0-9 bytes (high bytes included) drawn from a small
+    pool, so keys repeat and equal-key order shows; values name the row."""
+    pool = [bytes(rng.randrange(256) for _ in range(rng.randrange(10)))
+            for _ in range(max(8, n // 6))]
+    return [(rng.choice(pool), b"%s%05d" % (tag, i)) for i in range(n)]
+
+
+def test_reregistered_producer_gets_its_new_routing():
+    """A producer that re-registers after the exchange with OTHER rows
+    (another count, other keys, other consumers) replaces its routing with
+    its span: the re-run equals a fresh edge fed the same final spans."""
+    W = 4
+    rng = random.Random(41)
+    first = _ragged_pairs(rng, 700, b"a")
+    other = _ragged_pairs(rng, 900, b"b")
+    replacement = _ragged_pairs(rng, 1100, b"c")
+
+    def feed(coord, edge, spans):
+        for idx, pairs in spans:
+            coord.register_producer(edge, idx, 2, W,
+                                    KVBatch.from_pairs(pairs),
+                                    key_width=8, value_width=8)
+        return [list(coord.wait_consumer(edge, w, 2, W,
+                                         timeout=60).iter_pairs())
+                for w in range(W)]
+
+    coord = MeshExchangeCoordinator()
+    assert feed(coord, "rr", [(0, first), (1, other)]) == \
+        _plain_route(first + other, W)
+    got = feed(coord, "rr", [(0, replacement)])
+    assert coord.exchanges_run == 2
+    assert len(coord.edges["rr"].spans[0][3]) == len(replacement)
+    fresh = feed(MeshExchangeCoordinator(), "rr2",
+                 [(0, replacement), (1, other)])
+    assert got == fresh == _plain_route(replacement + other, W)
+
+
+def test_consumers_exceed_devices_assembled_bit_exactly():
+    """W = 2x the devices, ragged binary keys: each device's sorted shard
+    is split into its consumer partitions by the native hash of the decoded
+    raw keys — every consumer's bytes equal the plain reference's, equal
+    keys in arrival order."""
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        pytest.skip("needs multiple virtual devices")
+    W = n_dev * 2
+    rng = random.Random(43)
+    spans = [_ragged_pairs(rng, 1500, tag) for tag in (b"a", b"b", b"c")]
+    coord = MeshExchangeCoordinator()
+    assert coord.devices_for(W) == n_dev
+    for idx, pairs in enumerate(spans):
+        coord.register_producer("wide-w", idx, 3, W,
+                                KVBatch.from_pairs(pairs),
+                                key_width=8, value_width=8)
+    golden = _plain_route(sum(spans, []), W)
+    for w in range(W):
+        got = coord.wait_consumer("wide-w", w, 3, W, timeout=60)
+        want = KVBatch.from_pairs(golden[w])
+        for name in ("key_bytes", "key_offsets", "val_bytes", "val_offsets"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, name)), getattr(want, name),
+                err_msg=f"consumer {w} {name}")
 
 
 def test_mesh_edge_skew_multi_round_inside_dag(tmp_path, monkeypatch):

@@ -45,7 +45,9 @@ import numpy as np
 from tez_tpu.common import faults, tracing
 from tez_tpu.common.counters import MESH_EXCHANGE_GROUP
 from tez_tpu.obs import flight as _flight
+from tez_tpu.ops import hostpool
 from tez_tpu.ops.keycodec import matrix_to_lanes, pad_to_matrix
+from tez_tpu.ops.native import fnv32_partition_native
 from tez_tpu.ops.runformat import KVBatch
 
 log = logging.getLogger(__name__)
@@ -103,7 +105,10 @@ class _EdgeState:
         self.coded: Optional[str] = None      # off|r2 (per-edge)
         self.split_after: Optional[int] = None
         self.counters = None                  # triggering producer's sink
-        self.spans: Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
+        #: producer -> (key lanes, key lengths, value words, consumer
+        #: partition of each row: hash % num_consumers, routed by the producer)
+        self.spans: Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray,
+                                    np.ndarray]] = {}
         #: producer -> (time.time() its rows were in, its trace context),
         #: kept while the span plane is armed: what the
         #: exchange.wait_peers spans are made from
@@ -146,6 +151,18 @@ def plan_rounds(counts: np.ndarray, per_round: int, num_devices: int,
             cap = min(_bucket(chunk), per_round)
         plan.append((quota, cap))
     return plan
+
+
+def arrival_ranks(group: np.ndarray, counts: np.ndarray) -> np.ndarray:
+    """Each row's rank among the rows of its group, in arrival order;
+    ``counts`` is the groups' histogram.  Callers pass ``group`` in the
+    narrowest unsigned dtype that holds it: numpy's stable argsort is a
+    radix sort up to 16 bits and a merge sort of whole keys beyond."""
+    within = np.arange(group.size, dtype=np.int64)
+    within -= np.repeat(np.cumsum(counts) - counts, counts)
+    ranks = np.empty(group.size, dtype=np.int64)
+    ranks[np.argsort(group, kind="stable")] = within
+    return ranks
 
 
 class MeshExchangeCoordinator:
@@ -267,9 +284,20 @@ class MeshExchangeCoordinator:
                                         key_width)
             lanes = matrix_to_lanes(kmat)
             vwords = _encode_values(batch, value_width)
+            # routing, once a row, where the raw key bytes are: the consumer
+            # partition hash % W (native, GIL released, in this producer's
+            # thread while slower producers still produce)
+            part = fnv32_partition_native(
+                batch.key_bytes, batch.key_offsets, num_consumers).astype(
+                    np.min_scalar_type(num_consumers))
         with self.lock:
             st = self.edges.setdefault(
                 edge_id, _EdgeState(num_producers, num_consumers, edge_id))
+            if num_consumers != st.num_consumers:
+                raise ValueError(
+                    f"mesh edge {edge_id}: producer {task_index} routed its "
+                    f"rows over {num_consumers} consumers, the edge has "
+                    f"{st.num_consumers}")
             if max_rows_per_round:
                 st.max_rows_per_round = int(max_rows_per_round)
             if engine:
@@ -282,7 +310,7 @@ class MeshExchangeCoordinator:
                 st.counters = counters
             st.spans[task_index] = (lanes,
                                     klens.astype(np.uint32),
-                                    vwords)
+                                    vwords, part)
             if tracing.armed():
                 st.arrived[task_index] = (time.time(),
                                           tracing.current_context())
@@ -487,14 +515,14 @@ class MeshExchangeCoordinator:
 
     def _execute(self, st: _EdgeState) -> List[KVBatch]:
         """Run the SPMD exchange for a complete edge.  CAP comes from exact
-        host-side partition counts (fnv_rows_host == the kernel's
-        partitioner), so the padded all-to-all cannot overflow; when the
+        host-side partition counts (each producer routed its own rows with
+        the native FNV == the kernel's partitioner; here they are only
+        added up), so the padded all-to-all cannot overflow; when the
         biggest partition exceeds max_rows_per_round the exchange runs in
         rank-sliced rounds and each consumer's rounds merge at the end.
         See the module docstring for the skew levers layered on top
         (histogram round sizing, the splitter, coded r2)."""
         from tez_tpu.common import metrics
-        from tez_tpu.ops.host_sort import fnv_rows_host
         from tez_tpu.ops.sorter import merge_sorted_runs
         from tez_tpu.ops.runformat import Run
         from tez_tpu.parallel.exchange import resolve_engine
@@ -530,28 +558,33 @@ class MeshExchangeCoordinator:
                     return a
                 return np.pad(a, ((0, 0), (0, width - a.shape[1])))
 
-            lanes = np.concatenate([_widen(s[0], max_lanes) for s in spans]) \
-                if spans else np.zeros((0, 1), np.uint32)
-            klens = np.concatenate([s[1] for s in spans]) \
-                if spans else np.zeros((0,), np.uint32)
-            vwords = np.concatenate([_widen(s[2], max_vw) for s in spans]) \
-                if spans else np.zeros((0, 1), np.uint32)
+            def _rows(arrays: List[np.ndarray], width: int) -> np.ndarray:
+                # pooled: 134 MB a DAG of the benchmark's mesh cell, found
+                # again by the next exchange instead of first-touched
+                return hostpool.concatenate(
+                    [_widen(a, width).reshape(-1) for a in arrays]
+                ).reshape(-1, width)
+
+            lanes = _rows([s[0] for s in spans], max_lanes)
+            klens = hostpool.concatenate([s[1] for s in spans])
+            vwords = _rows([s[2] for s in spans], max_vw)
             total = lanes.shape[0]
             num_lanes = lanes.shape[1]
             value_words = vwords.shape[1]
             if total == 0:
                 return [KVBatch.empty() for _ in range(W)]
 
-            # exact routing on host: byte-masked FNV over the padded key matrix
-            # (reconstruct the byte matrix from lanes — cheap, vectorized).
-            # Routing is hash % D; with D | W that equals (hash % W) % D, so
-            # device d receives exactly the rows of consumer partitions
-            # {c : c % D == d} (split apart after the exchange).
+            # exact routing: every producer hashed its own rows' raw key
+            # bytes to the consumer partition hash % W at registration; the
+            # plan only adds up.  Routing is hash % D; with D | W that equals
+            # (hash % W) % D, so device d receives exactly the rows of
+            # consumer partitions {c : c % D == d} (split apart after the
+            # exchange).  Destinations stay in the narrowest dtype holding D:
+            # numpy's stable argsort is a radix sort up to 16 bits.
             from tez_tpu.ops.device import _bucket
-            from tez_tpu.ops.keycodec import lanes_to_matrix
-            kmat = lanes_to_matrix(lanes)
-            hashes = fnv_rows_host(kmat, klens.astype(np.int64))
-            rdest = (hashes % np.uint32(D)).astype(np.int64)
+            dest_dtype = np.min_scalar_type(D)
+            rdest = (np.concatenate([s[3] for s in spans]) % D) \
+                .astype(dest_dtype, copy=False)
             counts = np.bincount(rdest, minlength=D)
             per_round = st.max_rows_per_round or self.max_rows_per_round
 
@@ -634,12 +667,7 @@ class MeshExchangeCoordinator:
                            a=len(plan), b=total)
 
             # rank of each row within its routing partition (arrival order)
-            order = np.argsort(rdest, kind="stable")
-            ranks = np.empty(total, dtype=np.int64)
-            starts = np.zeros(D + 1, dtype=np.int64)
-            np.cumsum(counts, out=starts[1:])
-            ranks[order] = np.arange(total, dtype=np.int64) - \
-                np.repeat(starts[:-1], counts)
+            ranks = arrival_ranks(rdest, counts)
 
         row_words = num_lanes + 1 + value_words   # lanes + klen + vwords
         sent_rows = dup_rows = buddy_wins = rounds_run = 0
@@ -669,15 +697,18 @@ class MeshExchangeCoordinator:
                     dests_all = np.concatenate(
                         [dests_all, (dests_all + 1) % D])
                     dup_rows += n_round
-                qc = np.bincount(dests_all, minlength=D)
-                lane_counts += qc
-                if coded:
                     # duplication doubled the quotas; re-derive the balanced
                     # cap from the combined histogram (coded always uses
                     # balanced placement — legacy tail-packing could put a
                     # whole destination's copies on one sender)
+                    qc = np.bincount(dests_all, minlength=D)
                     cap = min(_bucket(max(1, -(-int(qc.max()) // D))),
                               per_round)
+                else:
+                    # the round carries each destination's arrival ranks
+                    # [lo, lo + per_round): its histogram is the plan's quota
+                    qc = quota
+                lane_counts += qc
                 balanced = coded or not self.legacy_sizing
                 if balanced:
                     # balanced blocked placement: destination d's rows split
@@ -686,24 +717,21 @@ class MeshExchangeCoordinator:
                     # ceil(quota_d / D) <= cap.  Contiguous chunks + the
                     # receiver's stable sender-major merge preserve global
                     # arrival order for equal keys.
-                    qorder = np.argsort(dests_all, kind="stable")
-                    lrank = np.empty(dests_all.size, dtype=np.int64)
-                    qstarts = np.zeros(D + 1, dtype=np.int64)
-                    np.cumsum(qc, out=qstarts[1:])
-                    lrank[qorder] = np.arange(dests_all.size, dtype=np.int64) \
-                        - np.repeat(qstarts[:-1], qc)
+                    if coded:
+                        # both copies of a row, ranked within their
+                        # destinations
+                        lrank = arrival_ranks(dests_all, qc)
+                    else:
+                        # a row's rank within the round is its rank within
+                        # its destination less the round's first: the plan
+                        # ranked every row once, nothing is sorted again
+                        lrank = ranks[sel] - lo
                     chunk_d = np.maximum(1, -(-qc // D))
-                    senders = lrank // chunk_d[dests_all]
+                    senders = (lrank // chunk_d[dests_all]).astype(dest_dtype)
                     loads = np.bincount(senders, minlength=D)
                     N = _bucket(int(loads.max()))
-                    place = np.argsort(senders, kind="stable")
-                    within = np.empty(senders.size, dtype=np.int64)
-                    lstarts = np.zeros(D + 1, dtype=np.int64)
-                    np.cumsum(loads, out=lstarts[1:])
-                    within[place] = \
-                        np.arange(senders.size, dtype=np.int64) - \
-                        np.repeat(lstarts[:-1], loads)
-                    pos = senders * N + within
+                    pos = senders.astype(np.int64) * N + \
+                        arrival_ranks(senders, loads)
                 else:
                     # legacy layout: rows in arrival order, zero tail pad
                     N = _bucket(-(-dests_all.size // D))
@@ -832,10 +860,8 @@ class MeshExchangeCoordinator:
                 batch = per_device[d]
                 if batch.num_records == 0:
                     continue
-                bmat, blens = pad_to_matrix(batch.key_bytes, batch.key_offsets,
-                                            num_lanes * 4)
-                c_part = (fnv_rows_host(bmat, blens.astype(np.int64)) %
-                          np.uint32(W)).astype(np.int64)
+                c_part = fnv32_partition_native(batch.key_bytes,
+                                                batch.key_offsets, W)
                 for c in np.unique(c_part):
                     csel = np.flatnonzero(c_part == c)
                     runs_per_consumer[int(c)].append(batch.take(csel))

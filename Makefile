@@ -1,12 +1,10 @@
 # Developer entry points.  Targets that set JAX_PLATFORMS=cpu run on the
-# host on purpose; `make bench` and `make smoke` need a chip and fail
-# without one.  Every bench line carries the platform it ran on.
+# host on purpose; `make smoke` needs a chip and fails without one.  The
+# benchmark is BENCHMARK.json: python3 benchmarks/run.py (PERF.md).
 
 PY ?= python
-OLD ?= /tmp/bench_old.json
-NEW ?= /tmp/bench_new.json
 
-.PHONY: test lint smoke bench bench-new bench-diff bench-store bench-sort bench-exchange bench-query chaos chaos-query-storm chaos-device-ooo chaos-device chaos-merge chaos-store chaos-push chaos-exchange chaos-ha chaos-stream chaos-slo-burn soak docs doctor top metrics-smoke
+.PHONY: test lint smoke chaos chaos-query-storm chaos-device-ooo chaos-device chaos-merge chaos-store chaos-push chaos-exchange chaos-ha chaos-stream chaos-slo-burn soak docs doctor top metrics-smoke
 
 test:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow'
@@ -19,44 +17,6 @@ lint:
 # the quickest proof the main path still runs on the chip (chip_smoke.py)
 smoke:
 	$(PY) chip_smoke.py
-
-bench:
-	$(PY) bench.py
-
-# capture a fresh bench run in the same shape the driver archives
-bench-new:
-	$(PY) bench.py | tee /tmp/bench_stdout.txt
-	$(PY) -c "import json; print(json.dumps({'tail': open('/tmp/bench_stdout.txt').read()}))" > $(NEW)
-
-# gate: nonzero exit when NEW drops >20% below OLD on any shared metric
-# (OLD and NEW are two captures made with bench-new on the same platform)
-bench-diff:
-	$(PY) -m tez_tpu.tools.bench_diff $(OLD) $(NEW)
-
-# buffer-store short-circuit micro-bench only: store leased zero-copy fetch
-# vs loopback TCP, plus the lineage seal/republish session leg
-bench-store:
-	JAX_PLATFORMS=cpu TEZ_BENCH_STORE_ONLY=1 $(PY) bench.py
-
-# external-sort scale leg: the same spill-heavy sort DAG end-to-end with
-# pull-based vs push-based shuffle; bench-diff enforces the ratio floor
-bench-sort:
-	JAX_PLATFORMS=cpu TEZ_BENCH_SORT_ONLY=1 $(PY) bench.py
-
-# MULTICHIP skewed-key exchange legs (8 virtual devices on CPU): padded
-# baseline vs ragged/skew-aware/coded, bit-identical outputs; bench-diff
-# enforces the skew-aware leg's min_vs_baseline >= 1.3 floor
-bench-exchange:
-	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 TEZ_BENCH_EXCHANGE_ONLY=1 $(PY) bench.py
-
-# query plane (docs/query.md): broadcast-vs-repartition legs on the
-# uniform + zipf corpora, then the adaptive-replan headline — run 1
-# repartitions by estimate, run 2 is replanned to broadcast from the
-# observed stats and must beat run 1 (bench-diff enforces the
-# min_vs_baseline >= 1.0 floor); the QUERY_REPLANNED event is asserted
-# in the JSONL journal and in doctor's rendering
-bench-query:
-	JAX_PLATFORMS=cpu TEZ_BENCH_QUERY_ONLY=1 $(PY) bench.py
 
 chaos:
 	$(PY) -m tez_tpu.tools.chaos --trials 3

@@ -1,5 +1,5 @@
-"""Async double-buffered device plane (ops/async_stage.py,
-ops/device_pipeline.py, DeviceSorter pipeline integration).
+"""Async double-buffered device plane (ops/async_stage.py, DeviceSorter
+pipeline integration).
 
 The scheduler's contract is asserted against a FAKE clock and thread
 events, never wall time: overlap (span k+1's encode starts before span k
@@ -128,72 +128,35 @@ def test_stage_error_propagates_and_poisons():
         pipe.submit(1, 1)
 
 
-# -- device scheduler (needs jax; tier-1 runs with JAX_PLATFORMS=cpu) -------
+# -- DeviceSorter on the plane (needs jax; tier-1 runs with JAX_PLATFORMS=cpu)
 
-def _mk_ragged(n, key_len, seed):
-    rng = np.random.default_rng(seed)
-    kb = rng.integers(0, 256, n * key_len, dtype=np.int64).astype(np.uint8)
-    ko = np.arange(n + 1, dtype=np.int64) * key_len
-    vb = rng.integers(0, 256, n * 8, dtype=np.int64).astype(np.uint8)
-    return kb, ko, vb
+def test_resident_span_sort_compiles_once_per_bucket():
+    """Spans of different sizes inside one power-of-two bucket launch ONE
+    compiled span sort; the next bucket compiles once more.  The kernels'
+    caches are process-wide, so the partition count is one no other test
+    sorts by."""
+    from tez_tpu.ops import device
+    from tez_tpu.ops.runformat import KVBatch
+    from tez_tpu.ops.sorter import DeviceSorter
 
+    def compiles_for(n):
+        rng = np.random.default_rng(n)
+        kb = rng.integers(0, 256, n * 8, dtype=np.int64).astype(np.uint8)
+        off = np.arange(n + 1, dtype=np.int64) * 8
+        sorter = DeviceSorter(num_partitions=11, engine="device",
+                              device_min_records=0)
+        sorter.write_batch(KVBatch(kb, off, kb.copy(), off))
+        started = []
+        with device.compile_listener(
+                lambda begins: started.append(1) if begins else None):
+            run = sorter.flush_run()
+        assert run.batch.num_records == n
+        return len(started)
 
-def test_scheduler_matches_sync_kernel():
-    """submit_ragged through the async plane == the sync device_shuffle_sort
-    over the concatenated spans (stable concat-sort == merge of span sorts)."""
-    from tez_tpu.ops.device_pipeline import (DeviceSpanScheduler,
-                                             device_shuffle_sort)
-    from tez_tpu.ops.keycodec import matrix_to_lanes, pad_to_matrix
-    key_len, nspans, per = 8, 3, 400
-    spans = [_mk_ragged(per, key_len, s) for s in range(nspans)]
-    sched = DeviceSpanScheduler(num_partitions=3, key_width=key_len,
-                                coalesce_records=nspans * per,
-                                paused=True)
-    for sid, (kb, ko, vb) in enumerate(spans):
-        sched.submit_ragged(sid, kb, ko, vb, 8)
-    sched.resume()
-    res = sched.results()
-    assert all(res[i] is res[0] for i in range(nspans))
-    sp_a, lanes_a, vals_a, perm_a, counts_a, n_a = res[0]
-
-    kb = np.concatenate([s[0] for s in spans])
-    ko = np.arange(nspans * per + 1, dtype=np.int64) * key_len
-    vb = np.concatenate([s[2] for s in spans])
-    n = nspans * per
-    mat, lengths = pad_to_matrix(kb, ko, key_len)
-    lanes = matrix_to_lanes(mat)
-    hash_w = 1 << max(2, (key_len - 1).bit_length())
-    hmat, hlens = pad_to_matrix(kb, ko, hash_w)
-    vals = np.ascontiguousarray(vb.reshape(n, 8)).view(np.uint32)
-    out = device_shuffle_sort(lanes, lengths.astype(np.int64), vals, hmat,
-                              hlens.astype(np.int32), 3)
-    sp_s, lanes_s, vals_s, perm_s, counts_s = [np.asarray(x) for x in out]
-    assert n_a == n
-    np.testing.assert_array_equal(counts_a, counts_s)
-    np.testing.assert_array_equal(perm_a[:n], perm_s[:n])
-    np.testing.assert_array_equal(lanes_a[:n], lanes_s[:n])
-    np.testing.assert_array_equal(vals_a[:n], vals_s[:n])
-
-
-def test_recompile_count_bounded_within_bucket():
-    """Varying span sizes inside one padding bucket must reuse ONE compiled
-    program — the kernel's compile cache may grow by at most one entry."""
-    from tez_tpu.ops.device_pipeline import (DeviceSpanScheduler,
-                                             _fused_pipeline)
-    key_len = 8
-
-    def run(n, seed):
-        kb, ko, vb = _mk_ragged(n, key_len, seed)
-        sched = DeviceSpanScheduler(num_partitions=2, key_width=key_len)
-        sched.submit_ragged(0, kb, ko, vb, 8)
-        return sched.results()
-
-    run(600, 0)                          # bucket warm (and maybe compile)
-    cache0 = _fused_pipeline.cache_size()
-    for i, n in enumerate((520, 700, 1000, 1024)):   # same padding bucket
-        run(n, i + 1)
-    assert _fused_pipeline.cache_size() - cache0 <= 1, \
-        "same-bucket spans recompiled the fused pipeline"
+    assert compiles_for(600) == 1                 # first span of (512, 1024]
+    assert [compiles_for(n) for n in (520, 700, 1000, 1024)] == [0, 0, 0, 0]
+    assert compiles_for(1025) == 1                # the next bucket
+    assert compiles_for(2048) == 0
 
 
 def _mk_batch(n, seed):

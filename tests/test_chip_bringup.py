@@ -44,7 +44,7 @@ def _spy(name, value):
     updates.append(name)
     return _orig(name, value)
 jax.config.update = _spy
-import tez_tpu.ops.device, tez_tpu.ops.device_pipeline, tez_tpu.parallel.exchange
+import tez_tpu.ops.device, tez_tpu.parallel.exchange
 from tez_tpu.ops import compile_cache
 import json
 print(json.dumps({"dir": compile_cache.cache_dir(),

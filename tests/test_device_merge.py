@@ -11,6 +11,7 @@ empty runs, single runs, > merge_factor cascades).
 """
 import random
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -267,3 +268,60 @@ def test_resident_merge_reads_uniformity_from_the_runs():
         assert [merged.batch.key(i) for i in range(4)] == \
             sorted(keys_a + [b"bbbb", b"cccc"])
         assert _static_flags(device._fused_resident_merge) == {flag}
+
+
+@pytest.mark.parametrize("skip_length_pass", [False, True])
+@pytest.mark.parametrize("num_lanes", [1, 2, 3, 4])
+def test_chip_sort_ladder_equals_variadic_sort_and_lexsort(
+        monkeypatch, num_lanes, skip_length_pass):
+    """The sort body the chip runs (chained stable single-key passes) and
+    the one XLA:CPU runs (one variadic sort) give the same partitions and
+    the same permutation, and both are numpy's stable lexsort.  Tier-1 is
+    XLA:CPU, where `single_pass_variadic()` answers True, so without this
+    test no tier-1 test traces the ladder the chip executes in every cell.
+    `_lsd_passes` is jitted directly: the cached Kernels would hand back
+    whichever body they were compiled with first."""
+    rng = np.random.default_rng(100 * num_lanes + skip_length_pass)
+    n, real = 512, 401
+    cap = num_lanes * 4 + 1
+    # a three-value alphabet and all-0xFF lanes: duplicates at every lane
+    lanes = rng.choice(np.array([0, 1, 0xFFFFFFFF], dtype=np.uint32),
+                       size=(n, num_lanes), p=[0.45, 0.45, 0.1])
+    partitions = rng.integers(0, 3, n).astype(np.int32)
+    if skip_length_pass:
+        lengths = np.full(n, num_lanes * 4, dtype=np.uint32)
+    else:
+        lengths = rng.integers(0, cap + 1, n).astype(np.uint32)
+        # zero-length keys beside "\0" and "\0\0": equal (all-zero) lanes,
+        # only the length pass tells them apart
+        lanes[:60] = 0
+        lengths[:60] = rng.integers(0, 3, 60)
+    # padding rows as the two staging paths write them: partition MAX, and
+    # lanes/lengths either all-ones (resident) or zero/uniform (host-fed)
+    partitions[real:] = np.iinfo(np.int32).max
+    lanes[real:450] = 0xFFFFFFFF
+    lengths[real:450] = 0xFFFFFFFF
+    lanes[450:] = 0
+
+    def run(variadic):
+        monkeypatch.setattr(device, "single_pass_variadic", lambda: variadic)
+        fn = jax.jit(lambda p, l, n_: device._lsd_passes(
+            p, l, n_, skip_length_pass))
+        sorts = fn.lower(partitions, lanes, lengths).as_text().count(
+            "stablehlo.sort")
+        sp, perm = fn(partitions, lanes, lengths)
+        return np.asarray(sp), np.asarray(perm), sorts
+
+    ladder, variadic = run(False), run(True)
+    # the two bodies really are two programs: L lane passes + partition
+    # (+ length) against one sort
+    assert ladder[2] == num_lanes + 2 - skip_length_pass
+    assert variadic[2] == 1
+    keys = [] if skip_length_pass else [lengths]
+    keys += [lanes[:, i] for i in range(num_lanes - 1, -1, -1)]
+    keys.append(partitions.astype(np.uint32))
+    want = np.lexsort(keys)               # stable; the last key is primary
+    for sp, perm, _sorts in (ladder, variadic):
+        np.testing.assert_array_equal(perm, want)
+        np.testing.assert_array_equal(sp, partitions[want])
+    assert (ladder[0][real:] == np.iinfo(np.int32).max).all()

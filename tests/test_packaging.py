@@ -23,7 +23,10 @@ def test_dist_full_and_minimal(tmp_path):
         names = tf.getnames()
     root = names[0].split("/")[0]
     assert any(n.endswith("tez_tpu/examples/driver.py") for n in names)
-    assert any(n.endswith("/bench.py") for n in names)
+    assert not any(n.endswith("/bench.py") for n in names)
+    assert f"{root}/README.md" in names
+    assert f"{root}/pyproject.toml" in names
+    assert f"{root}/docs/device_pipeline.md" in names
     assert any(n.endswith("native/ragged.cpp") for n in names)
     # every source the Makefile needs must ship, or make -C native fails
     assert any(n.endswith("native/shuffle_server.cpp") for n in names)

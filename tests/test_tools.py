@@ -284,36 +284,6 @@ def test_counter_diff_cli(history_dir, capsys):
     assert "wall delta" in out
 
 
-def test_bench_diff_gate(tmp_path, capsys):
-    """bench_diff matches metrics by their pre-paren prefix, skips 0.0
-    sentinels, and exits nonzero only on a >threshold drop."""
-    import json
-
-    from tez_tpu.tools import bench_diff
-
-    def write(name, values):
-        lines = [json.dumps({
-            "metric": f"{m} (qualifiers change {name})", "value": v,
-            "unit": "MB/s", "vs_baseline": 1.0})
-            for m, v in values.items()]
-        p = tmp_path / name
-        p.write_text(json.dumps({"tail": "\n".join(lines), "rc": 0}))
-        return str(p)
-
-    old = write("old.json", {"sort": 100.0, "e2e": 50.0, "stalled": 0.0})
-    ok = write("ok.json", {"sort": 85.0, "e2e": 60.0, "stalled": 0.0})
-    bad = write("bad.json", {"sort": 70.0, "e2e": 60.0, "stalled": 0.0})
-    assert bench_diff.diff(old, ok) == 0       # -15% is inside the gate
-    assert bench_diff.diff(old, bad) == 1      # -30% regresses
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "unavailable sentinel" in out
-    # raw-stdout input (no wrapper) parses too
-    raw = tmp_path / "raw.txt"
-    raw.write_text("noise\n" + json.dumps(
-        {"metric": "sort (raw)", "value": 99.0, "unit": "MB/s"}) + "\n")
-    assert bench_diff.diff(old, str(raw)) == 0
-
-
 def test_log_split(tmp_path):
     """tez-log-split analog: interleaved attempt logs carve into per-attempt
     files, continuation lines follow their record."""

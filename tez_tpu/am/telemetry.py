@@ -6,8 +6,7 @@ into the bounded rings of :mod:`tez_tpu.obs.timeseries`, then runs the
 SLO watchdog's burn-rate evaluation against the fresh windows — so
 burn-alert latency is bounded by the sampler period, not by DAG
 completions.  The tick is the ONLY hot-path cost of the live plane: a
-dict snapshot plus ring appends, off every data-plane lock, which is how
-the always-on plane stays inside the 3% armed-overhead gate.
+dict snapshot plus ring appends, off every data-plane lock.
 
 :meth:`TelemetrySampler.live_status` is the continuous doctor: the
 post-hoc blame sweep of ``tools/doctor.py`` re-runs *incrementally* over

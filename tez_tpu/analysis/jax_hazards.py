@@ -30,7 +30,8 @@ from tez_tpu.analysis.core import Checker, Context, Finding
 #: Modules whose code runs per-span / per-batch on the device data
 #: plane — where one stray host sync stalls the whole overlap schedule.
 _HOT_PATH_MODULES = (
-    "ops/async_stage.py", "ops/device_pipeline.py", "ops/device.py",
+    "ops/async_stage.py", "ops/sorter.py", "ops/device.py",
+    "library/merge_manager.py",
     "parallel/exchange.py", "parallel/coordinator.py",
 )
 

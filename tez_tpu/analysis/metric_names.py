@@ -7,7 +7,7 @@ Codes:
 - ``hist-unregistered`` — ``metrics.observe(name)`` / ``timer(name)``
   with a literal name missing from WELL_KNOWN_HISTOGRAMS (it records
   fine at runtime but is invisible to /metrics consumers that iterate
-  the well-known list and to the bench diff sections).
+  the well-known list and to counter_diff's sections).
 - ``hist-unused`` — a WELL_KNOWN_HISTOGRAMS entry whose name appears
   nowhere else in the package.
 - ``hist-undocumented`` — WELL_KNOWN entry not in docs/observability.md.

@@ -334,7 +334,7 @@ AM_METRICS_SAMPLE_PERIOD_MS = _key(
     "into the bounded time-series rings that feed GET /metrics.json "
     "windows, burn-rate SLO alerts, GET /doctor/live and graft top.  "
     "The plane is always-on like the flight recorder (one snapshot per "
-    "tick off the hot path, inside the 3% armed-overhead budget); "
+    "tick off the hot path); "
     "0 disables the sampler thread entirely (docs/telemetry.md)")
 AM_METRICS_RING_SAMPLES = _key(
     "tez.am.metrics.ring.samples", 512, Scope.AM,
@@ -972,7 +972,7 @@ QUERY_JOIN_STRATEGY = _key(
     "tez.query.join.strategy", "auto", Scope.DAG,
     "force the join lowering: 'auto' = pick by stats vs "
     "tez.query.broadcast.max-mb, 'broadcast' / 'repartition' = always "
-    "that physical strategy (test/bench override; also what a "
+    "that physical strategy (test override; also what a "
     "PlanFeedback replan pins per node)")
 QUERY_REDUCERS = _key(
     "tez.query.reducers", 2, Scope.DAG,

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import os
 import threading
 import time
 from typing import Any, Callable, Dict, Iterator, List, Tuple
@@ -64,10 +63,8 @@ def single_pass_variadic() -> bool:
     faster than the chained ladder (one comparator walk instead of L+2
     full passes over the permutation).  Accelerator backends keep the
     chained passes (~25 s to compile per shape on a v5e; the variadic
-    sort's compile time there is unmeasured — ROADMAP S2).  Evaluated at
+    sort's compile and run time there is not measured).  Evaluated at
     trace time (Python-level branch in the jitted bodies)."""
-    if os.environ.get("TEZ_TPU_FORCE_LSD_PASSES"):
-        return False
     return backend_platform() == "cpu"
 
 
@@ -787,29 +784,3 @@ def merge_runs(partitions: np.ndarray, lanes: np.ndarray,
         perm = np.asarray(perm_dev)
     with tracing.span("merge.gather", cat="merge", rows=n):
         return perm[:n].astype(np.int64)
-
-
-# ---------------------------------------------------------------------------
-# segmented (per-partition) counts
-# ---------------------------------------------------------------------------
-def _partition_histogram_impl(partitions: jnp.ndarray,
-                              num_partitions: int) -> jnp.ndarray:
-    one_hot = jax.nn.one_hot(partitions, num_partitions, dtype=jnp.int32)
-    return one_hot.sum(axis=0)
-
-
-_partition_histogram = Kernel(_partition_histogram_impl,
-                              "partition_histogram",
-                              static_argnames=("num_partitions",))
-
-
-def partition_counts(partitions: np.ndarray, num_partitions: int) -> np.ndarray:
-    n = partitions.shape[0]
-    if n == 0:
-        return np.zeros(num_partitions, dtype=np.int64)
-    nb = _bucket(n)
-    if nb != n:
-        partitions = np.pad(partitions, (0, nb - n), constant_values=-1)
-    out = _partition_histogram(jnp.asarray(partitions),
-                               num_partitions=num_partitions)
-    return np.asarray(out).astype(np.int64)

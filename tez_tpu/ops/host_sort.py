@@ -1,9 +1,9 @@
-"""Host sorting engine: numpy lexsort fallback for device-less environments.
-
-Reference parity: the reference ships two sorters (PipelinedSorter /
-DefaultSorter) selected by config; here 'device' (ops.device kernels) vs
-'host' (this module) selected by tez.runtime.sorter.class.  Byte-identical
-output contract with the device engine (same golden tests).
+"""The FNV-1a row hash in numpy: the reference the device kernels
+(ops/device.py ``_fnv_rows``), the native routing
+(``fnv32_partition_native``) and the scalar HashPartitioner are held to by
+the exchange tests and tools/chaos.py.  It sorts nothing: the host sorting
+engine that ``tez.runtime.sorter.class`` selects is native/spansort.cpp,
+driven from ops/sorter.py.
 """
 from __future__ import annotations
 
@@ -19,25 +19,3 @@ def fnv_rows_host(key_mat: np.ndarray, lengths: np.ndarray) -> np.ndarray:
             & np.uint64(0xFFFFFFFF)
         h = np.where(j < lengths, nh, h)
     return h.astype(np.uint32)
-
-
-def host_hash_partition(key_mat: np.ndarray, lengths: np.ndarray,
-                        num_partitions: int) -> np.ndarray:
-    return (fnv_rows_host(key_mat, lengths) %
-            np.uint32(num_partitions)).astype(np.int32)
-
-
-def host_sort_run(partitions: np.ndarray, lanes: np.ndarray,
-                  lengths: np.ndarray) -> tuple:
-    """np.lexsort by (partition, lanes..., clamped length) — the host twin
-    of device.sort_run (stable, same key order)."""
-    n = partitions.shape[0]
-    if n == 0:
-        return partitions, np.zeros(0, dtype=np.int32)
-    width_cap = lanes.shape[1] * 4 + 1
-    clamped = np.minimum(lengths.astype(np.int64), width_cap)
-    # lexsort: LAST key is most significant
-    cols = [clamped] + [lanes[:, i] for i in range(lanes.shape[1] - 1, -1, -1)]
-    cols.append(partitions)
-    perm = np.lexsort(cols).astype(np.int32)
-    return partitions[perm], perm

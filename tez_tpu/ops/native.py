@@ -493,7 +493,7 @@ def merge_runs_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
 def owc_proxy_counts(corpus_path: str, num_producers: int,
                      num_partitions: int, combine: bool = True
                      ) -> "Tuple[float, dict]":
-    """Shared baseline harness for bench.py / spill_bench: run the
+    """Baseline harness of tools/spill_bench.py: run the
     reference-semantics proxy over a corpus FILE and parse its output
     lines into {word(str): count}.  Parse errors (corrupt proxy output)
     raise."""

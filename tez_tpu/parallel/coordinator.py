@@ -16,7 +16,7 @@ The exchange plane is skew- and straggler-aware:
   global max: each destination's round rows are balanced across senders in
   contiguous arrival-order chunks, so the per-pair CAP shrinks by up to D×
   versus the padded worst case (``plan_rounds``; ``legacy_sizing=True``
-  keeps the old formulation as the bench baseline).
+  keeps the old formulation: the tests' and chaos' golden reference).
 * The fair-shuffle splitter is folded in: an edge that keeps arriving with
   one partition over ``max_rows_per_round`` (``split.after`` consecutive
   exchanges, tracked across recurring DAG runs by edge suffix) gets its hot
@@ -174,7 +174,7 @@ class MeshExchangeCoordinator:
         self._mesh = mesh
         self.max_rows_per_round = max_rows_per_round
         self.engine = engine            # default; per-edge conf overrides
-        self.legacy_sizing = legacy_sizing   # bench baseline: max-part CAP
+        self.legacy_sizing = legacy_sizing   # golden reference: max-part CAP
         self.split_after = split_after  # 0 = splitter disabled
         self.lock = threading.Condition()
         self.edges: Dict[str, _EdgeState] = {}

@@ -59,12 +59,11 @@ def _try_build_native() -> str | None:
 
 def build(minimal: bool, out_dir: str) -> str:
     from tez_tpu.version import __version__
-    # bench.py + docs/ exist only in a source checkout (native sources now
-    # ship inside the wheel, so they no longer distinguish the two)
-    if not (os.path.exists(os.path.join(_REPO, "bench.py"))
-            and os.path.isdir(os.path.join(_REPO, "docs"))):
+    # docs/ exists only in a source checkout (native sources ship inside
+    # the wheel, so they do not distinguish the two)
+    if not os.path.isdir(os.path.join(_REPO, "docs")):
         raise SystemExit(
-            "tez-dist assembles from a source checkout (docs/, bench.py, "
+            "tez-dist assembles from a source checkout (docs/, "
             f"pyproject.toml beside the package); {_REPO} lacks them — "
             "run it from the repository root")
     name = f"tez-tpu-{__version__}" + ("-minimal" if minimal else "")
@@ -89,14 +88,11 @@ def build(minimal: bool, out_dir: str) -> str:
         members.append((full, rel))
 
     if not minimal:
-        for extra_dir in ("docs",):
-            for full, rel in _walk_files(os.path.join(_REPO, extra_dir),
-                                         f"{name}/{extra_dir}"):
-                members.append((full, rel))
-        for extra in ("bench.py", "README.md"):
-            p = os.path.join(_REPO, extra)
-            if os.path.exists(p):
-                members.append((p, f"{name}/{extra}"))
+        members.extend(_walk_files(os.path.join(_REPO, "docs"),
+                                   f"{name}/docs"))
+        readme = os.path.join(_REPO, "README.md")
+        if os.path.exists(readme):
+            members.append((readme, f"{name}/README.md"))
     pyproject = os.path.join(_REPO, "pyproject.toml")
     if os.path.exists(pyproject):
         members.append((pyproject, f"{name}/pyproject.toml"))

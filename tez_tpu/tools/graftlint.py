@@ -9,7 +9,7 @@
 Exit codes: 0 = clean (no findings outside the committed baseline),
 1 = new findings, 2 = internal error.  Output is stable and sorted —
 ``path:line: code [checker] message`` — so run-to-run diffs are
-reviewable the way tools/bench_diff.py reports are.
+reviewable.
 
 The baseline (``tez_tpu/tools/graftlint_baseline.json``) holds triaged
 known-finding identities; the gate fails only on findings *not* listed

@@ -7,8 +7,8 @@ skewed when ``skew > 0`` (hot join/group keys, the shape the skew-replan
 path exists for).  The same seed always writes byte-identical .tbl
 files.
 
-``CORPUS_QUERIES`` is the fixed query suite bench, chaos
-(``--query-storm``), the tenant soak, and tests/test_query.py all run:
+``CORPUS_QUERIES`` is the fixed query suite chaos (``--query-storm``),
+the tenant soak, and tests/test_query.py all run:
 every relational operator (scan/filter/project/hash_join/
 sort_merge_join/auto join/aggregate/window/limit/semi joins) is covered,
 and every query carries a numpy oracle producing the exact sorted

@@ -137,7 +137,8 @@ class TaskCounter(enum.Enum):
 # started re-rounding or re-partitioning to absorb skew).
 MESH_EXCHANGE_GROUP = "MeshExchange"
 MESH_EXCHANGE_EFFICIENCY_COUNTERS = (
-    "exchange.rows.sent", "exchange.bytes.sent",
+    "exchange.rows.sent", "exchange.rows.placed.native",
+    "exchange.bytes.sent",
     "exchange.coded.duplicate.bytes", "exchange.coded.buddy.wins")
 MESH_EXCHANGE_PRESSURE_COUNTERS = ("exchange.rounds", "exchange.splits")
 

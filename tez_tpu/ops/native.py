@@ -88,6 +88,16 @@ _SIGNATURES = {
                                 _P, _P, _P], ctypes.c_double),
     "owc_proxy_v2": ([_P, _I64, _I32, _I32, _I32, _P, _I64, _P],
                      ctypes.c_double),
+    "tz_exchange_encode": ([_P, _P, _P, _P, _I64, _I32, _I32, _P, _P, _P,
+                            _I32], None),
+    "tz_exchange_dest_hist": ([_P, _I32, _P, _I32, _I32, _P], None),
+    "tz_exchange_place": ([_I32, _P, _P, _P, _P, _P, _P, _P, _I32, _P, _P,
+                           _I32, _I64, _I64, _P, _P, _I64, _I32, _I32,
+                           _P, _P, _P, _P, _P], None),
+    "tz_exchange_decode_sizes": ([_P, _P, _I64, _P, _I64, _I32, _I32, _I32,
+                                  _P], None),
+    "tz_exchange_decode_rows": ([_P, _P, _P, _I64, _P, _I64, _I32, _I32,
+                                 _I32, _P, _P, _P, _P, _P], None),
 }
 
 
@@ -524,3 +534,187 @@ def adjacent_equal_native(data: np.ndarray, offsets: np.ndarray,
         out.ctypes.data_as(ctypes.c_void_p),
         ctypes.c_int32(min(8, os.cpu_count() or 1)))
     return out.astype(bool)
+
+
+# ---------------------------------------------------------------------------
+# Mesh exchange row passes (parallel/coordinator.py; native/ragged.cpp says
+# what each walks).  Every call releases the GIL for the whole pass.
+# ---------------------------------------------------------------------------
+
+def _threads() -> int:
+    return min(8, os.cpu_count() or 1)
+
+
+def _ptr(a: np.ndarray) -> ctypes.c_void_p:
+    return a.ctypes.data_as(ctypes.c_void_p)
+
+
+def _dests(dests: np.ndarray, num_dests: int) -> np.ndarray:
+    """Destinations as the contiguous unsigned array the passes index
+    their per-destination counters by."""
+    if dests.dtype not in (np.uint8, np.uint16, np.uint32):
+        raise TypeError(f"dests: expected uint8/16/32, got {dests.dtype}")
+    if int(dests.max(initial=0)) >= num_dests:
+        raise ValueError(f"a destination beyond the {num_dests} there are")
+    return np.ascontiguousarray(dests)
+
+
+def _rows_u32(a: np.ndarray, what: str) -> np.ndarray:
+    """`a` as the C-contiguous uint32 rows the native passes index."""
+    if a.dtype != np.uint32:
+        raise TypeError(f"{what}: expected uint32, got {a.dtype}")
+    return np.ascontiguousarray(a)
+
+
+def exchange_encode_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
+                           val_bytes: np.ndarray, val_offsets: np.ndarray,
+                           key_width: int, value_width: int
+                           ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """A producer's ragged batch as exchange rows: (lanes u32[n, L], klens
+    u32[n], vwords u32[n, 1 + VW]) with L / VW the widths in bytes rounded
+    up to whole words — what ``keycodec.pad_to_matrix`` +
+    ``matrix_to_lanes`` give for the keys, and for the values behind a
+    first word that holds the value's length."""
+    lib = _load()
+    n = len(key_offsets) - 1
+    if len(val_offsets) - 1 != n:
+        raise ValueError(f"{n} keys but {len(val_offsets) - 1} values")
+    num_lanes = (int(key_width) + 3) // 4
+    value_words = (int(value_width) + 3) // 4
+    key_bytes = np.ascontiguousarray(key_bytes, dtype=np.uint8)
+    key_offsets = np.ascontiguousarray(key_offsets, dtype=np.int64)
+    val_bytes = np.ascontiguousarray(val_bytes, dtype=np.uint8)
+    val_offsets = np.ascontiguousarray(val_offsets, dtype=np.int64)
+    if n and (int(key_offsets[-1]) > key_bytes.size or
+              int(val_offsets[-1]) > val_bytes.size):
+        raise ValueError("offsets run past the bytes")
+    lanes = hostpool.empty(n * num_lanes, np.uint32).reshape(n, num_lanes)
+    klens = hostpool.empty(n, np.uint32)
+    vwords = hostpool.empty(n * (1 + value_words), np.uint32) \
+        .reshape(n, 1 + value_words)
+    lib.tz_exchange_encode(
+        _ptr(key_bytes), _ptr(key_offsets), _ptr(val_bytes),
+        _ptr(val_offsets), n, num_lanes, value_words,
+        _ptr(lanes), _ptr(klens), _ptr(vwords), _threads())
+    return lanes, klens, vwords
+
+
+def exchange_dest_hist_native(dests: np.ndarray, bounds: np.ndarray,
+                              num_dests: int) -> np.ndarray:
+    """int64[len(bounds) - 1, num_dests]: the rows of each destination in
+    each chunk ``dests[bounds[t]:bounds[t + 1]]`` (unsigned, 1/2/4 bytes,
+    every value below ``num_dests``)."""
+    lib = _load()
+    dests = _dests(dests, num_dests)
+    bounds = np.ascontiguousarray(bounds, dtype=np.int64)
+    if bounds[0] < 0 or bounds[-1] > dests.size or \
+            (np.diff(bounds) < 0).any():
+        raise ValueError("chunk bounds outside the destinations")
+    hist = np.empty((len(bounds) - 1, num_dests), np.int64)
+    lib.tz_exchange_dest_hist(_ptr(dests), dests.itemsize, _ptr(bounds),
+                              len(bounds) - 1, num_dests, _ptr(hist))
+    return hist
+
+
+def exchange_place_native(chunks: "list", bounds: np.ndarray,
+                          dests: np.ndarray, rank_base: np.ndarray,
+                          fill_base: np.ndarray, lo: int, per_round: int,
+                          chunk_d: np.ndarray, loads: np.ndarray,
+                          rows_per_sender: int, num_lanes: int,
+                          value_words: int) -> Tuple[np.ndarray, ...]:
+    """One round's device inputs from the producers' spans where they lie
+    (``tz_exchange_place``).  ``chunks[t]`` is the (lanes, klens, vwords)
+    of rows ``[bounds[t], bounds[t + 1])`` of the edge: slices of one
+    producer's span, possibly narrower than ``num_lanes`` /
+    ``value_words`` (widened with zero words here).  ``rank_base[t, d]``
+    is the rank of the chunk's first row of destination d among all rows of
+    d; ``fill_base[t, s]`` the rows earlier chunks put in sender s's block
+    this round; ``loads[s]`` the block's rows.  Returns (r_lanes u32[D*N,
+    L], r_klens u32[D*N], r_vwords u32[D*N, VW], r_valid bool[D*N], r_dests
+    u32[D*N]) in pooled memory, every element written."""
+    lib = _load()
+    T, D, N = len(chunks), len(loads), int(rows_per_sender)
+    dests = _dests(dests, D)
+    bounds = np.ascontiguousarray(bounds, dtype=np.int64)
+    rank_base = np.ascontiguousarray(rank_base, dtype=np.int64)
+    fill_base = np.ascontiguousarray(fill_base, dtype=np.int64)
+    chunk_d = np.ascontiguousarray(chunk_d, dtype=np.int64)
+    loads = np.ascontiguousarray(loads, dtype=np.int64)
+    if bounds.shape != (T + 1,) or rank_base.shape != (T, D) or \
+            fill_base.shape != (T, D) or chunk_d.shape != (D,) or \
+            int(bounds[-1]) > dests.size or int(loads.max(initial=0)) > N or \
+            int(chunk_d.min(initial=1)) < 1:
+        raise ValueError("placement plan does not fit its chunks")
+    held = []      # the contiguous slices the pointers point into
+    ptrs = [(ctypes.c_void_p * T)() for _ in range(3)]
+    widths = np.empty((2, T), np.int32)
+    for t, (lanes, klens, vwords) in enumerate(chunks):
+        rows = int(bounds[t + 1] - bounds[t])
+        lanes, klens, vwords = (_rows_u32(lanes, "lanes"),
+                                _rows_u32(klens, "klens"),
+                                _rows_u32(vwords, "vwords"))
+        if lanes.shape[0] != rows or klens.shape != (rows,) or \
+                vwords.shape[0] != rows or lanes.shape[1] > num_lanes or \
+                vwords.shape[1] > value_words:
+            raise ValueError(f"chunk {t} does not match its bounds or widths")
+        held.append((lanes, klens, vwords))
+        for ptr, a in zip(ptrs, held[-1]):
+            ptr[t] = a.ctypes.data
+        widths[0, t], widths[1, t] = lanes.shape[1], vwords.shape[1]
+    r_lanes = hostpool.empty(D * N * num_lanes, np.uint32) \
+        .reshape(D * N, num_lanes)
+    r_klens = hostpool.empty(D * N, np.uint32)
+    r_vwords = hostpool.empty(D * N * value_words, np.uint32) \
+        .reshape(D * N, value_words)
+    r_valid = hostpool.empty(D * N, np.bool_)
+    r_dests = hostpool.empty(D * N, np.uint32)
+    lib.tz_exchange_place(
+        T, ptrs[0], ptrs[1], ptrs[2], _ptr(widths[0]), _ptr(widths[1]),
+        _ptr(bounds), _ptr(dests), dests.itemsize, _ptr(rank_base),
+        _ptr(fill_base), D, int(lo), int(per_round),
+        _ptr(chunk_d), _ptr(loads), N, num_lanes, value_words,
+        _ptr(r_lanes), _ptr(r_klens), _ptr(r_vwords), _ptr(r_valid),
+        _ptr(r_dests))
+    del held
+    return r_lanes, r_klens, r_vwords, r_valid, r_dests
+
+
+def exchange_decode_native(lanes: np.ndarray, klens: np.ndarray,
+                           vwords: np.ndarray, keep: np.ndarray,
+                           value_words: Optional[int] = None
+                           ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
+                                      np.ndarray]:
+    """The rows of an exchange shard where ``keep`` is set, as ragged
+    (key_bytes, key_offsets, val_bytes, val_offsets): lengths to offsets,
+    then bytes, in row order.  ``value_words`` (default: all of them) is
+    how many of a ``vwords`` row's words after the first are value bytes;
+    further columns are skipped."""
+    lib = _load()
+    lanes = _rows_u32(lanes, "lanes")
+    klens = _rows_u32(klens, "klens")
+    vwords = _rows_u32(vwords, "vwords")
+    keep = np.ascontiguousarray(keep).astype(np.bool_, copy=False)
+    n, num_lanes = lanes.shape
+    vstride = vwords.shape[1]
+    if value_words is None:
+        value_words = vstride - 1
+    if klens.shape != (n,) or vwords.shape[0] != n or keep.shape != (n,) \
+            or not 0 <= value_words < vstride:
+        raise ValueError("shard arrays disagree on their rows or widths")
+    chunks = _threads()
+    sizes = np.empty((chunks, 3), np.int64)
+    lib.tz_exchange_decode_sizes(_ptr(klens), _ptr(vwords), vstride,
+                                 _ptr(keep), n, num_lanes, value_words,
+                                 chunks, _ptr(sizes))
+    starts = np.ascontiguousarray(np.cumsum(sizes, axis=0) - sizes)
+    rows, key_total, val_total = (int(x) for x in sizes.sum(axis=0))
+    key_bytes = hostpool.empty(key_total, np.uint8)
+    val_bytes = hostpool.empty(val_total, np.uint8)
+    key_offsets = hostpool.empty(rows + 1, np.int64)
+    val_offsets = hostpool.empty(rows + 1, np.int64)
+    key_offsets[0] = val_offsets[0] = 0
+    lib.tz_exchange_decode_rows(
+        _ptr(lanes), _ptr(klens), _ptr(vwords), vstride, _ptr(keep), n,
+        num_lanes, value_words, chunks, _ptr(starts), _ptr(key_bytes),
+        _ptr(key_offsets), _ptr(val_bytes), _ptr(val_offsets))
+    return key_bytes, key_offsets, val_bytes, val_offsets

@@ -28,6 +28,14 @@ The exchange plane is skew- and straggler-aware:
   ``mesh.exchange.delay`` fault point fires per (round, device) on the
   readback threads so chaos can prove the masking.
 
+The host touches every row three times, each time in one native pass
+(``native/ragged.cpp``, GIL released) where the rows already are: a
+producer encodes its own batch at registration, the executing thread
+places a round straight from the producers' spans into pooled blocks, and
+each reader thread decodes the shard it has just read.  What stays on the
+executing thread is arithmetic on histograms (docs/exchange.md "Row
+passes").  The coded r2 edge and legacy sizing keep the numpy placement.
+
 Single-controller topology: every runner in this process shares one
 coordinator (the analog of local_shuffle_service); a multi-host deployment
 runs one coordinator per host participating in a global jax mesh, with the
@@ -46,8 +54,11 @@ from tez_tpu.common import faults, tracing
 from tez_tpu.common.counters import MESH_EXCHANGE_GROUP
 from tez_tpu.obs import flight as _flight
 from tez_tpu.ops import hostpool
-from tez_tpu.ops.keycodec import matrix_to_lanes, pad_to_matrix
-from tez_tpu.ops.native import fnv32_partition_native
+from tez_tpu.ops.native import (exchange_decode_native,
+                                exchange_dest_hist_native,
+                                exchange_encode_native,
+                                exchange_place_native,
+                                fnv32_partition_native)
 from tez_tpu.ops.runformat import KVBatch
 
 log = logging.getLogger(__name__)
@@ -58,40 +69,14 @@ class MeshCapacityError(RuntimeError):
     multi-round; callers fall back to the fair-shuffle split path."""
 
 
-def _encode_values(batch: KVBatch, value_width: int) -> np.ndarray:
-    """Values -> u32[N, 1 + value_width/4]: word 0 is the true byte length,
-    the rest the zero-padded value bytes as big-endian words."""
-    vmat, vlens = pad_to_matrix(batch.val_bytes, batch.val_offsets,
-                                value_width)
-    words = matrix_to_lanes(vmat)
-    return np.concatenate([vlens.astype(np.uint32)[:, None],
-                           words.astype(np.uint32)], axis=1)
-
-
-def _decode_rows(lanes: np.ndarray, lengths: np.ndarray, values: np.ndarray,
-                 valid: np.ndarray) -> KVBatch:
-    """Exchange output -> KVBatch (vectorized byte reconstruction)."""
-    from tez_tpu.ops.keycodec import lanes_to_matrix
-    sel = np.flatnonzero(valid)
-    if sel.size == 0:
-        return KVBatch.empty()
-    lanes = lanes[sel]
-    klens = lengths[sel].astype(np.int64)
-    vwords = values[sel]
-    n, L = lanes.shape
-    kmat = lanes_to_matrix(lanes)
-    kmask = np.arange(L * 4)[None, :] < klens[:, None]
-    key_bytes = kmat[kmask]
-    key_offsets = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(klens, out=key_offsets[1:])
-
-    vlens = vwords[:, 0].astype(np.int64)
-    vmat = lanes_to_matrix(np.ascontiguousarray(vwords[:, 1:]))
-    vmask = np.arange(vmat.shape[1])[None, :] < vlens[:, None]
-    val_bytes = vmat[vmask]
-    val_offsets = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(vlens, out=val_offsets[1:])
-    return KVBatch(key_bytes, key_offsets, val_bytes, val_offsets)
+def _decode_shard(lanes: np.ndarray, lengths: np.ndarray, values: np.ndarray,
+                  keep: np.ndarray,
+                  value_words: Optional[int] = None) -> KVBatch:
+    """The kept rows of an exchange output shard -> KVBatch, in one native
+    pass (lengths to offsets, then bytes); ``value_words`` as
+    ``exchange_decode_native`` takes it."""
+    return KVBatch(*exchange_decode_native(lanes, lengths, values, keep,
+                                           value_words))
 
 
 class _EdgeState:
@@ -163,6 +148,152 @@ def arrival_ranks(group: np.ndarray, counts: np.ndarray) -> np.ndarray:
     ranks = np.empty(group.size, dtype=np.int64)
     ranks[np.argsort(group, kind="stable")] = within
     return ranks
+
+
+#: chunks the native row passes cut an edge's rows into: a thread each
+PLACE_CHUNKS = 8
+
+
+def _row_chunks(spans) -> Tuple[List[Tuple[np.ndarray, ...]], np.ndarray]:
+    """The producers' rows where they lie, cut into about ``PLACE_CHUNKS``
+    runs of consecutive rows, none across two producers: (chunks, bounds)
+    with chunks[t] the (lanes, klens, vwords) views of rows [bounds[t],
+    bounds[t + 1]) of the edge in arrival order (producer by producer)."""
+    total = sum(s[0].shape[0] for s in spans)
+    chunks: List[Tuple[np.ndarray, ...]] = []
+    bounds = [0]
+    for lanes, klens, vwords, _ in spans:
+        n = lanes.shape[0]
+        if n == 0:
+            continue
+        step = -(-n // max(1, round(PLACE_CHUNKS * n / total)))
+        for a in range(0, n, step):
+            b = min(n, a + step)
+            chunks.append((lanes[a:b], klens[a:b], vwords[a:b]))
+            bounds.append(bounds[-1] + b - a)
+    return chunks, np.asarray(bounds, dtype=np.int64)
+
+
+def _place_round_native(chunks, bounds: np.ndarray, rdest: np.ndarray,
+                        hist: np.ndarray, lo: int, per_round: int,
+                        quota: np.ndarray, num_lanes: int, value_words: int):
+    """One round's device inputs by the native row pass: balanced blocked
+    placement (destination d's round rows in <= D contiguous arrival-order
+    runs, run j -> sender j), as ``_ReferencePlacement`` does it with
+    index arrays.  Here the arithmetic is on ``hist`` (rows of each
+    destination in each chunk) alone: it says where each chunk's rows of a
+    destination begin in that destination's ranks, so how many of them each
+    sender takes this round and where in a sender's block each chunk starts
+    to fill; the pass then walks every chunk once with running counters.
+    Returns ((r_lanes, r_klens, r_vwords, r_valid, r_dests), N)."""
+    from tez_tpu.ops.device import _bucket
+    D = hist.shape[1]
+    rank_base = np.cumsum(hist, axis=0) - hist
+    # a chunk's rows of destination d hold the round ranks [first, end)
+    first = np.clip(rank_base - lo, 0, quota)
+    end = np.clip(rank_base + hist - lo, 0, quota)
+    chunk_d = np.maximum(1, -(-quota // D))
+    # sender j takes d's round ranks [j * chunk_d[d], (j + 1) * chunk_d[d])
+    edges = np.arange(D + 1) * chunk_d[:, None]
+    per_sender = np.clip(
+        np.minimum(end[:, :, None], edges[:, 1:]) -
+        np.maximum(first[:, :, None], edges[:, :-1]), 0, None).sum(axis=1)
+    loads = per_sender.sum(axis=0)
+    N = _bucket(int(loads.max()))
+    fill_base = np.cumsum(per_sender, axis=0) - per_sender
+    return exchange_place_native(
+        chunks, bounds, rdest, rank_base, fill_base, lo, per_round, chunk_d,
+        loads, N, num_lanes, value_words), N
+
+
+class _ReferencePlacement:
+    """A round placed with numpy index arrays over one concatenation of
+    the edge's rows: what the coded r2 edge (every row twice, a routing tag
+    word) and legacy sizing (the tests' and chaos' golden reference) use."""
+
+    def __init__(self, spans, rdest: np.ndarray, counts: np.ndarray,
+                 num_lanes: int, value_words: int):
+        def _rows(arrays: List[np.ndarray], width: int) -> np.ndarray:
+            # narrow spans zero-padded to the edge's width; pooled memory
+            return hostpool.concatenate(
+                [(a if a.shape[1] == width else
+                  np.pad(a, ((0, 0), (0, width - a.shape[1])))).reshape(-1)
+                 for a in arrays]).reshape(-1, width)
+
+        self.lanes = _rows([s[0] for s in spans], num_lanes)
+        self.klens = hostpool.concatenate([s[1] for s in spans])
+        self.vwords = _rows([s[2] for s in spans], value_words)
+        self.rdest = rdest
+        # rank of each row within its routing partition (arrival order)
+        self.ranks = arrival_ranks(rdest, counts)
+
+    def place_round(self, lo: int, per_round: int, quota: np.ndarray,
+                    cap: int, coded: bool, legacy: bool):
+        """-> ((r_lanes, r_klens, r_vwords, r_valid, r_dests), N, cap, the
+        round's rows a destination)."""
+        from tez_tpu.ops.device import _bucket
+        D = quota.size
+        rdest, ranks = self.rdest, self.ranks
+        value_words = self.vwords.shape[1]
+        sel = np.flatnonzero((ranks >= lo) & (ranks < lo + per_round))
+        rows_idx = sel
+        dests_all = rdest[sel]
+        rtag = None
+        if coded:
+            # r2: every row ALSO goes to its destination's rotation
+            # buddy.  An extra value word carries the routing partition
+            # (same on both copies) so each shard can tell its primary
+            # rows from buddy copies — not derivable from the key once
+            # the splitter has re-routed rows.
+            rows_idx = np.concatenate([sel, sel])
+            rtag = np.concatenate([dests_all, dests_all]).astype(np.uint32)
+            dests_all = np.concatenate([dests_all, (dests_all + 1) % D])
+            # duplication doubled the quotas; re-derive the balanced
+            # cap from the combined histogram (coded always uses
+            # balanced placement — legacy tail-packing could put a
+            # whole destination's copies on one sender)
+            qc = np.bincount(dests_all, minlength=D)
+            cap = min(_bucket(max(1, -(-int(qc.max()) // D))), per_round)
+        else:
+            qc = quota
+        if coded or not legacy:
+            # balanced blocked placement: destination d's rows split
+            # into <= D contiguous arrival-order chunks, chunk j ->
+            # sender j, so no (sender, dest) pair exceeds
+            # ceil(quota_d / D) <= cap.  Contiguous chunks + the
+            # receiver's stable sender-major merge preserve global
+            # arrival order for equal keys.
+            if coded:
+                # both copies of a row, ranked within their destinations
+                lrank = arrival_ranks(dests_all, qc)
+            else:
+                # a row's rank within the round is its rank within
+                # its destination less the round's first
+                lrank = ranks[sel] - lo
+            chunk_d = np.maximum(1, -(-qc // D))
+            senders = (lrank // chunk_d[dests_all]).astype(rdest.dtype)
+            loads = np.bincount(senders, minlength=D)
+            N = _bucket(int(loads.max()))
+            pos = senders.astype(np.int64) * N + \
+                arrival_ranks(senders, loads)
+        else:
+            # legacy layout: rows in arrival order, zero tail pad
+            N = _bucket(-(-dests_all.size // D))
+            pos = np.arange(dests_all.size, dtype=np.int64)
+        vw = value_words + (1 if coded else 0)
+        r_lanes = np.zeros((D * N, self.lanes.shape[1]), np.uint32)
+        r_klens = np.zeros(D * N, np.uint32)
+        r_vwords = np.zeros((D * N, vw), np.uint32)
+        r_valid = np.zeros(D * N, bool)
+        r_dests = np.zeros(D * N, np.uint32)
+        r_lanes[pos] = self.lanes[rows_idx]
+        r_klens[pos] = self.klens[rows_idx]
+        r_vwords[pos, :value_words] = self.vwords[rows_idx]
+        if coded:
+            r_vwords[pos, value_words] = rtag
+        r_valid[pos] = True
+        r_dests[pos] = dests_all.astype(np.uint32)
+        return (r_lanes, r_klens, r_vwords, r_valid, r_dests), N, cap, qc
 
 
 class MeshExchangeCoordinator:
@@ -280,13 +411,15 @@ class MeshExchangeCoordinator:
             value_width = max(value_width, ((max_val + 3) // 4) * 4)
         with tracing.span("exchange.pack", cat="exchange", stage="producer",
                           rows=batch.num_records):
-            kmat, klens = pad_to_matrix(batch.key_bytes, batch.key_offsets,
-                                        key_width)
-            lanes = matrix_to_lanes(kmat)
-            vwords = _encode_values(batch, value_width)
-            # routing, once a row, where the raw key bytes are: the consumer
-            # partition hash % W (native, GIL released, in this producer's
-            # thread while slower producers still produce)
+            # both native, GIL released, in this producer's thread while
+            # slower producers still produce.  The rows: key lanes (the
+            # zero-padded key as big-endian words), key lengths, value
+            # words behind a first word that holds the value's length
+            lanes, klens, vwords = exchange_encode_native(
+                batch.key_bytes, batch.key_offsets, batch.val_bytes,
+                batch.val_offsets, key_width, value_width)
+            # the routing, once a row, where the raw key bytes are: the
+            # consumer partition hash % W
             part = fnv32_partition_native(
                 batch.key_bytes, batch.key_offsets, num_consumers).astype(
                     np.min_scalar_type(num_consumers))
@@ -308,9 +441,7 @@ class MeshExchangeCoordinator:
                 st.split_after = int(split_after)
             if counters is not None:
                 st.counters = counters
-            st.spans[task_index] = (lanes,
-                                    klens.astype(np.uint32),
-                                    vwords, part)
+            st.spans[task_index] = (lanes, klens, vwords, part)
             if tracing.armed():
                 st.arrived[task_index] = (time.time(),
                                           tracing.current_context())
@@ -435,16 +566,21 @@ class MeshExchangeCoordinator:
             self._compiled[key] = fn
         return fn
 
-    def _read_shards(self, arrs, mesh, edge_id: str, round_idx: int):
+    def _read_shards(self, arrs, mesh, edge_id: str, round_idx: int,
+                     decode=None):
         """Materialize the exchange outputs one device at a time, each on
         its own daemon reader thread.  Every reader fires the
         ``mesh.exchange.delay`` fault point (detail
         ``<edge>:round=<r>:device=<d>``) before touching its shard — the
         chaos lever that turns one chip into a readback straggler, since
-        the jitted SPMD body itself is not instrumentable.  Returns
-        (events, results, any_done); results[d] becomes the device's
-        (lanes, klens, vwords, valid) tuple, or the exception its reader
-        hit (a faulted chip), once events[d] is set."""
+        the jitted SPMD body itself is not instrumentable.  With ``decode``
+        the reader that has just materialized a shard also decodes it,
+        under its own ``exchange.decode`` span, so the shards decode side
+        by side and the executing thread only collects them.  Returns
+        (events, results, any_done); results[d] becomes
+        ``decode(lanes, klens, vwords, valid)`` of the device's shard (the
+        tuple itself without ``decode``), or the exception its reader hit
+        (a faulted chip), once events[d] is set."""
         D = mesh.devices.size
         pos = {dev: i for i, dev in enumerate(mesh.devices.flat)}
         shard_maps = []
@@ -465,7 +601,12 @@ class MeshExchangeCoordinator:
                     faults.fire(
                         "mesh.exchange.delay",
                         detail=f"{edge_id}:round={round_idx}:device={d}")
-                    results[d] = tuple(np.asarray(m[d]) for m in shard_maps)
+                    shard = tuple(np.asarray(m[d]) for m in shard_maps)
+                if decode is not None:
+                    with tracing.span("exchange.decode", cat="exchange",
+                                      parent=ctx, device=d, round=round_idx):
+                        shard = decode(*shard)
+                results[d] = shard
             except BaseException as e:  # noqa: BLE001 — surfaced by reader
                 results[d] = e
             finally:
@@ -548,29 +689,12 @@ class MeshExchangeCoordinator:
             mesh = self.mesh_for(D)     # holds W/D consumer partitions
             with self.lock:
                 spans = [st.spans[i] for i in sorted(st.spans)]
-            # harmonize widths: spans auto-widened independently — zero-pad
-            # narrow ones (zero lanes/words == absent bytes; order unaffected)
-            max_lanes = max((s[0].shape[1] for s in spans), default=1)
-            max_vw = max((s[2].shape[1] for s in spans), default=1)
-
-            def _widen(a: np.ndarray, width: int) -> np.ndarray:
-                if a.shape[1] == width:
-                    return a
-                return np.pad(a, ((0, 0), (0, width - a.shape[1])))
-
-            def _rows(arrays: List[np.ndarray], width: int) -> np.ndarray:
-                # pooled: 134 MB a DAG of the benchmark's mesh cell, found
-                # again by the next exchange instead of first-touched
-                return hostpool.concatenate(
-                    [_widen(a, width).reshape(-1) for a in arrays]
-                ).reshape(-1, width)
-
-            lanes = _rows([s[0] for s in spans], max_lanes)
-            klens = hostpool.concatenate([s[1] for s in spans])
-            vwords = _rows([s[2] for s in spans], max_vw)
-            total = lanes.shape[0]
-            num_lanes = lanes.shape[1]
-            value_words = vwords.shape[1]
+            # widths: spans auto-widened independently — the narrow ones
+            # are zero-padded to the edge's (zero lanes/words == absent
+            # bytes; order unaffected) where a round is placed
+            num_lanes = max((s[0].shape[1] for s in spans), default=1)
+            value_words = max((s[2].shape[1] for s in spans), default=1)
+            total = sum(s[0].shape[0] for s in spans)
             if total == 0:
                 return [KVBatch.empty() for _ in range(W)]
 
@@ -579,13 +703,17 @@ class MeshExchangeCoordinator:
             # plan only adds up.  Routing is hash % D; with D | W that equals
             # (hash % W) % D, so device d receives exactly the rows of
             # consumer partitions {c : c % D == d} (split apart after the
-            # exchange).  Destinations stay in the narrowest dtype holding D:
-            # numpy's stable argsort is a radix sort up to 16 bits.
-            from tez_tpu.ops.device import _bucket
-            dest_dtype = np.min_scalar_type(D)
-            rdest = (np.concatenate([s[3] for s in spans]) % D) \
-                .astype(dest_dtype, copy=False)
-            counts = np.bincount(rdest, minlength=D)
+            # exchange).  Destinations stay in the narrowest dtype holding D
+            # (a byte a row to concatenate, count and re-home).  The rows
+            # themselves stay where the producers left them, cut into
+            # chunks the native passes take a thread each; the plan works
+            # on the chunks' histogram of destinations.
+            rdest = np.concatenate([s[3] for s in spans])
+            if W != D:
+                rdest = (rdest % D).astype(np.min_scalar_type(D), copy=False)
+            chunks, bounds = _row_chunks(spans)
+            hist = exchange_dest_hist_native(rdest, bounds, D)
+            counts = hist.sum(axis=0)
             per_round = st.max_rows_per_round or self.max_rows_per_round
 
             # ---- fair-shuffle splitter: an edge whose largest partition has
@@ -649,7 +777,8 @@ class MeshExchangeCoordinator:
                     rows = np.flatnonzero(orig_rdest == d)  # ascending==arrival
                     rdest[rows] = np.repeat(np.arange(D), amounts)
                     splits += 1
-                counts = np.bincount(rdest, minlength=D)
+                hist = exchange_dest_hist_native(rdest, bounds, D)
+                counts = hist.sum(axis=0)
                 with self.lock:
                     self.partition_splits += splits
                 log.info("mesh exchange %s: splitter engaged after %d "
@@ -665,96 +794,49 @@ class MeshExchangeCoordinator:
             plan = plan_rounds(counts, per_round, D, legacy=self.legacy_sizing)
             _flight.record(_flight.EXCHANGE, "plan", st.edge_id,
                            a=len(plan), b=total)
-
-            # rank of each row within its routing partition (arrival order)
-            ranks = arrival_ranks(rdest, counts)
+            # who places a round: the native row pass, unless the edge is
+            # coded (rows duplicated, a routing tag word) or sized the
+            # legacy way (the golden reference) — those keep the numpy
+            # placement, over one concatenation and one ranking of the rows
+            native = not coded and not self.legacy_sizing
+            if not native:
+                reference = _ReferencePlacement(spans, rdest, counts,
+                                                num_lanes, value_words)
 
         row_words = num_lanes + 1 + value_words   # lanes + klen + vwords
-        sent_rows = dup_rows = buddy_wins = rounds_run = 0
+        sent_rows = native_rows = dup_rows = buddy_wins = rounds_run = 0
         lane_counts = np.zeros(D, dtype=np.int64)
         per_round_results: List[List[KVBatch]] = []
         for r, (quota, cap) in enumerate(plan):
             lo = r * per_round
-            sel = np.flatnonzero((ranks >= lo) & (ranks < lo + per_round))
-            n_round = sel.size
+            # the round carries each destination's arrival ranks
+            # [lo, lo + per_round): its histogram is the plan's quota
+            n_round = int(quota.sum())
             if n_round == 0:
                 continue
             t_round = time.perf_counter()
             with tracing.span("exchange.pack", cat="exchange", round=r,
                               rows=n_round):
-                rows_idx = sel
-                dests_all = rdest[sel]
-                rtag = None
-                if coded:
-                    # r2: every row ALSO goes to its destination's rotation
-                    # buddy.  An extra value word carries the routing partition
-                    # (same on both copies) so each shard can tell its primary
-                    # rows from buddy copies — not derivable from the key once
-                    # the splitter has re-routed rows.
-                    rows_idx = np.concatenate([sel, sel])
-                    rtag = np.concatenate([dests_all, dests_all]) \
-                        .astype(np.uint32)
-                    dests_all = np.concatenate(
-                        [dests_all, (dests_all + 1) % D])
-                    dup_rows += n_round
-                    # duplication doubled the quotas; re-derive the balanced
-                    # cap from the combined histogram (coded always uses
-                    # balanced placement — legacy tail-packing could put a
-                    # whole destination's copies on one sender)
-                    qc = np.bincount(dests_all, minlength=D)
-                    cap = min(_bucket(max(1, -(-int(qc.max()) // D))),
-                              per_round)
+                if native:
+                    device_inputs, N = _place_round_native(
+                        chunks, bounds, rdest, hist, lo, per_round, quota,
+                        num_lanes, value_words)
+                    native_rows += n_round
+                    lane_counts += quota
                 else:
-                    # the round carries each destination's arrival ranks
-                    # [lo, lo + per_round): its histogram is the plan's quota
-                    qc = quota
-                lane_counts += qc
-                balanced = coded or not self.legacy_sizing
-                if balanced:
-                    # balanced blocked placement: destination d's rows split
-                    # into <= D contiguous arrival-order chunks, chunk j ->
-                    # sender j, so no (sender, dest) pair exceeds
-                    # ceil(quota_d / D) <= cap.  Contiguous chunks + the
-                    # receiver's stable sender-major merge preserve global
-                    # arrival order for equal keys.
+                    device_inputs, N, cap, qc = reference.place_round(
+                        lo, per_round, quota, cap, coded,
+                        self.legacy_sizing)
+                    lane_counts += qc
                     if coded:
-                        # both copies of a row, ranked within their
-                        # destinations
-                        lrank = arrival_ranks(dests_all, qc)
-                    else:
-                        # a row's rank within the round is its rank within
-                        # its destination less the round's first: the plan
-                        # ranked every row once, nothing is sorted again
-                        lrank = ranks[sel] - lo
-                    chunk_d = np.maximum(1, -(-qc // D))
-                    senders = (lrank // chunk_d[dests_all]).astype(dest_dtype)
-                    loads = np.bincount(senders, minlength=D)
-                    N = _bucket(int(loads.max()))
-                    pos = senders.astype(np.int64) * N + \
-                        arrival_ranks(senders, loads)
-                else:
-                    # legacy layout: rows in arrival order, zero tail pad
-                    N = _bucket(-(-dests_all.size // D))
-                    pos = np.arange(dests_all.size, dtype=np.int64)
-                vw = value_words + (1 if coded else 0)
-                r_lanes = np.zeros((D * N, num_lanes), np.uint32)
-                r_klens = np.zeros(D * N, np.uint32)
-                r_vwords = np.zeros((D * N, vw), np.uint32)
-                r_valid = np.zeros(D * N, bool)
-                r_dests = np.zeros(D * N, np.uint32)
-                r_lanes[pos] = lanes[rows_idx]
-                r_klens[pos] = klens[rows_idx]
-                r_vwords[pos, :value_words] = vwords[rows_idx]
-                if coded:
-                    r_vwords[pos, value_words] = rtag
-                r_valid[pos] = True
-                r_dests[pos] = dests_all.astype(np.uint32)
+                        dup_rows += n_round
+                vw = device_inputs[2].shape[1]
             with tracing.span("exchange.launch", cat="exchange", round=r,
                               rows=D * N):
                 fn = self._compiled_fn(mesh, num_lanes, N, cap, vw,
                                        ragged=(engine == "ragged"))
                 out_lanes, out_klens, out_vwords, out_valid, dropped = \
-                    fn(r_lanes, r_klens, r_vwords, r_valid, r_dests)
+                    fn(*device_inputs)
             # the dropped flag is a tiny replicated array: reading it does
             # not serialize the per-device readback below (the delay fault
             # stalls our reader threads, not device compute) — but it does
@@ -768,7 +850,7 @@ class MeshExchangeCoordinator:
                     f"(cap {cap}, round {r}) — capacity accounting bug")
             events, results, any_done = self._read_shards(
                 (out_lanes, out_klens, out_vwords, out_valid), mesh,
-                st.edge_id, r)
+                st.edge_id, r, decode=None if coded else _decode_shard)
             round_parts: List[KVBatch] = []
             if coded:
                 with tracing.span("exchange.readback", cat="exchange",
@@ -782,22 +864,21 @@ class MeshExchangeCoordinator:
                         dl, dk, dv, dval = results[chosen[p]]
                         keep = dval.astype(bool) & \
                             (dv[:, value_words] == p)
-                        round_parts.append(_decode_rows(
-                            dl, dk,
-                            np.ascontiguousarray(dv[:, :value_words]),
-                            keep))
+                        round_parts.append(
+                            _decode_shard(dl, dk, dv, keep, value_words))
             else:
+                # every reader decodes the shard it read: collect them
                 for d in range(D):
                     with tracing.span("exchange.readback", cat="exchange",
                                       round=r, what="shard", device=d):
                         events[d].wait()
                     if isinstance(results[d], BaseException):
                         raise results[d]
-                    dl, dk, dv, dval = results[d]
-                    with tracing.span("exchange.decode", cat="exchange",
-                                      round=r, device=d):
-                        round_parts.append(
-                            _decode_rows(dl, dk, dv, dval.astype(bool)))
+                    round_parts.append(results[d])
+            # the round's inputs go back to the host pool only now that its
+            # outputs are read: XLA:CPU may alias a numpy buffer it was
+            # handed instead of copying it
+            del device_inputs
             per_round_results.append(round_parts)
             metrics.observe("mesh.exchange.round",
                             (time.perf_counter() - t_round) * 1000.0,
@@ -819,6 +900,8 @@ class MeshExchangeCoordinator:
         if st.counters is not None:
             g = st.counters.group(MESH_EXCHANGE_GROUP)
             g.find_counter("exchange.rows.sent").increment(sent_rows)
+            g.find_counter("exchange.rows.placed.native").increment(
+                native_rows)
             g.find_counter("exchange.bytes.sent").increment(
                 sent_rows * row_words * 4)
             g.find_counter("exchange.rounds").increment(rounds_run)

@@ -16,6 +16,9 @@ at ``reduce_trace``  the profiler's file is still there (``run.py`` removes
 at ``label_gap``     for each idle gap the reducer labels, every span name's
     cover of it and not only the largest (``breakdown.idle_gaps`` keeps
     that one)
+at ``result_line``   the window's DAGs as the harness observed them: the
+    ``MeshExchange`` counters a DAG (``exchange_counters_a_dag``; empty in
+    a cell with no mesh edge), which no metric reports one by one
 
 and, after the run, reads the span buffer for
 
@@ -26,6 +29,12 @@ spans_a_dag  spans of the window over the DAGs that started in it, by name
 self_s_a_dag ``trace_reduce.self_intervals`` over the window's
              ``program_spans()``, by name, over those DAGs: what a task's
              wall is made of
+exchange_self_s_a_dag  the same self time for the ``exchange.*`` spans
+             alone, each named with the argument that says which of its
+             sites it is (``stage``, else ``what``, else ``device``: a
+             shard on a reader thread, else ``round``: the executing
+             thread), so ``exchange.decode`` reads as shards and
+             ``assemble`` and ``exchange.pack`` as producers and the round
 
 The clock check: for every span with a ``tez.<name>`` twin in the profiler's
 file, |(annotation start - marker offset) - span start| — the reducer's
@@ -89,6 +98,17 @@ def clock_check(path, spans, marks, mark_name):
     return out
 
 
+def exchange_site(span) -> str:
+    """``exchange.<phase>[<site>]``: which of a phase's sites a span is."""
+    for key in ("stage", "what"):
+        if key in span.args:
+            return f"{span.name}[{span.args[key]}]"
+    for key in ("device", "round"):
+        if key in span.args:
+            return f"{span.name}[{key}]"
+    return span.name
+
+
 def main() -> int:
     import run as bench_run
     import trace_reduce
@@ -123,8 +143,20 @@ def main() -> int:
                            if v >= 0.05}})
         return label_gap(lo, hi, selfs)
 
+    result_line = bench_run.result_line
+
+    def line_and_keep(args, spec, devices, res):
+        dags = res["obs"]["dags"]
+        totals = collections.Counter()
+        for dag in dags:
+            totals.update(dag["counters"].get("MeshExchange", {}))
+        found["exchange_counters_a_dag"] = {
+            k: v / len(dags) for k, v in sorted(totals.items())}
+        return result_line(args, spec, devices, res)
+
     trace_reduce.reduce_trace = reduce_and_keep
     trace_reduce.label_gap = label_and_keep
+    bench_run.result_line = line_and_keep
     rc = bench_run.main(sys.argv[1:])
     if "marks" not in found:
         return rc
@@ -145,6 +177,15 @@ def main() -> int:
     self_s = collections.Counter()
     for name, a, b in trace_reduce.self_intervals(window):
         self_s[name] += b - a
+    # the exchange's spans by site: same rows, same self time, other names
+    # (a span's self time depends on every span of its thread, so all go in)
+    by_site = collections.Counter()
+    for name, a, b in trace_reduce.self_intervals(
+            [(exchange_site(s) if s.cat == "exchange" else s.name,
+              s.start, s.end, s.thread) for s in spans
+             if marks["start"] <= s.start <= marks["stop"]]):
+        if name.startswith("exchange."):
+            by_site[name] += b - a
     events = found.pop("merge_program_events")
     args = dict(zip(sys.argv[1::2], sys.argv[2::2]))
     summary = {
@@ -158,6 +199,8 @@ def main() -> int:
                                     row[0] for row in window).most_common()},
         "self_s_a_dag": {k: round(v / dags, 4)
                          for k, v in self_s.most_common()},
+        "exchange_self_s_a_dag": {k: round(v / dags, 4)
+                                  for k, v in by_site.most_common()},
         "merge_program_events": dict(events),
         "merge_program_events_a_dag": sum(events.values()) / dags,
         **found}

@@ -1,0 +1,7 @@
+"""join_s_per_dag: see join_s_per_dag.json."""
+import span_metrics
+
+
+def read(obs):
+    return span_metrics.self_s_per_dag(
+        obs, ("join.align", "join.match", "join.emit"))

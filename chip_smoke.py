@@ -67,11 +67,14 @@ def require(ok: bool, what: str) -> None:
 
 
 def build_native() -> None:
-    """`make clean all` from the committed sources, then prove the library
-    the package loads is that fresh build."""
+    """`make -B all` from the committed sources, then prove the library
+    the package loads is that fresh build.  Not `clean all`: the Makefile
+    puts a build in place by rename, so a process that is loading or
+    packing the library meanwhile (the tier-1 suite runs this script beside
+    tests/test_packaging.py) never finds it gone."""
     native_dir = os.path.join(HERE, "tez_tpu", "native")
     t0 = time.time()
-    subprocess.run(["make", "-s", "-C", native_dir, "clean", "all"],
+    subprocess.run(["make", "-s", "-C", native_dir, "-B", "all"],
                    check=True)
     from tez_tpu.ops import native
     so = native.loaded_path()

@@ -126,6 +126,15 @@ class TaskCounter(enum.Enum):
     # (ops/sorter.py _take: after a span sort, in a merge): over the input
     # bytes, how many times a record is moved that way
     PAYLOAD_GATHER_BYTES = enum.auto()
+    # batch merge-join (library/join.py): rows read from each sorted input,
+    # keys written, and the rows of both sides (unpadded) and launches of
+    # the device match -- a match on the host engine moves neither.  The
+    # join's rows are NOT merge rows: DEVICE_MERGE_RECORDS stays the merges'
+    JOIN_LEFT_RECORDS = enum.auto()
+    JOIN_RIGHT_RECORDS = enum.auto()
+    JOIN_OUTPUT_RECORDS = enum.auto()
+    JOIN_MATCH_ROWS = enum.auto()
+    JOIN_MATCH_LAUNCHES = enum.auto()
 
 
 # Mesh ICI exchange plane (parallel/coordinator.py): string-named counters

@@ -456,6 +456,10 @@ class OrderedGroupedKVInput(LogicalInput):
             ctx, "tez.runtime.shuffle.push.eager-merge-threshold",
             0.5)) if push_on else 0.0
         self._mm_budget = budget_mb << 20
+        #: the merge plane's routing, for a processor that works on the
+        #: merged blocks with the same engine (library/join.py)
+        self.merge_engine = merge_engine
+        self.merge_min_records = merge_min
         self._mm_kwargs = dict(
             key_width=self.key_width, engine=merge_engine,
             merge_factor=factor,

@@ -659,7 +659,11 @@ class ShuffleMergeManager:
                     self.lock.notify_all()
         with self.lock:
             self._raise_if_broken()
-            mem = sorted(self._mem)
+            # the committed batches pass to the final merge: held here any
+            # longer, their views of the producers' key lanes pin HBM until
+            # the collector finds this manager's cycle (its pipeline holds
+            # its bound methods), DAGs later
+            mem, self._mem = sorted(self._mem), []
             disk = list(self._disk_runs)
             # no byte-size filter: empty PARTITIONS never commit (gated by
             # the producer's row-count flags), and a committed source whose

@@ -488,6 +488,25 @@ void tz_exchange_encode(const uint8_t* key_bytes, const int64_t* key_offsets,
     });
 }
 
+// Ragged keys -> lanes u32[n, num_lanes] (big-endian words, zero-padded) and
+// lens i32[n]: what keycodec.pad_to_matrix + matrix_to_lanes give at `width`
+// bytes (num_lanes = width rounded up to words).  A key longer than `width`
+// keeps its true length and its first `width` bytes.
+void tz_encode_key_lanes(const uint8_t* key_bytes, const int64_t* key_offsets,
+                         int64_t n, int32_t width, int32_t num_lanes,
+                         uint32_t* lanes, int32_t* lens, int32_t n_threads) {
+    const int64_t key_cap = width;
+    over_row_chunks(n, n_threads, [=](int, int64_t lo, int64_t hi) {
+        for (int64_t i = lo; i < hi; i++) {
+            int64_t klen = key_offsets[i + 1] - key_offsets[i];
+            lens[i] = (int32_t)klen;
+            pack_be_words(key_bytes + key_offsets[i],
+                          std::min(klen, key_cap), lanes + i * num_lanes,
+                          num_lanes);
+        }
+    });
+}
+
 // Rows of each destination in each chunk of `dests` (elements of
 // dest_size bytes): hist[n_chunks][D], one thread a chunk.
 void tz_exchange_dest_hist(const void* dests, int32_t dest_size,

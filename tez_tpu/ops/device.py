@@ -479,17 +479,25 @@ def _resident_sort(num_partitions: int, splits, donate: bool) -> Callable:
 # the async pipeline runs them on different threads so span k+1's staging
 # overlaps span k's in-flight sort.
 
-def stage_resident_span(lanes: np.ndarray, lengths: np.ndarray):
-    """Host bucket-pad + H2D upload.  Returns (lanes_dev, lens_dev, n,
-    skip_length_pass)."""
-    n = lanes.shape[0]
-    uniform, _pad = uniform_clamped_lengths(lengths, lanes.shape[1] * 4 + 1)
-    nb = _bucket(n)
+def _pad_to_bucket(lanes: np.ndarray, lengths: np.ndarray
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+    """Rows padded to their bucket with tail sentinels: lanes all ones,
+    length -1."""
+    n, nb = lanes.shape[0], _bucket(lanes.shape[0])
     lengths = lengths.astype(np.int32)
     if nb != n:
         lanes = np.pad(lanes, ((0, nb - n), (0, 0)),
                        constant_values=np.uint32(0xFFFFFFFF))
         lengths = np.pad(lengths, (0, nb - n), constant_values=-1)
+    return lanes, lengths
+
+
+def stage_resident_span(lanes: np.ndarray, lengths: np.ndarray):
+    """Host bucket-pad + H2D upload.  Returns (lanes_dev, lens_dev, n,
+    skip_length_pass)."""
+    n = lanes.shape[0]
+    uniform, _pad = uniform_clamped_lengths(lengths, lanes.shape[1] * 4 + 1)
+    lanes, lengths = _pad_to_bucket(lanes, lengths)
     return (jax.device_put(jnp.asarray(lanes)),
             jax.device_put(jnp.asarray(lengths)), n, uniform)
 
@@ -523,12 +531,7 @@ def sort_span_resident(lanes: np.ndarray, lengths: np.ndarray,
     if n == 0:
         return (np.zeros(0, np.int32), np.zeros(0, np.int32), None)
     uniform, _pad = uniform_clamped_lengths(lengths, lanes.shape[1] * 4 + 1)
-    nb = _bucket(n)
-    lengths = lengths.astype(np.int32)
-    if nb != n:
-        lanes = np.pad(lanes, ((0, nb - n), (0, 0)),
-                       constant_values=np.uint32(0xFFFFFFFF))
-        lengths = np.pad(lengths, (0, nb - n), constant_values=-1)
+    lanes, lengths = _pad_to_bucket(lanes, lengths)
     # uniform real lengths make the length pass an identity reorder even
     # with tail sentinels present: sentinel order is fully decided by the
     # final partition pass (partition MAX)
@@ -784,3 +787,80 @@ def merge_runs(partitions: np.ndarray, lanes: np.ndarray,
         perm = np.asarray(perm_dev)
     with tracing.span("merge.gather", cat="merge", rows=n):
         return perm[:n].astype(np.int64)
+
+
+# ---------------------------------------------------------------------------
+# merge-join match = sort of the two sides' concatenation + neighbour compare
+# ---------------------------------------------------------------------------
+def _matches_after_sort(xp, perm, lanes, lens, n_left: int):
+    """`perm` orders the concatenation [left rows, right rows] by (lanes,
+    length), stably: among equal keys the left rows stand first.  A key
+    both sides hold therefore shows exactly one place where a left row is
+    followed by a right row of the same key: that left row's index stands
+    in the result, -1 everywhere else (sentinel rows, length < 0, never
+    match).  xp: numpy on the host engine, jax.numpy traced."""
+    s_lanes, s_lens = lanes[perm], lens[perm]
+    same = (s_lens[:-1] == s_lens[1:]) & (s_lens[:-1] >= 0) & \
+        (s_lanes[:-1] == s_lanes[1:]).all(axis=1)
+    is_left = perm < n_left
+    return xp.where(is_left[:-1] & ~is_left[1:] & same, perm[:-1], -1)
+
+
+def _join_match_impl(left_lanes, left_lens, right_lanes, right_lens,
+                     skip_length_pass: bool = False):
+    """Semi-join match of two key-sorted sides, each padded to its bucket
+    with sentinels: one stable LSD sort of their concatenation -- the side
+    is the least significant column by the order of concatenation, which a
+    stable sort keeps -- then a neighbour compare.  Returns i32[B - 1]: the
+    left row of every key both sides hold (once a key), -1 elsewhere, in
+    key order."""
+    lanes = jnp.concatenate([left_lanes, right_lanes], axis=0)
+    lens = jnp.concatenate([left_lens, right_lens], axis=0)
+    parts = jnp.where(lens < 0, jnp.int32(np.iinfo(np.int32).max),
+                      jnp.int32(0))
+    sort_lens = jnp.where(lens < 0, jnp.uint32(0xFFFFFFFF),
+                          lens.astype(jnp.uint32))
+    _, perm = _lsd_passes(parts, lanes, sort_lens, skip_length_pass)
+    return _matches_after_sort(jnp, perm, lanes, lens, left_lanes.shape[0])
+
+
+_join_match = Kernel(
+    _join_match_impl, "join_match", static_argnames=("skip_length_pass",),
+    launch_rows=lambda ll, _ln, rl, _rn: int(ll.shape[0] + rl.shape[0]))
+
+
+def join_match(left_lanes: np.ndarray, left_lens: np.ndarray,
+               right_lanes: np.ndarray, right_lens: np.ndarray
+               ) -> np.ndarray:
+    """The left rows whose key the right side holds too, one row a distinct
+    key, ascending.  Both sides are key-sorted lanes of one width holding
+    whole keys (u32[n, L], lengths i32[n]); duplicates on either side are
+    fine.  Each side is padded to its own bucket, so the program's compile
+    key is (left bucket, right bucket, L); only the matches' row indices
+    come back."""
+    uniform, _pad = uniform_clamped_lengths(
+        np.concatenate([left_lens, right_lens]), left_lanes.shape[1] * 4 + 1)
+    with tracing.span("join.match", cat="join", stage="stage",
+                      rows=len(left_lens) + len(right_lens)):
+        operands = [jnp.asarray(a) for a in
+                    _pad_to_bucket(left_lanes, left_lens) +
+                    _pad_to_bucket(right_lanes, right_lens)]
+    with tracing.span("join.match", cat="join", stage="launch"):
+        hits_dev = _join_match(*operands, skip_length_pass=uniform)
+    # the host blocks here for the device, as in merge.readback
+    with tracing.span("join.match", cat="join", stage="readback"):
+        hits = np.asarray(hits_dev)
+        return hits[hits >= 0].astype(np.int64)
+
+
+def join_match_host(left_lanes: np.ndarray, left_lens: np.ndarray,
+                    right_lanes: np.ndarray, right_lens: np.ndarray
+                    ) -> np.ndarray:
+    """join_match on the host engine: numpy's stable lexsort in the sort's
+    place, the same neighbour compare."""
+    lanes = np.concatenate([left_lanes, right_lanes])
+    lens = np.concatenate([left_lens, right_lens]).astype(np.int32)
+    perm = np.lexsort((lens,) + tuple(
+        lanes[:, i] for i in range(lanes.shape[1] - 1, -1, -1)))
+    hits = _matches_after_sort(np, perm, lanes, lens, len(left_lens))
+    return hits[hits >= 0].astype(np.int64)

@@ -73,7 +73,18 @@ def matrix_to_lanes(mat: np.ndarray) -> np.ndarray:
 
 def encode_keys(key_bytes: np.ndarray, offsets: np.ndarray,
                 width: int) -> tuple[np.ndarray, np.ndarray]:
-    """Ragged keys -> (uint32 lanes [N, ceil(width/4)], lengths[N])."""
+    """Ragged keys -> (uint32 lanes [N, ceil(width/4)], lengths[N]).
+
+    Keys of one length take pad_to_matrix's reshape; a large span of keys of
+    several lengths takes the native pass, since the numpy gather builds an
+    (N, width) index matrix (3 s against 0.03 s at 900,000 keys of 17-23
+    bytes)."""
+    from tez_tpu.ops.native import MIN_NATIVE_BYTES, encode_key_lanes_native
+    n = len(offsets) - 1
+    if n and key_bytes.nbytes >= MIN_NATIVE_BYTES and \
+            int(offsets[-1]) - int(offsets[0]) != n * int(
+                offsets[1] - offsets[0]):
+        return encode_key_lanes_native(key_bytes, offsets, width)
     mat, lengths = pad_to_matrix(key_bytes, offsets, width)
     return matrix_to_lanes(mat), lengths
 

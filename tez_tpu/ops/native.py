@@ -90,6 +90,7 @@ _SIGNATURES = {
                      ctypes.c_double),
     "tz_exchange_encode": ([_P, _P, _P, _P, _I64, _I32, _I32, _P, _P, _P,
                             _I32], None),
+    "tz_encode_key_lanes": ([_P, _P, _I64, _I32, _I32, _P, _P, _I32], None),
     "tz_exchange_dest_hist": ([_P, _I32, _P, _I32, _I32, _P], None),
     "tz_exchange_place": ([_I32, _P, _P, _P, _P, _P, _P, _P, _I32, _P, _P,
                            _I32, _I64, _I64, _P, _P, _I64, _I32, _I32,
@@ -597,6 +598,25 @@ def exchange_encode_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
         _ptr(val_offsets), n, num_lanes, value_words,
         _ptr(lanes), _ptr(klens), _ptr(vwords), _threads())
     return lanes, klens, vwords
+
+
+def encode_key_lanes_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
+                            width: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Ragged keys as sort lanes: (lanes u32[n, ceil(width / 4)], lengths
+    i32[n]), what ``keycodec.pad_to_matrix`` + ``matrix_to_lanes`` give, in
+    one threaded pass with no index matrix."""
+    lib = _load()
+    n = len(key_offsets) - 1
+    num_lanes = (int(width) + 3) // 4
+    key_bytes = np.ascontiguousarray(key_bytes, dtype=np.uint8)
+    key_offsets = np.ascontiguousarray(key_offsets, dtype=np.int64)
+    if n and int(key_offsets[-1]) > key_bytes.size:
+        raise ValueError("offsets run past the bytes")
+    lanes = hostpool.empty(n * num_lanes, np.uint32).reshape(n, num_lanes)
+    lengths = hostpool.empty(n, np.int32)
+    lib.tz_encode_key_lanes(_ptr(key_bytes), _ptr(key_offsets), n, int(width),
+                            num_lanes, _ptr(lanes), _ptr(lengths), _threads())
+    return lanes, lengths
 
 
 def exchange_dest_hist_native(dests: np.ndarray, bounds: np.ndarray,

@@ -26,8 +26,7 @@ from tez_tpu.common import tracing
 from tez_tpu.common.counters import TaskCounter, TezCounters
 from tez_tpu.ops import device
 from tez_tpu.ops.keycodec import (encode_keys, encode_split_keys,
-                                  matrix_to_lanes, pad_to_matrix,
-                                  range_partitions)
+                                  pad_to_matrix, range_partitions)
 from tez_tpu.ops.runformat import (FileRun, KVBatch, PartitionedRunWriter,
                                    Run, adjacent_equal_rows, gather_ragged,
                                    save_run_partitioned)
@@ -582,8 +581,7 @@ class DeviceSorter:
         if wmax > self.key_width:
             return None
         eff = ((max(wmax, 1) + 3) // 4) * 4
-        mat, lengths = pad_to_matrix(batch.key_bytes, batch.key_offsets, eff)
-        return matrix_to_lanes(mat), lengths
+        return encode_keys(batch.key_bytes, batch.key_offsets, eff)
 
     def _splits_for(self, lanes: np.ndarray):
         """The range partitioner's split rows at these lanes' width (None:
@@ -784,8 +782,7 @@ class DeviceSorter:
         if engine == "host":
             return self._native_host_sort(batch, sort_bytes, sort_offsets,
                                           custom_partitions, t0)
-        mat, lengths = pad_to_matrix(sort_bytes, sort_offsets, self.key_width)
-        lanes = matrix_to_lanes(mat)
+        lanes, lengths = encode_keys(sort_bytes, sort_offsets, self.key_width)
         if custom_partitions is not None:
             sorted_partitions, perm = device.sort_run(custom_partitions,
                                                       lanes, lengths)
@@ -1234,8 +1231,7 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
                       len(runs), final)
         return Run.from_sorted_batch(sorted_batch, sorted_partitions,
                                      num_partitions)
-    mat, lengths = pad_to_matrix(sort_bytes, sort_offsets, key_width)
-    lanes = matrix_to_lanes(mat)
+    lanes, lengths = encode_keys(sort_bytes, sort_offsets, key_width)
     # the concatenation is in run-arrival order: one stable sort of it IS
     # the merge (equal keys keep run order); prefix-equal beyond-cap keys
     # still fall to the host tie-break below

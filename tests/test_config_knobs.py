@@ -142,7 +142,7 @@ def test_edge_event_pull_respects_max_events():
     edge._lock = threading.Lock()
     edge._events = [(i, 0, DataMovementEvent(source_index=0,
                                              user_payload=None,
-                                             target_index=0))
+                                             target_index=0), 0.0)
                     for i in range(10)]
     edge.edge_manager = _PassThroughManager()
     out, seq = edge.get_events_for_task(0, 0, max_events=4)

@@ -93,6 +93,9 @@ class FakeAM:
     def kill_attempt_in_runner(self, attempt_id):
         pass
 
+    def wake_vertex_tasks(self, vertex_id):
+        pass
+
     def deliver_processor_events(self, v, events, idx):
         pass
 
@@ -156,6 +159,25 @@ def test_happy_path_to_succeeded():
         finish_attempt(am, att)
     assert impl.state is DAGState.SUCCEEDED
     assert am.finished == [DAGState.SUCCEEDED]
+
+
+def test_can_commit_does_not_wait_for_the_dispatcher_to_see_the_start():
+    """A runner asks from its own thread; an attempt that is done within
+    milliseconds asks while TA_STARTED_REMOTELY is still queued.  A refusal
+    there left a leaf output unpublished.  An attempt the AM has ended may
+    not commit, and the first to ask keeps the right."""
+    from tez_tpu.am.task_impl import TaskAttemptState
+    am = FakeAM()
+    impl = build_dag(am, vertices=(("a", 1),), edges=())
+    start_dag(am, impl)
+    first = am.launch_requests[0]
+    task = impl.vertex_by_name("a").tasks[0]
+    assert task.attempt(first).state is TaskAttemptState.SUBMITTED
+    assert task.can_commit(first)
+    finish_attempt(am, first, state="failed")
+    assert not task.can_commit(first)
+    second = am.launch_requests[-1]
+    assert second != first and task.can_commit(second)
 
 
 def test_task_retries_until_limit_then_fails_dag():

@@ -84,3 +84,19 @@ def undocumented_spans(span_names, doc_text: str) -> set:
             continue
         missing.add(name)
     return missing
+
+
+# ---------------------------------------------------------------------------
+# histograms and counters (docs/observability.md tables outside the span
+# vocabulary)
+# ---------------------------------------------------------------------------
+
+def undocumented_metrics(names, doc_text: str) -> set:
+    """Histogram and counter names that no table row of the document opens
+    with: a row's first cell holds the name back-quoted."""
+    import re
+    documented = set()
+    for line in doc_text.splitlines():
+        if line.startswith("| `"):
+            documented.update(re.findall(r"`([^`]+)`", line.split("|")[1]))
+    return {name for name in names if name not in documented}

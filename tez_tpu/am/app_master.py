@@ -400,6 +400,11 @@ class DAGAppMaster:
     def kill_attempt_in_runner(self, attempt_id: TaskAttemptId) -> None:
         self.task_comm.kill_attempt(attempt_id)
 
+    def wake_vertex_tasks(self, vertex_id: Any) -> None:
+        """Events became deliverable to this vertex's tasks: its live
+        attempts heartbeat now (task_comm.wake_vertex)."""
+        self.task_comm.wake_vertex(vertex_id)
+
     def deliver_processor_events(self, vertex: Any, events: Sequence[Any],
                                  task_indices: Sequence[int]) -> None:
         for idx in task_indices:

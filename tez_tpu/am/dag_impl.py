@@ -418,8 +418,11 @@ class DAGImpl:
 
     # -- misc hooks used by vertices/managers --------------------------------
     def notify_new_edge_events(self, edge: EdgeImpl) -> None:
-        """New producer events available; wake any waiting consumers (local
-        mode: consumers poll via heartbeat, so this is a no-op hook point)."""
+        """A producer's events were added to ``edge``'s log: the destination
+        vertex's live attempts heartbeat now and pull them, instead of at
+        the end of their reporter's sleep (attempts with no waker — remote
+        runners — find them at their next beat, as before)."""
+        self.ctx.wake_vertex_tasks(edge.destination_vertex.vertex_id)
 
     def send_custom_events_to_tasks(self, vertex: VertexImpl,
                                     events: Sequence[Any],

@@ -22,6 +22,7 @@ from tez_tpu.api.events import (CompositeDataMovementEvent,
                                 CompositeRoutedDataMovementEvent,
                                 DataMovementEvent, InputFailedEvent,
                                 TezAPIEvent)
+from tez_tpu.common import clock
 from tez_tpu.common.payload import UserPayload
 from tez_tpu.dag.edge_property import DataMovementType, EdgeProperty
 
@@ -178,8 +179,9 @@ class EdgeImpl:
         self.source_vertex = source_vertex
         self.destination_vertex = destination_vertex
         self._lock = threading.Lock()
-        # Ordered producer event log: (src_task, attempt_number, event)
-        self._events: List[Tuple[int, int, TezAPIEvent]] = []
+        # Ordered producer event log: (src_task, attempt_number, event,
+        # epoch second it was added: the start of am.task.event_wait)
+        self._events: List[Tuple[int, int, TezAPIEvent, float]] = []
         self.edge_manager: EdgeManagerPluginOnDemand = None  # type: ignore
 
     def initialize(self) -> None:
@@ -211,7 +213,8 @@ class EdgeImpl:
     def add_source_event(self, src_task: int, attempt_number: int,
                          event: TezAPIEvent) -> None:
         with self._lock:
-            self._events.append((src_task, attempt_number, event))
+            self._events.append((src_task, attempt_number, event,
+                                 clock.wall_s()))
 
     def source_event_count(self) -> int:
         with self._lock:
@@ -219,19 +222,22 @@ class EdgeImpl:
 
     # -- consumer side (on-demand pull) --------------------------------------
     def get_events_for_task(self, dest_task: int, from_seq: int,
-                            max_events: int = 0
+                            max_events: int = 0,
+                            stamps: Optional[List[float]] = None
                             ) -> Tuple[List[TezAPIEvent], int]:
         """Route events [from_seq:] for one destination task.  Returns the
         routed events and the new high-water mark.  ``max_events`` > 0
         stops consuming log entries once that many routed events are out
         (tez.task.max-event-backlog); the high-water mark then points at
-        the first unconsumed entry so the rest arrive on later pulls."""
+        the first unconsumed entry so the rest arrive on later pulls.
+        ``stamps``, when given, gains one entry a routed event: the second
+        its log entry was added."""
         with self._lock:
             snapshot = self._events[from_seq:]
         consumed = 0
         out: List[TezAPIEvent] = []
         em = self.edge_manager
-        for src_task, version, ev in snapshot:
+        for src_task, version, ev, added_s in snapshot:
             routed: List[TezAPIEvent] = []
             if isinstance(ev, CompositeDataMovementEvent):
                 meta = em.route_composite_data_movement_event_to_destination(
@@ -266,6 +272,8 @@ class EdgeImpl:
                 break
             consumed += 1
             out.extend(routed)
+            if stamps is not None:
+                stamps.extend([added_s] * len(routed))
             if max_events and len(out) >= max_events:
                 break
         return out, from_seq + consumed

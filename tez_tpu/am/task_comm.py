@@ -8,13 +8,19 @@ In local mode this is a plain in-process object; a multi-host deployment puts
 a gRPC server in front of the same interface (the TaskCommunicator service
 plugin seam).  Heartbeats batch task events up and pull routed input events
 down, exactly like TezHeartbeatRequest/Response.
+
+The heartbeat's period is the liveness period, not the clock on which events
+move: a runner that shares this process registers a waker for its attempt
+(``register_waker``), and whenever something becomes deliverable to a live
+attempt (``wake_vertex``, ``deliver_custom_events``, ``kill_attempt``) the AM
+calls it, so the reporter beats now.  The response is built by the same ``_pull_events`` either way.
 """
 from __future__ import annotations
 
 import dataclasses
 import logging
 import threading
-from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from tez_tpu.api.events import TezAPIEvent, TezEvent
 from tez_tpu.am.events import (TaskAttemptEvent, TaskAttemptEventType,
@@ -47,17 +53,27 @@ class HeartbeatRequest:
 class HeartbeatResponse:
     events: List[TezAPIEvent]
     should_die: bool = False
+    #: the pull stopped short of what the AM holds for this attempt (cut by
+    #: tez.task.max-event-backlog, or more arrived meanwhile): beat again now
+    more: bool = False
+    #: per event, the epoch second it became routable at the AM (0.0 = it
+    #: was there before the attempt); the runner's am.task.event_wait
+    routable_s: Optional[List[float]] = None
 
 
 class _AttemptSession:
     __slots__ = ("edge_seqs", "killed", "last_heartbeat", "custom_events",
-                 "custom_seq", "last_progress", "last_activity")
+                 "custom_stamps", "last_progress", "last_activity", "waker")
 
     def __init__(self) -> None:
         self.edge_seqs: Dict[str, int] = {}
         self.killed = False
         self.last_heartbeat = clock.wall_s()
         self.custom_events: List[TezAPIEvent] = []
+        self.custom_stamps: List[float] = []
+        # set by a runner that shares this process (register_waker): makes
+        # its reporter heartbeat now instead of at the end of its sleep
+        self.waker: Optional[Callable[[], None]] = None
         # progress-stuck detection (TaskHeartbeatHandler progress check):
         # an attempt that heartbeats but whose progress never moves and
         # which generates no events is hung, not alive
@@ -198,8 +214,9 @@ class TaskCommunicatorManager:
             self.ctx.dispatch(TaskAttemptEvent(
                 TaskAttemptEventType.TA_STATUS_UPDATE, request.attempt_id,
                 counters=request.counters, progress=request.progress))
-        events = self._pull_events(request.attempt_id, session)
-        return HeartbeatResponse(events=events, should_die=session.killed)
+        events, stamps, more = self._pull_events(request.attempt_id, session)
+        return HeartbeatResponse(events=events, should_die=session.killed,
+                                 more=more, routable_s=stamps)
 
     def can_commit(self, attempt_id: TaskAttemptId, epoch: int = 0,
                    window_id: int = 0, stream: str = "") -> bool:
@@ -259,12 +276,24 @@ class TaskCommunicatorManager:
             s = self._sessions.get(attempt_id)
         return s.killed if s is not None else True
 
+    def register_waker(self, attempt_id: TaskAttemptId,
+                       waker: Callable[[], None]) -> None:
+        """In-process runners only (a RemoteUmbilical has no such method and
+        its runner keeps to the interval): ``waker`` is called, from
+        whichever AM thread made something deliverable, to have the
+        attempt's reporter heartbeat now.  It must not block."""
+        with self._lock:
+            s = self._sessions.get(attempt_id)
+            if s is not None:
+                s.waker = waker
+
     # -- AM-facing -----------------------------------------------------------
     def kill_attempt(self, attempt_id: TaskAttemptId) -> None:
         with self._lock:
             s = self._sessions.get(attempt_id)
             if s is not None:
                 s.killed = True
+        self._wake([s])
 
     def deliver_custom_events(self, attempt_id: TaskAttemptId,
                               events: Sequence[TezAPIEvent]) -> None:
@@ -272,6 +301,25 @@ class TaskCommunicatorManager:
             s = self._sessions.get(attempt_id)
             if s is not None:
                 s.custom_events.extend(events)
+                s.custom_stamps.extend([clock.wall_s()] * len(events))
+        self._wake([s])
+
+    def wake_vertex(self, vertex_id: Any) -> None:
+        """Events became routable to ``vertex_id``'s tasks (a producer's
+        events on an in-edge, root-input events): every live attempt of it
+        heartbeats now.  One pass over the live sessions — no more than
+        the runner slots — per call."""
+        with self._lock:
+            sessions = [s for a, s in self._sessions.items()
+                        if a.vertex_id == vertex_id]
+        self._wake(sessions)
+
+    @staticmethod
+    def _wake(sessions: Sequence[Optional[_AttemptSession]]) -> None:
+        for s in sessions:
+            waker = s.waker if s is not None else None
+            if waker is not None:
+                waker()
 
     def sessions_snapshot(self) -> Dict[TaskAttemptId, float]:
         """Excludes sessions already marked to die — the monitor must not
@@ -319,21 +367,27 @@ class TaskCommunicatorManager:
             if dag is not None else None
 
     def _pull_events(self, attempt_id: TaskAttemptId,
-                     session: _AttemptSession) -> List[TezAPIEvent]:
+                     session: _AttemptSession
+                     ) -> Tuple[List[TezAPIEvent], List[float], bool]:
+        """(events, the second each became routable, more left behind)."""
         vertex = self._vertex_for(attempt_id)
         if vertex is None:
-            return []
+            return [], [], False
         # bound one heartbeat response (tez.task.max-event-backlog): a
         # 10k-source fan-in must stream events across heartbeats, not ship
         # one giant response that stalls the umbilical
         from tez_tpu.common import config as C
         max_events = int(self.ctx.conf.get(C.TASK_MAX_EVENT_BACKLOG)) \
             if getattr(self.ctx, "conf", None) is not None else 0
+        stamps: List[float] = []
         out = vertex.get_task_events(attempt_id.task_id.id,
                                      session.edge_seqs,
-                                     max_events=max_events)
+                                     max_events=max_events, stamps=stamps)
+        more = vertex.has_task_events(session.edge_seqs)
         with self._lock:
             if session.custom_events:
                 out.extend(("__custom__", ev) for ev in session.custom_events)
+                stamps.extend(session.custom_stamps)
                 session.custom_events = []
-        return out
+                session.custom_stamps = []
+        return out, stamps, more

@@ -307,7 +307,12 @@ class TaskImpl:
             return self.successful_attempt == attempt_id
         if self.commit_attempt is None:
             att = self.attempts.get(attempt_id.id)
-            if att is None or att.state is not TaskAttemptState.RUNNING:
+            # asked from the runner's thread: an attempt that finishes
+            # within milliseconds asks while its TA_STARTED_REMOTELY still
+            # waits on the dispatcher (state SUBMITTED).  Only an attempt
+            # the AM has ended may not commit — a refusal leaves the
+            # output unpublished
+            if att is None or att.state in TERMINAL_ATTEMPT_STATES:
                 return False
             self.commit_attempt = attempt_id
         return self.commit_attempt == attempt_id

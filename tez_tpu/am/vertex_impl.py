@@ -724,6 +724,7 @@ class VertexImpl:
             edge = self.out_edges.get(src.edge_vertex_name) if src else None
             if edge is not None:
                 edge.add_source_event(src_task, version, ev)
+                self.dag.notify_new_edge_events(edge)
         elif isinstance(ev, VertexManagerEvent):
             target = self.dag.vertex_by_name(ev.target_vertex_name)
             if target is not None and target.vertex_manager is not None:
@@ -764,13 +765,16 @@ class VertexImpl:
     # ------------------------------------------------ consumer event pull
     def get_task_events(self, task_index: int,
                         seqs: Dict[str, int],
-                        max_events: int = 0) -> List[tuple]:
+                        max_events: int = 0,
+                        stamps: Optional[List[float]] = None) -> List[tuple]:
         """Pull routed events for one of this vertex's tasks as
         (input_name, event) pairs.  ``seqs`` maps in-edge id -> consumed
         high-water mark, updated in place.  ``max_events`` > 0 bounds one
         pull (tez.task.max-event-backlog): the high-water marks only
         advance past what was returned, so the remainder arrives on later
-        heartbeats instead of one giant response."""
+        heartbeats instead of one giant response.  ``stamps``, when given,
+        gains an entry an event: the second it became routable (0.0 for a
+        root-input event, which is there before any attempt)."""
         out: List[tuple] = []
         for edge in self.in_edges.values():
             if max_events and len(out) >= max_events:
@@ -778,18 +782,29 @@ class VertexImpl:
             seq = seqs.get(edge.id, 0)
             limit = max_events - len(out) if max_events else 0
             events, new_seq = edge.get_events_for_task(task_index, seq,
-                                                       max_events=limit)
+                                                       max_events=limit,
+                                                       stamps=stamps)
             seqs[edge.id] = new_seq
             out.extend((edge.source_vertex.name, e) for e in events)
-        # root input events, delivered once
-        key = "__root__"
-        if not seqs.get(key):
-            for name, events in self.root_input_events.items():
-                for ev in events:
-                    if ev.target_index == task_index:
-                        out.append((name, ev))
-            seqs[key] = 1
+        # root input events: each once, a high-water mark an input (a
+        # vertex manager may add more while the task runs)
+        for name, events in self.root_input_events.items():
+            key = "__root__" + name
+            seen = seqs.get(key, 0)
+            for ev in events[seen:]:
+                if ev.target_index == task_index:
+                    out.append((name, ev))
+                    if stamps is not None:
+                        stamps.append(0.0)
+            seqs[key] = len(events)
         return out
+
+    def has_task_events(self, seqs: Dict[str, int]) -> bool:
+        """Does any in-edge's log reach past ``seqs``' high-water mark: a
+        pull cut by ``max_events`` left entries behind, or a producer added
+        some since."""
+        return any(edge.source_event_count() > seqs.get(edge.id, 0)
+                   for edge in self.in_edges.values())
 
     # ---------------------------------------------------------- task specs
     def build_task_spec(self, attempt_id: TaskAttemptId) -> TaskSpec:

@@ -135,6 +135,7 @@ class _VMContext(VertexManagerPluginContext):
             self, input_name: str,
             events: Sequence[InputDataInformationEvent]) -> None:
         self.vertex.root_input_events.setdefault(input_name, []).extend(events)
+        self.vertex.ctx.wake_vertex_tasks(self.vertex.vertex_id)
 
     def get_total_available_resource(self) -> int:
         return self.vertex.ctx.total_slots()

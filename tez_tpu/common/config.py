@@ -447,8 +447,11 @@ TASK_MAX_EVENT_BACKLOG = _key(
     "later heartbeats (reference: TezTaskAttemptListener maxEventsToGet)")
 TASK_AM_HEARTBEAT_INTERVAL_MS = _key(
     "tez.task.am.heartbeat.interval-ms", 50, Scope.VERTEX,
-    "TaskReporter heartbeat period (reference: "
-    "tez.task.am.heartbeat.interval-ms.max)")
+    "TaskReporter liveness period: progress, counters, should_die and the "
+    "epoch/window fences move at this period; events for a live attempt do "
+    "not wait for it (the AM wakes an in-process runner's reporter when it "
+    "has some; a remote runner finds them at its next beat). Reference: "
+    "tez.task.am.heartbeat.interval-ms.max")
 COUNTERS_MAX = _key("tez.counters.max", 1200, Scope.AM,
                     "Counter-per-group cap (Limits.java)")
 COUNTERS_MAX_GROUPS = _key("tez.counters.max.groups", 500, Scope.AM,

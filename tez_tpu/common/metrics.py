@@ -156,6 +156,11 @@ WELL_KNOWN_HISTOGRAMS = ("shuffle.fetch.rtt", "spill.write", "shuffle.merge",
                          # attempt scheduled -> a runner thread picks it up
                          # (the am.task.queue span's duration)
                          "am.task.queue_wait",
+                         # event delivery (runtime/task_runner.py): the AM
+                         # made an event routable to a live attempt (or the
+                         # attempt started, for one that was there first) ->
+                         # the runner hands it to the input
+                         "am.task.event_wait",
                          # flight recorder (obs/flight.py): one snapshot
                          # serialize + atomic write when a dump trigger
                          # (DAG failure, breaker-open, watchdog, shed) fires

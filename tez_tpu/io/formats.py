@@ -12,6 +12,7 @@ in the descriptor payload.
 from __future__ import annotations
 
 import os
+import threading
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import dataclasses
@@ -387,33 +388,34 @@ class MRInput(LogicalInput):
         self._format = resolve_format(payload.get("format", "text"),
                                       payload.get("format_params"))
         self._splits: List[FileSplit] = []
-        self._has_split_event = False
+        self._has_split_event = threading.Event()
         if payload.get("static_splits"):
             self._splits = list(payload["static_splits"])
-            self._has_split_event = True
+            self._has_split_event.set()
         return []
 
     def handle_events(self, events: Sequence[TezAPIEvent]) -> None:
         for ev in events:
             if isinstance(ev, InputDataInformationEvent):
                 self._splits.extend(ev.user_payload or [])
-                self._has_split_event = True
+                self._has_split_event.set()
                 total = sum(s.length for s in ev.user_payload or [])
                 self.context.counters.increment(
                     TaskCounter.INPUT_SPLIT_LENGTH_BYTES, total)
 
     def _wait_splits(self) -> None:
         import time
-        if self._has_split_event:
+        if self._has_split_event.is_set():
             return
-        # the task is up before its root-input event: it polls until a
-        # heartbeat brings the splits (device idle meanwhile: a named wait)
+        # the reader is asked for before handle_events has seen the
+        # root-input event (it comes with the reporter's first beat, and is
+        # replayed when initialize ends): a named wait, over as the event
+        # is handed in.  The turns only serve the kill check and the deadline.
         deadline = time.time() + 60
         with tracing.span("input.wait_splits", cat="task"):
-            while not self._has_split_event:
+            while not self._has_split_event.wait(0.05):
                 if time.time() > deadline:
                     raise TimeoutError("no split event received")
-                time.sleep(0.01)
                 self.context.notify_progress()
 
     def get_reader(self) -> Reader:

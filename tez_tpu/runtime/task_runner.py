@@ -4,6 +4,11 @@ Reference parity: tez-runtime-internals/.../runtime/
 LogicalIOProcessorRuntimeTask.java:169 (initialize :234, run :378, close :385)
 + TezTaskRunner2 (kill/abort races) + TaskReporter.java:79 (heartbeat thread
 batching events/counters, receiving routed events back).
+
+The reporter beats once as it starts, then every interval (liveness), and in
+between whenever it is woken: by the AM, through the waker an in-process
+umbilical lets it register (something became deliverable to this attempt),
+or by a response that says the pull left events behind.
 """
 from __future__ import annotations
 
@@ -63,6 +68,11 @@ class TaskRunner:
         self._event_lock = threading.Lock()
         self._killed = threading.Event()
         self._done = threading.Event()
+        # the reporter sleeps on this: set by the AM's waker, by a response
+        # with more to pull, and by _done; wakes that arrive while a beat
+        # is in flight coalesce into the one beat that follows it
+        self._wake = threading.Event()
+        self._start_s = 0.0
         self._fatal: Optional[Tuple[BaseException | None, str]] = None
         # Incoming events arriving before IO initialize() completes are
         # trapped and replayed (reference: TezTrapEventHandler).  The
@@ -71,6 +81,7 @@ class TaskRunner:
         self._inputs_ready = threading.Event()
         self._dispatch_lock = threading.Lock()
         self._trapped_incoming: List[Tuple[str, TezAPIEvent]] = []
+        self._trapped_stamps: List[float] = []
 
     # -- called by contexts --------------------------------------------------
     def enqueue_events(self, events: Sequence[TezEvent]) -> None:
@@ -88,7 +99,7 @@ class TaskRunner:
     # -- lifecycle -----------------------------------------------------------
     def run(self) -> str:
         """Returns final state string: SUCCEEDED | FAILED | KILLED."""
-        start = time.time()
+        start = self._start_s = time.time()
         from tez_tpu.runtime.diagnostics import (RuntimeStatsUpdater,
                                                  ThreadDumpHelper)
         stats = RuntimeStatsUpdater(self.counters)
@@ -134,6 +145,7 @@ class TaskRunner:
                 f"{type(e).__name__}: {e}\n{traceback.format_exc(limit=20)}")
         finally:
             self._done.set()
+            self._wake.set()
             dumper.stop()
             reporter.join(timeout=5)
         stats.update(final=True)
@@ -213,9 +225,10 @@ class TaskRunner:
         # replay are atomic w.r.t. heartbeat deliveries)
         with self._dispatch_lock:
             trapped, self._trapped_incoming = self._trapped_incoming, []
+            stamps, self._trapped_stamps = self._trapped_stamps, []
             self._inputs_ready.set()
             if trapped:
-                self._dispatch_incoming(trapped)
+                self._dispatch_incoming(trapped, stamps)
 
     def _try_reuse(self) -> bool:
         """Cross-DAG output reuse: when EVERY output reports a sealed store
@@ -274,29 +287,40 @@ class TaskRunner:
             return out
 
     def _heartbeat_loop(self) -> None:
-        from tez_tpu.am.task_comm import HeartbeatRequest
         try:
             interval = float(self.spec.conf.get(
                 "tez.task.am.heartbeat.interval-ms",
                 HEARTBEAT_INTERVAL * 1000)) / 1000.0
         except (TypeError, ValueError):
             interval = HEARTBEAT_INTERVAL
-        while not self._done.wait(interval):
+        # an umbilical that shares the AM's process takes a waker; a remote
+        # one (one framed connection, shared with can_commit and task_done)
+        # does not, and its reporter keeps to the interval
+        register = getattr(self.umbilical, "register_waker", None)
+        if register is not None:
+            register(self.spec.attempt_id, self._wake.set)
+        woken = False       # the first beat goes out at once, by no wake
+        while not self._done.is_set():
+            self._wake.clear()
             try:
-                self._heartbeat_once()
+                self._heartbeat_once(woken)
             except BaseException:  # noqa: BLE001
                 log.exception("heartbeat failed for %s", self.spec.attempt_id)
                 self._killed.set()
                 return
+            woken = self._wake.wait(interval)
         # final pull-free flush happens via task_done/task_failed
 
-    def _heartbeat_once(self) -> None:
+    def _heartbeat_once(self, woken: bool = False) -> None:
         from tez_tpu.am.task_comm import HeartbeatRequest
         req = HeartbeatRequest(self.spec.attempt_id, self._drain_events(),
                                counters=None, progress=self.progress,
                                epoch=getattr(self.spec, "am_epoch", 0),
                                window_id=getattr(self.spec, "window_id", 0),
                                stream=getattr(self.spec, "stream", ""))
+        if woken:
+            self.counters.find_counter(
+                "TaskUmbilical", "am.heartbeat.woken").increment(1)
         t0 = time.perf_counter()
         resp = self.umbilical.heartbeat(req)
         metrics.observe("am.heartbeat.rtt",
@@ -305,13 +329,26 @@ class TaskRunner:
         if resp.should_die:
             self._killed.set()
         if resp.events:
+            stamps = resp.routable_s or [0.0] * len(resp.events)
             with self._dispatch_lock:
                 if not self._inputs_ready.is_set():
                     self._trapped_incoming.extend(resp.events)
+                    self._trapped_stamps.extend(stamps)
                 else:
-                    self._dispatch_incoming(resp.events)
+                    self._dispatch_incoming(resp.events, stamps)
+        if resp.more:
+            self._wake.set()
 
-    def _dispatch_incoming(self, events: List[Tuple[str, TezAPIEvent]]) -> None:
+    def _dispatch_incoming(self, events: List[Tuple[str, TezAPIEvent]],
+                           routable_s: Sequence[float] = ()) -> None:
+        # am.task.event_wait: the AM made the event routable (or, for one
+        # that was there first, this attempt started) -> handed over here
+        now = time.time()
+        for stamp in routable_s:
+            metrics.observe(
+                "am.task.event_wait",
+                max(0.0, now - max(stamp, self._start_s)) * 1000.0,
+                counters=self.counters)
         by_input: Dict[str, List[TezAPIEvent]] = {}
         for input_name, ev in events:
             if isinstance(ev, CustomProcessorEvent):

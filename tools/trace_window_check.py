@@ -18,7 +18,12 @@ at ``label_gap``     for each idle gap the reducer labels, every span name's
     that one)
 at ``result_line``   the window's DAGs as the harness observed them: the
     ``MeshExchange`` counters a DAG (``exchange_counters_a_dag``; empty in
-    a cell with no mesh edge), which no metric reports one by one
+    a cell with no mesh edge), which no metric reports one by one, and
+    ``event_delivery_a_dag``: histogram ``am.task.event_wait`` (events a
+    DAG, p50 / p95 / highest bucket in ms: the AM had the event -> the
+    runner handed it to the input) beside the beats a DAG
+    (``am.heartbeat.rtt``'s count) and how many of them a wake sent
+    (counter ``am.heartbeat.woken``)
 
 and, after the run, reads the span buffer for
 
@@ -29,6 +34,12 @@ spans_a_dag  spans of the window over the DAGs that started in it, by name
 self_s_a_dag ``trace_reduce.self_intervals`` over the window's
              ``program_spans()``, by name, over those DAGs: what a task's
              wall is made of
+timeline_ms  where in a DAG each phase stands: for every ``<vertex>/<span
+             name>`` (the vertex of the attempt a span's parent chain ends
+             in, ``am`` for the AM's own), the median over the window's DAGs
+             of its first start and its last end, in ms from the root
+             span's start, in the order they begin — what the head of a DAG
+             and each stage boundary are made of
 exchange_self_s_a_dag  the same self time for the ``exchange.*`` spans
              alone, each named with the argument that says which of its
              sites it is (``stage``, else ``what``, else ``device``: a
@@ -109,6 +120,55 @@ def exchange_site(span) -> str:
     return span.name
 
 
+def timeline(spans, roots, marks) -> dict:
+    """``<vertex>/<name>`` -> [first start, last end], ms from the DAG's
+    start, medians over the DAGs that began in the window."""
+    by_id = {s.span_id: s for s in spans}
+
+    def vertex(span) -> str:
+        while span is not None:
+            if span.name.startswith("attempt:"):
+                return span.args.get("vertex", "?")
+            span = by_id.get(span.parent_id)
+        return "am"
+
+    per_dag = collections.defaultdict(dict)
+    for s in spans:
+        root = roots.get(s.trace_id)
+        if root is None or s is root or \
+                not marks["start"] <= root.start <= marks["stop"]:
+            continue
+        key = f"{vertex(s)}/{s.name.split(':')[0]}"
+        at = s.start - root.start, s.end - root.start
+        first, last = per_dag[key].get(s.trace_id, at)
+        per_dag[key][s.trace_id] = min(first, at[0]), max(last, at[1])
+    rows = {key: [round(1e3 * statistics.median(v[i] for v in dags.values()),
+                        1) for i in (0, 1)]
+            for key, dags in per_dag.items()}
+    return dict(sorted(rows.items(), key=lambda kv: kv[1]))
+
+
+def event_delivery(dags) -> dict:
+    """The window's ``am.task.event_wait`` and heartbeat counts, a DAG."""
+    from tez_tpu.common import metrics
+    groups = collections.defaultdict(collections.Counter)
+    for dag in dags:
+        for group, counters in dag["counters"].items():
+            if group == "TaskUmbilical" or group.startswith(
+                    metrics.HIST_GROUP_PREFIX + "am."):
+                groups[group].update(counters)
+    hists = metrics.histograms_from_counters(groups)
+    wait = hists.get("am.task.event_wait", {})
+    n = len(dags)
+    return {
+        "events": wait.get("count", 0) / n,
+        "event_wait_ms": {k: wait.get(k) for k in ("p50", "p95", "max_ms")},
+        "event_wait_mean_ms": wait.get("sum_us", 0) / 1e3 / max(
+            1, wait.get("count", 0)),
+        "beats": hists.get("am.heartbeat.rtt", {}).get("count", 0) / n,
+        "woken": groups["TaskUmbilical"].get("am.heartbeat.woken", 0) / n}
+
+
 def main() -> int:
     import run as bench_run
     import trace_reduce
@@ -152,6 +212,7 @@ def main() -> int:
             totals.update(dag["counters"].get("MeshExchange", {}))
         found["exchange_counters_a_dag"] = {
             k: v / len(dags) for k, v in sorted(totals.items())}
+        found["event_delivery_a_dag"] = event_delivery(dags)
         return result_line(args, spec, devices, res)
 
     trace_reduce.reduce_trace = reduce_and_keep
@@ -201,6 +262,7 @@ def main() -> int:
                          for k, v in self_s.most_common()},
         "exchange_self_s_a_dag": {k: round(v / dags, 4)
                                   for k, v in by_site.most_common()},
+        "timeline_ms": timeline(spans, roots, marks),
         "merge_program_events": dict(events),
         "merge_program_events_a_dag": sum(events.values()) / dags,
         **found}

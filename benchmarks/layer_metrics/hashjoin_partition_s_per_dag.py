@@ -1,0 +1,6 @@
+"""hashjoin_partition_s_per_dag: see hashjoin_partition_s_per_dag.json."""
+import span_metrics
+
+
+def read(obs):
+    return span_metrics.self_s_per_dag(obs, ("unordered.partition",))

@@ -135,6 +135,14 @@ class TaskCounter(enum.Enum):
     JOIN_OUTPUT_RECORDS = enum.auto()
     JOIN_MATCH_ROWS = enum.auto()
     JOIN_MATCH_LAUNCHES = enum.auto()
+    # unordered output (library/unordered.py): rows that came by
+    # write_batch and were placed by partition natively (a one-partition,
+    # broadcast, output places them where they are) -- a per-record writer
+    # moves it not.  The batch hash join (library/join.py hash_join_blocks)
+    # reuses the JOIN_* names: LEFT = stream rows probed, RIGHT = build
+    # rows (once a joiner), MATCH_ROWS = the rows of a probe block and of
+    # the build handed to the device probe, a launch
+    UNORDERED_PARTITION_RECORDS = enum.auto()
 
 
 # Mesh ICI exchange plane (parallel/coordinator.py): string-named counters

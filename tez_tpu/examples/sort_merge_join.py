@@ -75,6 +75,21 @@ class VectorForwardingProcessor(SimpleProcessor):
             writer.write_batch(batch)
 
 
+def format_key_lines(keys, sep: bytes):
+    """A batch of keys as ``key<sep>1`` lines (uint8 array): one ragged
+    gather over [key rows, the line's tail]."""
+    import numpy as np
+    from tez_tpu.ops.runformat import gather_ragged
+    n = keys.num_records
+    tail = np.frombuffer(sep + b"1\n", np.uint8)
+    pool_bytes = np.concatenate([keys.key_bytes, tail])
+    pool_offsets = np.append(keys.key_offsets,
+                             keys.key_offsets[-1] + len(tail))
+    perm = np.full(2 * n, n, dtype=np.int64)   # key_i, tail, ...
+    perm[0::2] = np.arange(n)
+    return gather_ragged(pool_bytes, pool_offsets, perm)[0]
+
+
 class VectorSortMergeJoinProcessor(SimpleProcessor):
     """The keys both sorted inputs hold, each once, as ``key<sep>1`` lines:
     the batch merge-join over both inputs' sorted blocks, and one ragged
@@ -82,34 +97,28 @@ class VectorSortMergeJoinProcessor(SimpleProcessor):
 
     def run(self, inputs: Dict[str, LogicalInput],
             outputs: Dict[str, LogicalOutput]) -> None:
-        import numpy as np
         from tez_tpu.library.join import merge_join_blocks, open_sorted_inputs
-        from tez_tpu.ops.runformat import gather_ragged
         left, right = (inputs[side] for side in SIDES)
         writer = outputs["output"].get_writer()
-        tail = np.frombuffer(getattr(writer, "sep", b"\t") + b"1\n", np.uint8)
+        sep = getattr(writer, "sep", b"\t")
         for keys in merge_join_blocks(
                 *open_sorted_inputs(left, right), key_width=left.key_width,
                 engine=left.merge_engine,
                 device_min_records=left.merge_min_records,
                 counters=self.context.counters):
-            n = keys.num_records
-            with tracing.span("processor.format", cat="task", rows=n):
-                pool_bytes = np.concatenate([keys.key_bytes, tail])
-                pool_offsets = np.append(
-                    keys.key_offsets, keys.key_offsets[-1] + len(tail))
-                perm = np.full(2 * n, n, dtype=np.int64)   # key_i, tail, ...
-                perm[0::2] = np.arange(n)
-                lines, _ = gather_ragged(pool_bytes, pool_offsets, perm)
-            writer.write_raw(memoryview(lines), n)
+            with tracing.span("processor.format", cat="task",
+                              rows=keys.num_records):
+                lines = format_key_lines(keys, sep)
+            writer.write_raw(memoryview(lines), keys.num_records)
             self.context.notify_progress()
 
 
-def _build_vector_dag(left_paths, right_paths, output_path: str,
-                      num_joiners: int, side_parallelism: int,
-                      key_width: int) -> DAG:
-    joiner = Vertex.create("joiner", ProcessorDescriptor.create(
-        VectorSortMergeJoinProcessor), num_joiners)
+def joiner_with_file_sink(processor, num_joiners: int,
+                          output_path: str) -> Vertex:
+    """The ``joiner`` vertex of a batch join DAG, its FileOutput sink
+    committed once."""
+    joiner = Vertex.create("joiner", ProcessorDescriptor.create(processor),
+                           num_joiners)
     joiner.add_data_sink("output", DataSinkDescriptor.create(
         OutputDescriptor.create("tez_tpu.io.file_output:FileOutput",
                                 payload={"path": output_path,
@@ -118,6 +127,38 @@ def _build_vector_dag(left_paths, right_paths, output_path: str,
         OutputCommitterDescriptor.create(
             "tez_tpu.io.file_output:FileOutputCommitter",
             payload={"path": output_path})))
+    return joiner
+
+
+def forwarding_scanner(name: str, paths, parallelism: int) -> Vertex:
+    """A vertex of VectorForwardingProcessor tasks over `paths` as text
+    splits."""
+    scanner = Vertex.create(name, ProcessorDescriptor.create(
+        VectorForwardingProcessor), parallelism)
+    scanner.add_data_source("input", DataSourceDescriptor.create(
+        InputDescriptor.create("tez_tpu.io.text:TextInput"),
+        InputInitializerDescriptor.create(
+            "tez_tpu.io.text:TextSplitGenerator",
+            payload={"paths": list(paths),
+                     "desired_splits": parallelism})))
+    return scanner
+
+
+def paths_by_directory(inputs, directories) -> Dict[str, list]:
+    """`inputs` grouped by the directory a path is (or lies in); a path
+    under none of `directories` raises KeyError."""
+    found: Dict[str, list] = {d: [] for d in directories}
+    for path in inputs:
+        where = path if os.path.isdir(path) else os.path.dirname(path)
+        found[os.path.basename(os.path.normpath(where))].append(path)
+    return found
+
+
+def _build_vector_dag(left_paths, right_paths, output_path: str,
+                      num_joiners: int, side_parallelism: int,
+                      key_width: int) -> DAG:
+    joiner = joiner_with_file_sink(VectorSortMergeJoinProcessor, num_joiners,
+                                   output_path)
     dag = DAG.create("SortMergeJoin")
     dag.add_vertex(joiner)
     # both edges partition by the same hash into the same partition count,
@@ -125,14 +166,7 @@ def _build_vector_dag(left_paths, right_paths, output_path: str,
     edge = OrderedPartitionedKVEdgeConfig.new_builder("bytes", "bytes")\
         .set_key_width(key_width).build()
     for side, paths in zip(SIDES, (left_paths, right_paths)):
-        scanner = Vertex.create(side, ProcessorDescriptor.create(
-            VectorForwardingProcessor), side_parallelism)
-        scanner.add_data_source("input", DataSourceDescriptor.create(
-            InputDescriptor.create("tez_tpu.io.text:TextInput"),
-            InputInitializerDescriptor.create(
-                "tez_tpu.io.text:TextSplitGenerator",
-                payload={"paths": list(paths),
-                         "desired_splits": side_parallelism})))
+        scanner = forwarding_scanner(side, paths, side_parallelism)
         dag.add_vertex(scanner)
         dag.add_edge(Edge.create(scanner, joiner,
                                  edge.create_default_edge_property()))
@@ -159,10 +193,7 @@ def build_bench_dag(inputs, out_dir: str, **kwargs):
     """The benchmark harness's builder: `inputs` holds both sides' paths,
     told apart by the directory a path is (or lies in): ``left``,
     ``right``."""
-    by_side: Dict[str, list] = {side: [] for side in SIDES}
-    for path in inputs:
-        where = path if os.path.isdir(path) else os.path.dirname(path)
-        by_side[os.path.basename(os.path.normpath(where))].append(path)
+    by_side = paths_by_directory(inputs, SIDES)
     return build_dag(by_side["left"], by_side["right"], out_dir, **kwargs)
 
 

@@ -30,6 +30,13 @@ class _BaseEdgeConfigBuilder:
         self.conf[key] = value
         return self
 
+    def set_key_width(self, width: int) -> "_BaseEdgeConfigBuilder":
+        """The edge's key lanes hold keys up to `width` bytes whole: what an
+        ordered edge sorts by, a mesh edge ships, and an unordered edge's
+        consumer may encode by (library/join.py)."""
+        self.conf["tez.runtime.tpu.key.width.bytes"] = width
+        return self
+
     def set_from_configuration(self, conf: Dict[str, Any]
                                ) -> "_BaseEdgeConfigBuilder":
         for k, v in conf.items():
@@ -92,10 +99,6 @@ class OrderedPartitionedKVEdgeConfig(_BaseEdgeConfigBuilder):
         self.conf["tez.runtime.combiner.class"] = combiner
         return self
 
-    def set_key_width(self, width: int) -> "OrderedPartitionedKVEdgeConfig":
-        self.conf["tez.runtime.tpu.key.width.bytes"] = width
-        return self
-
     def set_pipelined(self, enabled: bool = True
                       ) -> "OrderedPartitionedKVEdgeConfig":
         self.conf["tez.runtime.pipelined-shuffle.enabled"] = enabled
@@ -148,11 +151,6 @@ class MeshOrderedPartitionedKVEdgeConfig(_BaseEdgeConfigBuilder):
     def new_builder(key_serde: str = "bytes", value_serde: str = "bytes"
                     ) -> "MeshOrderedPartitionedKVEdgeConfig":
         return MeshOrderedPartitionedKVEdgeConfig(key_serde, value_serde)
-
-    def set_key_width(self, width: int
-                      ) -> "MeshOrderedPartitionedKVEdgeConfig":
-        self.conf["tez.runtime.tpu.key.width.bytes"] = width
-        return self
 
     def set_value_width(self, width: int
                         ) -> "MeshOrderedPartitionedKVEdgeConfig":

@@ -9,8 +9,17 @@ match (ops/device.py ``join_match``: one stable sort of the two sides'
 lanes concatenated, then a neighbour compare), and the matching keys come
 out as a KVBatch with zero-width values.
 
-Only ``how="semi_distinct"`` -- what the source runs.  ``inner`` and
-``semi`` stay with the query layer's row path (query/processors.py).
+Only ``how="semi_distinct"`` -- what the source runs.  ``inner`` stays with
+the query layer's row path (query/processors.py).
+
+Beside it the batch hash join (HashJoinExample.java's HashJoinProcessor:
+the hash side read whole into a set, the stream side walked past it, a key
+written whenever the set holds it): ``hash_join_blocks``.  The build side
+is collected whole, encoded once and kept on the device; the stream side
+is cut into probe blocks by row count, each matched against the build
+(ops/device.py ``join_probe``: the match's sort, then every stream row
+whose run of equal keys holds a build row), the matching stream rows
+emitted.  Only ``how="semi"``, for the same reason.
 """
 from __future__ import annotations
 
@@ -139,10 +148,7 @@ def merge_join_blocks(left_blocks: Iterable[KVBatch],
                           counters)
             if len(hits):
                 with tracing.span("join.emit", cat="join", rows=len(hits)):
-                    keys, offsets = gather_ragged(
-                        pieces[0].key_bytes, pieces[0].key_offsets, hits)
-                    out = KVBatch(keys, offsets, np.zeros(0, np.uint8),
-                                  np.zeros(len(hits) + 1, np.int64))
+                    out = _key_batch(pieces[0], hits)
                 emitted += len(hits)
                 yield out
         if not growing:
@@ -152,6 +158,128 @@ def merge_join_blocks(left_blocks: Iterable[KVBatch],
     if counters is not None:
         counters.increment(TaskCounter.JOIN_LEFT_RECORDS, left.consumed)
         counters.increment(TaskCounter.JOIN_RIGHT_RECORDS, right.consumed)
+        counters.increment(TaskCounter.JOIN_OUTPUT_RECORDS, emitted)
+
+
+#: stream rows a probe block: with the build side's bucket it is the probe
+#: program's compile key, so it is cut by row count, never by which fetch
+#: came first
+PROBE_BLOCK_ROWS = 1 << 20
+
+
+def _key_batch(batch: KVBatch, rows: np.ndarray) -> KVBatch:
+    """The keys of `rows` of `batch`, with zero-width values."""
+    keys, offsets = gather_ragged(batch.key_bytes, batch.key_offsets, rows)
+    return KVBatch(keys, offsets, np.zeros(0, np.uint8),
+                   np.zeros(len(rows) + 1, np.int64))
+
+
+def _probe_blocks(batches: Iterable[KVBatch], block_rows: int
+                  ) -> Iterator[KVBatch]:
+    """The stream's rows in arrival order as blocks of exactly `block_rows`
+    (the last one shorter), whatever the sizes the batches come in."""
+    pending: list = []
+    rows = block = 0
+    stream = iter(batches)
+    while True:
+        with tracing.span("join.probe", cat="join", block=block) as span:
+            while rows < block_rows:
+                batch = next(stream, None)
+                if batch is None:
+                    break
+                if batch.num_records:
+                    pending.append(batch)
+                    rows += batch.num_records
+            if not rows:
+                return
+            # the last batch gives what the block still lacks; its other
+            # rows wait for the next block
+            last, over = pending[-1], max(rows - block_rows, 0)
+            keep = last.num_records - over
+            pieces = pending[:-1] + [last.slice_rows(0, keep)]
+            piece = pieces[0] if len(pieces) == 1 else KVBatch.concat(pieces)
+            pending = [last.slice_rows(keep, last.num_records)] if over \
+                else []
+            rows = over
+            span.annotate(rows=piece.num_records)
+        yield piece
+        block += 1
+
+
+def hash_join_blocks(build_batches: Iterable[KVBatch],
+                     stream_batches: Iterable[KVBatch], how: str = "semi",
+                     key_width: int = 16, engine: str = "auto",
+                     device_min_records: int = DEVICE_SORT_MIN_RECORDS,
+                     counters: Optional[TezCounters] = None,
+                     block_rows: int = PROBE_BLOCK_ROWS
+                     ) -> Iterator[KVBatch]:
+    """Yield the stream rows whose key the build side holds -- every
+    occurrence, in arrival order -- as KVBatches of keys with zero-width
+    values, a probe block at a time.  `build_batches` is read whole first
+    (an unordered input's ``iter_batches()``), `stream_batches` as it
+    comes; keys may repeat on either side.
+
+    A build side with a key beyond the edge's lane width, or with fewer
+    rows than the routing floor, is probed on the host engine, in lanes as
+    wide as the longest key; so is a block with a stream key beyond the
+    lanes.  An empty build side yields nothing and launches nothing."""
+    if how != "semi":
+        raise ValueError(f"hash_join_blocks does {how!r} not: the batch "
+                         f"operator is semi (query/processors.py has "
+                         f"inner, by row)")
+    engine = resolve_engine(engine)
+    width = ((key_width + 3) // 4) * 4      # the device's lanes: the edge's
+    on_device, build_longest = None, 0
+    with tracing.span("join.build", cat="join") as span:
+        parts = [b for b in build_batches if b.num_records]
+        build = KVBatch.concat(parts) if parts else KVBatch.empty()
+        n_build = build.num_records
+        span.annotate(rows=n_build)
+        if n_build:
+            build_longest = int(np.diff(build.key_offsets).max())
+            if engine == "device" and build_longest <= key_width and \
+                    n_build >= device_min_records:
+                lanes, lens = encode_keys(build.key_bytes, build.key_offsets,
+                                          width)
+                on_device = device.stage_join_build(lanes, lens)
+                uniform = device.uniform_clamped_lengths(lens, width + 1)
+    host_build: dict = {}       # lane width -> the build side encoded at it
+    probed = emitted = 0
+    for block in _probe_blocks(stream_batches, block_rows) if n_build else ():
+        n = block.num_records
+        probed += n
+        longest = int(np.diff(block.key_offsets).max())
+        if on_device is not None and longest <= key_width:
+            with tracing.span("join.match", cat="join", stage="encode",
+                              how="semi", rows=n, engine="device"):
+                lanes, lens = encode_keys(block.key_bytes, block.key_offsets,
+                                          width)
+                same = uniform[0] and device.uniform_clamped_lengths(
+                    lens, width + 1) == uniform
+            hits = device.join_probe(lanes, lens, on_device, uniform=same)
+            if counters is not None:
+                counters.increment(TaskCounter.JOIN_MATCH_ROWS, n + n_build)
+                counters.increment(TaskCounter.JOIN_MATCH_LAUNCHES)
+        else:
+            wide = ((max(longest, build_longest, 1) + 3) // 4) * 4
+            with tracing.span("join.match", cat="join", stage="encode",
+                              how="semi", rows=n, engine="host"):
+                if wide not in host_build:
+                    host_build[wide] = encode_keys(
+                        build.key_bytes, build.key_offsets, wide)
+                sides = encode_keys(block.key_bytes, block.key_offsets,
+                                    wide) + host_build[wide]
+            with tracing.span("join.match", cat="join", stage="host",
+                              how="semi"):
+                hits = device.join_probe_host(*sides)
+        if len(hits):
+            with tracing.span("join.emit", cat="join", rows=len(hits)):
+                out = _key_batch(block, hits)
+            emitted += len(hits)
+            yield out
+    if counters is not None:
+        counters.increment(TaskCounter.JOIN_LEFT_RECORDS, probed)
+        counters.increment(TaskCounter.JOIN_RIGHT_RECORDS, n_build)
         counters.increment(TaskCounter.JOIN_OUTPUT_RECORDS, emitted)
 
 

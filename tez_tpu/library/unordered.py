@@ -8,16 +8,20 @@ direct-write for 1 partition), UnorderedKVOutput (broadcast writer),
 ShuffleManager.java:108 + UnorderedKVReader (streaming consumption as
 fetches complete, no merge).
 
-TPU shape: records batch into spans; a span is partitioned with the device
-hash kernel and grouped with a single-key partition sort pass (one u32 sort
-— no key ordering), yielding the same Run container the shuffle service
-serves.
+TPU shape: records batch into spans -- pre-serialized KVBatches through
+``write_batch``, single records through ``write`` -- and a span is
+partitioned on the host by three native passes with the GIL released: the
+FNV-1a hash of every key (byte-identical to HashPartitioner and the device
+kernel), one counting pass that groups the rows by partition in arrival
+order, one ragged gather that moves them.  No key ordering and no device
+work: this edge's device work is its consumer's (library/join.py).  The
+result is the same Run container the shuffle service serves.  The input
+hands each fetched KVBatch over once, as its fetch completes
+(``iter_batches``), waiting on the fetch table's condition.
 """
 from __future__ import annotations
 
 import logging
-import os
-import threading
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -29,12 +33,13 @@ from tez_tpu.api.events import (CompositeDataMovementEvent,
                                 VertexManagerEvent, pack_empty_partitions)
 from tez_tpu.api.runtime import (KeyValueReader, KeyValuesWriter,
                                  LogicalInput, LogicalOutput, Reader, Writer)
+from tez_tpu.common import tracing
 from tez_tpu.common.counters import TaskCounter
 from tez_tpu.library.inputs import ShuffleFetchTable, _conf_get
 from tez_tpu.library.outputs import output_path_component
-from tez_tpu.ops import device
-from tez_tpu.ops.keycodec import pad_to_matrix
-from tez_tpu.ops.runformat import KVBatch, Run
+from tez_tpu.ops.native import (fnv32_partition_native,
+                                group_by_partition_native)
+from tez_tpu.ops.runformat import KVBatch, Run, gather_ragged
 from tez_tpu.ops.serde import get_serde
 from tez_tpu.ops.sorter import SpanBuffer
 from tez_tpu.shuffle.service import local_shuffle_service
@@ -43,7 +48,7 @@ log = logging.getLogger(__name__)
 
 
 class UnorderedPartitionedWriter:
-    """Hash-partition spans on device; no key sort."""
+    """Hash-partition spans on the host, natively; no key sort."""
 
     def __init__(self, num_partitions: int, span_budget_bytes: int,
                  counters: Any, single_partition_skip_buffer: bool = True):
@@ -64,12 +69,26 @@ class UnorderedPartitionedWriter:
         if self._span.nbytes >= self.span_budget:
             self._partition_span()
 
+    def write_batch(self, batch: KVBatch) -> None:
+        """A KVBatch of PRE-SERIALIZED records joins the span whole (no
+        per-record Python); it may share a span with single records, whose
+        batches then stand before its records."""
+        self._span.add_batch(batch)
+        self._out_records_ctr.increment(batch.num_records)
+        if self._span.nbytes >= self.span_budget:
+            self._partition_span()
+
     def _partition_span(self) -> None:
         if self._span.num_records == 0:
             return
+        by_batch = sum(b.num_records for b in self._span.batches)
         batch = self._span.to_batch()
         self._span = SpanBuffer()
         run = self.partition_batch(batch)
+        # rows that came as batches (a one-partition output places them
+        # where they are): a per-record writer moves this not
+        self.counters.increment(TaskCounter.UNORDERED_PARTITION_RECORDS,
+                                by_batch)
         if self.on_spill is not None:
             self.on_spill(run, self.num_spills)
         else:
@@ -79,22 +98,29 @@ class UnorderedPartitionedWriter:
         self.num_spills += 1
 
     def partition_batch(self, batch: KVBatch) -> Run:
-        if self.num_partitions == 1:
-            # skip-buffer direct path (reference :direct-write mode)
-            return Run(batch, np.array([0, batch.num_records], dtype=np.int64))
-        klens = batch.key_offsets[1:] - batch.key_offsets[:-1]
-        wmax = int(klens.max(initial=1))
-        hash_w = 1 << max(2, (wmax - 1).bit_length())
-        mat, lengths = pad_to_matrix(batch.key_bytes, batch.key_offsets,
-                                     hash_w)
-        partitions = device.hash_partition(mat, lengths, self.num_partitions)
-        # single stable pass groups rows by partition, preserving arrival
-        # order within each partition
-        sorted_parts, perm = device.sort_run(
-            partitions, np.zeros((len(partitions), 0), dtype=np.uint32),
-            np.zeros(len(partitions), dtype=np.int64))
-        return Run.from_sorted_batch(batch.take(perm), sorted_parts,
-                                     self.num_partitions)
+        """The batch's rows grouped by partition, arrival order kept inside
+        each: hash, one counting pass, one gather, all native."""
+        n, parts = batch.num_records, self.num_partitions
+        if parts == 1:
+            # skip-buffer direct path (reference :direct-write mode): the
+            # broadcast output moves nothing
+            return Run(batch, np.array([0, n], dtype=np.int64))
+        with tracing.span("unordered.partition", cat="output", stage="hash",
+                          rows=n, partitions=parts):
+            partitions = fnv32_partition_native(
+                batch.key_bytes, batch.key_offsets, parts)
+        with tracing.span("unordered.partition", cat="output", stage="group",
+                          rows=n, partitions=parts):
+            perm, row_index = group_by_partition_native(partitions, parts)
+        with tracing.span("unordered.partition", cat="output",
+                          stage="gather", rows=n, partitions=parts):
+            keys = gather_ragged(batch.key_bytes, batch.key_offsets, perm)
+            if batch.val_bytes.size:
+                vals = gather_ragged(batch.val_bytes, batch.val_offsets,
+                                     perm)
+            else:                   # zero-width values: nothing to move
+                vals = batch.val_bytes, batch.val_offsets
+        return Run(KVBatch(*keys, *vals), row_index)
 
     def flush(self) -> Optional[Run]:
         if self.on_spill is not None:
@@ -139,6 +165,16 @@ class _UnorderedWriterFacade(KeyValuesWriter):
         self._out_bytes_ctr.increment(len(k) + len(v))
         self._n += 1
         if (self._n & 0x3FFF) == 0:
+            self.context.notify_progress()
+
+    def write_batch(self, batch: KVBatch) -> None:
+        """Batch-first write path: a KVBatch of PRE-SERIALIZED records goes
+        to the span whole, as OrderedPartitionedKVOutput's does to its
+        sorter."""
+        with tracing.span("output.write", cat="task",
+                          rows=batch.num_records):
+            self.writer.write_batch(batch)
+            self._out_bytes_ctr.increment(batch.nbytes)
             self.context.notify_progress()
 
 
@@ -239,31 +275,58 @@ class StreamingKVReader(KeyValueReader):
         self.val_serde = val_serde
         self.context = context
 
-    def __iter__(self) -> Iterator[Tuple[Any, Any]]:
-        import time
-        consumed_slots: set = set()
-        consumed_batches: Dict[int, int] = {}
+    def iter_batches(self) -> Iterator[KVBatch]:
+        """Each fetched KVBatch once, as its fetch completes, in slot order
+        among those that are there.  The wait is on the fetch table's
+        condition, which every committed fetch, failed fetch and slot reset
+        notifies (``shuffle.wait`` brackets it); the timeout only lets a
+        killed attempt out.  A slot reset by an InputFailedEvent starts
+        again empty: batches of it already handed over stay handed over,
+        and as many of the re-fetched version's are skipped."""
+        table = self.table
+        consumed: Dict[int, int] = {}
         n = 0
         while True:
-            with self.table.lock:
-                ready: List[Tuple[int, KVBatch]] = []
-                done = self.table.completed >= self.table.num_slots
-                for si, s in enumerate(self.table.slots):
-                    start = consumed_batches.get(si, 0)
-                    for b in s.batches[start:]:
-                        ready.append((si, b))
-                    consumed_batches[si] = len(s.batches)
-            for si, batch in ready:
-                for k, v in batch.iter_pairs():
-                    yield (self.key_serde.from_bytes(k),
-                           self.val_serde.from_bytes(v))
-                    n += 1
-            if done and not ready:
-                break
+            with table.lock:
+                ready = self._take_ready(consumed)
+                if not ready and table.completed < table.num_slots:
+                    with tracing.span("shuffle.wait", cat="shuffle"):
+                        ready = self._wait_ready(consumed)
             if not ready:
-                time.sleep(0.02)
-                self.context.notify_progress()
+                break
+            for batch in ready:
+                n += batch.num_records
+                yield batch
         self.context.counters.increment(TaskCounter.INPUT_RECORDS_PROCESSED, n)
+
+    def _take_ready(self, consumed: Dict[int, int]) -> List[KVBatch]:
+        """The batches fetched since the last call (table.lock held)."""
+        ready: List[KVBatch] = []
+        for si, s in enumerate(self.table.slots):
+            ready.extend(s.batches[consumed.get(si, 0):])
+            consumed[si] = max(consumed.get(si, 0), len(s.batches))
+        return ready
+
+    def _wait_ready(self, consumed: Dict[int, int]) -> List[KVBatch]:
+        """Block (table.lock held) until a batch is there or every slot is
+        complete; [] means the input is at its end."""
+        table = self.table
+        while True:
+            if table.failed:
+                raise RuntimeError(f"shuffle failed: {table.diagnostics}")
+            ready = self._take_ready(consumed)
+            if ready or table.completed >= table.num_slots:
+                return ready
+            table.lock.wait(0.2)
+            # raises TaskKilledError if the AM killed this attempt (or the
+            # heartbeat died) — never block forever
+            self.context.notify_progress()
+
+    def __iter__(self) -> Iterator[Tuple[Any, Any]]:
+        for batch in self.iter_batches():
+            for k, v in batch.iter_pairs():
+                yield (self.key_serde.from_bytes(k),
+                       self.val_serde.from_bytes(v))
 
 
 class UnorderedKVInput(LogicalInput):
@@ -275,6 +338,14 @@ class UnorderedKVInput(LogicalInput):
                                              "bytes"))
         self.val_serde = get_serde(_conf_get(ctx, "tez.runtime.value.class",
                                              "bytes"))
+        #: the edge's lane width and the sort plane's routing, for a
+        #: processor that works on the fetched batches with the same engine
+        #: (library/join.py), as OrderedGroupedKVInput exposes them
+        self.key_width = int(_conf_get(ctx, "tez.runtime.tpu.key.width.bytes",
+                                       16))
+        self.merge_engine = _conf_get(ctx, "tez.runtime.sorter.class", "auto")
+        self.merge_min_records = int(_conf_get(
+            ctx, "tez.runtime.tpu.device.sort.min.records", 1 << 16))
         self.table = ShuffleFetchTable(ctx, self.num_physical_inputs,
                                        my_partition=ctx.task_index)
         ctx.request_initial_memory(0, None)

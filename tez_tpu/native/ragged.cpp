@@ -146,6 +146,23 @@ void tz_fnv32_partition(const uint8_t* key_bytes, const int64_t* key_offsets,
     for (auto& th : pool) th.join();
 }
 
+// Stable grouping of rows by partition: one counting pass, one placing pass.
+//   parts      : n partition ids, each in [0, num_partitions)
+//   perm       : n entries out: the rows of partition 0 in arrival order,
+//                then partition 1's, ...
+//   row_index  : num_partitions + 1 entries out: partition p's rows are
+//                perm[row_index[p] : row_index[p + 1]]
+void tz_group_by_partition(const int32_t* parts, int64_t n,
+                           int32_t num_partitions, int64_t* perm,
+                           int64_t* row_index) {
+    std::fill(row_index, row_index + num_partitions + 1, (int64_t)0);
+    for (int64_t i = 0; i < n; i++) row_index[parts[i] + 1]++;
+    for (int32_t p = 0; p < num_partitions; p++)
+        row_index[p + 1] += row_index[p];
+    std::vector<int64_t> at(row_index, row_index + num_partitions);
+    for (int64_t i = 0; i < n; i++) perm[at[parts[i]]++] = i;
+}
+
 }  // extern "C"
 
 // ---------------------------------------------------------------------------

@@ -806,21 +806,39 @@ def _matches_after_sort(xp, perm, lanes, lens, n_left: int):
     return xp.where(is_left[:-1] & ~is_left[1:] & same, perm[:-1], -1)
 
 
-def _join_match_impl(left_lanes, left_lens, right_lanes, right_lens,
-                     skip_length_pass: bool = False):
-    """Semi-join match of two key-sorted sides, each padded to its bucket
-    with sentinels: one stable LSD sort of their concatenation -- the side
-    is the least significant column by the order of concatenation, which a
-    stable sort keeps -- then a neighbour compare.  Returns i32[B - 1]: the
-    left row of every key both sides hold (once a key), -1 elsewhere, in
-    key order."""
-    lanes = jnp.concatenate([left_lanes, right_lanes], axis=0)
-    lens = jnp.concatenate([left_lens, right_lens], axis=0)
+def _sort_two_sides(first_lanes, first_lens, second_lanes, second_lens,
+                    skip_length_pass: bool):
+    """Traced: one stable LSD sort of two sides' concatenation, each padded
+    to its bucket with sentinels -- the side is the least significant
+    column by the order of concatenation, which a stable sort keeps.
+    Returns (perm, lanes, lens) of the concatenation."""
+    lanes = jnp.concatenate([first_lanes, second_lanes], axis=0)
+    lens = jnp.concatenate([first_lens, second_lens], axis=0)
     parts = jnp.where(lens < 0, jnp.int32(np.iinfo(np.int32).max),
                       jnp.int32(0))
     sort_lens = jnp.where(lens < 0, jnp.uint32(0xFFFFFFFF),
                           lens.astype(jnp.uint32))
     _, perm = _lsd_passes(parts, lanes, sort_lens, skip_length_pass)
+    return perm, lanes, lens
+
+
+def _lexsort_two_sides(first_lanes, first_lens, second_lanes, second_lens):
+    """_sort_two_sides on the host engine: numpy's stable lexsort."""
+    lanes = np.concatenate([first_lanes, second_lanes])
+    lens = np.concatenate([first_lens, second_lens]).astype(np.int32)
+    perm = np.lexsort((lens,) + tuple(
+        lanes[:, i] for i in range(lanes.shape[1] - 1, -1, -1)))
+    return perm, lanes, lens
+
+
+def _join_match_impl(left_lanes, left_lens, right_lanes, right_lens,
+                     skip_length_pass: bool = False):
+    """Semi-join match of two key-sorted sides: the sort of their
+    concatenation, then a neighbour compare.  Returns i32[B - 1]: the left
+    row of every key both sides hold (once a key), -1 elsewhere, in key
+    order."""
+    perm, lanes, lens = _sort_two_sides(left_lanes, left_lens, right_lanes,
+                                        right_lens, skip_length_pass)
     return _matches_after_sort(jnp, perm, lanes, lens, left_lanes.shape[0])
 
 
@@ -858,9 +876,99 @@ def join_match_host(left_lanes: np.ndarray, left_lens: np.ndarray,
                     ) -> np.ndarray:
     """join_match on the host engine: numpy's stable lexsort in the sort's
     place, the same neighbour compare."""
-    lanes = np.concatenate([left_lanes, right_lanes])
-    lens = np.concatenate([left_lens, right_lens]).astype(np.int32)
-    perm = np.lexsort((lens,) + tuple(
-        lanes[:, i] for i in range(lanes.shape[1] - 1, -1, -1)))
+    perm, lanes, lens = _lexsort_two_sides(left_lanes, left_lens,
+                                           right_lanes, right_lens)
     hits = _matches_after_sort(np, perm, lanes, lens, len(left_lens))
     return hits[hits >= 0].astype(np.int64)
+
+
+# ---------------------------------------------------------------------------
+# hash-join probe = sort of [stream block, build] + run-wise "holds a build
+# row" -- no hash table and no binary search: the same stable passes as the
+# merges and the merge-join's match
+# ---------------------------------------------------------------------------
+def _probe_hits_after_sort(xp, cummax_from_end, perm, lanes, lens,
+                           n_stream: int):
+    """`perm` orders the concatenation [stream rows, build rows] by (lanes,
+    length), stably: inside a run of equal keys the stream rows stand
+    first, the build rows last.  A run holds a build row exactly when its
+    last row is one, and every stream row of such a run is a hit: the run's
+    last row carries (its distance from the end) * 2 + (is it a build row),
+    and a running maximum from the end hands the nearest run end's value to
+    every row before it.  Returns i32[B]: the stream row at each sorted
+    place that is a hit, -1 elsewhere (sentinel rows, length < 0, never
+    match)."""
+    n = perm.shape[0]
+    s_lanes, s_lens = lanes[perm], lens[perm]
+    same_as_next = (s_lens[:-1] == s_lens[1:]) & \
+        (s_lanes[:-1] == s_lanes[1:]).all(axis=1)
+    run_end = xp.concatenate([~same_as_next, xp.ones(1, dtype=bool)])
+    is_stream = perm < n_stream
+    from_end = xp.arange(n - 1, -1, -1, dtype=xp.int32)
+    carried = cummax_from_end(xp.where(
+        run_end, from_end * 2 + (~is_stream).astype(xp.int32), 0))
+    held = (carried & 1) == 1
+    return xp.where(is_stream & held & (s_lens >= 0), perm, -1)
+
+
+def _join_probe_impl(stream_lanes, stream_lens, build_lanes, build_lens,
+                     skip_length_pass: bool = False):
+    """Semi-join probe of one block of stream rows against the build side,
+    each padded to its bucket with sentinels: join_match's sort, then every
+    stream row whose key the build side holds -- each occurrence, where
+    the match keeps one row a distinct key.  Returns i32[B]: hits' stream
+    row indices, -1 elsewhere, in key order."""
+    perm, lanes, lens = _sort_two_sides(stream_lanes, stream_lens,
+                                        build_lanes, build_lens,
+                                        skip_length_pass)
+    return _probe_hits_after_sort(
+        jnp, lambda v: jax.lax.cummax(v, axis=0, reverse=True), perm, lanes,
+        lens, stream_lanes.shape[0])
+
+
+_join_probe = Kernel(
+    _join_probe_impl, "join_probe", static_argnames=("skip_length_pass",),
+    launch_rows=lambda sl, _sn, bl, _bn: int(sl.shape[0] + bl.shape[0]))
+
+
+def stage_join_build(build_lanes: np.ndarray, build_lens: np.ndarray):
+    """The build side padded to its bucket and uploaded: device arrays a
+    joiner keeps across its probes (``join_probe``'s `build`)."""
+    with tracing.span("join.match", cat="join", stage="stage", how="semi",
+                      rows=len(build_lens)):
+        return tuple(jnp.asarray(a)
+                     for a in _pad_to_bucket(build_lanes, build_lens))
+
+
+def join_probe(stream_lanes: np.ndarray, stream_lens: np.ndarray,
+               build: tuple, uniform: bool = False) -> np.ndarray:
+    """The rows of one stream block whose key the build side holds, every
+    occurrence, ascending by row.  `build` is ``stage_join_build``'s pair
+    (lanes of the block's width holding whole keys); duplicates on either
+    side are fine.  The program's compile key is (block bucket, build
+    bucket, L); only the hits' row indices come back.  `uniform`: one key
+    length over both sides, so the length pass is an identity."""
+    with tracing.span("join.match", cat="join", stage="stage", how="semi",
+                      rows=len(stream_lens)):
+        operands = [jnp.asarray(a)
+                    for a in _pad_to_bucket(stream_lanes, stream_lens)]
+    with tracing.span("join.match", cat="join", stage="launch", how="semi"):
+        hits_dev = _join_probe(*operands, *build, skip_length_pass=uniform)
+    # the host blocks here for the device, as in merge.readback
+    with tracing.span("join.match", cat="join", stage="readback",
+                      how="semi"):
+        hits = np.asarray(hits_dev)
+        return np.sort(hits[hits >= 0]).astype(np.int64)
+
+
+def join_probe_host(stream_lanes: np.ndarray, stream_lens: np.ndarray,
+                    build_lanes: np.ndarray, build_lens: np.ndarray
+                    ) -> np.ndarray:
+    """join_probe on the host engine: numpy's stable lexsort in the sort's
+    place, the same run-wise test."""
+    perm, lanes, lens = _lexsort_two_sides(stream_lanes, stream_lens,
+                                           build_lanes, build_lens)
+    hits = _probe_hits_after_sort(
+        np, lambda v: np.maximum.accumulate(v[::-1])[::-1], perm, lanes,
+        lens, len(stream_lens))
+    return np.sort(hits[hits >= 0]).astype(np.int64)

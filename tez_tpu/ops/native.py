@@ -77,6 +77,7 @@ _SIGNATURES = {
     "hash_sum_i64": ([_P, _P, _I64, _P, _P, _P], _I64),
     "tz_split_ws": ([_P, _I64, _P, _P], _I64),
     "tz_fnv32_partition": ([_P, _P, _I64, _I32, _P, _I32], None),
+    "tz_group_by_partition": ([_P, _I64, _I32, _P, _P], None),
     "tz_sort_partition_keys": ([_P, _P, _P, _I64, _P, _I32], None),
     "tz_merge_runs": ([_P, _P, _P, _P, _I32, _P, _I32], None),
     "gather_fixed_u8": ([_P, _I64, _P, _I64, _P, _I32], None),
@@ -419,6 +420,25 @@ def fnv32_partition_native(key_bytes: np.ndarray, key_offsets: np.ndarray,
         parts.ctypes.data_as(ctypes.c_void_p),
         ctypes.c_int32(min(8, os.cpu_count() or 1)))
     return parts
+
+
+def group_by_partition_native(parts: np.ndarray, num_partitions: int
+                              ) -> Tuple[np.ndarray, np.ndarray]:
+    """Stable grouping of rows by partition (int32 ids below
+    `num_partitions`, as fnv32_partition_native gives them): one counting
+    pass.  Returns (perm int64[n]: partition 0's rows in arrival order,
+    then partition 1's, ...; row_index int64[P + 1])."""
+    lib = _load()
+    parts = np.ascontiguousarray(parts, dtype=np.int32)
+    if int(parts.max(initial=0)) >= num_partitions or \
+            int(parts.min(initial=0)) < 0:
+        raise ValueError(f"a partition outside the {num_partitions} there "
+                         f"are")
+    perm = hostpool.empty(len(parts), np.int64)
+    row_index = np.empty(num_partitions + 1, dtype=np.int64)
+    lib.tz_group_by_partition(_ptr(parts), len(parts), int(num_partitions),
+                              _ptr(perm), _ptr(row_index))
+    return perm, row_index
 
 
 def sort_partition_keys_native(key_bytes: np.ndarray,

@@ -143,7 +143,7 @@ def run_wordcount(td: str, args, exchange: str, mb: int, tag: str) -> dict:
 
 def report_compiles(tag: str, compiles, wall: float) -> None:
     per_kernel: dict = {}
-    for name, _sig, secs, _t_done in compiles:
+    for name, _sig, secs, _sort_ops, _t_done in compiles:
         n, tot, mx = per_kernel.get(name, (0, 0.0, 0.0))
         per_kernel[name] = (n + 1, tot + secs, max(mx, secs))
     total = sum(t for _, t, _ in per_kernel.values())

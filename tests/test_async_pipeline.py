@@ -609,10 +609,10 @@ def test_sorter_compile_error_surfaces_but_injected_oom_fails_over(
     assert counters.group(COUNTER_GROUP).find_counter(
         "device.failover.spans").value == 1
 
-    def refused(lanes, lengths, num_partitions, skip_length_pass=False):
+    def refused(lanes, lengths, num_partitions):
         raise ValueError("Shape mismatch in input, indices and output")
     broken = Kernel(refused, "resident_hash_sort",
-                    static_argnames=("num_partitions", "skip_length_pass"))
+                    static_argnames=("num_partitions",))
     monkeypatch.setattr(device, "_HASH_SORTS", (broken, broken))
     # the poison surfaces at whichever comes first: the next submit (which
     # wraps it) or the drain (which re-raises it)

@@ -105,7 +105,7 @@ def test_backend_init_failure_propagates(fresh_backend_query, monkeypatch):
         raise RuntimeError("Unable to initialize backend 'tpu': ABORTED: "
                            "libtpu multi-process lockfile")
     monkeypatch.setattr(device.jax, "default_backend", boom)
-    for query in (device.accelerator_present, device.single_pass_variadic,
+    for query in (device.accelerator_present,
                   lambda: sorter.resolve_engine("auto"),
                   lambda: sorter.DeviceSorter(num_partitions=2,
                                               engine="auto")):

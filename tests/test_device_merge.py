@@ -206,122 +206,192 @@ def test_resident_all_ff_keys_at_the_lane_cap_sort_before_the_pads():
     perm = device.merge_resident_slices([_resident_view(r, 8) for r in runs])
     np.testing.assert_array_equal(
         perm, sorted(range(len(keys)), key=keys.__getitem__))
-    # one length everywhere, the length pass left out: pads still last
+    # one length everywhere: the pads' length code still puts them last
     same_length = [[ALL_FF, ALL_FF], [b"aaaaaaaa", ALL_FF]]
     perm = device.merge_resident_slices(
-        [_resident_view(r, 8) for r in same_length], uniform_lengths=True)
+        [_resident_view(r, 8) for r in same_length])
     np.testing.assert_array_equal(perm, [2, 0, 1, 3])
 
 
-def _static_flags(kernel):
-    return {dict(key[2])["skip_length_pass"] for key in kernel._compiled}
-
-
-def test_uniform_lengths_skip_the_length_pass_and_mixed_do_not():
-    """What decides is the input: one clamped length on every real row
-    compiles (and runs) the program without the length pass; one odd row
-    brings the pass back.  Same order from either program."""
+def test_one_program_whatever_the_lengths_say():
+    """The length rides in the sort's last key beside the arrival order, so
+    rows of one length and rows of several run the SAME compiled program
+    (the ladder compiled one with the length pass and one without) and
+    both come out in key order."""
     rng = random.Random(5)
     keys = sorted(f"w{rng.randrange(10 ** 4):07d}".encode()
                   for _ in range(500)) * 2          # two equal runs
-    lanes, lengths = _key_columns(keys, 8)
     partitions = np.zeros(len(keys), dtype=np.int32)
-    want = sorted(range(len(keys)), key=keys.__getitem__)
-
-    device._merge_sort._compiled.clear()
-    np.testing.assert_array_equal(
-        device.merge_runs(partitions, lanes, lengths), want)
-    assert _static_flags(device._merge_sort) == {True}
-    # the full program on the same rows: identical permutation
-    nb = device._bucket(len(keys))
-    full = device._merge_sort(
-        jnp.asarray(np.pad(partitions, (0, nb - len(keys)),
-                           constant_values=np.iinfo(np.int32).max)),
-        jnp.asarray(np.pad(lanes, ((0, nb - len(keys)), (0, 0)))),
-        jnp.asarray(np.pad(lengths.astype(np.uint32), (0, nb - len(keys)),
-                           constant_values=9)),
-        skip_length_pass=False)
-    np.testing.assert_array_equal(np.asarray(full)[:len(keys)], want)
-
-    device._merge_sort._compiled.clear()
     mixed = [b"w"] + keys[:499] + keys[500:]        # still two sorted runs
-    lanes, lengths = _key_columns(mixed, 8)
-    np.testing.assert_array_equal(
-        device.merge_runs(partitions, lanes, lengths),
-        sorted(range(len(mixed)), key=mixed.__getitem__))
-    assert _static_flags(device._merge_sort) == {False}
+
+    device._merge_sort._compiled.clear()
+    for rows in (keys, mixed):
+        lanes, lengths = _key_columns(rows, 8)
+        np.testing.assert_array_equal(
+            device.merge_runs(partitions, lanes, lengths),
+            sorted(range(len(rows)), key=rows.__getitem__))
+    assert device._merge_sort.cache_size() == 1
 
 
-def test_resident_merge_reads_uniformity_from_the_runs():
-    """merge_sorted_runs makes the span sort's own test on the runs it is
-    handed and the resident program is compiled to match."""
+def test_resident_merge_is_one_program_for_uniform_and_mixed_runs():
+    """merge_sorted_runs hands the resident views over as they are: no
+    host pass over the runs' key lengths decides which program runs."""
     def resident_run(keys):
         batch = KVBatch.from_pairs([(k, b"v") for k in keys])
         batch.dev_keys = _resident_view(keys, 8)
         return Run(batch, np.array([0, len(keys)], dtype=np.int64))
 
-    for keys_a, flag in (([b"aaaa", b"cccc"], True), ([b"a", b"cccc"], False)):
-        device._fused_resident_merge._compiled.clear()
+    device._fused_resident_merge._compiled.clear()
+    for keys_a in ([b"aaaa", b"cccc"], [b"a", b"cccc"]):
         merged = merge_sorted_runs(
             [resident_run(keys_a), resident_run([b"bbbb", b"cccc"])], 1, 8,
             engine="device", device_min_records=0)
         assert [merged.batch.key(i) for i in range(4)] == \
             sorted(keys_a + [b"bbbb", b"cccc"])
-        assert _static_flags(device._fused_resident_merge) == {flag}
+    assert device._fused_resident_merge.cache_size() == 1
 
 
-@pytest.mark.parametrize("skip_length_pass", [False, True])
-@pytest.mark.parametrize("num_lanes", [1, 2, 3, 4])
-def test_chip_sort_ladder_equals_variadic_sort_and_lexsort(
-        monkeypatch, num_lanes, skip_length_pass):
-    """The sort body the chip runs (chained stable single-key passes) and
-    the one XLA:CPU runs (one variadic sort) give the same partitions and
-    the same permutation, and both are numpy's stable lexsort.  Tier-1 is
-    XLA:CPU, where `single_pass_variadic()` answers True, so without this
-    test no tier-1 test traces the ladder the chip executes in every cell.
-    `_lsd_passes` is jitted directly: the cached Kernels would hand back
-    whichever body they were compiled with first."""
-    rng = np.random.default_rng(100 * num_lanes + skip_length_pass)
+def _sort_operands(lowered_text):
+    """Operands of every sort operation in a lowered module."""
+    import re
+    return [len(args.split(",")) for args in re.findall(
+        r'"stablehlo\.sort"\(([^)]*)\)', lowered_text)]
+
+
+def _sort_body_rows(num_lanes, one_length, seed):
+    """512 rows for the sort body: duplicates at every lane, zero-length
+    keys beside "\\0", and both kinds of padding rows."""
+    rng = np.random.default_rng(seed)
     n, real = 512, 401
     cap = num_lanes * 4 + 1
     # a three-value alphabet and all-0xFF lanes: duplicates at every lane
     lanes = rng.choice(np.array([0, 1, 0xFFFFFFFF], dtype=np.uint32),
                        size=(n, num_lanes), p=[0.45, 0.45, 0.1])
     partitions = rng.integers(0, 3, n).astype(np.int32)
-    if skip_length_pass:
+    if one_length:
         lengths = np.full(n, num_lanes * 4, dtype=np.uint32)
     else:
         lengths = rng.integers(0, cap + 1, n).astype(np.uint32)
         # zero-length keys beside "\0" and "\0\0": equal (all-zero) lanes,
-        # only the length pass tells them apart
+        # only the length tells them apart
         lanes[:60] = 0
         lengths[:60] = rng.integers(0, 3, 60)
     # padding rows as the two staging paths write them: partition MAX, and
-    # lanes/lengths either all-ones (resident) or zero/uniform (host-fed)
+    # lanes/lengths either all-ones (resident) or zero/at the cap (host-fed)
     partitions[real:] = np.iinfo(np.int32).max
     lanes[real:450] = 0xFFFFFFFF
     lengths[real:450] = 0xFFFFFFFF
     lanes[450:] = 0
+    return partitions, lanes, lengths, real
 
-    def run(variadic):
-        monkeypatch.setattr(device, "single_pass_variadic", lambda: variadic)
-        fn = jax.jit(lambda p, l, n_: device._lsd_passes(
-            p, l, n_, skip_length_pass))
-        sorts = fn.lower(partitions, lanes, lengths).as_text().count(
-            "stablehlo.sort")
-        sp, perm = fn(partitions, lanes, lengths)
-        return np.asarray(sp), np.asarray(perm), sorts
 
-    ladder, variadic = run(False), run(True)
-    # the two bodies really are two programs: L lane passes + partition
-    # (+ length) against one sort
-    assert ladder[2] == num_lanes + 2 - skip_length_pass
-    assert variadic[2] == 1
-    keys = [] if skip_length_pass else [lengths]
-    keys += [lanes[:, i] for i in range(num_lanes - 1, -1, -1)]
-    keys.append(partitions.astype(np.uint32))
-    want = np.lexsort(keys)               # stable; the last key is primary
-    for sp, perm, _sorts in (ladder, variadic):
-        np.testing.assert_array_equal(perm, want)
-        np.testing.assert_array_equal(sp, partitions[want])
-    assert (ladder[0][real:] == np.iinfo(np.int32).max).all()
+def _assert_sort_body_is_lexsort(partitions, lanes, lengths, real):
+    """`_lsd_passes` is jitted afresh: a cached Kernel, or jit's own cache
+    of the function, would hand back what it traced first.  Returns the
+    lowered text."""
+    fn = jax.jit(lambda p, l, n_: device._lsd_passes(p, l, n_))
+    sp, perm, s_lanes, s_lens = (np.asarray(x) for x in fn(
+        partitions, lanes, lengths))
+    want = np.lexsort(                    # stable; the last key is primary
+        [lengths] + [lanes[:, i] for i in range(lanes.shape[1] - 1, -1, -1)]
+        + [partitions.astype(np.uint32)])
+    np.testing.assert_array_equal(perm, want)
+    np.testing.assert_array_equal(sp, partitions[want])
+    np.testing.assert_array_equal(s_lanes, lanes[want])
+    np.testing.assert_array_equal(s_lens, lengths[want].astype(np.int32))
+    assert (sp[real:] == np.iinfo(np.int32).max).all()
+    return fn.lower(partitions, lanes, lengths).as_text()
+
+
+@pytest.mark.parametrize("one_length", [False, True])
+@pytest.mark.parametrize("num_lanes", [1, 2, 3, 4, 6])
+def test_sort_body_is_lexsort_and_returns_the_sorted_columns(
+        num_lanes, one_length):
+    """The one sort body every sort, merge, match and probe program traces
+    gives numpy's stable lexsort by (partition, lanes..., length) -- the
+    same partitions, the same permutation -- and the key columns it returns
+    are the columns gathered by that permutation: rows of one length and
+    of several, duplicates, zero-length keys, both kinds of padding."""
+    text = _assert_sort_body_is_lexsort(*_sort_body_rows(
+        num_lanes, one_length, 100 * num_lanes + one_length))
+    # length and arrival order share the last key: partition + lanes + 1
+    assert _sort_operands(text) == [num_lanes + 2]
+
+
+def test_sort_body_where_length_and_row_number_do_not_share_a_key(
+        monkeypatch):
+    """More rows than the last key has bits for beside the length code: the
+    length and the arrival order are an operand each, same answer."""
+    monkeypatch.setattr(device, "_TAIL_KEY_BITS", 12)   # 512 rows need 9
+    text = _assert_sort_body_is_lexsort(*_sort_body_rows(2, False, 9))
+    assert _sort_operands(text) == [2 + 3]
+
+
+def _six_programs():
+    """name -> (traced function, arguments at two lanes, sort operands):
+    the six programs the cells launch.  A span sort keeps its partition
+    column; a merge, the match and the probe sort by key alone."""
+    lanes = np.zeros((256, 2), np.uint32)
+    lens = np.zeros(256, np.int32)
+    splits = (np.zeros((3, 2), np.uint32), np.zeros(3, np.int32))
+    two_sides = (lanes, lens, lanes, lens)
+    return {
+        "resident_hash_sort": (
+            lambda a, b: device._fused_resident_hash_sort_impl(a, b, 4),
+            (lanes, lens), 4),
+        "fused_resident_range_sort": (
+            device._fused_resident_range_sort_impl, (lanes, lens) + splits, 4),
+        "resident_merge_sort": (
+            device._fused_resident_merge_impl, ([lanes] * 4, [lens] * 4), 3),
+        "merge_sort": (
+            device._merge_sort_impl, (lens, lanes, lens.astype(np.uint32)), 4),
+        "join_match": (device._join_match_impl, two_sides, 3),
+        "join_probe": (device._join_probe_impl, two_sides, 3),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_six_programs()))
+def test_each_program_is_one_sort_and_gathers_nothing(name):
+    """The lowered text of each of the six programs holds ONE sort
+    operation, of as many operands as its key columns and the shared last
+    key, and no gather: the sorted columns are the sort's outputs.  (The
+    ladder lowered to L+2 sorts with a gather before each and two after.)"""
+    fn, args, operands = _six_programs()[name]
+    text = jax.jit(fn).lower(*args).as_text()
+    assert _sort_operands(text) == [operands]
+    assert "stablehlo.gather" not in text
+    assert "dynamic_slice" not in text
+
+
+def test_compile_span_and_log_carry_the_sort_ops_of_the_lowered_module():
+    """The witness of which sort body a program traced: `sort_ops` on the
+    `kernel.compile` span and in COMPILE_LOG, documented with the span."""
+    import os
+    from tez_tpu.common import tracing
+    from tests.trace_schema import undocumented_span_args
+    rng = np.random.default_rng(7)
+    lanes = rng.integers(0, 9, (300, 2)).astype(np.uint32)
+    device._merge_sort._compiled.clear()
+    logged = len(device.COMPILE_LOG)
+    tracing.clear_all()
+    tracing.arm("sort-ops-test")
+    try:
+        device.merge_runs(np.zeros(300, np.int32), lanes,
+                          np.full(300, 8, np.int32))
+        spans = tracing.snapshot()
+    finally:
+        tracing.clear_all()
+    (compiled,) = [s for s in spans if s.name == "kernel.compile"]
+    assert compiled.args["kernel"] == "merge_sort"
+    assert compiled.args["sort_ops"] == 1
+    (entry,) = device.COMPILE_LOG[logged:]
+    name, _sig, secs, sort_ops, t_done = entry
+    assert (name, sort_ops) == ("merge_sort", 1)
+    # benchmarks/run.py counts the window's compiles by the LAST field
+    assert secs < 1e6 < t_done
+    doc = open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "observability.md")).read()
+    assert undocumented_span_args("kernel.compile", compiled.args,
+                                  doc) == set()
+    assert undocumented_span_args("kernel.compile", {"made_up": 1},
+                                  doc) == {"made_up"}

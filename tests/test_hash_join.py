@@ -199,13 +199,11 @@ def test_join_probe_agrees_with_join_match_where_keys_are_unique():
     stream.sort(), build.sort()
     sides = _encoded(stream) + _encoded(build)
     assert np.array_equal(_device_probe(*sides), device.join_match(*sides))
-    # one key length over both sides: the length pass is skipped
+    # one key length over both sides: the same program, the host twin's hits
     same = [k for k in keys if len(k) == 20]
     sides = _encoded(same) + _encoded(same[::3])
-    on_device = device.stage_join_build(*sides[2:])
-    assert np.array_equal(
-        device.join_probe(*sides[:2], on_device, uniform=True),
-        device.join_probe(*sides[:2], on_device))
+    assert np.array_equal(_device_probe(*sides),
+                          device.join_probe_host(*sides))
 
 
 # ---------------------------------------------------------------------------

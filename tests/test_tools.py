@@ -332,3 +332,50 @@ def test_client_session_expiry(tmp_path):
         if proc.poll() is None:
             proc.terminate()
         proc.wait(timeout=10)
+
+
+# -------------------------------------------- tools/trace_window_check.py
+@pytest.fixture()
+def window_check():
+    """The trace tool as a module; it puts the checkout and benchmarks/ at
+    the head of ``sys.path`` as it is imported, which is undone here."""
+    import importlib.util
+    import sys
+    saved = list(sys.path)
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "trace_window_check.py")
+    spec = importlib.util.spec_from_file_location("trace_window_check", path)
+    module = importlib.util.module_from_spec(spec)
+    try:
+        spec.loader.exec_module(module)
+        yield module
+    finally:
+        sys.path[:] = saved
+
+
+def test_trace_tool_puts_device_time_beside_launches(window_check):
+    import collections
+    programs = window_check.kernel_programs()
+    # the six programs the benchmark's readers find by name, and the
+    # donating flavors beside their plain twins
+    assert {programs[k] for k in (
+        "resident_hash_sort", "fused_resident_range_sort",
+        "resident_merge_sort", "merge_sort", "join_match", "join_probe")} == {
+        "_fused_resident_hash_sort_impl", "_fused_resident_range_sort_impl",
+        "_fused_resident_merge_impl", "_merge_sort_impl", "_join_match_impl",
+        "_join_probe_impl"}
+    assert programs["resident_hash_sort_donated"] == \
+        programs["resident_hash_sort"]
+    table = window_check.program_table(
+        {"_join_probe_impl": 0.8, "_slice_to_bucket_impl": 0.1},
+        collections.Counter({"kernel.join_probe": 16,
+                             "kernel.resident_hash_sort": 3,
+                             "kernel.resident_hash_sort_donated": 5,
+                             "kernel.no_such_kernel": 1}),
+        programs, dags=2)
+    assert table == {
+        "_join_probe_impl": {"launches_a_dag": 8.0,
+                             "device_ms_a_launch": 50.0},
+        # launched, but not among the programs the trace gave a time for
+        "_fused_resident_hash_sort_impl": {"launches_a_dag": 4.0,
+                                           "device_ms_a_launch": None}}

@@ -100,3 +100,15 @@ def undocumented_metrics(names, doc_text: str) -> set:
         if line.startswith("| `"):
             documented.update(re.findall(r"`([^`]+)`", line.split("|")[1]))
     return {name for name in names if name not in documented}
+
+
+def undocumented_span_args(span_name: str, arg_names, doc_text: str) -> set:
+    """Arguments of one recorded span that its row of the vocabulary table
+    does not name back-quoted (``kernel.compile``: `kernel`, `signature`,
+    `sort_ops`)."""
+    section = doc_text.split("### Span vocabulary", 1)[1]
+    section = section.split("\n### ", 1)[0]
+    rows = [line for line in section.splitlines()
+            if line.startswith("| ") and f"`{span_name}`" in line.split("|")[1]]
+    assert len(rows) == 1, f"{span_name}: {len(rows)} vocabulary rows"
+    return {a for a in arg_names if f"`{a}`" not in rows[0]}

@@ -242,7 +242,6 @@ def hash_join_blocks(build_batches: Iterable[KVBatch],
                 lanes, lens = encode_keys(build.key_bytes, build.key_offsets,
                                           width)
                 on_device = device.stage_join_build(lanes, lens)
-                uniform = device.uniform_clamped_lengths(lens, width + 1)
     host_build: dict = {}       # lane width -> the build side encoded at it
     probed = emitted = 0
     for block in _probe_blocks(stream_batches, block_rows) if n_build else ():
@@ -254,9 +253,7 @@ def hash_join_blocks(build_batches: Iterable[KVBatch],
                               how="semi", rows=n, engine="device"):
                 lanes, lens = encode_keys(block.key_bytes, block.key_offsets,
                                           width)
-                same = uniform[0] and device.uniform_clamped_lengths(
-                    lens, width + 1) == uniform
-            hits = device.join_probe(lanes, lens, on_device, uniform=same)
+            hits = device.join_probe(lanes, lens, on_device)
             if counters is not None:
                 counters.increment(TaskCounter.JOIN_MATCH_ROWS, n + n_build)
                 counters.increment(TaskCounter.JOIN_MATCH_LAUNCHES)

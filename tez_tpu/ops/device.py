@@ -8,11 +8,13 @@ compiled functions are cached per-process (jit cache) and survive across
 tasks via runner reuse.
 
 Sorting model: keys are fixed-width uint32 lanes (ops/keycodec); the sort is
-a single variadic stable `lax.sort` over (partition, lane_0..lane_{L-1})
-carrying the record permutation — XLA lowers this to its optimized on-device
-sort; the merge of k sorted runs reuses the same kernel on the concatenation
-(sort networks beat heap-merge on TPU's vector units; runs' stable order
-preserves within-key arrival order like the reference's MergeQueue).
+a single variadic `lax.sort` over (partition, lane_0..lane_{L-1}, length |
+arrival order), every operand a key, whose outputs are the sorted columns
+and the record permutation (`_lsd_passes`) — XLA lowers this to its
+on-device sort; the merge of k sorted runs is the same sort of the
+concatenation (sort networks beat heap-merge on TPU's vector units; the
+arrival order as last key preserves within-key run order like the
+reference's MergeQueue).
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ import numpy as np
 
 from tez_tpu.common import tracing
 from tez_tpu.ops import compile_cache    # importing it places the cache
+from tez_tpu.ops import hostpool
 
 FNV_OFFSET = np.uint32(2166136261)
 FNV_PRIME = np.uint32(16777619)
@@ -53,19 +56,6 @@ def backend_platform() -> str:
             "could be claimed.  Set JAX_PLATFORMS=cpu to run on the host "
             "on purpose.")
     return platform
-
-
-def single_pass_variadic() -> bool:
-    """True when the sort body should use ONE variadic multi-key `lax.sort`
-    instead of chained single-key LSD passes.
-
-    XLA:CPU compiles the N-operand variadic sort instantly and runs it ~2x
-    faster than the chained ladder (one comparator walk instead of L+2
-    full passes over the permutation).  Accelerator backends keep the
-    chained passes (~25 s to compile per shape on a v5e; the variadic
-    sort's compile and run time there is not measured).  Evaluated at
-    trace time (Python-level branch in the jitted bodies)."""
-    return backend_platform() == "cpu"
 
 
 def accelerator_present() -> bool:
@@ -131,10 +121,11 @@ def _first_operand_rows(*args: Any) -> int:
     return int(shape[0]) if shape else 1
 
 
-#: (kernel name, signature, compile seconds, wall-clock time it finished)
-#: of every compile this process did — chip_smoke.py prints it as the cold
-#: set-up cost per kernel.
-COMPILE_LOG: List[Tuple[str, str, float, float]] = []
+#: (kernel name, signature, compile seconds, sort operations in the lowered
+#: module, wall-clock time it finished) of every compile this process did —
+#: chip_smoke.py prints it as the cold set-up cost per kernel.  The time it
+#: finished stays LAST: benchmarks/run.py counts the window's compiles by it.
+COMPILE_LOG: List[Tuple[str, str, float, int, float]] = []
 
 
 class Kernel:
@@ -154,6 +145,10 @@ class Kernel:
                  launch_rows: Callable[..., int] = _first_operand_rows
                  ) -> None:
         self.name = name
+        #: the traced function's name: the profiler calls the compiled
+        #: program ``jit_<program>`` (tools/trace_window_check.py puts the
+        #: device time of one beside the launches of the other)
+        self.program = fn.__name__
         #: rows one launch works on (padded: sentinels included), from its
         #: operands — the first operand's unless the kernel takes two runs
         self._launch_rows = launch_rows
@@ -205,14 +200,20 @@ class Kernel:
                     t0 = time.perf_counter()
                     try:
                         with tracing.span("kernel.compile", cat="kernel",
-                                          kernel=self.name, signature=sig):
-                            exe = self._jit.lower(*args, **static).compile()
+                                          kernel=self.name,
+                                          signature=sig) as span:
+                            lowered = self._jit.lower(*args, **static)
+                            # the witness of which sort body was traced
+                            sort_ops = lowered.as_text().count(
+                                "stablehlo.sort")
+                            span.annotate(sort_ops=sort_ops)
+                            exe = lowered.compile()
                     except Exception as e:
                         raise KernelCompileError(
                             f"kernel {self.name}[{sig}] failed to compile: "
                             f"{type(e).__name__}: {e}") from e
                     COMPILE_LOG.append((self.name, sig,
-                                        time.perf_counter() - t0,
+                                        time.perf_counter() - t0, sort_ops,
                                         time.time()))
                     self._compiled[key] = exe
             return exe
@@ -239,15 +240,9 @@ def is_resource_exhausted(exc: BaseException) -> bool:
     return any(m in msg for m in _OOM_MARKERS)
 
 
-def uniform_clamped_lengths(lengths: np.ndarray, width_cap: int):
-    """(is_uniform, pad_value) over CLAMPED lengths — the shared uniformity
-    test for the skip-length-pass optimization (clamp first: all-long keys
-    compare equal at the cap)."""
-    if len(lengths) == 0:
-        return False, width_cap
-    clamped = np.minimum(lengths.astype(np.int64), width_cap)
-    lo, hi = int(clamped.min()), int(clamped.max())
-    return lo == hi, (lo if lo == hi else width_cap)
+#: bits of the sort's last key, which the length code and the row number
+#: share (a test narrows it to reach the branch where they do not fit)
+_TAIL_KEY_BITS = 32
 
 
 def _bucket(n: int, floor: int = 256) -> int:
@@ -315,43 +310,61 @@ def _hash_to_partitions(key_mat: jnp.ndarray, hash_lengths: jnp.ndarray,
         (h % jnp.uint32(num_partitions)).astype(jnp.int32))
 
 
-def _lsd_passes(partitions: jnp.ndarray, lanes: jnp.ndarray,
-                lengths: jnp.ndarray,
-                skip_length_pass: bool = False
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Traced body shared by the fused kernels: stable LSD passes by
-    (partition, lanes..., clamped length).
+def _lsd_passes(partitions, lanes: jnp.ndarray, lengths: jnp.ndarray
+                ) -> Tuple[jnp.ndarray, ...]:
+    """Traced body shared by every sort, merge, match and probe program:
+    ONE variadic `lax.sort` by ([partition,] lane_0..lane_{L-1}, length,
+    arrival order), every operand a key.  Returns (sorted partitions i32,
+    perm, sorted lanes u32[n, L], sorted lengths i32[n]): the sorted
+    columns are the sort's own outputs, nothing is gathered by the
+    permutation (on a v5e a gather costs 5-9 ns a row, a whole sort
+    operand 0.5: PERF.md PR 35).  `partitions` None: by key alone, and None
+    comes back in its place.  `lengths`: i32 with -1 on a sentinel row, or
+    the same bits as u32 (all ones); -1 comes back on those rows.
 
-    skip_length_pass: set when every key in the span has the same length —
-    the pass would be an identity reorder (fixed-width key workloads save a
-    full sort pass).  The partition pass always runs: it doubles as the
-    padding separator (pad rows carry partition MAX)."""
-    n = partitions.shape[0]
-    perm = jnp.arange(n, dtype=jnp.int32)
-    if single_pass_variadic():
-        # one variadic sort == the full LSD ladder: lexicographic
-        # (partition, lane_0..lane_{L-1}[, length]) with perm as the FINAL
-        # key.  perm is unique, so the composite order is total: an
-        # UNSTABLE sort is deterministic and equal-key rows land in
-        # ascending-perm (= arrival) order — bit-identical to the stable
-        # ladder, and XLA:CPU's unstable sort is ~25% faster.
-        keys = (partitions.astype(jnp.uint32),)
-        keys += tuple(lanes[:, i] for i in range(lanes.shape[1]))
-        if not skip_length_pass:
-            keys += (lengths.astype(jnp.uint32),)
-        res = jax.lax.sort(keys + (perm,), dimension=0, is_stable=False,
+    The arrival order is the LAST key and unique, so the order is total: an
+    unstable sort is deterministic and equal keys keep arrival order, as
+    the stable LSD ladder of L+2 single-key passes this replaced did (the
+    name is the ladder's).  Compile time grows with the square of the
+    operands (~13 s + 5.6 s a pair of them on the chip), so the length --
+    at most 4L+1, clamped at the lane cap, or all ones on a sentinel row --
+    and the arrival order share one u32 key wherever the rows leave the
+    bits: the length code above, the row number below."""
+    n, num_lanes = lanes.shape
+    order = jnp.arange(n, dtype=jnp.uint32)
+    lengths = lengths.astype(jnp.uint32)
+    keys = () if partitions is None else (partitions.astype(jnp.uint32),)
+    first = len(keys)                       # where the lanes stand
+    keys += tuple(lanes[:, i] for i in range(num_lanes))
+    sentinel_code = 4 * num_lanes + 2       # over every length a row has
+    shift = _TAIL_KEY_BITS - sentinel_code.bit_length()
+    if n <= 1 << shift:
+        tail = (jnp.minimum(lengths, sentinel_code) << shift) | order
+        res = jax.lax.sort(keys + (tail,), dimension=0, is_stable=False,
                            num_keys=len(keys) + 1)
-        return res[0].astype(jnp.int32), res[-1]
-    if not skip_length_pass:
-        _, perm = jax.lax.sort((lengths.astype(jnp.uint32), perm),
-                               dimension=0, is_stable=True, num_keys=1)
-    for i in range(lanes.shape[1] - 1, -1, -1):
-        _, perm = jax.lax.sort((lanes[:, i][perm], perm),
-                               dimension=0, is_stable=True, num_keys=1)
-    sorted_parts, perm = jax.lax.sort(
-        (partitions.astype(jnp.uint32)[perm], perm),
-        dimension=0, is_stable=True, num_keys=1)
-    return sorted_parts.astype(jnp.int32), perm
+        code = (res[-1] >> shift).astype(jnp.int32)
+        s_lens = jnp.where(code == sentinel_code, -1, code)
+        perm = res[-1] & jnp.uint32((1 << shift) - 1)
+    else:
+        res = jax.lax.sort(keys + (lengths, order), dimension=0,
+                           is_stable=False, num_keys=len(keys) + 2)
+        s_lens, perm = res[-2].astype(jnp.int32), res[-1]
+    return (None if partitions is None else res[0].astype(jnp.int32),
+            perm.astype(jnp.int32),
+            jnp.stack(res[first:first + num_lanes], axis=1), s_lens)
+
+
+def _sort_by_key(lanes: jnp.ndarray, lens: jnp.ndarray
+                 ) -> Tuple[jnp.ndarray, ...]:
+    """The sort of one partition's rows, sentinels (length < 0) included:
+    by (lanes, length, arrival order).  A sentinel's lanes are all ones as
+    every staging path writes them (put so again here: no real key sorts
+    after it), and its length code is the largest, so the sentinels stand
+    at the tail without a partition column.  Returns (perm, sorted lanes,
+    sorted lengths i32)."""
+    return _lsd_passes(None, jnp.where((lens < 0)[:, None],
+                                       jnp.uint32(0xFFFFFFFF), lanes),
+                       lens)[1:]
 
 
 # ---------------------------------------------------------------------------
@@ -376,33 +389,29 @@ def _fnv_rows_from_lanes(lanes: jnp.ndarray,
 
 
 def _resident_span_sort(sentinel: jnp.ndarray, partitions: jnp.ndarray,
-                        lanes: jnp.ndarray, lengths: jnp.ndarray,
-                        skip_length_pass: bool) -> Tuple[jnp.ndarray, ...]:
+                        lanes: jnp.ndarray, lengths: jnp.ndarray
+                        ) -> Tuple[jnp.ndarray, ...]:
     """What the fused resident span sorts share, everything but the
     partition step: sentinel rows (length < 0) take partition MAX and sort
-    to the tail; the LSD passes; the sorted key columns returned as device
+    to the tail; the sort; its sorted key columns returned as device
     arrays so downstream merges never re-upload them.  `sentinel` is traced
     by the caller BEFORE its partition step: the hash kernel's operations
     then stand in the order they always had, and its compiled programs
     (and their compile-cache keys) are the ones from before the range
     kernel existed."""
-    partitions = jnp.where(
-        sentinel, jnp.int32(np.iinfo(np.int32).max), partitions)
-    sort_lens = jnp.where(lengths < 0, jnp.uint32(0xFFFFFFFF),
-                          lengths.astype(jnp.uint32))
-    sp, perm = _lsd_passes(partitions, lanes, sort_lens, skip_length_pass)
-    return sp, perm, lanes[perm], lengths[perm]
+    return _lsd_passes(
+        jnp.where(sentinel, jnp.int32(np.iinfo(np.int32).max), partitions),
+        lanes, lengths)
 
 
 def _fused_resident_hash_sort_impl(lanes: jnp.ndarray, lengths: jnp.ndarray,
-                                   num_partitions: int,
-                                   skip_length_pass: bool = False
+                                   num_partitions: int
                                    ) -> Tuple[jnp.ndarray, ...]:
     """hash-from-lanes + the resident span sort."""
     h = _fnv_rows_from_lanes(lanes, lengths)
     return _resident_span_sort(
         lengths < 0, (h % jnp.uint32(num_partitions)).astype(jnp.int32),
-        lanes, lengths, skip_length_pass)
+        lanes, lengths)
 
 
 def _range_partitions(lanes: jnp.ndarray, lengths: jnp.ndarray,
@@ -425,14 +434,13 @@ def _range_partitions(lanes: jnp.ndarray, lengths: jnp.ndarray,
 
 def _fused_resident_range_sort_impl(lanes: jnp.ndarray, lengths: jnp.ndarray,
                                     split_lanes: jnp.ndarray,
-                                    split_lengths: jnp.ndarray,
-                                    skip_length_pass: bool = False
+                                    split_lengths: jnp.ndarray
                                     ) -> Tuple[jnp.ndarray, ...]:
     """range-partition-from-lanes + the resident span sort."""
     return _resident_span_sort(
         lengths < 0,
         _range_partitions(lanes, lengths, split_lanes, split_lengths),
-        lanes, lengths, skip_length_pass)
+        lanes, lengths)
 
 
 def _resident_sort_kernels(fn: Callable, name: str,
@@ -450,10 +458,9 @@ def _resident_sort_kernels(fn: Callable, name: str,
 #: (plain, donating) by the partitioner's batch form
 _HASH_SORTS = _resident_sort_kernels(
     _fused_resident_hash_sort_impl, "resident_hash_sort",
-    ("num_partitions", "skip_length_pass"))
+    ("num_partitions",))
 _RANGE_SORTS = _resident_sort_kernels(
-    _fused_resident_range_sort_impl, "fused_resident_range_sort",
-    ("skip_length_pass",))
+    _fused_resident_range_sort_impl, "fused_resident_range_sort", ())
 
 
 def _resident_sort(num_partitions: int, splits, donate: bool) -> Callable:
@@ -461,7 +468,7 @@ def _resident_sort(num_partitions: int, splits, donate: bool) -> Callable:
     else: the hash kernel with its partition count, or, where `splits`
     (split_lanes u32[P-1, L], split_lengths i32[P-1], encoded at the span's
     lane width) are given, the range kernel with them.  Returns
-    launch(lanes, lengths, skip_length_pass).  Donation on accelerator
+    launch(lanes, lengths).  Donation on accelerator
     backends only -- XLA:CPU ignores it (with a warning per call)."""
     if splits is None:
         pair, rows, static = _HASH_SORTS, (), {"num_partitions":
@@ -470,8 +477,7 @@ def _resident_sort(num_partitions: int, splits, donate: bool) -> Callable:
         pair, rows, static = _RANGE_SORTS, tuple(
             jnp.asarray(s) for s in splits), {}
     kernel = pair[bool(donate and accelerator_present())]
-    return lambda lanes, lengths, skip: kernel(
-        lanes, lengths, *rows, skip_length_pass=skip, **static)
+    return lambda lanes, lengths: kernel(lanes, lengths, *rows, **static)
 
 
 # -- decomposed resident-span stages (ops/async_stage.py pipeline) ----------
@@ -479,36 +485,48 @@ def _resident_sort(num_partitions: int, splits, donate: bool) -> Callable:
 # the async pipeline runs them on different threads so span k+1's staging
 # overlaps span k's in-flight sort.
 
-def _pad_to_bucket(lanes: np.ndarray, lengths: np.ndarray
-                   ) -> Tuple[np.ndarray, np.ndarray]:
-    """Rows padded to their bucket with tail sentinels: lanes all ones,
-    length -1."""
+def _upload_rows(rows: np.ndarray, bucket: int = 0, fill: int = 0
+                 ) -> jnp.ndarray:
+    """A host matrix of few columns (u32[n, L] key lanes, u8[n, W] key
+    bytes) as a device array of the same shape, or of `bucket` rows with
+    the tail filled.  The chip keeps such an array column-major (layout
+    {0,1}: the rows are the minor dimension), so handed the row-major
+    matrix PJRT transposes it on its transfer threads in hundreds of
+    thousands of little pieces: 740,000 `Transpose` events a hash-join DAG
+    in the profiler's file, 0.7 GB of profiler memory a traced DAG (PERF.md
+    PR 35).  One transposed copy here, from the pool and padded in the same
+    pass, goes up as it lies; the `.T` on the device relabels its bytes."""
+    n, width = rows.shape
+    bucket = max(bucket, n)
+    flipped = hostpool.empty(width * bucket, rows.dtype).reshape(width,
+                                                                 bucket)
+    np.copyto(flipped[:, :n], rows.T)
+    flipped[:, n:] = fill
+    return jnp.asarray(flipped).T
+
+
+def _upload_padded(lanes: np.ndarray, lengths: np.ndarray
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Rows padded to their bucket with tail sentinels (lanes all ones,
+    length -1), on the device."""
     n, nb = lanes.shape[0], _bucket(lanes.shape[0])
-    lengths = lengths.astype(np.int32)
-    if nb != n:
-        lanes = np.pad(lanes, ((0, nb - n), (0, 0)),
-                       constant_values=np.uint32(0xFFFFFFFF))
-        lengths = np.pad(lengths, (0, nb - n), constant_values=-1)
-    return lanes, lengths
+    return (_upload_rows(lanes, nb, 0xFFFFFFFF),
+            jnp.asarray(np.pad(lengths.astype(np.int32), (0, nb - n),
+                               constant_values=-1)))
 
 
 def stage_resident_span(lanes: np.ndarray, lengths: np.ndarray):
-    """Host bucket-pad + H2D upload.  Returns (lanes_dev, lens_dev, n,
-    skip_length_pass)."""
-    n = lanes.shape[0]
-    uniform, _pad = uniform_clamped_lengths(lengths, lanes.shape[1] * 4 + 1)
-    lanes, lengths = _pad_to_bucket(lanes, lengths)
-    return (jax.device_put(jnp.asarray(lanes)),
-            jax.device_put(jnp.asarray(lengths)), n, uniform)
+    """Host bucket-pad + H2D upload.  Returns (lanes_dev, lens_dev, n)."""
+    return _upload_padded(lanes, lengths) + (lanes.shape[0],)
 
 
 def dispatch_resident_span(staged, num_partitions: int, splits=None):
     """Launch the fused kernel; returns in-flight device arrays immediately
     (JAX async dispatch) — block via readback_resident_span.  splits as
     _resident_sort takes them: by range instead of by hash."""
-    lanes_dev, lens_dev, n, uniform = staged
+    lanes_dev, lens_dev, n = staged
     sp, perm, out_lanes, out_lens = _resident_sort(
-        num_partitions, splits, donate=True)(lanes_dev, lens_dev, uniform)
+        num_partitions, splits, donate=True)(lanes_dev, lens_dev)
     return sp, perm, out_lanes, out_lens, n
 
 
@@ -530,14 +548,9 @@ def sort_span_resident(lanes: np.ndarray, lengths: np.ndarray,
     n = lanes.shape[0]
     if n == 0:
         return (np.zeros(0, np.int32), np.zeros(0, np.int32), None)
-    uniform, _pad = uniform_clamped_lengths(lengths, lanes.shape[1] * 4 + 1)
-    lanes, lengths = _pad_to_bucket(lanes, lengths)
-    # uniform real lengths make the length pass an identity reorder even
-    # with tail sentinels present: sentinel order is fully decided by the
-    # final partition pass (partition MAX)
     sp, perm, out_lanes, out_lens = _resident_sort(
         num_partitions, splits, donate=False)(
-            jnp.asarray(lanes), jnp.asarray(lengths), uniform)
+            *_upload_padded(lanes, lengths))
     sp = np.asarray(sp)[:n]
     perm = np.asarray(perm)[:n]
     return sp, perm, (out_lanes, out_lens, 0, n)
@@ -566,24 +579,16 @@ _slice_to_bucket = Kernel(_slice_to_bucket_impl, "slice_to_bucket",
                           static_argnames=("out_rows", "out_lanes"))
 
 
-def _fused_resident_merge_impl(lanes_list, lens_list,
-                               skip_length_pass: bool = False):
+def _fused_resident_merge_impl(lanes_list, lens_list):
     """Single-partition k-way merge of device-resident sorted key columns:
-    stable sort of the concatenation (TezMerger semantics — equal keys keep
+    the sort of the concatenation (TezMerger semantics — equal keys keep
     run order).  Sentinel rows (length < 0) sort to the tail."""
-    lanes = jnp.concatenate(lanes_list, axis=0)
-    lens = jnp.concatenate(lens_list, axis=0)
-    parts = jnp.where(lens < 0, jnp.int32(np.iinfo(np.int32).max),
-                      jnp.int32(0))
-    sort_lens = jnp.where(lens < 0, jnp.uint32(0xFFFFFFFF),
-                          lens.astype(jnp.uint32))
-    _, perm = _lsd_passes(parts, lanes, sort_lens, skip_length_pass)
-    return perm
+    return _sort_by_key(jnp.concatenate(lanes_list, axis=0),
+                        jnp.concatenate(lens_list, axis=0))[0]
 
 
 _fused_resident_merge = Kernel(
     _fused_resident_merge_impl, "resident_merge_sort",
-    static_argnames=("skip_length_pass",),
     launch_rows=lambda lanes_list, lens_list: sum(
         int(l.shape[0]) for l in lanes_list))
 
@@ -602,17 +607,14 @@ def _map_bucketed_perm(perm: np.ndarray, counts, common: int) -> np.ndarray:
     return (host_offsets[run_id] + within)[real].astype(np.int64)
 
 
-def merge_resident_slices(slices, uniform_lengths: bool = False
-                          ) -> np.ndarray:
-    """k-way merge over device-resident key views: one stable sort of the
-    slices' concatenation, each slice cut to a common bucket on the device.
+def merge_resident_slices(slices) -> np.ndarray:
+    """k-way merge over device-resident key views: one sort of the slices'
+    concatenation, each slice cut to a common bucket on the device.
 
     slices: list of (lanes_dev, lens_dev, lo, hi).  Returns the merge
     permutation into the HOST concatenation of the real rows (run order
     preserved for equal keys).  No key bytes move host->device; only the
-    permutation comes back.  uniform_lengths: the caller saw one key length
-    on every real row (uniform_clamped_lengths), so the length pass is an
-    identity reorder and is left out of the program."""
+    permutation comes back."""
     counts = [hi - lo for (_l, _n, lo, hi) in slices]
     # ONE common bucket for every slice: the merge program's compile key is
     # then (k, B, L) instead of the full ordered tuple of per-run sizes —
@@ -630,8 +632,7 @@ def merge_resident_slices(slices, uniform_lengths: bool = False
             lanes_list.append(sl)
             lens_list.append(ln)
     with tracing.span("merge.launch", cat="merge", runs=len(slices)):
-        perm_dev = _fused_resident_merge(lanes_list, lens_list,
-                                         skip_length_pass=uniform_lengths)
+        perm_dev = _fused_resident_merge(lanes_list, lens_list)
     # the host blocks here for the device: this merge's own work and every
     # launch other threads queued ahead of it on the chip
     with tracing.span("merge.readback", cat="merge",
@@ -643,21 +644,26 @@ def merge_resident_slices(slices, uniform_lengths: bool = False
 
 def _fused_hash_sort_impl(key_mat: jnp.ndarray, hash_lengths: jnp.ndarray,
                           lanes: jnp.ndarray, sort_lengths: jnp.ndarray,
-                          num_partitions: int,
-                          skip_length_pass: bool = False
+                          num_partitions: int
                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One dispatch: full-key FNV hash-partition + LSD sort.  Fusing all
-    passes into a single XLA program matters on TPU: per-dispatch latency
-    (host<->device round trips) would otherwise dominate small spans."""
+    """One dispatch: full-key FNV hash-partition + the sort.  One XLA
+    program matters on TPU: per-dispatch latency (host<->device round
+    trips) would otherwise dominate small spans."""
     partitions = _hash_to_partitions(key_mat, hash_lengths, num_partitions)
-    return _lsd_passes(partitions, lanes, sort_lengths, skip_length_pass)
+    return _lsd_passes(partitions, lanes, sort_lengths)[:2]
 
 
-_fused_hash_sort = Kernel(
-    _fused_hash_sort_impl, "hash_sort",
-    static_argnames=("num_partitions", "skip_length_pass"))
+_fused_hash_sort = Kernel(_fused_hash_sort_impl, "hash_sort",
+                          static_argnames=("num_partitions",))
 
-_fused_sort = Kernel(_lsd_passes, "sort_run")
+
+
+def _sort_run_impl(partitions, lanes, lengths):
+    """The sort alone: (sorted partitions, permutation)."""
+    return _lsd_passes(partitions, lanes, lengths)[:2]
+
+
+_fused_sort = Kernel(_sort_run_impl, "sort_run")
 
 
 def hash_sort_span(key_mat: np.ndarray, hash_lengths: np.ndarray,
@@ -670,26 +676,17 @@ def hash_sort_span(key_mat: np.ndarray, hash_lengths: np.ndarray,
         return np.zeros(0, np.int32), np.zeros(0, np.int32)
     width_cap = lanes.shape[1] * 4 + 1
     slen = np.minimum(lengths.astype(np.int64), width_cap)
-    # uniform clamped lengths over REAL rows: the length pass would be an
-    # identity reorder — skip a full sort pass.  Pad rows are irrelevant to
-    # every pass but the final partition one (which sweeps them to the tail
-    # as a block), so they are padded with the same uniform value.
-    uniform, pad_len = uniform_clamped_lengths(slen, width_cap)
     nb = _bucket(n)
-    hash_lengths = hash_lengths.astype(np.int32)
-    if nb != n:
-        pad = nb - n
-        key_mat = np.pad(key_mat, ((0, pad), (0, 0)), constant_values=255)
-        hash_lengths = np.pad(hash_lengths, (0, pad), constant_values=-1)
-        lanes = np.pad(lanes, ((0, pad), (0, 0)),
-                       constant_values=np.uint32(0xFFFFFFFF))
-        slen = np.pad(slen, (0, pad), constant_values=pad_len)
-    sp, perm = _fused_hash_sort(jnp.asarray(key_mat),
-                                jnp.asarray(hash_lengths),
-                                jnp.asarray(lanes),
-                                jnp.asarray(slen.astype(np.uint32)),
-                                num_partitions=num_partitions,
-                                skip_length_pass=uniform)
+    # the partition column (MAX on a pad row, whose hash length is -1)
+    # sweeps the pads to the tail whatever their length says
+    sp, perm = _fused_hash_sort(
+        _upload_rows(key_mat, nb, 255),
+        jnp.asarray(np.pad(hash_lengths.astype(np.int32), (0, nb - n),
+                           constant_values=-1)),
+        _upload_rows(lanes, nb, 0xFFFFFFFF),
+        jnp.asarray(np.pad(slen.astype(np.uint32), (0, nb - n),
+                           constant_values=width_cap)),
+        num_partitions=num_partitions)
     sp = np.asarray(sp)
     perm = np.asarray(perm)
     if nb != n:
@@ -701,32 +698,31 @@ def hash_sort_span(key_mat: np.ndarray, hash_lengths: np.ndarray,
 def _stage_sort_columns(partitions: np.ndarray, lanes: np.ndarray,
                         lengths: np.ndarray):
     """Clamp lengths at the lane cap, pad the three sort columns to the
-    bucket of their rows (pads carry partition MAX: the partition pass
-    alone sweeps them to the tail) and upload.  Returns (device operands,
-    uniform) — uniform: one clamped length on every real row, so a length
-    pass would be an identity reorder."""
+    bucket of their rows (pads carry partition MAX: the partition column
+    alone sweeps them to the tail) and upload.  Returns the device
+    operands."""
     n = partitions.shape[0]
     width_cap = lanes.shape[1] * 4 + 1
-    slen = np.minimum(lengths.astype(np.int64), width_cap)
-    uniform, pad_len = uniform_clamped_lengths(slen, width_cap)
-    slen = slen.astype(np.uint32)
+    slen = np.minimum(lengths.astype(np.int64), width_cap).astype(np.uint32)
     nb = _bucket(n)
-    if nb != n:
-        partitions = np.pad(partitions, (0, nb - n),
-                            constant_values=np.iinfo(np.int32).max)
-        lanes = np.pad(lanes, ((0, nb - n), (0, 0)))
-        slen = np.pad(slen, (0, nb - n), constant_values=pad_len)
-    return (jnp.asarray(partitions), jnp.asarray(lanes),
-            jnp.asarray(slen)), uniform
+    return (jnp.asarray(np.pad(partitions, (0, nb - n),
+                               constant_values=np.iinfo(np.int32).max)),
+            _upload_rows(lanes, nb),
+            jnp.asarray(np.pad(slen, (0, nb - n),
+                               constant_values=width_cap)))
 
 
 def sort_run(partitions: np.ndarray, lanes: np.ndarray,
              lengths: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """LSD radix sort by (partition, key lanes, clamped length): stable
-    single-key u32 passes from least- to most-significant key, fused into
-    the one compiled `_fused_sort` program (variadic N-operand `lax.sort`
-    costs minutes of XLA compile time at large N on TPU; chained single-key
-    sorts compile in seconds).
+    """Stable sort by (partition, key lanes, clamped length): the one
+    variadic `lax.sort` of `_lsd_passes` as the compiled `_fused_sort`
+    program.  (Until PR 35 an LSD ladder of stable single-key passes, each
+    gathering its key by the permutation, for fear of the variadic sort's
+    compile time.  Measured on a v5e, PERF.md PR 35: the ladder compiles in
+    20-22 s and runs 12.5 / 51 / 119 ms at 2^18 x 2 / 2^20 x 3 / 1.5 x 2^20
+    x 6 lanes, nearly all of it the gathers; this sort 1.4 / 4.0 / 11 ms
+    with the length as an operand of its own, compiling in 49 / 67 / 197 s
+    -- compile time grows with the square of the operands.)
 
     The clamped length disambiguates keys whose zero padding collides (if
     padded prefixes are equal, the longer key == shorter key + trailing
@@ -739,22 +735,20 @@ def sort_run(partitions: np.ndarray, lanes: np.ndarray,
     n = partitions.shape[0]
     if n == 0:
         return partitions, np.zeros(0, dtype=np.int32)
-    operands, _uniform = _stage_sort_columns(partitions, lanes, lengths)
-    sorted_parts, perm = _fused_sort(*operands)
+    sorted_parts, perm = _fused_sort(
+        *_stage_sort_columns(partitions, lanes, lengths))
     return (np.asarray(sorted_parts)[:n], np.asarray(perm)[:n])
 
 
 # ---------------------------------------------------------------------------
 # merge of sorted runs = sort of concatenation (stable; run order preserved)
 # ---------------------------------------------------------------------------
-def _merge_sort_impl(partitions, lanes, lengths,
-                     skip_length_pass: bool = False):
-    """The LSD sort under a program name of its own: the permutation."""
-    return _lsd_passes(partitions, lanes, lengths, skip_length_pass)[1]
+def _merge_sort_impl(partitions, lanes, lengths):
+    """The sort under a program name of its own: the permutation."""
+    return _lsd_passes(partitions, lanes, lengths)[1]
 
 
-_merge_sort = Kernel(_merge_sort_impl, "merge_sort",
-                     static_argnames=("skip_length_pass",))
+_merge_sort = Kernel(_merge_sort_impl, "merge_sort")
 
 #: the programs that compare rows in a merge; ``slice_to_bucket`` stages
 #: their operands.  DEVICE_MERGE_LAUNCH_ROWS sums the rows of these, so
@@ -766,9 +760,9 @@ MERGE_LEVEL_KERNELS = ("merge_sort", "resident_merge_sort")
 def merge_runs(partitions: np.ndarray, lanes: np.ndarray,
                lengths: np.ndarray) -> np.ndarray:
     """k-way merge of host-fed sorted runs, handed over as their
-    concatenation in run-arrival order: ONE launch of the stable LSD sort
-    by (partition, key lanes, clamped length) over the concatenation padded
-    once to its bucket.  Stability keeps equal keys in run order (TezMerger
+    concatenation in run-arrival order: ONE launch of the sort by
+    (partition, key lanes, clamped length, arrival order) over the
+    concatenation padded once to its bucket: equal keys keep run order (TezMerger
     segment-queue semantics).  Returns the permutation into the
     concatenation; pads (partition MAX) sort to the tail and are cut.  Like
     sort_run, prefix-equal beyond-cap keys compare equal here and are
@@ -778,9 +772,9 @@ def merge_runs(partitions: np.ndarray, lanes: np.ndarray,
         return np.zeros(0, dtype=np.int64)
     nb = _bucket(n)
     with tracing.span("merge.stage", cat="merge", rows=n, bucket=nb):
-        operands, uniform = _stage_sort_columns(partitions, lanes, lengths)
+        operands = _stage_sort_columns(partitions, lanes, lengths)
     with tracing.span("merge.launch", cat="merge"):
-        perm_dev = _merge_sort(*operands, skip_length_pass=uniform)
+        perm_dev = _merge_sort(*operands)
     # the host blocks here for the device: this merge's own work and every
     # launch other threads queued ahead of it on the chip
     with tracing.span("merge.readback", cat="merge", rows=nb):
@@ -792,58 +786,53 @@ def merge_runs(partitions: np.ndarray, lanes: np.ndarray,
 # ---------------------------------------------------------------------------
 # merge-join match = sort of the two sides' concatenation + neighbour compare
 # ---------------------------------------------------------------------------
-def _matches_after_sort(xp, perm, lanes, lens, n_left: int):
+def _matches_after_sort(xp, perm, s_lanes, s_lens, n_left: int):
     """`perm` orders the concatenation [left rows, right rows] by (lanes,
-    length), stably: among equal keys the left rows stand first.  A key
-    both sides hold therefore shows exactly one place where a left row is
+    length), stably: among equal keys the left rows stand first;
+    `s_lanes`, `s_lens` are the key columns in that order.  A key both
+    sides hold therefore shows exactly one place where a left row is
     followed by a right row of the same key: that left row's index stands
     in the result, -1 everywhere else (sentinel rows, length < 0, never
     match).  xp: numpy on the host engine, jax.numpy traced."""
-    s_lanes, s_lens = lanes[perm], lens[perm]
     same = (s_lens[:-1] == s_lens[1:]) & (s_lens[:-1] >= 0) & \
         (s_lanes[:-1] == s_lanes[1:]).all(axis=1)
     is_left = perm < n_left
     return xp.where(is_left[:-1] & ~is_left[1:] & same, perm[:-1], -1)
 
 
-def _sort_two_sides(first_lanes, first_lens, second_lanes, second_lens,
-                    skip_length_pass: bool):
-    """Traced: one stable LSD sort of two sides' concatenation, each padded
-    to its bucket with sentinels -- the side is the least significant
-    column by the order of concatenation, which a stable sort keeps.
-    Returns (perm, lanes, lens) of the concatenation."""
-    lanes = jnp.concatenate([first_lanes, second_lanes], axis=0)
-    lens = jnp.concatenate([first_lens, second_lens], axis=0)
-    parts = jnp.where(lens < 0, jnp.int32(np.iinfo(np.int32).max),
-                      jnp.int32(0))
-    sort_lens = jnp.where(lens < 0, jnp.uint32(0xFFFFFFFF),
-                          lens.astype(jnp.uint32))
-    _, perm = _lsd_passes(parts, lanes, sort_lens, skip_length_pass)
-    return perm, lanes, lens
+def _sort_two_sides(first_lanes, first_lens, second_lanes, second_lens):
+    """Traced: one sort of two sides' concatenation, each padded to its
+    bucket with sentinels -- the side is the least significant column by
+    the order of concatenation, which the sort keeps among equal keys.
+    Returns (perm, sorted lanes, sorted lens) of the concatenation."""
+    return _sort_by_key(
+        jnp.concatenate([first_lanes, second_lanes], axis=0),
+        jnp.concatenate([first_lens, second_lens], axis=0))
 
 
 def _lexsort_two_sides(first_lanes, first_lens, second_lanes, second_lens):
-    """_sort_two_sides on the host engine: numpy's stable lexsort."""
+    """_sort_two_sides on the host engine: numpy's stable lexsort, the
+    columns gathered by its permutation."""
     lanes = np.concatenate([first_lanes, second_lanes])
     lens = np.concatenate([first_lens, second_lens]).astype(np.int32)
     perm = np.lexsort((lens,) + tuple(
         lanes[:, i] for i in range(lanes.shape[1] - 1, -1, -1)))
-    return perm, lanes, lens
+    return perm, lanes[perm], lens[perm]
 
 
-def _join_match_impl(left_lanes, left_lens, right_lanes, right_lens,
-                     skip_length_pass: bool = False):
+def _join_match_impl(left_lanes, left_lens, right_lanes, right_lens):
     """Semi-join match of two key-sorted sides: the sort of their
     concatenation, then a neighbour compare.  Returns i32[B - 1]: the left
     row of every key both sides hold (once a key), -1 elsewhere, in key
     order."""
-    perm, lanes, lens = _sort_two_sides(left_lanes, left_lens, right_lanes,
-                                        right_lens, skip_length_pass)
-    return _matches_after_sort(jnp, perm, lanes, lens, left_lanes.shape[0])
+    perm, s_lanes, s_lens = _sort_two_sides(left_lanes, left_lens,
+                                            right_lanes, right_lens)
+    return _matches_after_sort(jnp, perm, s_lanes, s_lens,
+                               left_lanes.shape[0])
 
 
 _join_match = Kernel(
-    _join_match_impl, "join_match", static_argnames=("skip_length_pass",),
+    _join_match_impl, "join_match",
     launch_rows=lambda ll, _ln, rl, _rn: int(ll.shape[0] + rl.shape[0]))
 
 
@@ -856,15 +845,12 @@ def join_match(left_lanes: np.ndarray, left_lens: np.ndarray,
     fine.  Each side is padded to its own bucket, so the program's compile
     key is (left bucket, right bucket, L); only the matches' row indices
     come back."""
-    uniform, _pad = uniform_clamped_lengths(
-        np.concatenate([left_lens, right_lens]), left_lanes.shape[1] * 4 + 1)
     with tracing.span("join.match", cat="join", stage="stage",
                       rows=len(left_lens) + len(right_lens)):
-        operands = [jnp.asarray(a) for a in
-                    _pad_to_bucket(left_lanes, left_lens) +
-                    _pad_to_bucket(right_lanes, right_lens)]
+        operands = _upload_padded(left_lanes, left_lens) + \
+            _upload_padded(right_lanes, right_lens)
     with tracing.span("join.match", cat="join", stage="launch"):
-        hits_dev = _join_match(*operands, skip_length_pass=uniform)
+        hits_dev = _join_match(*operands)
     # the host blocks here for the device, as in merge.readback
     with tracing.span("join.match", cat="join", stage="readback"):
         hits = np.asarray(hits_dev)
@@ -876,9 +862,9 @@ def join_match_host(left_lanes: np.ndarray, left_lens: np.ndarray,
                     ) -> np.ndarray:
     """join_match on the host engine: numpy's stable lexsort in the sort's
     place, the same neighbour compare."""
-    perm, lanes, lens = _lexsort_two_sides(left_lanes, left_lens,
-                                           right_lanes, right_lens)
-    hits = _matches_after_sort(np, perm, lanes, lens, len(left_lens))
+    perm, s_lanes, s_lens = _lexsort_two_sides(left_lanes, left_lens,
+                                               right_lanes, right_lens)
+    hits = _matches_after_sort(np, perm, s_lanes, s_lens, len(left_lens))
     return hits[hits >= 0].astype(np.int64)
 
 
@@ -887,11 +873,12 @@ def join_match_host(left_lanes: np.ndarray, left_lens: np.ndarray,
 # row" -- no hash table and no binary search: the same stable passes as the
 # merges and the merge-join's match
 # ---------------------------------------------------------------------------
-def _probe_hits_after_sort(xp, cummax_from_end, perm, lanes, lens,
+def _probe_hits_after_sort(xp, cummax_from_end, perm, s_lanes, s_lens,
                            n_stream: int):
     """`perm` orders the concatenation [stream rows, build rows] by (lanes,
-    length), stably: inside a run of equal keys the stream rows stand
-    first, the build rows last.  A run holds a build row exactly when its
+    length), stably, and `s_lanes`, `s_lens` are the key columns in that
+    order: inside a run of equal keys the stream rows stand first, the
+    build rows last.  A run holds a build row exactly when its
     last row is one, and every stream row of such a run is a hit: the run's
     last row carries (its distance from the end) * 2 + (is it a build row),
     and a running maximum from the end hands the nearest run end's value to
@@ -899,7 +886,6 @@ def _probe_hits_after_sort(xp, cummax_from_end, perm, lanes, lens,
     place that is a hit, -1 elsewhere (sentinel rows, length < 0, never
     match)."""
     n = perm.shape[0]
-    s_lanes, s_lens = lanes[perm], lens[perm]
     same_as_next = (s_lens[:-1] == s_lens[1:]) & \
         (s_lanes[:-1] == s_lanes[1:]).all(axis=1)
     run_end = xp.concatenate([~same_as_next, xp.ones(1, dtype=bool)])
@@ -911,23 +897,21 @@ def _probe_hits_after_sort(xp, cummax_from_end, perm, lanes, lens,
     return xp.where(is_stream & held & (s_lens >= 0), perm, -1)
 
 
-def _join_probe_impl(stream_lanes, stream_lens, build_lanes, build_lens,
-                     skip_length_pass: bool = False):
+def _join_probe_impl(stream_lanes, stream_lens, build_lanes, build_lens):
     """Semi-join probe of one block of stream rows against the build side,
     each padded to its bucket with sentinels: join_match's sort, then every
     stream row whose key the build side holds -- each occurrence, where
     the match keeps one row a distinct key.  Returns i32[B]: hits' stream
     row indices, -1 elsewhere, in key order."""
-    perm, lanes, lens = _sort_two_sides(stream_lanes, stream_lens,
-                                        build_lanes, build_lens,
-                                        skip_length_pass)
+    perm, s_lanes, s_lens = _sort_two_sides(stream_lanes, stream_lens,
+                                            build_lanes, build_lens)
     return _probe_hits_after_sort(
-        jnp, lambda v: jax.lax.cummax(v, axis=0, reverse=True), perm, lanes,
-        lens, stream_lanes.shape[0])
+        jnp, lambda v: jax.lax.cummax(v, axis=0, reverse=True), perm,
+        s_lanes, s_lens, stream_lanes.shape[0])
 
 
 _join_probe = Kernel(
-    _join_probe_impl, "join_probe", static_argnames=("skip_length_pass",),
+    _join_probe_impl, "join_probe",
     launch_rows=lambda sl, _sn, bl, _bn: int(sl.shape[0] + bl.shape[0]))
 
 
@@ -936,24 +920,21 @@ def stage_join_build(build_lanes: np.ndarray, build_lens: np.ndarray):
     joiner keeps across its probes (``join_probe``'s `build`)."""
     with tracing.span("join.match", cat="join", stage="stage", how="semi",
                       rows=len(build_lens)):
-        return tuple(jnp.asarray(a)
-                     for a in _pad_to_bucket(build_lanes, build_lens))
+        return _upload_padded(build_lanes, build_lens)
 
 
 def join_probe(stream_lanes: np.ndarray, stream_lens: np.ndarray,
-               build: tuple, uniform: bool = False) -> np.ndarray:
+               build: tuple) -> np.ndarray:
     """The rows of one stream block whose key the build side holds, every
     occurrence, ascending by row.  `build` is ``stage_join_build``'s pair
     (lanes of the block's width holding whole keys); duplicates on either
     side are fine.  The program's compile key is (block bucket, build
-    bucket, L); only the hits' row indices come back.  `uniform`: one key
-    length over both sides, so the length pass is an identity."""
+    bucket, L); only the hits' row indices come back."""
     with tracing.span("join.match", cat="join", stage="stage", how="semi",
                       rows=len(stream_lens)):
-        operands = [jnp.asarray(a)
-                    for a in _pad_to_bucket(stream_lanes, stream_lens)]
+        operands = _upload_padded(stream_lanes, stream_lens)
     with tracing.span("join.match", cat="join", stage="launch", how="semi"):
-        hits_dev = _join_probe(*operands, *build, skip_length_pass=uniform)
+        hits_dev = _join_probe(*operands, *build)
     # the host blocks here for the device, as in merge.readback
     with tracing.span("join.match", cat="join", stage="readback",
                       how="semi"):
@@ -966,9 +947,9 @@ def join_probe_host(stream_lanes: np.ndarray, stream_lens: np.ndarray,
                     ) -> np.ndarray:
     """join_probe on the host engine: numpy's stable lexsort in the sort's
     place, the same run-wise test."""
-    perm, lanes, lens = _lexsort_two_sides(stream_lanes, stream_lens,
-                                           build_lanes, build_lens)
+    perm, s_lanes, s_lens = _lexsort_two_sides(stream_lanes, stream_lens,
+                                               build_lanes, build_lens)
     hits = _probe_hits_after_sort(
-        np, lambda v: np.maximum.accumulate(v[::-1])[::-1], perm, lanes,
-        lens, len(stream_lens))
+        np, lambda v: np.maximum.accumulate(v[::-1])[::-1], perm, s_lanes,
+        s_lens, len(stream_lens))
     return np.sort(hits[hits >= 0]).astype(np.int64)

@@ -1071,8 +1071,7 @@ def _record_launches(counters: Optional[TezCounters], tally: dict) -> None:
                            if k in tally))
 
 
-def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int,
-                                uniform_lengths: bool
+def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int
                                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Multi-partition device-resident merge: each run's HBM key columns are
     (partition, key)-sorted, so partition p occupies the contiguous rows
@@ -1096,7 +1095,7 @@ def _merge_resident_partitioned(live: Sequence[Run], num_partitions: int,
                 bases.append(off + lo)
         if not slices:
             continue
-        perm = device.merge_resident_slices(slices, uniform_lengths)
+        perm = device.merge_resident_slices(slices)
         with tracing.span("merge.gather", cat="merge", rows=len(perm)):
             cnts = np.asarray([hi - lo for (_l, _n, lo, hi) in slices],
                               dtype=np.int64)
@@ -1159,19 +1158,13 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
             # device-resident merge: key columns are already in HBM from
             # the producers' span sorts — only the permutation comes back
             # (VERDICT r1 item 4; TezMerger semantics preserved)
-            # the same test the span sort makes: one key length on every
-            # row leaves the length pass out (resident views hold whole
-            # keys, so the clamp at the lane cap never bites)
-            uniform, _pad = device.uniform_clamped_lengths(
-                np.concatenate([np.diff(r.batch.key_offsets) for r in live]),
-                max(v[0].shape[1] for v in views) * 4 + 1)
             with device.launch_tally() as tally:
                 if num_partitions == 1:
-                    perm = device.merge_resident_slices(views, uniform)
+                    perm = device.merge_resident_slices(views)
                     row_index = None
                 else:
                     perm, row_index = _merge_resident_partitioned(
-                        live, num_partitions, uniform)
+                        live, num_partitions)
             _record_merge_ms(counters, t0)
             _record_launches(counters, tally)
             with tracing.span("merge.gather", cat="merge", rows=len(perm)):

@@ -12,7 +12,8 @@ at ``reduce_trace``  the profiler's file is still there (``run.py`` removes
     its working directory afterwards): its size, the clock check, and the
     ``XLA Modules`` events of the merge programs (what
     ``merge_launches_per_dag`` has to agree with) from the planes
-    ``load_xplane`` returns
+    ``load_xplane`` returns, and every program's events and device seconds
+    inside the window (``program_device_s``)
 at ``label_gap``     for each idle gap the reducer labels, every span name's
     cover of it and not only the largest (``breakdown.idle_gaps`` keeps
     that one)
@@ -40,6 +41,14 @@ timeline_ms  where in a DAG each phase stands: for every ``<vertex>/<span
              of its first start and its last end, in ms from the root
              span's start, in the order they begin — what the head of a DAG
              and each stage boundary are made of
+programs     per compiled program, the window's ``kernel.<Kernel.name>``
+             spans of the kernels that trace it a DAG (``launches_a_dag``)
+             and the program's device time in the trace over that count
+             (``device_ms_a_launch``): what one launch costs the chip
+compiled     every signature this process compiled (``COMPILE_LOG``): the
+             kernel, the signature, the seconds, and ``sort_ops``, the sort
+             operations in its lowered module -- the witness of which sort
+             body was traced
 exchange_self_s_a_dag  the same self time for the ``exchange.*`` spans
              alone, each named with the argument that says which of its
              sites it is (``stage``, else ``what``, else ``device``: a
@@ -148,6 +157,34 @@ def timeline(spans, roots, marks) -> dict:
     return dict(sorted(rows.items(), key=lambda kv: kv[1]))
 
 
+def kernel_programs() -> dict:
+    """``Kernel.name`` -> the program it traces, of every kernel of
+    ``ops/device.py`` (a donating flavor traces its plain twin's)."""
+    from tez_tpu.ops import device
+    return {k.name: k.program
+            for value in vars(device).values()
+            for k in (value if isinstance(value, tuple) else (value,))
+            if isinstance(k, device.Kernel)}
+
+
+def program_table(device_s, kernel_spans, programs, dags) -> dict:
+    """Per program: launches a DAG, by the ``kernel.<name>`` spans of the
+    kernels that trace it, and device ms a launch, its device seconds in
+    the trace over that count.  A program nothing launched in the window is
+    left out; one the trace did not see reads no device time."""
+    launches = collections.Counter()
+    for name, count in kernel_spans.items():
+        program = programs.get(name[len("kernel."):])
+        if program is not None:
+            launches[program] += count
+    return {program: {
+        "launches_a_dag": round(count / dags, 2),
+        "device_ms_a_launch": round(
+            device_s[program] * 1e3 / count, 3) if program in device_s
+        else None}
+        for program, count in launches.most_common()}
+
+
 def event_delivery(dags) -> dict:
     """The window's ``am.task.event_wait`` and heartbeat counts, a DAG."""
     from tez_tpu.common import metrics
@@ -190,7 +227,11 @@ def main() -> int:
             for line in p["lines"] if line["name"] == trace_reduce.MODULES_LINE
             for e in line["events"]
             if trace_reduce.program_name(e[0]) in MERGE_PROGRAMS)
-        return trace_reduce.reduce_planes(planes, n_devices, spans, marks)
+        res = trace_reduce.reduce_planes(planes, n_devices, spans, marks)
+        # the reducer's own per-program sums (its ten largest)
+        found["program_device_s"] = dict(
+            res["breakdown"]["device_ops"]) if res else {}
+        return res
 
     def label_and_keep(lo, hi, selfs):
         cover = collections.Counter()
@@ -248,6 +289,10 @@ def main() -> int:
         if name.startswith("exchange."):
             by_site[name] += b - a
     events = found.pop("merge_program_events")
+    from tez_tpu.ops import device
+    kernel_spans = collections.Counter(
+        row[0] for row in window if row[0].startswith("kernel.")
+        and row[0] != "kernel.compile")
     args = dict(zip(sys.argv[1::2], sys.argv[2::2]))
     summary = {
         "workload": args.get("--workload"), "seed": args.get("--seed"),
@@ -263,6 +308,12 @@ def main() -> int:
         "exchange_self_s_a_dag": {k: round(v / dags, 4)
                                   for k, v in by_site.most_common()},
         "timeline_ms": timeline(spans, roots, marks),
+        "programs": program_table(found.pop("program_device_s"),
+                                  kernel_spans, kernel_programs(), dags),
+        "compiled": [{"kernel": name, "signature": sig,
+                      "seconds": round(secs, 2), "sort_ops": sort_ops}
+                     for name, sig, secs, sort_ops, _t in
+                     device.COMPILE_LOG],
         "merge_program_events": dict(events),
         "merge_program_events_a_dag": sum(events.values()) / dags,
         **found}

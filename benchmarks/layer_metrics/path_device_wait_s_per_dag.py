@@ -1,0 +1,6 @@
+"""path_device_wait_s_per_dag: see path_device_wait_s_per_dag.json."""
+import path_metrics
+
+
+def read(obs):
+    return path_metrics.path_s_per_dag(obs, "device wait")

@@ -131,22 +131,32 @@ def test_spans_export_valid_trace_event_json():
 
 
 def test_critical_path_picks_dominant():
+    """The walk of a one-thread buffer: every second of the period under one
+    name, the envelope's own time `unnamed`, the dominant member named."""
     from tez_tpu.tools.trace_export import (critical_path,
-                                            critical_path_report)
+                                            critical_path_report,
+                                            dominant_span)
     tracing.arm(scope="t")
     with tracing.span("dag", cat="dag") as root:
         with tracing.span("fast", vertex="a"):
             pass
         with tracing.span("slow", vertex="b") as slow:
             slow.start -= 0.5                          # fake 500ms of work
+        root.start -= 0.6                              # 100ms of its own
     spans = tracing.snapshot()
     path = critical_path(spans)
-    assert [s.name for s in path] == ["dag", "slow"]
-    assert path[0].trace_id == root.trace_id
+    assert path["seconds"] == pytest.approx(root.end - root.start)
+    assert sum(path["by_class"].values()) == pytest.approx(path["seconds"])
+    assert path["by_name"]["slow"] == pytest.approx(0.5, abs=1e-3)
+    assert path["by_class"]["unnamed"] == pytest.approx(0.1, abs=1e-3)
+    assert path["miss"] == 0 and path["steps"]["guess"] == 0
+    chain = [c["name"] for c in path["chain"]]
+    assert chain[0] == chain[-1] == "dag.dag" and "fast" in chain
+    assert dominant_span(path)["span_id"] == slow.span_id
     rep = critical_path_report(spans)
     assert rep["dominant"]["name"] == "slow"
     assert rep["dominant"]["vertex"] == "b"
-    assert rep["chain"][0]["name"] == "dag"
+    assert rep["chain"][0]["name"] == "dag.dag"
 
 
 # ------------------------------------------------------- latency histograms
@@ -423,6 +433,14 @@ NEW_SPAN_SITES = [
     ("exchange.wait_peers", "exchange"), ("exchange.plan", "exchange"),
     ("exchange.pack", "exchange"), ("exchange.launch", "exchange"),
     ("exchange.readback", "exchange"), ("exchange.decode", "exchange"),
+    # PR 36: the DAG boundary, the envelopes' children, the stall witness
+    ("submit_dag", "client"), ("wake", "client"), ("status", "client"),
+    ("build", "client"), ("am.dag.admit", "am"), ("am.dag.init", "am"),
+    ("task.instantiate", "task"), ("processor.initialize", "task"),
+    ("input.initialize", "task"), ("output.initialize", "task"),
+    ("input.start", "task"), ("task.events", "task"),
+    ("input.close", "task"), ("processor.close", "task"),
+    ("finish", "task"), ("host.stall", "host"),
 ]
 
 
@@ -437,6 +455,7 @@ def test_disarmed_new_sites_are_noop(name, cat):
     with metrics.timer(name):
         pass
     tracing.event(name, rows=1)
+    assert tracing.here() == ""          # the link helper: one flag load
     assert tracing.snapshot() == []
 
 
@@ -562,9 +581,13 @@ def test_merge_launch_rows_are_the_padded_concatenation():
 # ------------------------------------------- cause across threads (ISSUE 26)
 
 def _chains_end_in(spans, root):
+    """Spans whose parent chain does not end in `root`; the client's own
+    spans and the stall witness's are roots by design and left out."""
     by_id = {s.span_id: s for s in spans}
     bad = []
     for s in spans:
+        if s.cat in ("client", "host"):
+            continue
         cur, hops = s, 0
         while cur.parent_id is not None and hops < 64:
             if cur.parent_id not in by_id:
@@ -659,7 +682,14 @@ def test_owc_every_span_hangs_under_the_dag_root(traced_owc):
     assert status.state.name == "SUCCEEDED" and dropped == 0
     (root,) = [s for s in spans if s.cat == "dag"]
     assert root.name == "dag:OrderedWordCount"
-    assert {s.trace_id for s in spans} == {root.trace_id}
+    # one trace a DAG; the client's own spans (submit_dag, wake, status:
+    # the root opens inside the first) and the stall witness's are roots
+    # of their own, found by the clock
+    own = [s for s in spans if s.cat in ("client", "host")]
+    assert {s.name for s in own if s.cat == "client"} == {
+        "build", "submit_dag", "wake", "status"}
+    assert all(s.parent_id is None for s in own)
+    assert {s.trace_id for s in spans if s not in own} == {root.trace_id}
     assert _chains_end_in(spans, root) == []
     names = {s.name.split(":")[0] for s in spans}
     # the worker-thread spans are the point: staging/readback threads,
@@ -672,6 +702,10 @@ def test_owc_every_span_hangs_under_the_dag_root(traced_owc):
             "processor.tokenize", "processor.sum", "processor.format",
             "output.write", "output.close", "output.commit",
             "am.task.queue", "am.task.done", "am.dag.commit",
+            "am.dag.admit", "am.dag.init",
+            "task.instantiate", "processor.initialize", "input.initialize",
+            "output.initialize", "input.start", "task.events",
+            "input.close", "processor.close", "finish",
             "am.vertex", "am.dag.finish"} <= names
     worker = {s.name for s in spans
               if s.thread.startswith("sorter-pipeline")}

@@ -12,7 +12,7 @@ import logging
 import os
 import threading
 import concurrent.futures
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from tez_tpu.am.dag_impl import DAGImpl, DAGState, TERMINAL_DAG_STATES
 from tez_tpu.am.events import (DAGEvent, DAGEventType, SchedulerEvent,
@@ -125,6 +125,9 @@ class DAGAppMaster:
         #: here: a terminal DAG's trailing events are dropped instead)
         self.retired_dags: Dict[str, DAGImpl] = {}
         self.completed_dags: Dict[str, DAGState] = {}
+        #: traced DAGs only: (the root span's end, the span that ended the
+        #: client's wait), taken by wait_for_dag for its ``client.wake``
+        self._dag_wakers: Dict[str, Tuple[float, str]] = {}
         self.completed_dag_names: Dict[str, str] = {}
         #: dag name -> latest dag_id that ran under it (client re-attach
         #: resolves recovered DAGs by name — dag ids are AM-assigned and a
@@ -463,6 +466,10 @@ class DAGAppMaster:
             # is left below is the notify
             sp.annotate(final_state=final.name)
             sp.finish()
+            # the client's wake-up starts here (wait_for_dag records it);
+            # what ended its wait is the commit where there was one
+            self._dag_wakers[str(dag.dag_id)] = (
+                sp.end, dag._commit_span.span_id or sp.span_id)
         tracing.clear(str(dag.dag_id))
         from tez_tpu.obs import flight
         if flight.armed():
@@ -499,6 +506,7 @@ class DAGAppMaster:
     def _start_dag(self, plan: DAGPlan, recovery_data: Any,
                    tenant: str, sub_id: str = "") -> DAGId:
         """Instantiate + start an admitted DAG (AdmissionController only)."""
+        t_admit = clock.wall_s()
         with self._dag_done:
             self._dag_seq += 1
             dag_id = DAGId(self.app_id, self._dag_seq)
@@ -566,10 +574,23 @@ class DAGAppMaster:
         from tez_tpu.common import tracing
         if tracing.install_from_conf(dag.conf, scope=str(dag_id)):
             sp = tracing.start_span(
-                f"dag:{plan.name}", cat="dag", lane=dag.trace_lane,
-                dag_id=str(dag_id), am_epoch=self.attempt)
+                f"dag:{plan.name}", cat="dag", parent=tracing.NEW_TRACE,
+                lane=dag.trace_lane, dag_id=str(dag_id),
+                am_epoch=self.attempt)
             dag.trace_span = sp
             dag.trace_carrier = sp.context.carrier()
+            # what the AM did before the root could open (the plan
+            # serialized and journaled, the DAG built from it), known only
+            # now: opened in the past, under the root, on the submitting
+            # thread; the root's own start stays where it was
+            admit = tracing.start_span("am.dag.admit", cat="am", parent=sp,
+                                       start=t_admit, after=tracing.here())
+            admit.finish()
+            # DAG and vertex init on the dispatcher, up to the first
+            # attempt scheduled (TaskScheduler.schedule ends it)
+            dag.trace_init_span = dag.start_am_span(
+                "am.dag.init", after=admit.span_id)
+            dag.trace_cause = dag.trace_init_span.span_id
         # flight recorder: armed per-DAG like the planes above; the ring
         # survives disarm so tools/doctor.py and GET-time snapshots can
         # read it after the run
@@ -625,7 +646,15 @@ class DAGAppMaster:
                 lambda: str(dag_id) in self.completed_dags, timeout)
             if not ok:
                 raise TimeoutError(f"DAG {dag_id} still running")
-            return self.completed_dags[str(dag_id)]
+            final = self.completed_dags[str(dag_id)]
+        waker = self._dag_wakers.pop(str(dag_id), None)
+        if waker is not None:
+            # the root's end -> this thread runs again: the short stretch
+            # of the client's wait that no other thread's work fills
+            from tez_tpu.common import tracing
+            tracing.start_span("wake", cat="client", start=waker[0],
+                               after=waker[1], dag_id=str(dag_id)).finish()
+        return final
 
     def kill_dag(self, dag_id: DAGId, reason: str = "killed by client") -> None:
         self.dispatch(DAGEvent(DAGEventType.DAG_KILL, dag_id,

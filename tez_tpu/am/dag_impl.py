@@ -62,6 +62,13 @@ class DAGImpl:
     trace_span: Any = None
     trace_carrier = ""
     _commit_span: Any = tracing.NOOP_SPAN
+    #: ``am.dag.init``: DAG_INIT dispatched -> the first attempt scheduled
+    trace_init_span: Any = tracing.NOOP_SPAN
+    #: id of the AM span whose handling last moved this DAG on (its init,
+    #: then each ``am.task.done``): what the next ``am.task.queue`` and the
+    #: commit come ``after`` -- the dispatcher is one thread, so the last
+    #: one handled is the one that scheduled them
+    trace_cause = ""
     #: seconds, DAG_STARTED -> DAG_FINISHED on the AM's clock: the value the
     #: DAG_FINISHED event holds; None until the DAG has finished
     time_taken: Optional[float] = None
@@ -253,7 +260,8 @@ class DAGImpl:
         # synchronous ctx.history) BEFORE any committer mutates the
         # filesystem — the write-ahead half of the two-phase commit
         commit_span = self._commit_span = self.start_am_span(
-            "am.dag.commit", committers=len(committers))
+            "am.dag.commit", committers=len(committers),
+            after=self.trace_cause)
         # attached: the ledger's fsync event hangs under the commit
         with tracing.attached(commit_span.context):
             self.ctx.history(HistoryEvent(

@@ -246,7 +246,7 @@ class EdgeImpl:
                     routed.append(CompositeRoutedDataMovementEvent(
                         source_index=meta.source, target_index_start=meta.target,
                         count=meta.count, user_payload=ev.user_payload,
-                        version=version))
+                        version=version, trace_after=ev.trace_after))
             elif isinstance(ev, DataMovementEvent):
                 meta = em.route_data_movement_event_to_destination(
                     src_task, ev.source_index, dest_task)
@@ -255,7 +255,8 @@ class EdgeImpl:
                         routed.append(DataMovementEvent(
                             source_index=ev.source_index,
                             user_payload=ev.user_payload,
-                            target_index=t, version=version))
+                            target_index=t, version=version,
+                            trace_after=ev.trace_after))
             elif isinstance(ev, InputFailedEvent):
                 meta = em.route_input_source_task_failed_event_to_destination(
                     src_task, dest_task)

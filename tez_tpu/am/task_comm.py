@@ -245,7 +245,7 @@ class TaskCommunicatorManager:
         # task's queued (TaskAttemptImpl._on_done ends it, on the
         # dispatcher): what the AM's event loop adds to a task's end
         span = am_span(self.ctx, attempt_id.dag_id, "am.task.done",
-                       attempt=str(attempt_id))
+                       attempt=str(attempt_id), after=tracing.here())
         if events:
             self._route_events(attempt_id, events)
         self.ctx.dispatch(TaskAttemptEvent(

@@ -148,6 +148,10 @@ class TaskAttemptImpl:
         span = getattr(event, "trace_span", None)   # am.task.done
         if span is not None:
             span.finish()
+            if span.span_id:
+                # what the dispatcher schedules next for this DAG (the
+                # attempts this one released, the commit) comes after it
+                self.vertex.dag.trace_cause = span.span_id
 
     def _on_failed(self, event: TaskAttemptEvent) -> None:
         self.finish_time = clock.wall_s()

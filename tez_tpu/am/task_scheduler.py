@@ -13,13 +13,13 @@ TPU-pod or GKE scheduler plugin would implement instead
 """
 from __future__ import annotations
 
+import dataclasses
 import heapq
 import itertools
 import logging
 import threading
 from typing import Any, Dict, List, Optional, Set, Tuple, TYPE_CHECKING
 
-from tez_tpu.am.dag_impl import am_span
 from tez_tpu.common import clock, metrics, tracing
 from tez_tpu.am.events import (SchedulerEvent, SchedulerEventType,
                                TaskAttemptEvent, TaskAttemptEventType)
@@ -118,9 +118,7 @@ class LocalTaskSchedulerService(TaskSchedulerService):
             if tracing.armed():
                 # scheduled -> a runner picks it up (get_task ends it, on
                 # the runner's thread); on the DAG's lane, under the root
-                self._queue_spans[attempt_id] = am_span(
-                    self.ctx, attempt_id.dag_id, "am.task.queue",
-                    attempt=str(attempt_id))
+                self._queue_spans[attempt_id] = self._queue_span(attempt_id)
             self._priorities[attempt_id] = priority
             self._queued_tenant[attempt_id] = tenant
             self._tenant_queued[tenant] = \
@@ -130,6 +128,18 @@ class LocalTaskSchedulerService(TaskSchedulerService):
             self._available.notify()
         self.ctx.ensure_runners(self.backlog())
         self._maybe_preempt()
+
+    def _queue_span(self, attempt_id: TaskAttemptId) -> Any:
+        """``am.task.queue`` of a traced DAG, ``after`` whatever the AM
+        handled last for it; the DAG's first one ends ``am.dag.init``."""
+        find = getattr(self.ctx, "find_dag", None)
+        dag = find(attempt_id.dag_id, include_retired=True) \
+            if find is not None else None
+        if dag is None:
+            return tracing.NOOP_SPAN
+        dag.trace_init_span.finish()       # idempotent: the first ends it
+        return dag.start_am_span("am.task.queue", attempt=str(attempt_id),
+                                 after=dag.trace_cause)
 
     def _drop_queued_tenant_locked(self, attempt_id: TaskAttemptId) -> None:
         tenant = self._queued_tenant.pop(attempt_id, None)
@@ -348,6 +358,10 @@ class LocalTaskSchedulerService(TaskSchedulerService):
             metrics.observe("am.task.queue_wait",
                             (clock.wall_s() - queued_at) * 1000.0)
         queue_span.finish()
+        if queue_span is not tracing.NOOP_SPAN:
+            # the attempt could not begin before it was handed out
+            spec = dataclasses.replace(spec,
+                                       trace_after=queue_span.span_id)
         return spec
 
     def _drr_pick_locked(self) -> Optional[str]:

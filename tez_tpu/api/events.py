@@ -28,6 +28,9 @@ class DataMovementEvent(TezAPIEvent):
     user_payload: Any = None
     target_index: int = -1
     version: int = 0    # producer attempt number
+    #: span id of the producer's ``output.close`` that made this event (a
+    #: traced in-process run only): the consumer's fetch is ``after`` it
+    trace_after: str = ""
 
     def with_target(self, target_index: int) -> "DataMovementEvent":
         return dataclasses.replace(self, target_index=target_index)
@@ -41,6 +44,7 @@ class CompositeDataMovementEvent(TezAPIEvent):
     count: int
     user_payload: Any = None
     version: int = 0
+    trace_after: str = ""     # see DataMovementEvent
 
     def expand(self) -> Tuple[DataMovementEvent, ...]:
         return tuple(
@@ -58,6 +62,7 @@ class CompositeRoutedDataMovementEvent(TezAPIEvent):
     count: int
     user_payload: Any = None
     version: int = 0
+    trace_after: str = ""     # see DataMovementEvent
 
 
 @dataclasses.dataclass

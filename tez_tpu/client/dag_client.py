@@ -11,6 +11,7 @@ import enum
 import time
 from typing import Any, Dict, List, Optional
 
+from tez_tpu.common import tracing
 from tez_tpu.common.counters import TezCounters
 from tez_tpu.common.ids import DAGId
 
@@ -116,10 +117,14 @@ class DAGClient:
         except TimeoutError:
             pass
         while True:
-            status = self.get_dag_status()
-            if status.is_completed:
-                # aggregate counters on the final read
-                return self.get_dag_status(with_counters=True)
+            # the client's last turn: the wait is over (or a poll is due),
+            # the status read, and on the final read the counters
+            with tracing.span("status", cat="client",
+                              dag_id=str(self.dag_id)):
+                status = self.get_dag_status()
+                if status.is_completed:
+                    # aggregate counters on the final read
+                    return self.get_dag_status(with_counters=True)
             if deadline is not None and time.time() > deadline:
                 raise TimeoutError(f"DAG {self.dag_id} not done")
             time.sleep(poll)

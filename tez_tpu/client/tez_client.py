@@ -132,10 +132,15 @@ class TezClient:
 
     def submit_dag(self, dag: DAG) -> DAGClient:
         assert self._started, "client not started"
-        conf = {k: v for k, v in self.conf.items()
-                if k not in self._CLIENT_ONLY_KEYS}
-        plan = dag.create_dag_plan(conf)
-        dag_id = self.framework_client.submit_dag(plan)
+        from tez_tpu.common import tracing
+        # the client's side of a DAG's head: the plan made, and the AM's
+        # admission up to its return (a root of its own: the DAG's root
+        # span opens inside, in the AM)
+        with tracing.span("submit_dag", cat="client", dag=dag.name):
+            conf = {k: v for k, v in self.conf.items()
+                    if k not in self._CLIENT_ONLY_KEYS}
+            plan = dag.create_dag_plan(conf)
+            dag_id = self.framework_client.submit_dag(plan)
         return self._track(DAGClient(self.framework_client.am, dag_id))
 
     def _track(self, handle: DAGClient) -> DAGClient:

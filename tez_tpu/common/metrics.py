@@ -161,6 +161,9 @@ WELL_KNOWN_HISTOGRAMS = ("shuffle.fetch.rtt", "spill.write", "shuffle.merge",
                          # attempt started, for one that was there first) ->
                          # the runner hands it to the input
                          "am.task.event_wait",
+                         # the same, for the events that became routable
+                         # after their attempt had started alone: the wake
+                         "am.task.event_wake",
                          # flight recorder (obs/flight.py): one snapshot
                          # serialize + atomic write when a dump trigger
                          # (DAG failure, breaker-open, watchdog, shed) fires

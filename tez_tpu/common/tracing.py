@@ -26,6 +26,14 @@ Design rules (mirroring common/faults.py):
   ``attached(ctx)`` (or opens its spans with ``parent=ctx``).  A span opened
   on a thread with no context is a root with a fresh trace id, tied to no
   DAG — docs/observability.md "Starting a thread".
+- Cause crosses a WAIT by a link: whoever ends a wait (completes a fetch
+  table, a pipeline's last span, an exchange) leaves ``here()`` -- where
+  its thread has got to, as a span id -- where the waiter already looks,
+  and the waiter puts it on its span as ``after=<id>``; a span that could
+  not begin before another ended carries the same.  The critical path
+  (tools/trace_export.py) crosses threads on these and nowhere else.
+- A stall witness thread lives while the plane is armed, and only then: it
+  records ``host.stall`` whenever a 10 ms sleep ends more than 50 ms late.
 - One clock: spans stamp ``time.time()`` (epoch seconds, the realtime clock
   the XLA profiler stamps with).  A span used as a context manager also
   enters a ``jax.profiler.TraceAnnotation("tez." + name)`` when jax is
@@ -38,8 +46,9 @@ interop with a real OTLP exporter later.
 """
 from __future__ import annotations
 
+import functools
 import itertools
-import os
+import random
 import sys
 import threading
 import time
@@ -80,12 +89,14 @@ def parse_carrier(s: Optional[str]) -> Optional[TraceContext]:
     return TraceContext(parts[1], parts[2])
 
 
+# ids have to be unique, not unguessable: the generator's state, no system
+# call a span (os.urandom is one, and slow in a sandboxed kernel)
 def _gen_trace_id() -> str:
-    return os.urandom(16).hex()
+    return "%032x" % random.getrandbits(128)
 
 
 def _gen_span_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % random.getrandbits(64)
 
 
 def thread_key() -> str:
@@ -175,6 +186,7 @@ class Span:
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
+        _TLS.last = self.span_id          # where this thread got to: here()
         self.finish(error=exc if isinstance(exc, BaseException) else None)
         return False
 
@@ -228,9 +240,16 @@ def _stack() -> List[Span]:
     return st
 
 
+#: ``parent=NEW_TRACE``: a root with a fresh trace id whatever span is open
+#: on the calling thread (a DAG's root opens inside the client's submit)
+NEW_TRACE: Any = object()
+
+
 def _resolve_parent(parent: Any) -> Tuple[str, Optional[str]]:
     """Return (trace_id, parent_span_id) honoring: explicit parent >
     thread-local current span > thread-attached ambient context > new root."""
+    if parent is NEW_TRACE:
+        return _gen_trace_id(), None
     if parent is None:
         st = _stack()
         if st:
@@ -312,6 +331,22 @@ def current_span() -> Optional[Span]:
     return st[-1] if st else None
 
 
+def here() -> str:
+    """Where this thread has got to, as a span id: the span open on it now,
+    else the last one it finished, else ``""`` -- and ``""`` after one flag
+    load while disarmed.  What a waker leaves where its waiter looks (a
+    fetch table, a future, a queue item); the waiter puts it on its own
+    span as ``after=<id>`` as it leaves the wait, and a reader can then
+    step from the wait to the work that ended it
+    (tools/trace_export.py ``critical_path``)."""
+    if not _armed:
+        return ""
+    st = _stack()
+    if st:
+        return st[-1].span_id
+    return getattr(_TLS, "last", "")
+
+
 def current_context() -> Optional[TraceContext]:
     """The causal coordinate a child started *now* on this thread would
     inherit — current span, else the thread-attached ambient context."""
@@ -347,6 +382,27 @@ def attached(parent: Any) -> Iterator[Optional[TraceContext]]:
         _TLS.ambient = prev
 
 
+def came_after(span_id: str) -> None:
+    """Put ``after=span_id`` on the span open on this thread: what a wait
+    does, as it returns, with the ``here()`` its waker left it."""
+    if _armed and span_id:
+        st = _stack()
+        if st:
+            st[-1].args["after"] = span_id
+
+
+def traced(name: str, cat: str = "") -> Any:
+    """Decorator: the call is a span `name` of this thread (the examples'
+    ``build_dag``: the client's ``build``)."""
+    def wrap(fn: Any) -> Any:
+        @functools.wraps(fn)
+        def run(*args: Any, **kwargs: Any) -> Any:
+            with span(name, cat=cat):
+                return fn(*args, **kwargs)
+        return run
+    return wrap
+
+
 def bound(fn: Any, ctx: Optional[TraceContext] = None) -> Any:
     """`fn`, to be run on another thread under `ctx` (this thread's context
     of now, unless one captured earlier is given): what a hand-off to an
@@ -365,6 +421,42 @@ def bound(fn: Any, ctx: Optional[TraceContext] = None) -> Any:
 
 
 # --------------------------------------------------------------------------
+# The stall witness
+# --------------------------------------------------------------------------
+
+STALL_NAME = "host.stall"
+STALL_PERIOD_S = 0.010
+STALL_LATE_S = 0.050
+
+
+class _StallWitness(threading.Thread):
+    """Sleeps 10 ms at a time on the monotonic clock and records a
+    ``host.stall`` span (a root of its own, on a lane of its own) whenever
+    it wakes more than 50 ms late: the machine stood still, or one thread
+    held the GIL that long -- either way no thread of this process could
+    have run.  Started by a configuration's arming (``install_from_conf``:
+    a traced DAG or session), stopped when the plane is disarmed."""
+
+    def __init__(self) -> None:
+        super().__init__(name="trace-stall-witness", daemon=True)
+        self.stop = threading.Event()
+
+    def run(self) -> None:
+        while True:
+            due = time.monotonic() + STALL_PERIOD_S
+            if self.stop.wait(STALL_PERIOD_S):
+                return
+            late = time.monotonic() - due
+            if late > STALL_LATE_S:
+                now = time.time()
+                sp = Span(STALL_NAME, "host", _gen_trace_id(), None,
+                          {"late_ms": round(late * 1000.0, 1)})
+                sp.thread = STALL_NAME
+                sp.start = now - late
+                sp.finish()
+
+
+# --------------------------------------------------------------------------
 # The plane (arming + ring buffer)
 # --------------------------------------------------------------------------
 
@@ -376,9 +468,13 @@ class TracePlane:
         self._scopes: set = set()
         self._buf: Optional[deque] = None
         self._seq = itertools.count()    # next() is atomic: no lock to record
+        self._witness: Optional[_StallWitness] = None   # armed, and only then
 
-    def install(self, scope: str,
-                capacity: int = DEFAULT_BUFFER_SPANS) -> None:
+    def install(self, scope: str, capacity: int = DEFAULT_BUFFER_SPANS,
+                witness: bool = False) -> None:
+        """``witness``: have the stall witness run until the plane is
+        disarmed (a configuration's arming asks for it; a test's or a
+        tool's ``arm()`` does not, unless it says so)."""
         global _armed
         with self._lock:
             self._scopes.add(scope)
@@ -386,6 +482,9 @@ class TracePlane:
                 old = list(self._buf) if self._buf is not None else []
                 self._buf = deque(old, maxlen=max(1, int(capacity)))
             _armed = True
+            if witness and self._witness is None:
+                self._witness = _StallWitness()
+                self._witness.start()
 
     def clear(self, scope: str) -> None:
         """Release one scope.  The buffer is deliberately retained so
@@ -395,6 +494,7 @@ class TracePlane:
             self._scopes.discard(scope)
             if not self._scopes:
                 _armed = False
+                self._stop_witness_locked()
 
     def clear_all(self) -> None:
         global _armed
@@ -403,6 +503,12 @@ class TracePlane:
             self._buf = None
             self._seq = itertools.count()
             _armed = False
+            self._stop_witness_locked()
+
+    def _stop_witness_locked(self) -> None:
+        if self._witness is not None:
+            self._witness.stop.set()
+            self._witness = None
 
     def record(self, sp: Span) -> None:
         # lock-free: spans finish under other modules' locks, and deque
@@ -441,9 +547,9 @@ def armed() -> bool:
     return _armed
 
 
-def arm(scope: str = "manual",
-        capacity: int = DEFAULT_BUFFER_SPANS) -> None:
-    _PLANE.install(scope, capacity)
+def arm(scope: str = "manual", capacity: int = DEFAULT_BUFFER_SPANS,
+        witness: bool = False) -> None:
+    _PLANE.install(scope, capacity, witness)
 
 
 def clear(scope: str) -> None:
@@ -473,5 +579,5 @@ def install_from_conf(conf: Any, scope: str) -> bool:
     if not (enabled is True or str(enabled) == "True"):
         return False
     capacity = int(conf.get(C.TRACE_BUFFER_SPANS))
-    _PLANE.install(scope, capacity)
+    _PLANE.install(scope, capacity, witness=True)
     return True

@@ -123,6 +123,7 @@ def build_dag(stream_paths, hash_paths, output_path: str,
     return planned.dag
 
 
+@tracing.traced("build", cat="client")
 def build_bench_dag(inputs, out_dir: str, **kwargs):
     """The benchmark harness's builder: `inputs` holds both sides' paths,
     told apart by the directory a path is (or lies in): ``left`` is the

@@ -122,7 +122,10 @@ class SumProcessor(SimpleProcessor):
                 n = batch.num_records
                 if n == 0:
                     continue
-                if bool(np.all(np.diff(batch.val_offsets) == 8)):
+                with tracing.span("processor.sum", cat="task", rows=n,
+                                  stage="check"):
+                    fixed = bool(np.all(np.diff(batch.val_offsets) == 8))
+                if fixed:
                     with tracing.span("processor.sum", cat="task", rows=n):
                         decoded = decode_longs_be(batch.val_bytes, n)
                         sums = np.add.reduceat(decoded, starts)
@@ -210,6 +213,7 @@ class NoOpSorterProcessor(SimpleProcessor):
                 writer.write(word, str(count))
 
 
+@tracing.traced("build", cat="client")
 def build_dag(input_paths, output_path: str, tokenizer_parallelism: int = -1,
               summation_parallelism: int = 2, sorter_parallelism: int = 1,
               combine: bool = True, pipelined: bool = False,

@@ -189,6 +189,7 @@ def build_dag(left_paths, right_paths, output_path: str,
     return planned.dag
 
 
+@tracing.traced("build", cat="client")
 def build_bench_dag(inputs, out_dir: str, **kwargs):
     """The benchmark harness's builder: `inputs` holds both sides' paths,
     told apart by the directory a path is (or lies in): ``left``,

@@ -58,6 +58,7 @@ class IdentityReduce(SimpleProcessor):
             writer.write_batch(block)
 
 
+@tracing.traced("build", cat="client")
 def build_dag(input_paths, output_path: str, map_parallelism: int = -1,
               reduce_parallelism: int = 2,
               sample_keys: int = SAMPLE_KEYS) -> DAG:

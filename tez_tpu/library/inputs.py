@@ -81,6 +81,9 @@ class ShuffleFetchTable:
         self.my_partition = my_partition
         self.slots = [_SlotState() for _ in range(num_slots)]
         self.completed = 0
+        #: tracing.here() of the thread that last completed a fetch: what
+        #: a ``shuffle.wait`` that this table's condition ended is ``after``
+        self.woken_by = ""
         self.lock = threading.Condition()
         self.service = local_shuffle_service()
         self.failed = False
@@ -175,14 +178,15 @@ class ShuffleFetchTable:
             self._scheduler.stop()
 
     def _fetch_local(self, payload: ShufflePayload,
-                     partition: int) -> KVBatch:
+                     partition: int, after: str = "") -> KVBatch:
         """Same-host short-circuit (Fetcher.java:288 local-disk fetch)."""
         import time as _time
         t0 = _time.perf_counter()
         with tracing.span("shuffle.fetch", cat="shuffle",
                           parent=self._trace, mode="local",
                           src=payload.path_component,
-                          spill=payload.spill_id, partition=partition):
+                          spill=payload.spill_id, partition=partition,
+                          after=after):
             faults.fire("shuffle.fetch.read", detail=payload.path_component)
             batch = self.service.fetch_partition(
                 payload.path_component, payload.spill_id, partition,
@@ -243,14 +247,17 @@ class ShuffleFetchTable:
         self._commit_fetch(slot, payload, version, stamp, generation, batch)
 
     def on_payload(self, slot: int, partition: int, payload: ShufflePayload,
-                   version: int = 0) -> None:
+                   version: int = 0, after: str = "") -> None:
+        """``after``: the span id the producer's event carried (its
+        ``output.close``), for this payload's fetch span."""
         # event delivery runs on the heartbeat thread: whatever it opens
         # (fetch, an oversized batch's spill write) is this task's
         with tracing.attached(self._trace):
-            self._on_payload(slot, partition, payload, version)
+            self._on_payload(slot, partition, payload, version, after)
 
     def _on_payload(self, slot: int, partition: int,
-                    payload: ShufflePayload, version: int = 0) -> None:
+                    payload: ShufflePayload, version: int = 0,
+                    after: str = "") -> None:
         mm = self.merge_manager
         with self.lock:
             s = self.slots[slot]
@@ -277,7 +284,7 @@ class ShuffleFetchTable:
                 payload.spill_id, partition,
                 cookie=(slot, partition, payload, version, stamp,
                         generation),
-                trace=self._trace))
+                trace=self._trace, after=after))
             return
         try:
             if payload.is_empty(partition):
@@ -309,7 +316,7 @@ class ShuffleFetchTable:
                             self._commit_fetch(slot, payload, version,
                                                stamp, generation, None)
                         return
-                batch = self._fetch_local(payload, partition)
+                batch = self._fetch_local(payload, partition, after)
                 with self._deliver_lock:
                     self.context.counters.increment(
                         TaskCounter.SHUFFLE_BYTES, batch.nbytes)
@@ -367,6 +374,9 @@ class ShuffleFetchTable:
                 if not s.complete:
                     s.complete = True
                     self.completed += 1
+            # who ends the wait this notifies: where this thread is (the
+            # fetch it has just made, the events' span around it)
+            self.woken_by = tracing.here()
             self.lock.notify_all()
 
     def on_input_failed(self, slot: int, version: int) -> None:
@@ -523,12 +533,14 @@ class OrderedGroupedKVInput(LogicalInput):
                     # CompositeRoutedDataMovementEvent.expand)
                     self.table.on_payload(ev.target_index_start + i,
                                           ev.source_index + i, payload,
-                                          version=ev.version)
+                                          version=ev.version,
+                                          after=ev.trace_after)
             elif isinstance(ev, DataMovementEvent):
                 payload = ev.user_payload
                 assert isinstance(payload, ShufflePayload), payload
                 self.table.on_payload(ev.target_index, ev.source_index,
-                                      payload, version=ev.version)
+                                      payload, version=ev.version,
+                                      after=ev.trace_after)
             elif isinstance(ev, InputFailedEvent):
                 self.table.on_input_failed(ev.target_index, ev.version)
             else:
@@ -540,6 +552,7 @@ class OrderedGroupedKVInput(LogicalInput):
             t0 = time.time()
             with tracing.span("shuffle.wait", cat="shuffle"):
                 self.table.wait_all()
+                tracing.came_after(self.table.woken_by)
             self.context.counters.find_counter(TaskCounter.SHUFFLE_PHASE_TIME)\
                 .increment(int((time.time() - t0) * 1000))
             t1 = time.time()
@@ -717,7 +730,8 @@ class StreamingGroupedKVReader(KeyValuesReader):
             return norm(k) if norm is not None else k
 
         def close_carry() -> Tuple[KVBatch, np.ndarray]:
-            out = carry[0] if len(carry) == 1 else KVBatch.concat(carry)
+            with tracing.span("input.carry", cat="task", pieces=len(carry)):
+                out = carry[0] if len(carry) == 1 else KVBatch.concat(carry)
             return out, np.zeros(1, dtype=np.int64)
 
         groups = 0
@@ -746,14 +760,16 @@ class StreamingGroupedKVReader(KeyValuesReader):
                 yield close_carry()
                 carry = []
             # hold the trailing (possibly open) group; emit the rest
-            last = int(starts[-1])
-            carry = [block.slice_rows(last, n)]
-            carry_key = sort_key(block, last)
-            if last > 0:
+            with tracing.span("input.carry", cat="task", rows=n):
+                last = int(starts[-1])
+                carry = [block.slice_rows(last, n)]
+                carry_key = sort_key(block, last)
+                head = block.slice_rows(0, last) if last > 0 else None
+            if head is not None:
                 groups += len(starts) - 1
                 records += last
                 self.context.notify_progress()
-                yield block.slice_rows(0, last), starts[:-1]
+                yield head, starts[:-1]
         if carry and sum(p.num_records for p in carry) > 0:
             groups += 1
             records += sum(p.num_records for p in carry)

@@ -292,6 +292,7 @@ class StreamingKVReader(KeyValueReader):
                 if not ready and table.completed < table.num_slots:
                     with tracing.span("shuffle.wait", cat="shuffle"):
                         ready = self._wait_ready(consumed)
+                        tracing.came_after(table.woken_by)
             if not ready:
                 break
             for batch in ready:
@@ -360,10 +361,12 @@ class UnorderedKVInput(LogicalInput):
                     # CompositeRoutedDataMovementEvent.expand)
                     self.table.on_payload(ev.target_index_start + i,
                                           ev.source_index + i, payload,
-                                          version=ev.version)
+                                          version=ev.version,
+                                          after=ev.trace_after)
             elif isinstance(ev, DataMovementEvent):
                 self.table.on_payload(ev.target_index, ev.source_index,
-                                      ev.user_payload, version=ev.version)
+                                      ev.user_payload, version=ev.version,
+                                      after=ev.trace_after)
             elif isinstance(ev, InputFailedEvent):
                 self.table.on_input_failed(ev.target_index, ev.version)
 

@@ -505,6 +505,7 @@ class AsyncSpanPipeline:
             collections.deque()
         self._in_flight = 0          # groups past the staging gate
         self._open_spans = 0         # submitted, not yet completed
+        self.last_done = ""          # tracing.here() of the last completion
         self._results: Dict[Any, Any] = {}
         self._completion_order: List[Any] = []
         self._error: Optional[BaseException] = None
@@ -698,6 +699,9 @@ class AsyncSpanPipeline:
                     self._completion_order.append(sid)
                 self.stats.completed += len(ids)
                 self._open_spans -= len(ids)
+                # where the completing thread has got to: what a drain()
+                # that this ends (the sorter's sort.flush) is ``after``
+                self.last_done = tracing.here()
                 self._cv.notify_all()
 
     def _staging_loop(self) -> None:
@@ -733,8 +737,12 @@ class AsyncSpanPipeline:
                 self._failover_group(group, ids, reason="breaker-open")
                 return True
             t0 = self._mark(ids, STAGE_ENCODE, "start")
+            # each stage comes after the one before, the first after the
+            # span that submitted the group (its context's: sort.collect)
             with tracing.span(STAGE_ENCODE, cat="device",
-                              spans=repr(list(ids))):
+                              spans=repr(list(ids)),
+                              after=group.ctx.span_id if group.ctx
+                              else "") as stage:
                 staged = [self._encode_fn(p) for p in group.payloads]
             t1 = self._mark(ids, STAGE_ENCODE, "end")
             self._observe(STAGE_ENCODE, t0, t1)
@@ -742,7 +750,8 @@ class AsyncSpanPipeline:
                 self._coalesce_fn(staged)
             t0 = self._mark(ids, STAGE_H2D, "start")
             with tracing.span(STAGE_H2D, cat="device",
-                              spans=repr(list(ids))):
+                              spans=repr(list(ids)),
+                              after=stage.span_id) as stage:
                 if self._stage_fn is not None:
                     one = self._stage_fn(one)
             t1 = self._mark(ids, STAGE_H2D, "end")
@@ -761,7 +770,8 @@ class AsyncSpanPipeline:
                         faults.fire("device.dispatch.hang",
                                     f"span={sid}")
                 with tracing.span(STAGE_DISPATCH, cat="device",
-                                  spans=repr(list(ids))), \
+                                  spans=repr(list(ids)),
+                                  after=stage.span_id) as stage, \
                         _compile_listener(functools.partial(
                             self._watch_compile, group, ids)):
                     inflight = self._dispatch_fn(one)
@@ -780,7 +790,7 @@ class AsyncSpanPipeline:
             with self._lock:
                 self.stats.dispatched += 1
             self._readback.submit(tracing.bound(self._readback_one),
-                                  group, ids)
+                                  group, ids, stage.span_id)
         except BaseException as e:  # noqa: BLE001 — surfaces via drain
             self._contain_failure(group, ids, e)
             if self._error is not None:
@@ -788,7 +798,8 @@ class AsyncSpanPipeline:
         return True
 
     # -- readback workers ----------------------------------------------------
-    def _readback_one(self, group: _Group, ids: Tuple[Any, ...]) -> None:
+    def _readback_one(self, group: _Group, ids: Tuple[Any, ...],
+                      after: str = "") -> None:
         try:
             if faults.armed():
                 for sid in ids:
@@ -798,7 +809,7 @@ class AsyncSpanPipeline:
                               self._watchdog_readback_ms)
             try:
                 with tracing.span(STAGE_D2H, cat="device",
-                                  spans=repr(list(ids))):
+                                  spans=repr(list(ids)), after=after):
                     result = self._readback_fn(group.inflight, ids)
             finally:
                 self._watch_end(group)

@@ -30,6 +30,7 @@ from typing import Callable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from tez_tpu.common import tracing
 from tez_tpu.ops.runformat import KVBatch, Run
 
 __all__ = ["iter_merged_blocks"]
@@ -112,7 +113,8 @@ class _Source:
             if self.pos < self.batch.num_records and \
                     self.sort_key(self.pos) != key:
                 return
-            piece = self.take_to(self.upper_bound(key))
+            with tracing.span("merge.cut", cat="merge", stage="equal"):
+                piece = self.take_to(self.upper_bound(key))
             if piece is not None:
                 yield piece
             if self.pos < self.batch.num_records:
@@ -151,15 +153,19 @@ def iter_merged_blocks(
             while s.advance():
                 yield s.batch
             return
-        boundary = min(s.last_key() for s in active)
-        # phase 1: rows strictly below the boundary key — safe to merge
-        # (no source can still hold an unseen row < boundary)
-        slices: List[Run] = []
-        for s in active:
-            piece = s.take_to(s.lower_bound(boundary))
-            if piece is not None:
-                slices.append(Run(piece, np.array([0, piece.num_records],
-                                                  dtype=np.int64)))
+        # one round's cut (host work on the reducer's own thread; a span
+        # a round, never across a yield)
+        with tracing.span("merge.cut", cat="merge", sources=len(active)):
+            boundary = min(s.last_key() for s in active)
+            # phase 1: rows strictly below the boundary key — safe to merge
+            # (no source can still hold an unseen row < boundary)
+            slices: List[Run] = []
+            for s in active:
+                piece = s.take_to(s.lower_bound(boundary))
+                if piece is not None:
+                    slices.append(Run(piece,
+                                      np.array([0, piece.num_records],
+                                               dtype=np.int64)))
         if len(slices) == 1:
             yield slices[0].batch
         elif slices:

@@ -324,6 +324,7 @@ class DeviceSorter:
             self._executor = concurrent.futures.ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="sortmaster")
         self._pending = []
+        self._last_bg = ""      # tracing.here() of the last sortmaster span
         import threading as _threading
         self._store_lock = _threading.Lock()
         self._span = SpanBuffer()
@@ -666,6 +667,7 @@ class DeviceSorter:
         pipe, self._pipeline = self._pipeline, None
         if pipe is not None:
             pipe.drain()
+            tracing.came_after(pipe.last_done)     # the caller's sort.flush
         if self._async_store_ids:
             order = sorted(range(len(self._async_store_ids)),
                            key=lambda i: self._async_store_ids[i])
@@ -701,6 +703,7 @@ class DeviceSorter:
                     # stays bounded by mem_budget, same as the sync path
                     with self._store_lock:
                         self._store_run(run)
+                self._last_bg = tracing.here()
 
             self._pending.append(self._executor.submit(tracing.bound(_bg)))
             return
@@ -898,6 +901,7 @@ class DeviceSorter:
                 self._executor = None
         if error is not None:
             raise error
+        tracing.came_after(self._last_bg)          # the caller's sort.flush
 
     # -- flush ---------------------------------------------------------------
     def flush(self) -> Optional[Run]:
@@ -1198,15 +1202,20 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
                               len(runs), final)
                 return Run(KVBatch(out_kb, out_ko, out_vb, out_vo),
                            row_index)
-    batch = KVBatch.concat([r.batch for r in runs])
-    partitions = np.concatenate([
-        np.repeat(np.arange(r.num_partitions, dtype=np.int32),
-                  np.diff(r.row_index)) for r in runs]) \
-        if runs else np.zeros(0, np.int32)
-    if key_normalizer is not None:
-        sort_bytes, sort_offsets = normalize_batch_keys(batch, key_normalizer)
-    else:
-        sort_bytes, sort_offsets = batch.key_bytes, batch.key_offsets
+    # the host's preparation of a merge of runs that are not on the device:
+    # the runs joined into one batch, then (device engine) its keys as lanes
+    with tracing.span("merge.encode", cat="merge", stage="concat",
+                      runs=len(runs)):
+        batch = KVBatch.concat([r.batch for r in runs])
+        partitions = np.concatenate([
+            np.repeat(np.arange(r.num_partitions, dtype=np.int32),
+                      np.diff(r.row_index)) for r in runs]) \
+            if runs else np.zeros(0, np.int32)
+        if key_normalizer is not None:
+            sort_bytes, sort_offsets = normalize_batch_keys(batch,
+                                                            key_normalizer)
+        else:
+            sort_bytes, sort_offsets = batch.key_bytes, batch.key_offsets
     if engine == "host":
         # native merge: the runs are ALREADY (partition, key)-sorted, so a
         # ladder of in-place merges (O(n log k)) replaces a full re-sort;
@@ -1224,7 +1233,9 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
                       len(runs), final)
         return Run.from_sorted_batch(sorted_batch, sorted_partitions,
                                      num_partitions)
-    lanes, lengths = encode_keys(sort_bytes, sort_offsets, key_width)
+    with tracing.span("merge.encode", cat="merge", stage="lanes",
+                      rows=batch.num_records):
+        lanes, lengths = encode_keys(sort_bytes, sort_offsets, key_width)
     # the concatenation is in run-arrival order: one stable sort of it IS
     # the merge (equal keys keep run order); prefix-equal beyond-cap keys
     # still fall to the host tie-break below
@@ -1244,8 +1255,9 @@ def merge_sorted_runs(runs: Sequence[Run], num_partitions: int,
         sorted_batch = _take(sorted_batch, refinement, counters)
     _record_merge(counters, t0, "device", batch.num_records, len(runs),
                   final)
-    return Run.from_sorted_batch(sorted_batch, sorted_partitions,
-                                 num_partitions)
+    with tracing.span("merge.encode", cat="merge", stage="index"):
+        return Run.from_sorted_batch(sorted_batch, sorted_partitions,
+                                     num_partitions)
 
 
 # ---------------------------------------------------------------------------

@@ -97,7 +97,10 @@ class _EdgeState:
         #: producer -> (time.time() its rows were in, its trace context),
         #: kept while the span plane is armed: what the
         #: exchange.wait_peers spans are made from
-        self.arrived: Dict[int, Tuple[float, object]] = {}
+        self.arrived: Dict[int, Tuple[float, object, str]] = {}
+        #: tracing.here() of the thread that ran the exchange, as it made
+        #: the results: what the consumers' ``shuffle.wait`` is ``after``
+        self.done_by = ""
         self.results: Optional[List[KVBatch]] = None
         self.error: Optional[BaseException] = None
         self.executing = False     # an _execute is in flight on some thread
@@ -410,7 +413,7 @@ class MeshExchangeCoordinator:
                     f"shuffle edge for records this large")
             value_width = max(value_width, ((max_val + 3) // 4) * 4)
         with tracing.span("exchange.pack", cat="exchange", stage="producer",
-                          rows=batch.num_records):
+                          rows=batch.num_records) as pack:
             # both native, GIL released, in this producer's thread while
             # slower producers still produce.  The rows: key lanes (the
             # zero-padded key as big-endian words), key lengths, value
@@ -423,6 +426,7 @@ class MeshExchangeCoordinator:
             part = fnv32_partition_native(
                 batch.key_bytes, batch.key_offsets, num_consumers).astype(
                     np.min_scalar_type(num_consumers))
+        pack_id = pack.span_id
         with self.lock:
             st = self.edges.setdefault(
                 edge_id, _EdgeState(num_producers, num_consumers, edge_id))
@@ -443,8 +447,10 @@ class MeshExchangeCoordinator:
                 st.counters = counters
             st.spans[task_index] = (lanes, klens, vwords, part)
             if tracing.armed():
+                # here(): the encode of this producer's rows just above
                 st.arrived[task_index] = (time.time(),
-                                          tracing.current_context())
+                                          tracing.current_context(),
+                                          pack_id)
             if isinstance(st.error, TimeoutError):
                 # a straggler poisoned the edge, and here it is: the edge
                 # is viable again — consumer RETRIES must see a fresh
@@ -481,6 +487,7 @@ class MeshExchangeCoordinator:
                     st.dirty = False
                     continue           # spans changed mid-run: go again
                 st.results = results
+                st.done_by = tracing.here()
                 st.error = None
                 st.executing = False
                 self.lock.notify_all()
@@ -533,6 +540,7 @@ class MeshExchangeCoordinator:
             if st.error is not None:
                 raise RuntimeError(
                     f"mesh exchange {edge_id} failed") from st.error
+            tracing.came_after(st.done_by)     # the caller's shuffle.wait
             return st.results[consumer_index]
 
     def cleanup_edge(self, edge_id: str) -> None:
@@ -567,7 +575,7 @@ class MeshExchangeCoordinator:
         return fn
 
     def _read_shards(self, arrs, mesh, edge_id: str, round_idx: int,
-                     decode=None):
+                     decode=None, after: str = ""):
         """Materialize the exchange outputs one device at a time, each on
         its own daemon reader thread.  Every reader fires the
         ``mesh.exchange.delay`` fault point (detail
@@ -580,7 +588,11 @@ class MeshExchangeCoordinator:
         (events, results, any_done); results[d] becomes
         ``decode(lanes, klens, vwords, valid)`` of the device's shard (the
         tuple itself without ``decode``), or the exception its reader hit
-        (a faulted chip), once events[d] is set."""
+        (a faulted chip), once events[d] is set.  ``after``: where the
+        executing thread had got to as it started the readers (its wait for
+        the round's dropped flag, after the launch), which their spans
+        come after; ``done_ids[d]`` (a fourth result) is where reader d had
+        got to as it set its event."""
         D = mesh.devices.size
         pos = {dev: i for i, dev in enumerate(mesh.devices.flat)}
         shard_maps = []
@@ -591,13 +603,15 @@ class MeshExchangeCoordinator:
             pos[s.device]: s.device.id for s in arrs[0].addressable_shards}
         events = [threading.Event() for _ in range(D)]
         results: List[object] = [None] * D
+        done_ids = [""] * D
         any_done = threading.Event()
         ctx = tracing.current_context()    # the executing producer's
 
         def _read(d: int) -> None:
             try:
                 with tracing.span("exchange.readback", cat="exchange",
-                                  parent=ctx, device=d, round=round_idx):
+                                  parent=ctx, device=d, round=round_idx,
+                                  after=after):
                     faults.fire(
                         "mesh.exchange.delay",
                         detail=f"{edge_id}:round={round_idx}:device={d}")
@@ -610,6 +624,7 @@ class MeshExchangeCoordinator:
             except BaseException as e:  # noqa: BLE001 — surfaced by reader
                 results[d] = e
             finally:
+                done_ids[d] = tracing.here()
                 events[d].set()
                 any_done.set()
 
@@ -618,7 +633,7 @@ class MeshExchangeCoordinator:
             # copy wins — it must never pin process exit
             threading.Thread(target=_read, args=(d,), daemon=True,
                              name=f"mesh-exchange-read-{d}").start()
-        return events, results, any_done
+        return events, results, any_done, done_ids
 
     def _select_coded(self, events, results, any_done,
                       num_devices: int) -> Tuple[Dict[int, int], int]:
@@ -678,11 +693,13 @@ class MeshExchangeCoordinator:
             # a lane of its own (no thread waited: the rows did)
             with self.lock:
                 arrived = dict(st.arrived)
-            for producer, (t_in, ctx) in arrived.items():
+            slowest = max(arrived.values(), key=lambda a: a[0],
+                          default=(0.0, None, ""))[2]
+            for producer, (t_in, ctx, _pack) in arrived.items():
                 tracing.start_span(
                     "exchange.wait_peers", cat="exchange", parent=ctx,
                     lane=f"exchange.wait_peers#{st.edge_id}/{producer}",
-                    start=t_in, producer=producer).finish()
+                    start=t_in, producer=producer, after=slowest).finish()
         with tracing.span("exchange.plan", cat="exchange"):
             W = st.num_consumers
             D = self.devices_for(W)     # devices carrying the exchange; each
@@ -848,9 +865,10 @@ class MeshExchangeCoordinator:
                 raise MeshCapacityError(
                     f"mesh exchange overflow: {dropped_total} rows dropped "
                     f"(cap {cap}, round {r}) — capacity accounting bug")
-            events, results, any_done = self._read_shards(
+            events, results, any_done, done_ids = self._read_shards(
                 (out_lanes, out_klens, out_vwords, out_valid), mesh,
-                st.edge_id, r, decode=None if coded else _decode_shard)
+                st.edge_id, r, decode=None if coded else _decode_shard,
+                after=tracing.here())
             round_parts: List[KVBatch] = []
             if coded:
                 with tracing.span("exchange.readback", cat="exchange",
@@ -872,6 +890,7 @@ class MeshExchangeCoordinator:
                     with tracing.span("exchange.readback", cat="exchange",
                                       round=r, what="shard", device=d):
                         events[d].wait()
+                        tracing.came_after(done_ids[d])
                     if isinstance(results[d], BaseException):
                         raise results[d]
                     round_parts.append(results[d])

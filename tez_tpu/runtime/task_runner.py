@@ -33,6 +33,17 @@ log = logging.getLogger(__name__)
 
 HEARTBEAT_INTERVAL = 0.05
 
+def _events_after(events: Sequence[Tuple[str, TezAPIEvent]]) -> str:
+    """The span id the newest of `events` carries from its producer (its
+    ``output.close``): what this delivery comes ``after``; "" untraced."""
+    if tracing.armed():
+        for _input_name, ev in reversed(events):
+            after = getattr(ev, "trace_after", "")
+            if after:
+                return after
+    return ""
+
+
 class TaskRunner:
     """Runs one task attempt to completion and reports to the umbilical."""
 
@@ -73,6 +84,9 @@ class TaskRunner:
         # is in flight coalesce into the one beat that follows it
         self._wake = threading.Event()
         self._start_s = 0.0
+        #: parent of the spans the reporter thread opens for this attempt
+        #: (``task.events``): the TaskSpec's carrier, then the attempt span
+        self._trace_ctx: Any = getattr(spec, "trace_context", "") or None
         self._fatal: Optional[Tuple[BaseException | None, str]] = None
         # Incoming events arriving before IO initialize() completes are
         # trapped and replayed (reference: TezTrapEventHandler).  The
@@ -111,17 +125,21 @@ class TaskRunner:
                                     daemon=True)
         reporter.start()
         from tez_tpu.common import ndc
+        carrier = getattr(self.spec, "trace_context", "")
         try:
             # adopt the AM's trace context (TaskSpec carrier) so the
             # attempt span — and everything under it, including shuffle
             # fetches delivered on other threads — shares the DAG trace id
             with ndc.context(str(self.spec.attempt_id)), \
-                    tracing.attached(getattr(self.spec, "trace_context", "")), \
+                    tracing.attached(carrier), \
                     tracing.span(f"attempt:{self.spec.attempt_id}",
                                  cat="task",
                                  vertex=self.spec.vertex_name,
                                  task_index=self.spec.task_index,
-                                 attempt=self.spec.attempt_number):
+                                 attempt=self.spec.attempt_number,
+                                 after=getattr(self.spec, "trace_after",
+                                               "")) as attempt:
+                self._trace_ctx = attempt.context or self._trace_ctx
                 with tracing.span("initialize", cat="task"):
                     self._initialize()
                 with tracing.span("run", cat="task"):
@@ -146,8 +164,18 @@ class TaskRunner:
         finally:
             self._done.set()
             self._wake.set()
-            dumper.stop()
-            reporter.join(timeout=5)
+        # what follows the attempt up to the AM knowing of its end: the
+        # reporter's last beat joined, the counters closed, task_done (the
+        # attempt's span ends where it did; this one stands beside it)
+        with tracing.attached(carrier), \
+                tracing.span("finish", cat="task", after=tracing.here(),
+                             vertex=self.spec.vertex_name, state=state):
+            return self._report(state, start, stats, dumper, reporter)
+
+    def _report(self, state: str, start: float, stats: Any, dumper: Any,
+                reporter: threading.Thread) -> str:
+        dumper.stop()
+        reporter.join(timeout=5)
         stats.update(final=True)
         self.counters.find_counter(TaskCounter.WALL_CLOCK_MILLISECONDS)\
             .set_value(int((time.time() - start) * 1000))
@@ -177,32 +205,35 @@ class TaskRunner:
         determinism, the IO init cost on TPU is kernel compilation which is
         cached in the object registry anyway)."""
         spec = self.spec
-        proc_ctx = TezProcessorContext(self, spec.processor_descriptor.payload)
-        self.processor = spec.processor_descriptor.instantiate(proc_ctx)
+        with tracing.span("task.instantiate", cat="task"):
+            proc_ctx = TezProcessorContext(
+                self, spec.processor_descriptor.payload)
+            self.processor = spec.processor_descriptor.instantiate(proc_ctx)
+            for i, ispec in enumerate(spec.inputs):
+                ictx = TezInputContext(self, ispec.input_descriptor.payload,
+                                       ispec.source_vertex_name, i)
+                inp = ispec.input_descriptor.instantiate(
+                    ictx, ispec.physical_input_count)
+                self.inputs[ispec.source_vertex_name] = inp
+            for i, ospec in enumerate(spec.outputs):
+                octx = TezOutputContext(self, ospec.output_descriptor.payload,
+                                        ospec.destination_vertex_name, i)
+                out = ospec.output_descriptor.instantiate(
+                    octx, ospec.physical_output_count)
+                self.outputs[ospec.destination_vertex_name] = out
 
-        init_events: List[TezEvent] = []
-        for i, ispec in enumerate(spec.inputs):
-            ictx = TezInputContext(self, ispec.input_descriptor.payload,
-                                   ispec.source_vertex_name, i)
-            inp = ispec.input_descriptor.instantiate(
-                ictx, ispec.physical_input_count)
-            self.inputs[ispec.source_vertex_name] = inp
-        for i, ospec in enumerate(spec.outputs):
-            octx = TezOutputContext(self, ospec.output_descriptor.payload,
-                                    ospec.destination_vertex_name, i)
-            out = ospec.output_descriptor.instantiate(
-                octx, ospec.physical_output_count)
-            self.outputs[ospec.destination_vertex_name] = out
-
-        self.processor.initialize()
+        with tracing.span("processor.initialize", cat="task"):
+            self.processor.initialize()
         for name, inp in self.inputs.items():
-            evs = inp.initialize() or []
-            if evs:
-                inp.context.send_events(evs)
+            with tracing.span("input.initialize", cat="task", input=name):
+                evs = inp.initialize() or []
+                if evs:
+                    inp.context.send_events(evs)
         for name, out in self.outputs.items():
-            evs = out.initialize() or []
-            if evs:
-                out.context.send_events(evs)
+            with tracing.span("output.initialize", cat="task", output=name):
+                evs = out.initialize() or []
+                if evs:
+                    out.context.send_events(evs)
 
         # group (merged) inputs presented to the processor as one entry
         for g in spec.group_inputs:
@@ -213,17 +244,19 @@ class TaskRunner:
             merged = g.merged_input_descriptor.instantiate(ictx, members)
             self.inputs[g.group_name] = merged
 
-        self.memory.make_initial_allocations()
+        with tracing.span("input.start", cat="task"):
+            self.memory.make_initial_allocations()
 
-        # auto-start non-merged inputs (reference: startable inputs started
-        # by the framework before processor.run)
-        for inp in self.inputs.values():
-            if not isinstance(inp, MergedLogicalInput):
-                inp.start()
+            # auto-start non-merged inputs (reference: startable inputs
+            # started by the framework before processor.run)
+            for inp in self.inputs.values():
+                if not isinstance(inp, MergedLogicalInput):
+                    inp.start()
 
         # replay any events trapped while initializing (ready-flag flip and
         # replay are atomic w.r.t. heartbeat deliveries)
-        with self._dispatch_lock:
+        with tracing.span("task.events", cat="task", replayed=True), \
+                self._dispatch_lock:
             trapped, self._trapped_incoming = self._trapped_incoming, []
             stamps, self._trapped_stamps = self._trapped_stamps, []
             self._inputs_ready.set()
@@ -268,16 +301,25 @@ class TaskRunner:
 
     def _close(self) -> None:
         self.check_killed()
-        for inp in self.inputs.values():
-            evs = inp.close() or []
-            if evs and not isinstance(inp, MergedLogicalInput):
-                inp.context.send_events(evs)
+        for name, inp in self.inputs.items():
+            with tracing.span("input.close", cat="task", input=name):
+                evs = inp.close() or []
+                if evs and not isinstance(inp, MergedLogicalInput):
+                    inp.context.send_events(evs)
         for name, out in self.outputs.items():
-            with tracing.span("output.close", cat="task", output=name):
+            with tracing.span("output.close", cat="task",
+                              output=name) as sp:
                 evs = out.close() or []
             if evs:
+                # the consumers' fetches come after this close: its id
+                # rides each event to their fetch tables (am/edge.py)
+                if sp.span_id:
+                    for ev in evs:
+                        if hasattr(ev, "trace_after"):
+                            ev.trace_after = sp.span_id
                 out.context.send_events(evs)
-        self.processor.close()
+        with tracing.span("processor.close", cat="task"):
+            self.processor.close()
 
     # -- heartbeat -----------------------------------------------------------
     def _drain_events(self) -> List[TezEvent]:
@@ -335,7 +377,10 @@ class TaskRunner:
                     self._trapped_incoming.extend(resp.events)
                     self._trapped_stamps.extend(stamps)
                 else:
-                    self._dispatch_incoming(resp.events, stamps)
+                    with tracing.span("task.events", cat="task",
+                                      parent=self._trace_ctx,
+                                      after=_events_after(resp.events)):
+                        self._dispatch_incoming(resp.events, stamps)
         if resp.more:
             self._wake.set()
 
@@ -343,12 +388,18 @@ class TaskRunner:
                            routable_s: Sequence[float] = ()) -> None:
         # am.task.event_wait: the AM made the event routable (or, for one
         # that was there first, this attempt started) -> handed over here
+        # am.task.event_wake: the same, for the events that came after the
+        # attempt started alone (the wake, with no `initialize` in it)
         now = time.time()
         for stamp in routable_s:
             metrics.observe(
                 "am.task.event_wait",
                 max(0.0, now - max(stamp, self._start_s)) * 1000.0,
                 counters=self.counters)
+            if stamp >= self._start_s:
+                metrics.observe("am.task.event_wake",
+                                max(0.0, now - stamp) * 1000.0,
+                                counters=self.counters)
         by_input: Dict[str, List[TezAPIEvent]] = {}
         for input_name, ev in events:
             if isinstance(ev, CustomProcessorEvent):

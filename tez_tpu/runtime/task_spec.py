@@ -58,6 +58,10 @@ class TaskSpec:
     #: attempt to the DAG's root span when the tracing plane is armed
     #: ("" = tracing disarmed; the runner then starts no spans).
     trace_context: str = ""
+    #: id of the ``am.task.queue`` span that ended as this attempt was
+    #: handed to a runner: the attempt's span is ``after`` it ("" = the DAG
+    #: is not traced).
+    trace_after: str = ""
     #: Content-addressed lineage hash of this task's vertex (spec + upstream
     #: closure, see tez_tpu.store.lineage).  Outputs publish under
     #: "<hash>/<task_index>/<dest>" so identical recurring DAGs in a session

@@ -47,6 +47,8 @@ class FetchRequest:
     #: caller's trace context (tracing.TraceContext | None): fetch spans,
     #: penalty-box holds and retry events parent under the consuming task
     trace: Any = None
+    #: span id the producer's event carried: the fetch span is ``after`` it
+    after: str = ""
     #: measured wire RTT of the successful fetch, stamped before delivery
     rtt_ms: float = 0.0
 
@@ -318,7 +320,8 @@ class FetchScheduler:
                     "shuffle.fetch", cat="shuffle", parent=req.trace,
                     mode="remote", host=f"{req.host}:{req.port}",
                     src=req.path, spill=req.spill, partition=req.partition,
-                    attempt=req.attempts, speculative=req.speculative)
+                    attempt=req.attempts, speculative=req.speculative,
+                    after=req.after)
                 t0 = time.perf_counter()
                 try:
                     with sp:

@@ -543,9 +543,9 @@ class DeviceHealthAnalyzer(Analyzer):
 
 
 class SpanCriticalPathAnalyzer(Analyzer):
-    """Span-based critical path over the live tracing buffer: the longest
-    causal chain through the recorded spans (tracing plane, this PR's
-    tentpole), naming which vertex/fetch/commit span dominates wall clock.
+    """Span-based critical path over the live tracing buffer: the walk of
+    ``trace_export.critical_path`` through this DAG's period, naming which
+    span and which vertex hold the most of its wall clock.
     Unlike CriticalPathAnalyzer (history timestamps, vertex granularity)
     this sees intra-attempt structure — a fetch stall or merge dominating a
     vertex shows up by name.  Empty when the DAG ran with tracing disarmed."""
@@ -555,33 +555,34 @@ class SpanCriticalPathAnalyzer(Analyzer):
         from tez_tpu.common import tracing
         from tez_tpu.tools.trace_export import critical_path_report
         spans = tracing.snapshot()
-        # scope to this DAG's trace when its root span is in the buffer
-        # (the buffer is process-global and may hold several DAGs)
-        dag_traces = {sp.trace_id for sp in spans
-                      if sp.cat == "dag" and
-                      sp.args.get("dag_id") == str(dag.dag_id)}
-        if dag_traces:
-            spans = [sp for sp in spans if sp.trace_id in dag_traces]
         if not spans:
             return AnalyzerResult(
                 self.name,
                 "no spans recorded (run with tez.trace.enabled=True)", [])
-        report = critical_path_report(spans)
+        # the buffer is process-global and may hold several DAGs: the walk
+        # is of this DAG's period (client submit -> final status) where
+        # its root span is in the buffer
+        report = critical_path_report(spans, str(dag.dag_id))
         dom = report["dominant"]
         chain = report["chain"]
-        # dominant VERTEX: attribute each chain span's self time to the
-        # nearest enclosing span that names a vertex (the attempt span),
-        # then take the vertex holding the most on-path time.  The dag
-        # root's own self time (AM scheduling overhead) stays unattributed.
+        # dominant VERTEX: a chain member's seconds go to the vertex its
+        # span names, or the nearest one before it on the path (the
+        # attempt's span).  The AM's and the client's stay unattributed.
         per_vertex: Dict[str, float] = {}
         cur = ""
         for c in chain:
+            if c["cat"] in ("am", "client", "dag"):
+                cur = ""
+                continue
             cur = c.get("vertex") or cur
             if cur:
                 per_vertex[cur] = per_vertex.get(cur, 0) + c.get("self_ms", 0)
         headline = "no dominant span"
         if dom:
-            headline = (f"critical chain of {len(chain)} span(s); dominant: "
+            classes = ", ".join(f"{k} {v:.1f}ms" for k, v in
+                                report["by_class_ms"].items() if v)
+            headline = (f"critical path of {len(chain)} stretch(es) "
+                        f"({classes}); dominant: "
                         f"{dom['name']} ({dom['duration_ms']:.1f}ms)")
             if per_vertex:
                 v, ms = max(per_vertex.items(), key=lambda kv: kv[1])

@@ -24,23 +24,28 @@ at ``result_line``   the window's DAGs as the harness observed them: the
     DAG, p50 / p95 / highest bucket in ms: the AM had the event -> the
     runner handed it to the input) beside the beats a DAG
     (``am.heartbeat.rtt``'s count) and how many of them a wake sent
-    (counter ``am.heartbeat.woken``)
+    (counter ``am.heartbeat.woken``), and ``am.task.event_wake``: the same
+    wait for the events that came after their attempt had started alone
+    (the wake, with no ``initialize`` in it); and ``critical_path_s_a_dag``:
+    the walk of ``trace_export.critical_path`` over the window's periods
+    (one DAG's client-side submit to the next one's), the one the
+    ``path_*`` metrics read -- seconds a period by span name and by class,
+    the hand-over stretches by the span that began after them, the steps
+    between threads by link / thread / guess, the stalls met with their
+    times, the share of a period the walk missed (0 unless it gave up), and
+    the last period's path as it ran (``last_period_chain``)
 
 and, after the run, reads the span buffer for
 
-orphans      spans whose ``trace_id`` is no DAG's root span's, and spans
-             whose ``parent_id`` resolves to nothing recorded
+orphans      spans whose ``trace_id`` is no DAG's root span's (the client's
+             own spans and ``host.stall`` are roots by design and not
+             counted), and spans whose ``parent_id`` resolves to nothing
+             recorded
 dropped      ``tracing.dropped()``
 spans_a_dag  spans of the window over the DAGs that started in it, by name
 self_s_a_dag ``trace_reduce.self_intervals`` over the window's
              ``program_spans()``, by name, over those DAGs: what a task's
              wall is made of
-timeline_ms  where in a DAG each phase stands: for every ``<vertex>/<span
-             name>`` (the vertex of the attempt a span's parent chain ends
-             in, ``am`` for the AM's own), the median over the window's DAGs
-             of its first start and its last end, in ms from the root
-             span's start, in the order they begin — what the head of a DAG
-             and each stage boundary are made of
 programs     per compiled program, the window's ``kernel.<Kernel.name>``
              spans of the kernels that trace it a DAG (``launches_a_dag``)
              and the program's device time in the trace over that count
@@ -129,34 +134,6 @@ def exchange_site(span) -> str:
     return span.name
 
 
-def timeline(spans, roots, marks) -> dict:
-    """``<vertex>/<name>`` -> [first start, last end], ms from the DAG's
-    start, medians over the DAGs that began in the window."""
-    by_id = {s.span_id: s for s in spans}
-
-    def vertex(span) -> str:
-        while span is not None:
-            if span.name.startswith("attempt:"):
-                return span.args.get("vertex", "?")
-            span = by_id.get(span.parent_id)
-        return "am"
-
-    per_dag = collections.defaultdict(dict)
-    for s in spans:
-        root = roots.get(s.trace_id)
-        if root is None or s is root or \
-                not marks["start"] <= root.start <= marks["stop"]:
-            continue
-        key = f"{vertex(s)}/{s.name.split(':')[0]}"
-        at = s.start - root.start, s.end - root.start
-        first, last = per_dag[key].get(s.trace_id, at)
-        per_dag[key][s.trace_id] = min(first, at[0]), max(last, at[1])
-    rows = {key: [round(1e3 * statistics.median(v[i] for v in dags.values()),
-                        1) for i in (0, 1)]
-            for key, dags in per_dag.items()}
-    return dict(sorted(rows.items(), key=lambda kv: kv[1]))
-
-
 def kernel_programs() -> dict:
     """``Kernel.name`` -> the program it traces, of every kernel of
     ``ops/device.py`` (a donating flavor traces its plain twin's)."""
@@ -196,14 +173,49 @@ def event_delivery(dags) -> dict:
                 groups[group].update(counters)
     hists = metrics.histograms_from_counters(groups)
     wait = hists.get("am.task.event_wait", {})
+    wake = hists.get("am.task.event_wake", {})
     n = len(dags)
     return {
         "events": wait.get("count", 0) / n,
         "event_wait_ms": {k: wait.get(k) for k in ("p50", "p95", "max_ms")},
         "event_wait_mean_ms": wait.get("sum_us", 0) / 1e3 / max(
             1, wait.get("count", 0)),
+        "events_after_start": wake.get("count", 0) / n,
+        "event_wake_ms": {k: wake.get(k) for k in ("p50", "p95", "max_ms")},
+        "event_wake_mean_ms": wake.get("sum_us", 0) / 1e3 / max(
+            1, wake.get("count", 0)),
         "beats": hists.get("am.heartbeat.rtt", {}).get("count", 0) / n,
         "woken": groups["TaskUmbilical"].get("am.heartbeat.woken", 0) / n}
+
+
+def path_table(obs) -> dict:
+    """``critical_path_s_a_dag``: the window's walk, a period."""
+    import path_metrics
+    path = path_metrics.window_path(obs)
+    if path is None:
+        return {"walked": False}
+    n = path["periods"]
+    steps = path["steps"]
+    return {
+        "walked": True, "periods": n, "path_s": path["seconds"] / n,
+        "miss": path["miss"],
+        "by_class": {k: round(v / n, 5) for k, v in path["by_class"].items()},
+        "by_name": {k: round(v / n, 5) for k, v in sorted(
+            path["by_name"].items(), key=lambda kv: -kv[1]) if v / n >= 5e-5},
+        "handoff_s": {k: round(v / n, 5) for k, v in sorted(
+            path["handoff_s"].items(), key=lambda kv: -kv[1])
+            if v / n >= 5e-5},
+        "steps_a_dag": {k: round(v / n, 2) for k, v in steps.items()},
+        "guess_share": steps["guess"] / max(1, sum(steps.values())),
+        "stalls": [[round(a - obs["dags"][0]["t_submit"], 3), round(d, 3)]
+                   for a, d in path["stalls"]],
+        # the last period's path as it ran, oldest first: [name, thread,
+        # ms from the period's start, ms], stretches of 1 ms and more
+        "last_period_chain": [
+            [c["name"], c["thread"].split("#")[0][-28:],
+             round((c["start"] - path["chain"][0]["start"]) * 1e3, 1),
+             round(c["seconds"] * 1e3, 1)]
+            for c in path["chain"] if c["seconds"] >= 1e-3]}
 
 
 def main() -> int:
@@ -254,6 +266,7 @@ def main() -> int:
         found["exchange_counters_a_dag"] = {
             k: v / len(dags) for k, v in sorted(totals.items())}
         found["event_delivery_a_dag"] = event_delivery(dags)
+        found["critical_path_s_a_dag"] = path_table(res["obs"])
         return result_line(args, spec, devices, res)
 
     trace_reduce.reduce_trace = reduce_and_keep
@@ -267,8 +280,11 @@ def main() -> int:
     spans = [s for s in tracing.snapshot() if s.end is not None]
     roots = {s.trace_id: s for s in spans if s.cat == "dag"}
     ids = {s.span_id for s in spans}
+    # the client's spans and the stall witness's are roots of their own,
+    # found by the window's clock: no orphans
     orphans = collections.Counter(
-        s.name.split(":")[0] for s in spans if s.trace_id not in roots)
+        s.name.split(":")[0] for s in spans if s.trace_id not in roots
+        and s.cat not in ("client", "host"))
     unresolved = collections.Counter(
         s.name.split(":")[0] for s in spans
         if s.parent_id and s.parent_id not in ids)
@@ -307,7 +323,6 @@ def main() -> int:
                          for k, v in self_s.most_common()},
         "exchange_self_s_a_dag": {k: round(v / dags, 4)
                                   for k, v in by_site.most_common()},
-        "timeline_ms": timeline(spans, roots, marks),
         "programs": program_table(found.pop("program_device_s"),
                                   kernel_spans, kernel_programs(), dags),
         "compiled": [{"kernel": name, "signature": sig,

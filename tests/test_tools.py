@@ -379,3 +379,14 @@ def test_trace_tool_puts_device_time_beside_launches(window_check):
         # launched, but not among the programs the trace gave a time for
         "_fused_resident_hash_sort_impl": {"launches_a_dag": 4.0,
                                            "device_ms_a_launch": None}}
+
+
+def test_trace_tool_counts_grouped_rows_by_path(window_check):
+    from tez_tpu.common.tracing import Span
+    spans = [Span("input.group", "task", "t", None, args)
+             for args in ({"rows": 300, "width": 8}, {"rows": 100, "width": 0},
+                          {"rows": 40, "width": -1},
+                          {"rows": 20})]        # a tree from before `width`
+    spans.append(Span("input.read", "task", "t", None, {"rows": 999}))
+    assert window_check.group_rows(spans, dags=2) == {"fixed": 200.0,
+                                                      "ragged": 30.0}

@@ -740,6 +740,21 @@ def test_owc_span_names_are_the_documented_vocabulary(traced_owc):
     assert undocumented_spans({"made.up"}, doc) == {"made.up"}
 
 
+def test_owc_groups_every_block_by_the_fixed_width_compare(traced_owc):
+    """OrderedWordCount's keys are one width (`w%07d` words, then 8-byte
+    counts): every `input.group` span says the fixed compare took its
+    block, with arguments its vocabulary row names."""
+    import os
+    from tests.trace_schema import undocumented_span_args
+    _status, _finished, spans, _dropped = traced_owc
+    groups = [s for s in spans if s.name == "input.group"]
+    assert groups and {s.args["width"] for s in groups} == {8}
+    doc = open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "observability.md")).read()
+    assert undocumented_span_args("input.group", groups[0].args, doc) \
+        == set()
+
+
 def test_dag_status_time_taken_is_the_events_local(traced_owc):
     status, finished, _spans, _dropped = traced_owc
     (event,) = finished

@@ -24,7 +24,7 @@ from tez_tpu.api.runtime import (KeyValueReader, KeyValuesReader,
                                  LogicalInput, MergedLogicalInput, Reader)
 from tez_tpu.common import faults, metrics, tracing
 from tez_tpu.common.counters import TaskCounter
-from tez_tpu.ops.runformat import KVBatch, adjacent_equal_rows
+from tez_tpu.ops.runformat import KVBatch, fixed_key_width, group_starts
 from tez_tpu.ops.serde import Serde, get_serde
 from tez_tpu.shuffle.service import (ShuffleDataNotFound,
                                      local_shuffle_service)
@@ -630,7 +630,7 @@ class GroupedKVReader(KeyValuesReader):
         n = batch.num_records
         if n == 0:
             return np.zeros(0, dtype=np.int64)
-        with tracing.span("input.group", cat="task", rows=n):
+        with tracing.span("input.group", cat="task", rows=n) as sp:
             if key_normalizer is not None:
                 # comparator-equality grouping (e.g. case-insensitive):
                 # adjacent keys with equal NORMALIZED forms form one group —
@@ -640,11 +640,9 @@ class GroupedKVReader(KeyValuesReader):
                 kb, ko = normalize_batch_keys(batch, key_normalizer)
             else:
                 kb, ko = batch.key_bytes, batch.key_offsets
-            lengths = ko[1:] - ko[:-1]
-            same = np.zeros(n, dtype=bool)
-            cand = np.flatnonzero(lengths[1:] == lengths[:-1])
-            same[cand + 1] = adjacent_equal_rows(kb, ko, cand)
-            return np.flatnonzero(~same).astype(np.int64)
+            width = fixed_key_width(ko)
+            sp.annotate(width=width)
+            return group_starts(kb, ko, width)
 
     def __iter__(self) -> Iterator[Tuple[Any, Iterator[Any]]]:
         n = self.batch.num_records

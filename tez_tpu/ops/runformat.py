@@ -154,6 +154,66 @@ def adjacent_equal_rows(data: np.ndarray, offsets: np.ndarray,
     return out
 
 
+#: the widest key `group_starts` compares a word at a time: four 8-byte
+#: words.  Each word is one strided pass over the block; the ragged path's
+#: native memcmp is as fast at 32 B and 2^20 rows, and faster past that.
+MAX_FIXED_WIDTH = 32
+
+
+def fixed_key_width(offsets: np.ndarray) -> int:
+    """The width `group_starts` compares keys at: the byte length every row
+    shares, if at most MAX_FIXED_WIDTH (0 rows: 0); else -1, its ragged
+    path."""
+    n = len(offsets) - 1
+    if n <= 0:
+        return 0
+    start, end = int(offsets[0]), int(offsets[-1])
+    w = int(offsets[1]) - start
+    if w > MAX_FIXED_WIDTH or end - start != n * w:
+        return -1
+    # compared as memoryviews, which keep the GIL: each numpy pass lets it
+    # go, and among a DAG's reducer threads taking it back costs more than
+    # the pass (PR 37, PERF.md §6).  Offsets never fall, so w == 0 here
+    # means every row is empty
+    if w and memoryview(offsets) != memoryview(np.arange(start, end + 1, w)):
+        return -1
+    return w
+
+
+def group_starts(data: np.ndarray, offsets: np.ndarray,
+                 width: Optional[int] = None) -> np.ndarray:
+    """Row indices where a new key begins in a block of sorted keys: row 0
+    and every row whose bytes differ from the row before
+    (ValuesIterator.java:45 semantics).  `width` is `fixed_key_width`'s
+    answer where the caller has it.  Keys of one width compare a word of
+    the row at a time, the last word ending at the row's end (so it may
+    overlap the one before: every byte is in some word); ragged keys compare
+    bytes where lengths agree."""
+    n = len(offsets) - 1
+    if n <= 0:
+        return np.zeros(0, dtype=np.int64)
+    w = fixed_key_width(offsets) if width is None else width
+    if w < 0:
+        lengths = offsets[1:] - offsets[:-1]
+        same = np.zeros(n, dtype=bool)
+        cand = np.flatnonzero(lengths[1:] == lengths[:-1])
+        same[cand + 1] = adjacent_equal_rows(data, offsets, cand)
+        return np.flatnonzero(~same).astype(np.int64)
+    if w == 0:
+        return np.zeros(1, dtype=np.int64)
+    rows = np.ascontiguousarray(data[offsets[0]:offsets[-1]])
+    size = 8 if w >= 8 else 4 if w >= 4 else 2 if w >= 2 else 1
+    new = np.empty(n, dtype=bool)
+    new[0] = True
+    for at in range(0, w, size):
+        word = np.ndarray((n,), f"u{size}", rows, min(at, w - size), (w,))
+        if at == 0:
+            np.not_equal(word[1:], word[:-1], out=new[1:])
+        else:
+            new[1:] |= word[1:] != word[:-1]
+    return np.flatnonzero(new).astype(np.int64, copy=False)
+
+
 def concat_ragged(parts: Sequence[Tuple[np.ndarray, np.ndarray]]
                   ) -> Tuple[np.ndarray, np.ndarray]:
     """Concatenate (data, offsets) raggeds."""

@@ -60,6 +60,12 @@ exchange_self_s_a_dag  the same self time for the ``exchange.*`` spans
              shard on a reader thread, else ``round``: the executing
              thread), so ``exchange.decode`` reads as shards and
              ``assemble`` and ``exchange.pack`` as producers and the round
+group_rows_a_dag  the rows the window's ``input.group`` spans grouped, a
+             DAG, by the path their ``width`` argument names: ``fixed`` (the
+             one width of every key of the block, compared a word at a
+             time) and ``ragged`` (-1: lengths differ, or keys wider than
+             ``runformat.MAX_FIXED_WIDTH``; a span with no ``width`` is a
+             tree from before PR 37 and counts here)
 
 The clock check: for every span with a ``tez.<name>`` twin in the profiler's
 file, |(annotation start - marker offset) - span start| — the reducer's
@@ -186,6 +192,16 @@ def event_delivery(dags) -> dict:
             1, wake.get("count", 0)),
         "beats": hists.get("am.heartbeat.rtt", {}).get("count", 0) / n,
         "woken": groups["TaskUmbilical"].get("am.heartbeat.woken", 0) / n}
+
+
+def group_rows(spans, dags) -> dict:
+    """``group_rows_a_dag``: rows grouped a DAG, by the path taken."""
+    rows = collections.Counter(fixed=0, ragged=0)
+    for s in spans:
+        if s.name == "input.group":
+            fixed = s.args.get("width", -1) >= 0
+            rows["fixed" if fixed else "ragged"] += s.args.get("rows", 0)
+    return {k: v / dags for k, v in rows.items()}
 
 
 def path_table(obs) -> dict:
@@ -323,6 +339,9 @@ def main() -> int:
                          for k, v in self_s.most_common()},
         "exchange_self_s_a_dag": {k: round(v / dags, 4)
                                   for k, v in by_site.most_common()},
+        "group_rows_a_dag": group_rows(
+            [s for s in spans if marks["start"] <= s.start <= marks["stop"]],
+            dags),
         "programs": program_table(found.pop("program_device_s"),
                                   kernel_spans, kernel_programs(), dags),
         "compiled": [{"kernel": name, "signature": sig,

@@ -1,0 +1,6 @@
+"""agg_s_per_dag: see agg_s_per_dag.json."""
+import span_metrics
+
+
+def read(obs):
+    return span_metrics.self_s_per_dag(obs, ("agg.fold", "agg.emit"))

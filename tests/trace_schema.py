@@ -112,3 +112,11 @@ def undocumented_span_args(span_name: str, arg_names, doc_text: str) -> set:
             if line.startswith("| ") and f"`{span_name}`" in line.split("|")[1]]
     assert len(rows) == 1, f"{span_name}: {len(rows)} vocabulary rows"
     return {a for a in arg_names if f"`{a}`" not in rows[0]}
+
+
+def undocumented_counters(names, doc_text: str) -> set:
+    """Counter names that the paragraphs after the span vocabulary table
+    ("Counters counted where the work happens") do not name back-quoted."""
+    section = doc_text.split("Counters counted where the work happens", 1)[1]
+    section = section.split("\n### ", 1)[0]
+    return {name for name in names if f"`{name}`" not in section}

@@ -143,6 +143,15 @@ class TaskCounter(enum.Enum):
     # rows (once a joiner), MATCH_ROWS = the rows of a probe block and of
     # the build handed to the device probe, a launch
     UNORDERED_PARTITION_RECORDS = enum.auto()
+    # batch group-by-sum (library/aggregate.py group_sum_blocks): input
+    # rows folded on the DEVICE, each once; the rows of a block and of the
+    # table handed to a device fold, a launch, unpadded; the fold's
+    # launches; rows of the final tables, whichever engine folded them.  A
+    # fold on the host engine moves none of the first three
+    AGG_INPUT_ROWS = enum.auto()
+    AGG_FOLD_ROWS = enum.auto()
+    AGG_LAUNCHES = enum.auto()
+    AGG_GROUPS = enum.auto()
 
 
 # Mesh ICI exchange plane (parallel/coordinator.py): string-named counters

@@ -174,15 +174,18 @@ def _key_batch(batch: KVBatch, rows: np.ndarray) -> KVBatch:
                    np.zeros(len(rows) + 1, np.int64))
 
 
-def _probe_blocks(batches: Iterable[KVBatch], block_rows: int
+def _probe_blocks(batches: Iterable[KVBatch], block_rows: int,
+                  name: str = "join.probe", cat: str = "join", **args: Any
                   ) -> Iterator[KVBatch]:
     """The stream's rows in arrival order as blocks of exactly `block_rows`
-    (the last one shorter), whatever the sizes the batches come in."""
+    (the last one shorter), whatever the sizes the batches come in.  Each
+    cut is a span `name` of `cat` with `args` (the hash join's
+    ``join.probe``)."""
     pending: list = []
     rows = block = 0
     stream = iter(batches)
     while True:
-        with tracing.span("join.probe", cat="join", block=block) as span:
+        with tracing.span(name, cat=cat, block=block, **args) as span:
             while rows < block_rows:
                 batch = next(stream, None)
                 if batch is None:

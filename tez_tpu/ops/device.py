@@ -953,3 +953,124 @@ def join_probe_host(stream_lanes: np.ndarray, stream_lens: np.ndarray,
         np, lambda v: np.maximum.accumulate(v[::-1])[::-1], perm, s_lanes,
         s_lens, len(stream_lens))
     return np.sort(hits[hits >= 0]).astype(np.int64)
+
+
+# ---------------------------------------------------------------------------
+# group fold = sort of [table, block] + neighbour compare + segment sum: the
+# group table of a hash aggregation kept on the device between launches
+# ---------------------------------------------------------------------------
+def _run_ends(xp, s_lanes, s_lens):
+    """True on the last row of every run of equal (lanes, length) among the
+    live rows (length >= 0) of a key-sorted column pair."""
+    same_as_next = (s_lens[:-1] == s_lens[1:]) & \
+        (s_lanes[:-1] == s_lanes[1:]).all(axis=1)
+    return (s_lens >= 0) & xp.concatenate([~same_as_next,
+                                           xp.ones(1, dtype=bool)])
+
+
+def _group_sum_impl(t_lanes, t_lens, t_sums, b_lanes, b_lens, b_vals,
+                    table_rows: int):
+    """Fold one block of (key, value) rows into a group table: the first
+    `table_rows` rows of the table (distinct keys, key-sorted, sentinels
+    after them) and the block (sentinels: length < 0, value 0) sorted
+    together by `_lsd_passes`, the values gathered into that order, a
+    neighbour compare marking each run's last row, and a running sum whose
+    differences at the run ends are the groups' sums.  A second sort, by
+    (not a run end, place), moves the run ends to the front in key order.
+
+    Sums are int32: the running sum may wrap, its differences are exact
+    while every group's sum fits (the caller's guard).  Returns the new
+    table at table_rows + block rows (lanes, lengths, sums; the live groups
+    first, sentinels after) and its row count, i32[]."""
+    lanes = jnp.concatenate([t_lanes[:table_rows], b_lanes], axis=0)
+    lens = jnp.concatenate([t_lens[:table_rows], b_lens], axis=0)
+    vals = jnp.concatenate([t_sums[:table_rows], b_vals], axis=0)
+    perm, s_lanes, s_lens = _sort_by_key(lanes, lens)
+    ends = _run_ends(jnp, s_lanes, s_lens)
+    running = jnp.cumsum(jnp.take(vals, perm, axis=0), dtype=jnp.int32)
+    n, num_lanes = lanes.shape
+    place = jnp.arange(n, dtype=jnp.uint32)
+    res = jax.lax.sort(
+        (jnp.where(ends, place, place | jnp.uint32(1 << 31)),)
+        + tuple(s_lanes[:, i] for i in range(num_lanes))
+        + (s_lens, running), dimension=0, is_stable=False, num_keys=1)
+    count = ends.sum(dtype=jnp.int32)
+    live = jnp.arange(n) < count
+    at_end = res[-1]
+    sums = at_end - jnp.concatenate([jnp.zeros(1, at_end.dtype),
+                                     at_end[:-1]])
+    return (jnp.where(live[:, None], jnp.stack(res[1:1 + num_lanes], axis=1),
+                      jnp.uint32(0xFFFFFFFF)),
+            jnp.where(live, res[-2], -1), jnp.where(live, sums, 0), count)
+
+
+_group_sum = Kernel(
+    _group_sum_impl, "group_sum", static_argnames=("table_rows",),
+    launch_rows=lambda tl, _tn, _ts, bl, _bn, _bv: int(tl.shape[0] +
+                                                       bl.shape[0]))
+
+#: the largest sum a device group table holds
+GROUP_SUM_MAX = int(np.iinfo(np.int32).max)
+
+
+@functools.lru_cache(maxsize=8)
+def empty_group_table(rows: int, num_lanes: int):
+    """A group table of `rows` sentinel rows on the device: what the first
+    fold of a task folds into.  Uploaded once a process: a fold reads its
+    table and never writes it."""
+    return (_upload_rows(np.zeros((0, num_lanes), np.uint32), rows,
+                         0xFFFFFFFF),
+            jnp.asarray(np.full(rows, -1, np.int32)),
+            jnp.asarray(np.zeros(rows, np.int32)))
+
+
+def stage_group_block(lanes: np.ndarray, lens: np.ndarray,
+                      vals: np.ndarray, bucket: int = 0):
+    """One block of rows padded to its bucket, or to `bucket` rows where
+    that is more (sentinels: lanes all ones, length -1, value 0), and
+    uploaded; the values have to fit int32."""
+    n = len(lens)
+    with tracing.span("agg.fold", cat="agg", stage="stage", rows=n):
+        nb = max(_bucket(n), bucket)
+        return (_upload_rows(lanes, nb, 0xFFFFFFFF),
+                jnp.asarray(np.pad(lens.astype(np.int32), (0, nb - n),
+                                   constant_values=-1)),
+                jnp.asarray(np.pad(vals.astype(np.int32), (0, nb - n))))
+
+
+def group_sum(table: tuple, table_rows: int, block: tuple) -> tuple:
+    """Launch the fold of a staged block into the resident `table` (device
+    lanes, lengths, sums of at least `table_rows` rows, every live group
+    among the first `table_rows`).  Returns the new table and its row count
+    as device arrays at once: the program is enqueued, nothing is waited
+    for.  The compile key is (table rows in, table_rows, block bucket,
+    lanes)."""
+    with tracing.span("agg.fold", cat="agg", stage="launch"):
+        *out, count = _group_sum(*table, *block, table_rows=table_rows)
+    return tuple(out), count
+
+
+def group_table_rows(table: tuple, count: int
+                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The first `count` rows of a device group table on the host: (lanes
+    u32[n, L], lengths i32[n], sums i64[n]), key-sorted."""
+    lanes, lens, sums = (np.asarray(a)[:count] for a in table)
+    return lanes, lens, sums.astype(np.int64)
+
+
+def group_sum_host(t_lanes: np.ndarray, t_lens: np.ndarray,
+                   t_sums: np.ndarray, b_lanes: np.ndarray,
+                   b_lens: np.ndarray, b_vals: np.ndarray
+                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """group_sum on the host engine, unpadded: numpy's stable lexsort in
+    the sort's place, the same run ends, int64 sums by np.add.reduceat.
+    Returns the new table (lanes, lengths, sums), key-sorted."""
+    perm, s_lanes, s_lens = _lexsort_two_sides(t_lanes, t_lens, b_lanes,
+                                               b_lens)
+    if not len(perm):
+        return s_lanes, s_lens, np.zeros(0, np.int64)
+    ends = np.flatnonzero(_run_ends(np, s_lanes, s_lens))
+    vals = np.concatenate([t_sums.astype(np.int64),
+                           b_vals.astype(np.int64)])[perm]
+    starts = np.concatenate([[0], ends[:-1] + 1])
+    return s_lanes[ends], s_lens[ends], np.add.reduceat(vals, starts)

@@ -237,9 +237,11 @@ def write_trace(trace: Dict[str, Any], path: str) -> str:
 # ``(no span)``) and one class, so the classes add up to the period.
 
 #: spans in which a host thread is blocked for the device, or for a launch
-#: queued ahead of its own (``join.match`` only with ``stage="readback"``)
+#: queued ahead of its own (``join.match`` and ``agg.fold`` only with
+#: ``stage="readback"``)
 DEVICE_WAITS = frozenset({"device.d2h", "merge.readback",
                           "exchange.readback"})
+STAGED_DEVICE_WAITS = frozenset({"join.match", "agg.fold"})
 #: envelopes: their self time is time no span names
 ENVELOPES = frozenset({"attempt", "initialize", "run", "close"})
 CLASSES = ("host work", "device wait", "control", "stall", "unnamed")
@@ -258,7 +260,7 @@ def path_name(sp: Span) -> str:
 
 def path_class(sp: Span) -> str:
     name = sp.name.split(":", 1)[0]
-    if name in DEVICE_WAITS or (name == "join.match" and
+    if name in DEVICE_WAITS or (name in STAGED_DEVICE_WAITS and
                                 sp.args.get("stage") == "readback"):
         return "device wait"
     if sp.cat in ("client", "am"):

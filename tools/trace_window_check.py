@@ -60,6 +60,11 @@ exchange_self_s_a_dag  the same self time for the ``exchange.*`` spans
              shard on a reader thread, else ``round``: the executing
              thread), so ``exchange.decode`` reads as shards and
              ``assemble`` and ``exchange.pack`` as producers and the round
+agg_a_dag    the group-by-sum's counters a DAG, beside ``programs``:
+             ``AGG_LAUNCHES`` (device folds), ``AGG_FOLD_ROWS`` (a block's
+             and the table's rows a fold, unpadded), ``AGG_INPUT_ROWS``
+             (rows folded on the device) and ``AGG_GROUPS`` (rows of the
+             final tables); zeros where no task aggregated
 group_rows_a_dag  the rows the window's ``input.group`` spans grouped, a
              DAG, by the path their ``width`` argument names: ``fixed`` (the
              one width of every key of the block, compared a word at a
@@ -204,6 +209,19 @@ def group_rows(spans, dags) -> dict:
     return {k: v / dags for k, v in rows.items()}
 
 
+AGG_COUNTERS = ("AGG_LAUNCHES", "AGG_FOLD_ROWS", "AGG_INPUT_ROWS",
+                "AGG_GROUPS")
+
+
+def agg_counters(dags) -> dict:
+    """``agg_a_dag``: the group-by-sum's counters over the DAGs, a DAG."""
+    totals = collections.Counter()
+    for dag in dags:
+        for counters in dag["counters"].values():
+            totals.update({k: counters.get(k, 0) for k in AGG_COUNTERS})
+    return {k: totals[k] / max(1, len(dags)) for k in AGG_COUNTERS}
+
+
 def path_table(obs) -> dict:
     """``critical_path_s_a_dag``: the window's walk, a period."""
     import path_metrics
@@ -283,6 +301,7 @@ def main() -> int:
             k: v / len(dags) for k, v in sorted(totals.items())}
         found["event_delivery_a_dag"] = event_delivery(dags)
         found["critical_path_s_a_dag"] = path_table(res["obs"])
+        found["agg_a_dag"] = agg_counters(dags)
         return result_line(args, spec, devices, res)
 
     trace_reduce.reduce_trace = reduce_and_keep
@@ -344,6 +363,7 @@ def main() -> int:
             dags),
         "programs": program_table(found.pop("program_device_s"),
                                   kernel_spans, kernel_programs(), dags),
+        "agg_a_dag": found.pop("agg_a_dag", {}),
         "compiled": [{"kernel": name, "signature": sig,
                       "seconds": round(secs, 2), "sort_ops": sort_ops}
                      for name, sig, secs, sort_ops, _t in

@@ -70,6 +70,50 @@ def _rows(rng, case, n=3000):
     return keys, values
 
 
+#: (table keys, table values, block keys, block values) of the cases that
+#: fold into a table already holding groups; the table comes in as the
+#: reference's answer for its rows (distinct keys, key-sorted)
+def _table_case(rng, case):
+    if case == "repeats":
+        # keys repeating inside the block and between table and block
+        vocabulary = [b"k%d" % i for i in range(40)]
+        table = [vocabulary[i] for i in rng.integers(0, 30, 200)]
+        block = [vocabulary[i] for i in rng.integers(10, 40, 2500)]
+        return (table, rng.integers(-50, 50, 200), block,
+                rng.integers(-50, 50, 2500))
+    if case == "ff_keys":
+        # keys of 0xFF bytes, the 8-byte one with lanes all ones like a
+        # sentinel's: never one group with the padding rows after them
+        ff = [b"\xff" * w for w in (4, 5, 8)]
+        block = [ff[i] for i in rng.integers(0, 3, 900)] + [b"\xfe"] * 100
+        return ff[2:] + [b"\xff" * 7], [5, 6], block, np.arange(1000)
+    if case == "lengths":
+        # one set of lanes, told apart by the length alone
+        shapes = [b"", b"\0", b"ab", b"ab\0", b"ab\0\0", b"ab\0\0\0\0\0\0"]
+        block = [shapes[i] for i in rng.integers(0, 6, 1500)]
+        return shapes[::2], [1, 2, 3], block, rng.integers(1, 9, 1500)
+    if case == "all_sentinels":
+        return [b"a", b"bb", b"\xff" * 8], [7, -3, 11], [], []
+    assert case == "wraps"
+    # 30 groups of +-2e9 each: the running sum wraps int32 many times
+    # over, every group's own sum fits
+    block = [b"g%02d" % (i % 30) for i in range(600)]
+    values = [(1 if i % 30 < 20 else -1) * 100_000_000 for i in range(600)]
+    return [b"g00", b"g29"], [47_483_647, -47_483_647], block, values
+
+
+TABLE_CASES = ["repeats", "ff_keys", "lengths", "all_sentinels", "wraps"]
+
+
+def _device_table(keys, sums, rows):
+    """A device group table of `rows` rows holding the given distinct,
+    key-sorted groups, sentinels after them."""
+    lanes, lens = _encoded(keys) if keys else (np.zeros((0, 2), np.uint32),
+                                              np.zeros(0, np.int32))
+    return device.stage_group_block(lanes, lens, np.asarray(sums, np.int64),
+                                    rows)
+
+
 def _table_pairs(lanes, lens, sums):
     return _pairs(aggregate._table_batch(lanes, lens,
                                          np.asarray(sums, np.int64)))
@@ -80,23 +124,83 @@ def _encoded(keys):
     return encode_keys(batch.key_bytes, batch.key_offsets, 8)
 
 
-@pytest.mark.parametrize("case", ["ragged", "hot_key", "values"])
+@pytest.mark.parametrize("case", ["ragged", "hot_key", "values"]
+                         + TABLE_CASES)
 @pytest.mark.parametrize("engine", ["device", "host"])
 def test_the_fold_of_one_block_is_the_reference(engine, case):
     rng = np.random.default_rng([7, len(case)])
-    keys, values = _rows(rng, case)
-    lanes, lens = _encoded(keys)
+    if case in TABLE_CASES:
+        t_keys, t_values, keys, values = _table_case(rng, case)
+        t_keys, t_sums = reference(t_keys, t_values)
+    else:
+        (keys, values), t_keys, t_sums = _rows(rng, case), [], []
+    lanes, lens = _encoded(keys) if keys else (np.zeros((0, 2), np.uint32),
+                                               np.zeros(0, np.int32))
+    values = np.asarray(values, np.int64)
     if engine == "device":
-        table = device.empty_group_table(256 + 4096, 2)
+        table = _device_table(t_keys, t_sums, 256 + 4096)
         out, count = device.group_sum(
             table, 256, device.stage_group_block(lanes, lens, values, 4096))
         got = device.group_table_rows(out, int(np.asarray(count)))
     else:
-        got = device.group_sum_host(np.zeros((0, 2), np.uint32),
-                                    np.zeros(0, np.int32),
-                                    np.zeros(0, np.int64), lanes, lens,
-                                    values)
-    assert _table_pairs(*got) == reference(keys, values)
+        t_lanes, t_lens = _encoded(t_keys) if t_keys else (
+            np.zeros((0, 2), np.uint32), np.zeros(0, np.int32))
+        got = device.group_sum_host(t_lanes, t_lens,
+                                    np.asarray(t_sums, np.int64), lanes,
+                                    lens, values)
+    assert _table_pairs(*got) == reference(t_keys + list(keys),
+                                           list(t_sums) + list(values))
+
+
+def _fold_lowered(table_rows=256, block_rows=1024):
+    """The fold's lowered text at a small shape, traced afresh (a Kernel's
+    compiled signatures, or jit's cache of the function, would hand back
+    what was traced first)."""
+    import jax
+    import jax.numpy as jnp
+    s = jax.ShapeDtypeStruct
+    rows = table_rows + block_rows
+    return jax.jit(lambda *a: device._group_sum_impl(
+        *a, table_rows=table_rows)).lower(
+        s((rows, 2), jnp.uint32), s((rows,), jnp.int32), s((rows,), jnp.int32),
+        s((block_rows, 2), jnp.uint32), s((block_rows,), jnp.int32),
+        s((block_rows,), jnp.int32)).as_text()
+
+
+def _sort_operands(text):
+    import re
+    return [len(args.split(",")) for args in re.findall(
+        r'"stablehlo\.sort"\(([^)]*)\)', text)]
+
+
+def test_the_fold_moves_no_column_by_a_gather():
+    """Every column the fold needs in key order rides a sort as an operand:
+    the first sort by (2 lanes, length) carries the values, the compaction
+    sort by (not a run end, place, length code) the 2 lanes and the running
+    sum.  Two sorts, no gather."""
+    text = _fold_lowered()
+    assert "stablehlo.gather" not in text
+    assert "dynamic_gather" not in text
+    assert text.count("stablehlo.sort") == 2
+    assert _sort_operands(text) == [4, 4]
+
+
+def test_a_fold_too_large_to_pack_the_length_carries_it(monkeypatch):
+    """Where place and the length code do not fit the compaction key
+    together, the length is an operand of its own: the same answer."""
+    import jax
+    monkeypatch.setattr(device, "_TAIL_KEY_BITS", 12)  # 1,280 rows need 11
+    assert _sort_operands(_fold_lowered()) == [4, 5]
+    rng = np.random.default_rng(5)
+    t_keys, t_values, keys, values = _table_case(rng, "lengths")
+    t_keys, t_sums = reference(t_keys, t_values)
+    lanes, lens = _encoded(keys)
+    fold = jax.jit(lambda *a: device._group_sum_impl(*a, table_rows=256))
+    *out, count = fold(*_device_table(t_keys, t_sums, 256 + 1024),
+                       *device.stage_group_block(lanes, lens, values, 1024))
+    got = device.group_table_rows(out, int(np.asarray(count)))
+    assert _table_pairs(*got) == reference(t_keys + keys,
+                                           list(t_sums) + list(values))
 
 
 def test_an_empty_block_leaves_the_table_as_it_was():

@@ -7,9 +7,11 @@ Python.  The fetched batches (``iter_batches()`` of an UnorderedKVInput) are
 cut into blocks of a fixed row count (library/join.py ``_probe_blocks``, so
 that compile keys follow the task's sizes, not fetch order); each block is
 folded into a group table that stays on the device from a task's first
-block to its last (ops/device.py ``group_sum``: one sort of [table, block],
-a neighbour compare, a segment sum), and the table is read back once, at
-the end, as a KVBatch of (key, sum).
+block to its last (ops/device.py ``group_sum``: one sort of [table, block]
+by key that carries the values along, a neighbour compare, a running sum,
+and a second sort that moves each group's last row to the front; no
+column is gathered), and the table is read back once, at the end, as a
+KVBatch of (key, sum).
 
 The host engine (``group_sum_host``) takes a task whose first block is
 under the routing floor, a key wider than the edge's lanes, or sums that

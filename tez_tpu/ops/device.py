@@ -14,7 +14,9 @@ and the record permutation (`_lsd_passes`) — XLA lowers this to its
 on-device sort; the merge of k sorted runs is the same sort of the
 concatenation (sort networks beat heap-merge on TPU's vector units; the
 arrival order as last key preserves within-key run order like the
-reference's MergeQueue).
+reference's MergeQueue).  The group fold alone sorts by key without the
+arrival order and needs no permutation: its values ride its own sort as a
+payload operand (`_group_sum_impl`).
 """
 from __future__ import annotations
 
@@ -241,7 +243,9 @@ def is_resource_exhausted(exc: BaseException) -> bool:
 
 
 #: bits of the sort's last key, which the length code and the row number
-#: share (a test narrows it to reach the branch where they do not fit)
+#: share, and of the group fold's compaction key, which holds a flag, the
+#: place and the length code (tests narrow it to reach the branches where
+#: they do not fit)
 _TAIL_KEY_BITS = 32
 
 
@@ -956,8 +960,9 @@ def join_probe_host(stream_lanes: np.ndarray, stream_lens: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# group fold = sort of [table, block] + neighbour compare + segment sum: the
-# group table of a hash aggregation kept on the device between launches
+# group fold = sort of [table, block] carrying the values + neighbour compare
+# + segment sum: the group table of a hash aggregation kept on the device
+# between launches
 # ---------------------------------------------------------------------------
 def _run_ends(xp, s_lanes, s_lens):
     """True on the last row of every run of equal (lanes, length) among the
@@ -972,28 +977,55 @@ def _group_sum_impl(t_lanes, t_lens, t_sums, b_lanes, b_lens, b_vals,
                     table_rows: int):
     """Fold one block of (key, value) rows into a group table: the first
     `table_rows` rows of the table (distinct keys, key-sorted, sentinels
-    after them) and the block (sentinels: length < 0, value 0) sorted
-    together by `_lsd_passes`, the values gathered into that order, a
-    neighbour compare marking each run's last row, and a running sum whose
-    differences at the run ends are the groups' sums.  A second sort, by
-    (not a run end, place), moves the run ends to the front in key order.
+    after them) and the block (sentinels: lanes all ones, length -1, value
+    0, as every staging path writes them) in one sort of their own, by
+    (lanes, length as u32) with the values as a payload operand, so that
+    they come out in key order without a gather; a neighbour compare
+    marking each run's last row, and a running sum whose differences at
+    the run ends are the groups' sums.  A second sort, by (not a run end,
+    place), moves the run ends to the front in key order, the lanes, the
+    running sum and the length (in the key's low bits where they are
+    free) riding along.
 
-    Sums are int32: the running sum may wrap, its differences are exact
-    while every group's sum fits (the caller's guard).  Returns the new
-    table at table_rows + block rows (lanes, lengths, sums; the live groups
-    first, sentinels after) and its row count, i32[]."""
+    No arrival order and no permutation: rows of one key are one group in
+    whatever order they stand, and the sum's differences do not depend on
+    the order it adds in.  The merges, the match and the probe need both
+    and keep `_lsd_passes`.  Sums are int32: the running sum may wrap, its
+    differences are exact while every group's sum fits (the caller's
+    guard).  Returns the new table at table_rows + block rows (lanes,
+    lengths, sums; the live groups first, sentinels after) and its row
+    count, i32[]."""
     lanes = jnp.concatenate([t_lanes[:table_rows], b_lanes], axis=0)
     lens = jnp.concatenate([t_lens[:table_rows], b_lens], axis=0)
     vals = jnp.concatenate([t_sums[:table_rows], b_vals], axis=0)
-    perm, s_lanes, s_lens = _sort_by_key(lanes, lens)
-    ends = _run_ends(jnp, s_lanes, s_lens)
-    running = jnp.cumsum(jnp.take(vals, perm, axis=0), dtype=jnp.int32)
     n, num_lanes = lanes.shape
+    # length -1 is all ones as u32: a sentinel sorts after every real key
+    # of its lanes, and its value 0 leaves the running sum as it was
+    res = jax.lax.sort(
+        tuple(lanes[:, i] for i in range(num_lanes))
+        + (lens.astype(jnp.uint32), vals), dimension=0, is_stable=False,
+        num_keys=num_lanes + 1)
+    s_lanes = jnp.stack(res[:num_lanes], axis=1)
+    s_lens = res[num_lanes].astype(jnp.int32)
+    ends = _run_ends(jnp, s_lanes, s_lens)
+    running = jnp.cumsum(res[-1], dtype=jnp.int32)
     place = jnp.arange(n, dtype=jnp.uint32)
+    # a live key is at most 4L bytes (longer ones fold on the host): where
+    # the bits allow, its length rides below `place` in the compaction key,
+    # one operand fewer (6.03 against 6.49 ms a launch at 2^18 + 2^20 rows
+    # on a TPU v5e: PERF.md §6)
+    code_bits = (4 * num_lanes).bit_length()
+    packed = n <= 1 << (_TAIL_KEY_BITS - 1 - code_bits)
+    if packed:
+        place = (place << code_bits) | \
+            jnp.maximum(s_lens, 0).astype(jnp.uint32)
     res = jax.lax.sort(
         (jnp.where(ends, place, place | jnp.uint32(1 << 31)),)
         + tuple(s_lanes[:, i] for i in range(num_lanes))
-        + (s_lens, running), dimension=0, is_stable=False, num_keys=1)
+        + (() if packed else (s_lens,)) + (running,), dimension=0,
+        is_stable=False, num_keys=1)
+    out_lens = (res[0] & jnp.uint32((1 << code_bits) - 1)).astype(
+        jnp.int32) if packed else res[-2]
     count = ends.sum(dtype=jnp.int32)
     live = jnp.arange(n) < count
     at_end = res[-1]
@@ -1001,7 +1033,7 @@ def _group_sum_impl(t_lanes, t_lens, t_sums, b_lanes, b_lens, b_vals,
                                      at_end[:-1]])
     return (jnp.where(live[:, None], jnp.stack(res[1:1 + num_lanes], axis=1),
                       jnp.uint32(0xFFFFFFFF)),
-            jnp.where(live, res[-2], -1), jnp.where(live, sums, 0), count)
+            jnp.where(live, out_lens, -1), jnp.where(live, sums, 0), count)
 
 
 _group_sum = Kernel(

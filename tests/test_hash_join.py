@@ -161,8 +161,12 @@ def test_a_build_side_under_the_routing_floor_is_probed_on_the_host():
 
 
 def test_hash_join_blocks_is_semi_only():
-    with pytest.raises(ValueError, match="semi"):
-        list(hash_join_blocks([], [], how="inner"))
+    """Semi, and inner where the build side's keys are unique (a
+    key-foreign-key join, tests/test_tpch_q3.py): every other kind of join
+    raises."""
+    for how in ("left_outer", "semi_distinct", "anti"):
+        with pytest.raises(ValueError, match="semi or inner"):
+            list(hash_join_blocks([], [], how=how))
 
 
 # ---------------------------------------------------------------------------

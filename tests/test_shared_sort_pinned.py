@@ -1,12 +1,14 @@
 """The shared sort body, pinned: `_lsd_passes` / `_sort_by_key` are traced by
 every merge, match and probe program, and their lowered text is their
-compile-cache key.  Each program's text (debug info off) at a small shape
+compile-cache key; the group fold's own sort is pinned beside them.
+Each program's text (debug info off) at a small shape
 is held to the sha256 checked in beside this file
 (`shared_sort_programs.json`): a kernel that takes a sort of its own must
 leave these programs byte for byte as they were, or the join cells'
 six-lane programs compile again cold."""
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -17,6 +19,7 @@ import pytest
 
 from tez_tpu.ops import device
 
+TABLE, BLOCK = 1 << 18, 1 << 20
 PINS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                     "shared_sort_programs.json")
 
@@ -33,6 +36,13 @@ def _programs():
         "_join_probe_impl": (device._join_probe_impl, (
             s((512, 6), u32), s((512,), i32), s((256, 6), u32),
             s((256,), i32))),
+        # WordCount's fold: a table of 2^18 groups and a block of 2^20
+        # rows of two lanes (the wc_unordered_zipf cell's one program)
+        "_group_sum_impl": (functools.partial(
+            device._group_sum_impl, table_rows=TABLE), (
+            s((TABLE + BLOCK, 2), u32), s((TABLE + BLOCK,), i32),
+            s((TABLE + BLOCK,), i32), s((BLOCK, 2), u32), s((BLOCK,), i32),
+            s((BLOCK,), i32))),
     }
 
 

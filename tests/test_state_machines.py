@@ -307,3 +307,60 @@ def test_container_blacklisted_after_repeated_failures():
     assert sched.get_task(cid, timeout=0.1) is None
     # ...but a healthy container still gets the work
     assert sched.get_task("container-y", timeout=0.1) is not None
+
+
+class CountingCommitter:
+    """A leaf output's committer that counts its commits (class-wide: the
+    AM instantiates it from the plan)."""
+
+    commits = 0
+
+    def __init__(self, context: Any) -> None:
+        self.context = context
+
+    def initialize(self) -> None:
+        pass
+
+    def setup_output(self) -> None:
+        pass
+
+    def commit_output(self) -> None:
+        CountingCommitter.commits += 1
+
+    def abort_output(self, final_state: str) -> None:
+        pass
+
+
+def test_vertices_that_finish_together_commit_the_dag_once():
+    """Two last vertices that complete before the DAG has seen the first
+    completion's event: the counters run ahead of the events, so the second
+    event finds every vertex counted too, and the DAG was committing
+    already.  It commits once (two commits of one output race each other:
+    one lists the staged files as the other removes them)."""
+    from tez_tpu.common.payload import OutputCommitterDescriptor
+    from tez_tpu.dag.dag import DataSinkDescriptor
+    am = FakeAM()
+    dag = DAG.create("t")
+    for name in ("a", "b"):
+        v = Vertex.create(name, ProcessorDescriptor.create(
+            "tez_tpu.library.processors:SimpleProcessor"), 1)
+        dag.add_vertex(v)
+    v.add_data_sink("out", DataSinkDescriptor.create(
+        OutputDescriptor.create("x:O"), OutputCommitterDescriptor.create(
+            f"{__name__}:CountingCommitter")))
+    impl = DAGImpl(DAGId("app_0_t", 1), dag.create_dag_plan(), am)
+    am.current_dag = impl
+    CountingCommitter.commits = 0
+    start_dag(am, impl)
+    attempts = list(am.launch_requests)
+    assert len(attempts) == 2
+    for att in attempts:
+        am.dispatch(TaskAttemptEvent(
+            TaskAttemptEventType.TA_STARTED_REMOTELY, att, container_id="c0"))
+    am.dispatcher.drain()
+    for att in attempts:                # both done before either is seen
+        am.dispatch(TaskAttemptEvent(TaskAttemptEventType.TA_DONE, att))
+    am.dispatcher.drain()
+    assert impl.state is DAGState.SUCCEEDED
+    assert CountingCommitter.commits == 1
+    assert am.finished == [DAGState.SUCCEEDED]

@@ -231,6 +231,14 @@ class DAGImpl:
             self.diagnostics.append(
                 f"vertex {event.vertex_name} failed")
             self._terminate_vertices("DAG failing: vertex failed")
+        if self.state is DAGState.COMMITTING:
+            # the counters run ahead of the events (on_vertex_completed
+            # counts, then queues this): a vertex that completed while an
+            # earlier completion's event was queued was counted before that
+            # event started the commit, so its own event finds the commit
+            # under way -- a second one would race the first on the same
+            # outputs
+            return DAGState.COMMITTING
         if self.completed_vertices == len(self.vertices):
             # last vertex finished; the root span ends where the client's
             # wait can return (app_master.on_dag_finished)

@@ -152,6 +152,21 @@ class TaskCounter(enum.Enum):
     AGG_FOLD_ROWS = enum.auto()
     AGG_LAUNCHES = enum.auto()
     AGG_GROUPS = enum.auto()
+    # typed columnar scan (io/formats.py ColumnarFormat,
+    # library/inputs.py filter_columns): rows and bytes of the named
+    # columns read from the column files, rows the scan's predicate kept
+    SCAN_ROWS_READ = enum.auto()
+    SCAN_BYTES_READ = enum.auto()
+    SCAN_ROWS_KEPT = enum.auto()
+    # key-foreign-key join (library/join.py hash_join_blocks how="inner"):
+    # the rows of a probe block and of the build side handed to the DEVICE
+    # probe, a launch, unpadded; the block's rows alone; the launches; the
+    # stream rows written with the build row's value carried, on either
+    # engine.  A probe on the host engine moves none of the first three
+    KFK_PROBE_ROWS = enum.auto()
+    KFK_PROBE_STREAM_ROWS = enum.auto()
+    KFK_PROBE_LAUNCHES = enum.auto()
+    KFK_OUTPUT_ROWS = enum.auto()
 
 
 # Mesh ICI exchange plane (parallel/coordinator.py): string-named counters

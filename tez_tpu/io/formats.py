@@ -295,9 +295,107 @@ class FixedWidthKVFormat(InputFormat):
         return _FixedWidthReader(splits, context, kb, vb)
 
 
+def _part_dirs(paths: Sequence[str]) -> List[str]:
+    """A table's part directories: a path that holds directories is a
+    table (its parts, in name order), any other path is a part."""
+    parts: List[str] = []
+    for p in paths:
+        subdirs = sorted(os.path.join(p, d) for d in os.listdir(p)
+                         if os.path.isdir(os.path.join(p, d)))
+        parts.extend(subdirs or [p])
+    return parts
+
+
+def _read_column(path: str, dtype: str):
+    """One column file as an array of `dtype`, in a pooled block."""
+    import numpy as np
+    from tez_tpu.ops import hostpool
+    dt = np.dtype(dtype)
+    size = os.path.getsize(path)
+    if size % dt.itemsize:
+        raise ValueError(f"{path}: {size} bytes are no whole number of "
+                         f"{dt.itemsize}-byte values")
+    col = hostpool.empty(size // dt.itemsize, dt)
+    with open(path, "rb", buffering=0) as fh:
+        got = fh.readinto(col.view(np.uint8))
+    if got != size:
+        raise IOError(f"{path}: read {got} of {size} bytes")
+    return col
+
+
+class _ColumnReader(Reader):
+    """A columnar split's reader: parts of columns, not (key, value)
+    records (``iter_columns``)."""
+
+    def __init__(self, splits: Sequence[FileSplit], context: Any,
+                 table: str, columns: Dict[str, str]):
+        self.splits = splits
+        self.context = context
+        self.table = table
+        self.columns = columns
+
+    def iter_columns(self) -> Iterator[Dict[str, Any]]:
+        """Each part of the splits as {column: numpy array}: the named
+        columns' files read whole into pooled memory (ops/hostpool.py: a
+        DAG reads the same sizes again), nothing else opened.  A part
+        whose columns disagree on their row count, or a file that is no
+        whole number of values, raises."""
+        counters = self.context.counters
+        names = ",".join(self.columns)
+        for split in self.splits:
+            with tracing.span("scan.read", cat="task", table=self.table,
+                              columns=names) as span:
+                cols = {name: _read_column(os.path.join(split.path, name),
+                                           dtype)
+                        for name, dtype in self.columns.items()}
+                rows = {len(v) for v in cols.values()}
+                if len(rows) > 1:
+                    raise ValueError(f"{split.path}: the columns "
+                                     f"{sorted(cols)} hold {sorted(rows)} "
+                                     f"rows, not one count")
+                n = rows.pop() if rows else 0
+                nbytes = sum(v.nbytes for v in cols.values())
+                span.annotate(rows=n, bytes=nbytes)
+            counters.increment(TaskCounter.SCAN_ROWS_READ, n)
+            counters.increment(TaskCounter.SCAN_BYTES_READ, nbytes)
+            counters.increment(FileSystemCounter.FILE_BYTES_READ, nbytes)
+            counters.increment(FileSystemCounter.FILE_READ_OPS, len(cols))
+            self.context.notify_progress()
+            yield cols
+
+
+class ColumnarFormat(InputFormat):
+    """Typed columns, one little-endian file a column a part (ORC's
+    layout, without its stripes or encodings): a table is a directory of
+    part directories, each holding a file of raw values a column, named
+    as the column, every column of a part the same row count.  Params:
+    ``table`` (the name spans give it) and ``columns``, {name: numpy dtype
+    string} of the columns the vertex reads; no other file is opened.  A
+    split is one part, whole."""
+
+    def _columns(self) -> Dict[str, str]:
+        columns = dict(self.params.get("columns") or {})
+        if not columns:
+            raise ValueError("a columnar scan names the columns it reads")
+        return columns
+
+    def compute_splits(self, paths: Sequence[str], desired: int,
+                       min_split_bytes: int = 64 * 1024) -> List[FileSplit]:
+        columns = self._columns()
+        return [FileSplit(part, 0, sum(
+            os.path.getsize(os.path.join(part, name)) for name in columns))
+            for part in _part_dirs(paths)]
+
+    def open(self, splits: Sequence[FileSplit], context: Any) -> Reader:
+        return _ColumnReader(splits, context,
+                             str(self.params.get("table", "")),
+                             self._columns())
+
+
 _REGISTRY = {
     "text": TextFormat,
     "fixed": FixedWidthKVFormat,
+    "columnar": ColumnarFormat,
 }
 
 

@@ -24,6 +24,7 @@ from tez_tpu.api.runtime import (KeyValueReader, KeyValuesReader,
                                  LogicalInput, MergedLogicalInput, Reader)
 from tez_tpu.common import faults, metrics, tracing
 from tez_tpu.common.counters import TaskCounter
+from tez_tpu.ops import hostpool
 from tez_tpu.ops.runformat import KVBatch, fixed_key_width, group_starts
 from tez_tpu.ops.serde import Serde, get_serde
 from tez_tpu.shuffle.service import (ShuffleDataNotFound,
@@ -176,6 +177,13 @@ class ShuffleFetchTable:
         self._closing = True
         if self._scheduler is not None:
             self._scheduler.stop()
+        # the input is closed: its fetched batches go now, not when the
+        # collector breaks the task's reference cycles (a full collection
+        # comes every few dozen DAGs, and until it does the pooled blocks
+        # behind them are made afresh for the next DAG's fetches)
+        with self.lock:
+            for s in self.slots:
+                s.batches = []
 
     def _fetch_local(self, payload: ShufflePayload,
                      partition: int, after: str = "") -> KVBatch:
@@ -833,3 +841,96 @@ class ConcatenatedMergedKVInput(MergedLogicalInput):
                             yield k, v
 
         return _Concat()
+
+
+# ---------------------------------------------------------------------------
+# the typed columnar scan's batch side (io/formats.py ColumnarFormat reads
+# the columns): a predicate over whole columns, and typed columns in and out
+# of the KV edges as fixed-width big-endian rows
+# ---------------------------------------------------------------------------
+
+def scan_rows(columns: Dict[str, np.ndarray], predicate: Any,
+              project: Any, counters: Any = None) -> KVBatch:
+    """The rows of `columns` for which ``predicate(columns)`` -- a boolean
+    array computed over whole columns, never a row at a time -- holds, as
+    the KVBatch of what ``project(kept columns)`` returns: (key columns,
+    value columns), expressions over whole columns among them, packed by
+    ``pack_rows``."""
+    n = len(next(iter(columns.values()))) if columns else 0
+    with tracing.span("scan.filter", cat="task", rows_in=n) as span:
+        keep = np.flatnonzero(predicate(columns))
+        kept = {name: np.take(col, keep, out=hostpool.empty(len(keep),
+                                                             col.dtype))
+                for name, col in columns.items()}
+        batch = pack_rows(*project(kept))
+        span.annotate(rows_out=len(keep))
+    if counters is not None:
+        counters.increment(TaskCounter.SCAN_ROWS_KEPT, len(keep))
+    return batch
+
+
+def _rows_dtype(dtypes: Sequence[Any]) -> np.dtype:
+    """Fixed-width rows of the given columns side by side, each value
+    big-endian in its dtype's width, as one structured dtype."""
+    big = [np.dtype(d).newbyteorder(">") for d in dtypes]
+    offsets = np.cumsum([0] + [d.itemsize for d in big])
+    return np.dtype({"names": [f"c{i}" for i in range(len(big))],
+                     "formats": big, "offsets": offsets[:-1].tolist(),
+                     "itemsize": int(offsets[-1])})
+
+
+def _rows_bytes(columns: Sequence[np.ndarray], n: int
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """`n` rows of the columns side by side (``_rows_dtype``), written a
+    column at a time into pooled memory: (bytes u8[n * width], offsets
+    i64[n + 1]); no columns, rows of width 0."""
+    if not columns:
+        return np.zeros(0, np.uint8), np.zeros(n + 1, np.int64)
+    row = _rows_dtype([c.dtype for c in columns])
+    data = hostpool.empty(n * row.itemsize)
+    records = data.view(row)
+    for name, col in zip(row.names, columns):
+        records[name] = col
+    offsets = hostpool.empty(n + 1, np.int64)
+    offsets[0] = 0
+    offsets[1:] = row.itemsize
+    return data, np.cumsum(offsets, out=offsets)
+
+
+def pack_rows(keys: Sequence[np.ndarray], values: Sequence[np.ndarray]
+              ) -> KVBatch:
+    """A KVBatch of typed columns: the key columns side by side, then the
+    value columns, each value big-endian (an unsigned or non-negative key
+    orders by its bytes as by its number).  An 8-byte long that
+    library/aggregate.py sums is written as ops/serde.py
+    ``encode_longs_be`` writes it: ``long_column``."""
+    n = len(keys[0])
+    key_bytes, key_offsets = _rows_bytes(keys, n)
+    val_bytes, val_offsets = _rows_bytes(values, n)
+    return KVBatch(key_bytes, key_offsets, val_bytes, val_offsets)
+
+
+def long_column(values: np.ndarray) -> np.ndarray:
+    """int64 values as u64 with the sign bit flipped, in pooled memory:
+    written big-endian, the bytes of VarLongSerde (``decode_longs_be``
+    reads them back)."""
+    return np.bitwise_xor(values.astype(np.int64, copy=False).view(np.uint64),
+                          np.uint64(1 << 63),
+                          out=hostpool.empty(len(values), np.uint64))
+
+
+def unpack_columns(data: np.ndarray, offsets: np.ndarray,
+                   dtypes: Sequence[str]) -> List[np.ndarray]:
+    """The typed columns of fixed-width rows that ``pack_rows`` wrote (a
+    batch's keys or values): one array a dtype, native byte order.  Rows
+    of another width raise."""
+    n = len(offsets) - 1
+    row = _rows_dtype(dtypes)
+    lo, hi = int(offsets[0]), int(offsets[-1])
+    if hi - lo != n * row.itemsize or (n and not bool(
+            np.all(np.diff(offsets) == row.itemsize))):
+        raise ValueError(f"rows of {row.itemsize} bytes ({list(dtypes)}) "
+                         f"expected: {n} rows hold {hi - lo} bytes")
+    records = np.ascontiguousarray(data[lo:hi]).view(row)
+    return [records[name].astype(np.dtype(d).newbyteorder("="))
+            for name, d in zip(row.names, dtypes)]

@@ -12,14 +12,24 @@ out as a KVBatch with zero-width values.
 Only ``how="semi_distinct"`` -- what the source runs.  ``inner`` stays with
 the query layer's row path (query/processors.py).
 
-Beside it the batch hash join (HashJoinExample.java's HashJoinProcessor:
-the hash side read whole into a set, the stream side walked past it, a key
-written whenever the set holds it): ``hash_join_blocks``.  The build side
-is collected whole, encoded once and kept on the device; the stream side
-is cut into probe blocks by row count, each matched against the build
-(ops/device.py ``join_probe``: the match's sort, then every stream row
-whose run of equal keys holds a build row), the matching stream rows
-emitted.  Only ``how="semi"``, for the same reason.
+Beside it the batch hash join: ``hash_join_blocks``.  The build side is
+collected whole, encoded once and kept on the device; the stream side is
+cut into probe blocks by row count, each probed against the build.  Two
+kinds:
+
+* ``how="semi"`` -- HashJoinExample.java's HashJoinProcessor (the hash side
+  read whole into a set, the stream side walked past it, a key written
+  whenever the set holds it), and a map join's filter (TPC-H Q3's orders
+  against the segment's customers, the rows' values kept): ops/device.py
+  ``join_probe``, the match's sort, then every stream row whose run of
+  equal keys holds a build row;
+* ``how="inner"`` with the build side's keys unique -- a key-foreign-key
+  join, the shuffle join of a fact table with the table its key points
+  into (Q3's lines against their orders): ops/device.py ``kfk_probe``, the
+  same sort and run-end carry, then the build row each stream row hit, so
+  that the build row's value is carried onto it (``join.carry``).  A
+  repeated build key raises: a many-to-many join, with its expansion of
+  pairs, is the query layer's row path (query/processors.py).
 """
 from __future__ import annotations
 
@@ -209,78 +219,138 @@ def _probe_blocks(batches: Iterable[KVBatch], block_rows: int,
         block += 1
 
 
+def _carried(stream: KVBatch, rows: np.ndarray, build: KVBatch,
+             build_rows: np.ndarray) -> KVBatch:
+    """The stream's `rows` with their keys, each value followed by the
+    value of the build row it hit (`build_rows`, one a row): the hits'
+    values gathered from each side, then one ragged gather that puts a
+    row's two pieces side by side.  Nothing the size of the block is
+    copied: a block has ~100 times the rows that hit."""
+    keys, key_offsets = gather_ragged(stream.key_bytes, stream.key_offsets,
+                                      rows)
+    s_vals, s_offsets = gather_ragged(stream.val_bytes, stream.val_offsets,
+                                      rows)
+    b_vals, b_offsets = gather_ragged(build.val_bytes, build.val_offsets,
+                                      build_rows)
+    n = len(rows)
+    perm = np.empty(2 * n, dtype=np.int64)          # stream_i, build_i
+    perm[0::2] = np.arange(n)
+    perm[1::2] = n + np.arange(n)
+    values, pair_offsets = gather_ragged(
+        np.concatenate([s_vals, b_vals]),
+        np.concatenate([s_offsets, b_offsets[1:] + s_offsets[-1]]), perm)
+    return KVBatch(keys, key_offsets, values, pair_offsets[0::2])
+
+
 def hash_join_blocks(build_batches: Iterable[KVBatch],
                      stream_batches: Iterable[KVBatch], how: str = "semi",
                      key_width: int = 16, engine: str = "auto",
                      device_min_records: int = DEVICE_SORT_MIN_RECORDS,
                      counters: Optional[TezCounters] = None,
-                     block_rows: int = PROBE_BLOCK_ROWS
-                     ) -> Iterator[KVBatch]:
+                     block_rows: int = PROBE_BLOCK_ROWS,
+                     values: bool = False) -> Iterator[KVBatch]:
     """Yield the stream rows whose key the build side holds -- every
-    occurrence, in arrival order -- as KVBatches of keys with zero-width
-    values, a probe block at a time.  `build_batches` is read whole first
-    (an unordered input's ``iter_batches()``), `stream_batches` as it
-    comes; keys may repeat on either side.
+    occurrence, in arrival order -- a probe block at a time.
+    `build_batches` is read whole first (an unordered input's
+    ``iter_batches()``), `stream_batches` as it comes.
 
-    A build side with a key beyond the edge's lane width, or with fewer
-    rows than the routing floor, is probed on the host engine, in lanes as
-    wide as the longest key; so is a block with a stream key beyond the
-    lanes.  An empty build side yields nothing and launches nothing."""
-    if how != "semi":
+    ``how="semi"``: keys may repeat on either side; the rows come as keys
+    with zero-width values, or with their own values where `values`.
+    ``how="inner"``: a key-foreign-key join -- the build side's keys are
+    unique (a repeated one raises ValueError), so each stream row hits at
+    most one build row, and its value comes out followed by that build
+    row's value (``join.carry``).
+
+    A launch whose rows, the block's and the build side's, fall under the
+    routing floor runs on the host engine, in lanes as wide as the longest
+    key; so does a build side with a key beyond the edge's lane width, and
+    a block with a stream key beyond them.  The build side goes up once a
+    joiner: at the build where it alone reaches the floor, else with the
+    first block that does, and every later block is probed beside it.  An
+    empty build side yields nothing and launches nothing."""
+    if how not in ("semi", "inner"):
         raise ValueError(f"hash_join_blocks does {how!r} not: the batch "
-                         f"operator is semi (query/processors.py has "
-                         f"inner, by row)")
+                         f"operator is semi or inner (key-foreign-key); "
+                         f"query/processors.py has the rest, by row")
+    inner = how == "inner"
     engine = resolve_engine(engine)
     width = ((key_width + 3) // 4) * 4      # the device's lanes: the edge's
+    stage = device.stage_kfk_build if inner else device.stage_join_build
     on_device, build_longest = None, 0
-    with tracing.span("join.build", cat="join") as span:
+    with tracing.span("join.build", cat="join", how=how) as span:
         parts = [b for b in build_batches if b.num_records]
         build = KVBatch.concat(parts) if parts else KVBatch.empty()
         n_build = build.num_records
         span.annotate(rows=n_build)
         if n_build:
             build_longest = int(np.diff(build.key_offsets).max())
-            if engine == "device" and build_longest <= key_width and \
-                    n_build >= device_min_records:
-                lanes, lens = encode_keys(build.key_bytes, build.key_offsets,
-                                          width)
-                on_device = device.stage_join_build(lanes, lens)
+        device_build = engine == "device" and n_build > 0 and \
+            build_longest <= key_width
+        if device_build and n_build >= device_min_records:
+            on_device = stage(*encode_keys(build.key_bytes, build.key_offsets,
+                                           width))
     host_build: dict = {}       # lane width -> the build side encoded at it
     probed = emitted = 0
-    for block in _probe_blocks(stream_batches, block_rows) if n_build else ():
+    for block in _probe_blocks(stream_batches, block_rows, how=how) \
+            if n_build else ():
         n = block.num_records
         probed += n
         longest = int(np.diff(block.key_offsets).max())
+        if on_device is None and device_build and \
+                n + n_build >= device_min_records:
+            on_device = stage(*encode_keys(build.key_bytes, build.key_offsets,
+                                           width))
         if on_device is not None and longest <= key_width:
             with tracing.span("join.match", cat="join", stage="encode",
-                              how="semi", rows=n, engine="device"):
+                              how=how, rows=n, engine="device"):
                 lanes, lens = encode_keys(block.key_bytes, block.key_offsets,
                                           width)
-            hits = device.join_probe(lanes, lens, on_device)
-            if counters is not None:
+            if inner:
+                hit = device.kfk_probe(lanes, lens, on_device)
+            else:
+                hits = device.join_probe(lanes, lens, on_device)
+            if counters is not None and inner:
+                counters.increment(TaskCounter.KFK_PROBE_ROWS, n + n_build)
+                counters.increment(TaskCounter.KFK_PROBE_STREAM_ROWS, n)
+                counters.increment(TaskCounter.KFK_PROBE_LAUNCHES)
+            elif counters is not None:
                 counters.increment(TaskCounter.JOIN_MATCH_ROWS, n + n_build)
                 counters.increment(TaskCounter.JOIN_MATCH_LAUNCHES)
         else:
             wide = ((max(longest, build_longest, 1) + 3) // 4) * 4
             with tracing.span("join.match", cat="join", stage="encode",
-                              how="semi", rows=n, engine="host"):
+                              how=how, rows=n, engine="host"):
                 if wide not in host_build:
                     host_build[wide] = encode_keys(
                         build.key_bytes, build.key_offsets, wide)
                 sides = encode_keys(block.key_bytes, block.key_offsets,
                                     wide) + host_build[wide]
             with tracing.span("join.match", cat="join", stage="host",
-                              how="semi"):
-                hits = device.join_probe_host(*sides)
+                              how=how):
+                if inner:
+                    hit = device.kfk_probe_host(*sides)
+                else:
+                    hits = device.join_probe_host(*sides)
+        if inner:
+            hits = np.flatnonzero(hit >= 0)
         if len(hits):
-            with tracing.span("join.emit", cat="join", rows=len(hits)):
-                out = _key_batch(block, hits)
+            if inner:
+                with tracing.span("join.carry", cat="join",
+                                  rows=len(hits)) as span:
+                    out = _carried(block, hits, build, hit[hits])
+                    span.annotate(value_bytes=int(out.val_offsets[-1]))
+            else:
+                with tracing.span("join.emit", cat="join", rows=len(hits)):
+                    out = block.take(hits) if values \
+                        else _key_batch(block, hits)
             emitted += len(hits)
             yield out
     if counters is not None:
         counters.increment(TaskCounter.JOIN_LEFT_RECORDS, probed)
         counters.increment(TaskCounter.JOIN_RIGHT_RECORDS, n_build)
         counters.increment(TaskCounter.JOIN_OUTPUT_RECORDS, emitted)
+        if inner:
+            counters.increment(TaskCounter.KFK_OUTPUT_ROWS, emitted)
 
 
 def open_sorted_inputs(left_input: Any, right_input: Any

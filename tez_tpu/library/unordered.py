@@ -127,16 +127,20 @@ class UnorderedPartitionedWriter:
             self._partition_span()
             return None
         self._partition_span()
-        if not self._runs:
+        # the runs leave the writer with this call: the shuffle service
+        # holds what it serves, and the writer, in its task's reference
+        # cycles, would hold them until a full collection
+        runs, self._runs = self._runs, []
+        if not runs:
             return Run(KVBatch.empty(),
                        np.zeros(self.num_partitions + 1, dtype=np.int64))
-        if len(self._runs) == 1:
-            return self._runs[0]
+        if len(runs) == 1:
+            return runs[0]
         # final "merge": per-partition concatenation (no ordering contract)
         parts: List[KVBatch] = []
         counts = np.zeros(self.num_partitions, dtype=np.int64)
         for p in range(self.num_partitions):
-            for r in self._runs:
+            for r in runs:
                 pb = r.partition(p)
                 if pb.num_records:
                     parts.append(pb)

@@ -877,18 +877,17 @@ def join_match_host(left_lanes: np.ndarray, left_lens: np.ndarray,
 # row" -- no hash table and no binary search: the same stable passes as the
 # merges and the merge-join's match
 # ---------------------------------------------------------------------------
-def _probe_hits_after_sort(xp, cummax_from_end, perm, s_lanes, s_lens,
-                           n_stream: int):
+def _run_end_carry(xp, cummax_from_end, perm, s_lanes, s_lens,
+                   n_stream: int):
     """`perm` orders the concatenation [stream rows, build rows] by (lanes,
     length), stably, and `s_lanes`, `s_lens` are the key columns in that
     order: inside a run of equal keys the stream rows stand first, the
-    build rows last.  A run holds a build row exactly when its
-    last row is one, and every stream row of such a run is a hit: the run's
-    last row carries (its distance from the end) * 2 + (is it a build row),
-    and a running maximum from the end hands the nearest run end's value to
-    every row before it.  Returns i32[B]: the stream row at each sorted
-    place that is a hit, -1 elsewhere (sentinel rows, length < 0, never
-    match)."""
+    build rows last, so a run holds a build row exactly when its last row
+    is one.  The run's last row carries (its distance from the end) * 2 +
+    (is it a build row), and a running maximum from the end hands the
+    nearest run end's value to every row before it.  Returns (is_stream,
+    held): a stream row at each sorted place, its run ends in a build
+    row."""
     n = perm.shape[0]
     same_as_next = (s_lens[:-1] == s_lens[1:]) & \
         (s_lanes[:-1] == s_lanes[1:]).all(axis=1)
@@ -897,7 +896,17 @@ def _probe_hits_after_sort(xp, cummax_from_end, perm, s_lanes, s_lens,
     from_end = xp.arange(n - 1, -1, -1, dtype=xp.int32)
     carried = cummax_from_end(xp.where(
         run_end, from_end * 2 + (~is_stream).astype(xp.int32), 0))
-    held = (carried & 1) == 1
+    return is_stream, (carried & 1) == 1
+
+
+def _probe_hits_after_sort(xp, cummax_from_end, perm, s_lanes, s_lens,
+                           n_stream: int):
+    """Every stream row whose run holds a build row is a hit
+    (`_run_end_carry`).  Returns i32[B]: the stream row at each sorted
+    place that is a hit, -1 elsewhere (sentinel rows, length < 0, never
+    match)."""
+    is_stream, held = _run_end_carry(xp, cummax_from_end, perm, s_lanes,
+                                     s_lens, n_stream)
     return xp.where(is_stream & held & (s_lens >= 0), perm, -1)
 
 
@@ -957,6 +966,117 @@ def join_probe_host(stream_lanes: np.ndarray, stream_lens: np.ndarray,
         np, lambda v: np.maximum.accumulate(v[::-1])[::-1], perm, s_lanes,
         s_lens, len(stream_lens))
     return np.sort(hits[hits >= 0]).astype(np.int64)
+
+
+# ---------------------------------------------------------------------------
+# key-foreign-key probe = the hash-join probe's sort and run-end carry, then
+# the build row each stream row hit: no gather and no search, one more sort
+# to put the answers back in arrival order
+# ---------------------------------------------------------------------------
+def _kfk_ranks_after_sort(xp, cummax_from_end, perm, s_lanes, s_lens,
+                          n_stream: int):
+    """With the build side's keys unique a run holds at most one build row,
+    its last (`_run_end_carry`), so the build rows standing before a stream
+    row are those of earlier runs: their count is the key rank of the build
+    row that ends its run (its place among the build side's keys in key
+    order).  Returns i32[B]: that rank at the sorted place of every stream
+    row that hits, -1 elsewhere (sentinel rows, length < 0, never match)."""
+    is_stream, held = _run_end_carry(xp, cummax_from_end, perm, s_lanes,
+                                     s_lens, n_stream)
+    live = s_lens >= 0
+    is_build = (~is_stream & live).astype(xp.int32)
+    before = xp.cumsum(is_build, dtype=xp.int32) - is_build
+    return xp.where(is_stream & held & live, before, -1)
+
+
+def _kfk_probe_impl(stream_lanes, stream_lens, build_lanes, build_lens):
+    """Key-foreign-key probe of one block of stream rows against a build
+    side of unique keys, each padded to its bucket with sentinels: the
+    semi probe's sort, the key rank of the build row each stream row hit
+    (`_kfk_ranks_after_sort`), and a sort by the permutation that carries
+    the ranks back to arrival order.  Returns i32[stream bucket]: a stream
+    row's build key rank, -1 where its key is not on the build side."""
+    n_stream = stream_lanes.shape[0]
+    perm, s_lanes, s_lens = _sort_two_sides(stream_lanes, stream_lens,
+                                            build_lanes, build_lens)
+    ranks = _kfk_ranks_after_sort(
+        jnp, lambda v: jax.lax.cummax(v, axis=0, reverse=True), perm,
+        s_lanes, s_lens, n_stream)
+    _perm, by_row = jax.lax.sort((perm, ranks), dimension=0,
+                                 is_stable=False, num_keys=1)
+    return by_row[:n_stream]
+
+
+_kfk_probe = Kernel(
+    _kfk_probe_impl, "kfk_probe",
+    launch_rows=lambda sl, _sn, bl, _bn: int(sl.shape[0] + bl.shape[0]))
+
+
+def _build_key_order(build_lanes: np.ndarray, build_lens: np.ndarray
+                     ) -> np.ndarray:
+    """The build side's rows in key order (lanes, then length: the
+    device sort's), or ValueError where two rows hold one key: a
+    key-foreign-key join's build keys are unique."""
+    order = np.lexsort((build_lens,) + tuple(
+        build_lanes[:, i] for i in range(build_lanes.shape[1] - 1, -1, -1)))
+    s_lanes, s_lens = build_lanes[order], build_lens[order]
+    same = (s_lens[1:] == s_lens[:-1]) & \
+        (s_lanes[1:] == s_lanes[:-1]).all(axis=1)
+    if same.any():
+        at = int(order[int(np.argmax(same))])
+        raise ValueError(f"key-foreign-key join: build row {at} repeats a "
+                         f"key another build row holds; the build side's "
+                         f"keys have to be unique")
+    return order.astype(np.int64)
+
+
+def stage_kfk_build(build_lanes: np.ndarray, build_lens: np.ndarray):
+    """The build side of a key-foreign-key join checked for unique keys,
+    padded to its bucket and uploaded, with its rows in key order: what a
+    joiner keeps across its probes (``kfk_probe``'s `build`)."""
+    with tracing.span("join.match", cat="join", stage="stage", how="inner",
+                      rows=len(build_lens)):
+        order = _build_key_order(build_lanes, build_lens)
+        return _upload_padded(build_lanes, build_lens) + (order,)
+
+
+def kfk_probe(stream_lanes: np.ndarray, stream_lens: np.ndarray,
+              build: tuple) -> np.ndarray:
+    """The build row each row of one stream block hit, -1 where its key is
+    not on the build side: i64[n], in arrival order.  `build` is
+    ``stage_kfk_build``'s; lanes of the block's width hold whole keys, and
+    stream keys may repeat.  The program's compile key is (block bucket,
+    build bucket, L); one i32 a stream row comes back."""
+    n = len(stream_lens)
+    with tracing.span("join.match", cat="join", stage="stage", how="inner",
+                      rows=n):
+        operands = _upload_padded(stream_lanes, stream_lens)
+    with tracing.span("join.match", cat="join", stage="launch", how="inner"):
+        ranks_dev = _kfk_probe(*operands, *build[:2])
+    # the host blocks here for the device, as in merge.readback
+    with tracing.span("join.match", cat="join", stage="readback",
+                      how="inner"):
+        ranks = np.asarray(ranks_dev)[:n]
+        return np.where(ranks >= 0, build[2][ranks], -1)
+
+
+def kfk_probe_host(stream_lanes: np.ndarray, stream_lens: np.ndarray,
+                   build_lanes: np.ndarray, build_lens: np.ndarray
+                   ) -> np.ndarray:
+    """kfk_probe on the host engine, unpadded: numpy's stable lexsort in
+    the sort's place, the same ranks, put back in arrival order by
+    indexing."""
+    order = _build_key_order(build_lanes, build_lens)
+    n = len(stream_lens)
+    perm, s_lanes, s_lens = _lexsort_two_sides(stream_lanes, stream_lens,
+                                               build_lanes, build_lens)
+    ranks = _kfk_ranks_after_sort(
+        np, lambda v: np.maximum.accumulate(v[::-1])[::-1], perm, s_lanes,
+        s_lens, n)
+    by_row = np.full(n, -1, dtype=np.int64)
+    stream = perm < n
+    by_row[perm[stream]] = ranks[stream]
+    return np.where(by_row >= 0, order[by_row], -1)
 
 
 # ---------------------------------------------------------------------------

@@ -65,6 +65,15 @@ agg_a_dag    the group-by-sum's counters a DAG, beside ``programs``:
              and the table's rows a fold, unpadded), ``AGG_INPUT_ROWS``
              (rows folded on the device) and ``AGG_GROUPS`` (rows of the
              final tables); zeros where no task aggregated
+scan_a_dag   the typed columnar scans a DAG, by table: rows and bytes the
+             ``scan.read`` spans read, rows the ``scan.filter`` spans kept
+             (a filter is its thread's last read's); empty where no task
+             scanned columns
+kfk_a_dag    the key-foreign-key join's counters a DAG: ``KFK_PROBE_LAUNCHES``
+             (device probes), ``KFK_PROBE_ROWS`` (a block's and the build's
+             rows a launch, unpadded), ``KFK_PROBE_STREAM_ROWS``,
+             ``KFK_OUTPUT_ROWS`` (the hits), and ``carried_rows``: the rows
+             the ``join.carry`` spans gave a build row's value
 group_rows_a_dag  the rows the window's ``input.group`` spans grouped, a
              DAG, by the path their ``width`` argument names: ``fixed`` (the
              one width of every key of the block, compared a word at a
@@ -222,6 +231,36 @@ def agg_counters(dags) -> dict:
     return {k: totals[k] / max(1, len(dags)) for k in AGG_COUNTERS}
 
 
+KFK_COUNTERS = ("KFK_PROBE_LAUNCHES", "KFK_PROBE_ROWS",
+                "KFK_PROBE_STREAM_ROWS", "KFK_OUTPUT_ROWS")
+
+
+def kfk_counters(dags) -> dict:
+    """``kfk_a_dag`` less ``carried_rows``: the join's counters a DAG."""
+    totals = collections.Counter()
+    for dag in dags:
+        for counters in dag["counters"].values():
+            totals.update({k: counters.get(k, 0) for k in KFK_COUNTERS})
+    return {k: totals[k] / max(1, len(dags)) for k in KFK_COUNTERS}
+
+
+def scan_tables(spans, dags) -> dict:
+    """``scan_a_dag``: rows read and kept and bytes read a DAG, by table."""
+    out = collections.defaultdict(collections.Counter)
+    last_read = {}
+    for s in sorted(spans, key=lambda s: s.start):
+        if s.name == "scan.read":
+            table = s.args.get("table", "")
+            out[table]["rows_read"] += s.args.get("rows", 0)
+            out[table]["bytes_read"] += s.args.get("bytes", 0)
+            last_read[s.thread] = table
+        elif s.name == "scan.filter":
+            out[last_read.get(s.thread, "")]["rows_kept"] += \
+                s.args.get("rows_out", 0)
+    return {table: {k: v / dags for k, v in sorted(c.items())}
+            for table, c in sorted(out.items())}
+
+
 def path_table(obs) -> dict:
     """``critical_path_s_a_dag``: the window's walk, a period."""
     import path_metrics
@@ -302,6 +341,7 @@ def main() -> int:
         found["event_delivery_a_dag"] = event_delivery(dags)
         found["critical_path_s_a_dag"] = path_table(res["obs"])
         found["agg_a_dag"] = agg_counters(dags)
+        found["kfk_a_dag"] = kfk_counters(dags)
         return result_line(args, spec, devices, res)
 
     trace_reduce.reduce_trace = reduce_and_keep
@@ -345,6 +385,8 @@ def main() -> int:
         row[0] for row in window if row[0].startswith("kernel.")
         and row[0] != "kernel.compile")
     args = dict(zip(sys.argv[1::2], sys.argv[2::2]))
+    in_window = [s for s in spans
+                 if marks["start"] <= s.start <= marks["stop"]]
     summary = {
         "workload": args.get("--workload"), "seed": args.get("--seed"),
         "spans": len(spans), "dropped": tracing.dropped(),
@@ -358,12 +400,14 @@ def main() -> int:
                          for k, v in self_s.most_common()},
         "exchange_self_s_a_dag": {k: round(v / dags, 4)
                                   for k, v in by_site.most_common()},
-        "group_rows_a_dag": group_rows(
-            [s for s in spans if marks["start"] <= s.start <= marks["stop"]],
-            dags),
+        "group_rows_a_dag": group_rows(in_window, dags),
         "programs": program_table(found.pop("program_device_s"),
                                   kernel_spans, kernel_programs(), dags),
         "agg_a_dag": found.pop("agg_a_dag", {}),
+        "scan_a_dag": scan_tables(in_window, dags),
+        "kfk_a_dag": {**found.pop("kfk_a_dag", {}), "carried_rows": sum(
+            s.args.get("rows", 0) for s in in_window
+            if s.name == "join.carry") / dags},
         "compiled": [{"kernel": name, "signature": sig,
                       "seconds": round(secs, 2), "sort_ops": sort_ops}
                      for name, sig, secs, sort_ops, _t in
